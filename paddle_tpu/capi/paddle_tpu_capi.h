@@ -32,7 +32,8 @@ typedef enum {
 
 typedef void* pd_tpu_model;
 
-/* Initialize the embedded runtime (Py_Initialize + jax on CPU).
+/* Initialize the embedded runtime (Py_Initialize; jax runs on the
+ * platform the environment selects, JAX_PLATFORMS).
  * Mirrors paddle_init(argc, argv). Safe to call once per process. */
 pd_tpu_error pd_tpu_init(void);
 

@@ -84,11 +84,13 @@ DEFINE_flag("benchmark", False,
             "(executor.cc:321-324)")
 DEFINE_flag("kernel_tier", "auto",
             "which lowering tier the hot-op dispatch sites use: 'auto' "
-            "(Pallas on TPU for the kernels measured to win — see "
-            "ops/pallas.AUTO_PALLAS — jnp elsewhere, so CPU suites never "
-            "pay interpret-mode kernels), 'pallas' (Pallas everywhere it "
+            "(Pallas on TPU for the families in ops/pallas.AUTO_PALLAS — "
+            "today only lstm; membership needs an on-chip observation, "
+            "see there — jnp elsewhere, so CPU suites never pay "
+            "interpret-mode kernels), 'pallas' (Pallas everywhere it "
             "has a lowering; interpret mode on CPU — the parity-test "
-            "setting), or 'jnp' (the plain jax.numpy lowerings, bitwise "
+            "setting; on a TPU a kernel Mosaic cannot compile raises), "
+            "or 'jnp' (the plain jax.numpy lowerings, bitwise "
             "the pre-tier behavior). Per-kernel fallback: an unsupported "
             "shape under a Pallas tier routes to the jnp twin silently "
             "and bumps ops.pallas.fallback_counts()")
@@ -99,13 +101,13 @@ DEFINE_flag("use_pallas_rnn", False,
             "use the Pallas whole-recurrence kernels (the hand-scheduled "
             "hl_cuda_lstm.cu analogs): LSTM and GRU each run their WHOLE "
             "sequence as one kernel with the recurrent weight VMEM-"
-            "resident across steps — measured on the v5e training lanes "
-            "(round 5): LSTM 1.22x (5.91 vs 7.21 ms/batch); GRU ranges "
-            "0.98-1.08x across sessions on the shared chip (the reset-"
-            "gated candidate forces two dependent matmuls per step, so "
-            "the VMEM-residency win is thinner). Default off so CPU test "
-            "runs avoid interpret-mode kernels; bench.py measures both "
-            "paths and reports the winner")
+            "resident across steps. On a TPU v5 lite (PR 21, "
+            "tools/kernel_probe.py, bs64 len100 hid512): LSTM "
+            "recurrence 1.22x and the LSTM-lane train step a tie "
+            "(3.386 vs 3.389 ms); GRU recurrence 1.61x, GRU train step "
+            "not measured. Default off so CPU test runs avoid "
+            "interpret-mode kernels; bench.py measures both paths and "
+            "reports the winner")
 DEFINE_flag("xla_compiler_options", "",
             "comma-separated k=v TPU compiler options forwarded to "
             "jit(compiler_options=...), e.g. "
@@ -133,10 +135,11 @@ DEFINE_flag("conv_space_to_depth", False,
 DEFINE_flag("bn_fusion_barrier", False,
             "A/B probe (default off): optimization barrier between a conv "
             "output and batch_norm's statistics reductions so XLA cannot "
-            "fuse the reduces INTO the conv kernel. MEASURED 13% WORSE on "
-            "the v5e ResNet-50 bench (2216 vs 2545 img/s, bench.py round-4 "
-            "notes) — the conv+stats fusion XLA picks is net positive; the "
-            "flag remains for future-hardware A/B runs only. The op checks "
+            "fuse the reduces INTO the conv kernel. 13% worse on the "
+            "ResNet-50 step when last measured (2026-07-30, on an earlier "
+            "revision; not measured on today's code) — the conv+stats "
+            "fusion XLA picks was net positive; the flag remains for A/B "
+            "runs only. The op checks "
             "OR this flag together with the one-sided flags below (this "
             "flag does not write them; read all three to know the state)")
 

@@ -141,6 +141,20 @@ class ChildSupervisor:
       forked child would inherit the parent's already-initialized XLA
       runtime in an unusable state).
 
+    Fork and JAX: ``fork`` is the default start method only because the
+    pserver child is numpy-only. A forked child of a process that has
+    initialised a JAX backend inherits a dead runtime, and on a TPU host
+    the parent that touched JAX already holds the chip. So nothing that
+    needs a device may be forked: device-using children are SPAWNED from
+    a parent that stays off JAX (``FleetSupervisor``), and the main
+    training path (``chip_smoke.py``: Program -> minimize ->
+    Executor.run) starts no child process at all.
+
+    A child that exits with a code listed in :attr:`FATAL_EXIT_CODES` is
+    NOT restarted: the code says a restart cannot help (a replica that
+    could not get its accelerator). The reason is printed at once, kept in
+    ``child_stats()``, and raised by :meth:`wait_ready`.
+
     ``startup_grace_s`` suppresses heartbeat-miss COUNTING for that long
     after each (re)spawn — a spawned replica pays a full interpreter +
     framework import plus model warmup before it binds, and terminating it
@@ -149,6 +163,9 @@ class ChildSupervisor:
     (liveness is checked regardless); the default 0.0 preserves the
     pserver supervisor's original timing exactly.
     """
+
+    # exit code -> why a restart cannot help (subclasses extend)
+    FATAL_EXIT_CODES: dict = {}
 
     def __init__(self, n_children, heartbeat_method="stats",
                  heartbeat_interval_s=0.25, heartbeat_timeout_s=None,
@@ -186,6 +203,9 @@ class ChildSupervisor:
         # "wedged: no heartbeat") — a dead child with no reason is
         # undebuggable in a fleet; surfaced via child_stats()
         self.last_restart_reason = [None] * n_children
+        # indices of children that died of a FATAL_EXIT_CODES code (never
+        # restarted; wait_ready raises their last_restart_reason)
+        self._fatal = set()
         self._max_restarts = int(max_restarts)
         self._hb_method = str(heartbeat_method)
         self._interval = float(heartbeat_interval_s)
@@ -285,6 +305,7 @@ class ChildSupervisor:
                                    "to retire")
             p = self._procs[i]
             self._procs[i] = None    # monitor skips None from here on
+            self._fatal.discard(i)
             address = tuple(self.addresses[i])
         with self._hb_lock:
             c = self._hb_clients[i]
@@ -368,12 +389,24 @@ class ChildSupervisor:
             return False
         reason = "wedged: no heartbeat" if wedged \
             else f"exited code {p.exitcode}"
+        fatal = None if wedged else self.FATAL_EXIT_CODES.get(p.exitcode)
+        if fatal is not None:
+            reason = f"{reason}: {fatal}"
         self.last_restart_reason[i] = reason
         print(f"[{self.obs_instance}] child {i} "
               f"{self.addresses[i]} {reason}", file=sys.stderr,
               flush=True)
         if self._stop.is_set():
             return True
+        if fatal is not None:
+            # a restart cannot help: give the child up NOW and let
+            # wait_ready raise the reason instead of timing out
+            self._fatal.add(i)
+            self._procs[i] = None
+            _flight_record("child_fatal", component=self.obs_instance,
+                           child=i, address=tuple(self.addresses[i]),
+                           reason=reason)
+            return False
         if self.restarts[i] >= self._max_restarts:
             self._procs[i] = None  # crash-looping: give the child up
             return False
@@ -454,7 +487,9 @@ class ChildSupervisor:
 
     def wait_ready(self, timeout=10.0):
         """Block until every live child answers an RPC — the post-start
-        (or post-restart) barrier callers want before sending work."""
+        (or post-restart) barrier callers want before sending work.
+        Raises RuntimeError as soon as a child has died of a
+        :attr:`FATAL_EXIT_CODES` code (waiting longer cannot help)."""
         deadline = time.monotonic() + timeout
         for i in range(len(self.addresses)):
             try:
@@ -465,6 +500,11 @@ class ChildSupervisor:
                     time.sleep(0.05)
             except IndexError:
                 break    # the fleet shrank mid-wait (retire_child)
+            if i in self._fatal:
+                raise RuntimeError(
+                    f"{self.obs_instance} child {i} "
+                    f"{tuple(self.addresses[i])} "
+                    f"{self.last_restart_reason[i]}")
         return True
 
     def stop(self):

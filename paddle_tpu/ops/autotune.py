@@ -91,6 +91,13 @@ _M_SELECTED = _METRICS.gauge(
     "— zero everywhere when no table is attached",
     labels=("kernel", "variant"))
 
+_M_VARIANT_FAILURES = _METRICS.counter(
+    "paddle_tpu_kernel_autotune_variant_failures",
+    "tuner candidates dropped because building or first running the "
+    "variant raised (on a chip: a kernel that cannot compile) — the "
+    "exception text rides a kernel_autotune_variant_failed flight event",
+    labels=("kernel", "variant"))
+
 _LOCK = threading.RLock()
 _ACTIVE = None              # the attached TuneTable (process-wide)
 _FORCED = {}                # kernel -> forced variant (tuner/tests)
@@ -242,18 +249,34 @@ def dispatch_variant(kernel, key, supported, tier_kernel=None):
         else "jnp"
 
 
+def note_variant_failure(kernel, variant, stage, error):
+    """Count and record a tuner candidate that raised (``stage`` is
+    "build" or "warmup"): a counter bump plus a flight event carrying the
+    exception text — a variant that cannot compile must not lose
+    silently."""
+    from ..obs.recorder import record as _flight_record
+
+    _M_VARIANT_FAILURES.labels(kernel=kernel, variant=variant).inc()
+    _flight_record("kernel_autotune_variant_failed",
+                   component="ops.autotune", kernel=kernel, variant=variant,
+                   stage=stage,
+                   error=f"{type(error).__name__}: {error}"[:2000])
+
+
 # ---------------------------------------------------------------------------
 # measurement core — THE interleaved best-of-N implementation
 # ---------------------------------------------------------------------------
 
-def measure(runners, repeats=3, inner=2):
+def measure(runners, repeats=3, inner=2, kernel="?"):
     """Time each runner: ``repeats`` interleaved windows of ``inner``
     calls each, best window kept — the bench.py best-of-N discipline,
     interleaved across variants so drift (thermal, a noisy neighbor)
     hits every variant equally instead of biasing whichever ran last.
     One untimed warmup call per runner absorbs trace+compile. Returns
     ``{name: best ms/call}``; a runner that raises during warmup is
-    dropped (a variant that cannot run cannot win)."""
+    dropped (a variant that cannot run cannot win) — counted and recorded
+    with its exception text under ``kernel`` (:func:`note_variant_failure`),
+    never silently."""
     import jax
 
     def block(out):
@@ -265,7 +288,8 @@ def measure(runners, repeats=3, inner=2):
     for name in sorted(runners):
         try:
             block(runners[name]())
-        except Exception:
+        except Exception as e:
+            note_variant_failure(kernel, name, "warmup", e)
             continue
         order.append(name)
     best = {}
@@ -638,13 +662,14 @@ class Tuner:
                 for n in cands:
                     try:
                         r = specs[n].build(key)
-                    except Exception:
+                    except Exception as e:
+                        note_variant_failure(kernel, n, "build", e)
                         r = None
                     if r is not None:
                         runners[n] = r
                 if len(runners) > 1:
                     timings = measure(runners, repeats=self.repeats,
-                                      inner=self.inner)
+                                      inner=self.inner, kernel=kernel)
                 if timings:
                     winner = min(timings, key=timings.get)
                 elif "jnp" in cands:
@@ -882,5 +907,6 @@ __all__ = [
     "active_table", "attach_for_bundle", "attach_table", "capture",
     "detach_table", "dispatch_variant", "fingerprint_key",
     "force_variant", "key_str", "make_key", "manifest_tune_digests",
-    "measure", "resolve_store", "table_fingerprint", "variant_allowed",
+    "measure", "note_variant_failure", "resolve_store", "table_fingerprint",
+    "variant_allowed",
 ]

@@ -10,11 +10,14 @@ selection, per-kernel fallback, and profiler attribution live in ONE place.
 
 Tier selection (the ``kernel_tier`` flag):
 
-* ``auto`` (default) — Pallas on TPU for the kernels measured to win
-  (:data:`AUTO_PALLAS`), jnp everywhere else (CPU suites never pay
-  interpret-mode kernels unless they opt in).
+* ``auto`` (default) — Pallas on TPU for the families in
+  :data:`AUTO_PALLAS` (admitted by an on-chip observation, see there), jnp
+  everywhere else (CPU suites never pay interpret-mode kernels unless they
+  opt in).
 * ``pallas`` — Pallas for every kernel with a lowering (interpret mode on
-  CPU: this is what the parity tests run).
+  CPU: this is what the parity tests run). On a TPU a kernel that Mosaic
+  cannot compile raises its compile error: nothing between
+  :func:`use_pallas` and ``pallas_call`` catches it.
 * ``jnp`` — the plain jax.numpy lowerings, bitwise-identical to the
   pre-tier behavior.
 
@@ -40,12 +43,25 @@ from ...core.flags import get_flag
 from ...core.profiler import record_event
 from ...obs.metrics import REGISTRY as _METRICS
 
-# kernels that default to Pallas under kernel_tier=auto on TPU — the
-# measured-to-win set (lstm 1.22x on v5e; gru measured 0.98-1.08x across
-# sessions so it stays opt-in via kernel_tier=pallas)
-AUTO_PALLAS = frozenset({
-    "lstm", "ctc", "conv_bn", "optimizer", "embedding_sgd",
-})
+# Families that default to Pallas under kernel_tier=auto on a TPU. A family
+# is admitted only by an on-chip observation (tools/kernel_probe.py) at the
+# shapes the repo runs: it lowered natively, matched its jnp twin, and its
+# step was not slower than the twin's in a same-process A/B. Taken on a
+# TPU v5 lite, jax 0.9.0 (PR 21; full lines in CHANGES.md / PERF.md):
+#   lstm          IN   bitwise equal to the scan twin; recurrence 0.458 vs
+#                      0.558 ms (1.22x), LSTM-lane train step 3.386 vs
+#                      3.389 ms (a tie, spread +-0.6%)
+#   conv_bn       out  lowers, but 0.2-0.65x of XLA's conv+BN fusions at
+#                      6 of 7 ResNet-50 shapes; fused flagship step 318.6
+#                      vs 102.5 ms unfused
+#   optimizer     out  arena momentum step 68.4 vs 26.0 ms (0.38x): the
+#                      per-step concat/split costs more than it saves
+#   ctc           out  does not lower: (1, S) block of a [b, S] array
+#   embedding_sgd out  does not lower: (1, D) block of a [R, D] array
+# Not in the set and not admitted by this rule yet: gru (recurrence 1.61x,
+# step not measured), paged_attention (does not lower under this jax).
+# Every family stays reachable with kernel_tier=pallas.
+AUTO_PALLAS = frozenset({"lstm"})
 
 # kernel family -> the deprecated flag that used to gate it
 _LEGACY_FLAGS = {
@@ -62,6 +78,15 @@ _M_FALLBACKS = _METRICS.counter(
     "paddle_tpu_pallas_fallbacks",
     "unsupported shapes routed pallas->jnp silently, per kernel family",
     labels=("kernel",))
+
+# Pallas dispatches by how they lowered: "native" (Mosaic, on a TPU) or
+# "interpret" (the CPU interpreter) — what chip_smoke.py reads to prove
+# that no kernel on the chip ran interpreted
+_M_DISPATCHES = _METRICS.counter(
+    "paddle_tpu_pallas_dispatches",
+    "Pallas kernel dispatches (counted at trace time, once per retrace), "
+    "per kernel family and lowering mode (native|interpret)",
+    labels=("kernel", "mode"))
 
 
 def _legacy_forced(kernel):
@@ -154,12 +179,31 @@ def reset_fallback_counts():
     _M_FALLBACKS.reset()
 
 
+def dispatch_counts():
+    """{kernel: {"native": n, "interpret": m}} — Pallas dispatches since
+    process start by lowering mode, derived from the
+    ``paddle_tpu_pallas_dispatches`` registry counter; families that
+    never dispatched a Pallas kernel are omitted."""
+    out = {}
+    for (kernel, mode), child in _M_DISPATCHES.children().items():
+        n = int(child.value)
+        if n:
+            out.setdefault(kernel, {"native": 0, "interpret": 0})[mode] = n
+    return out
+
+
 @contextmanager
 def kernel_span(tier, kernel):
     """Profiler span around one kernel dispatch: chrome traces show
     ``pallas/<kernel>`` vs ``jnp/<kernel>`` (kind="kernel") so tier time is
     attributable per op. Host spans: real time in eager mode, trace-time
-    under jit (the repo's standard record_event semantics)."""
+    under jit (the repo's standard record_event semantics). Every
+    non-jnp span is also one counted Pallas dispatch
+    (:func:`dispatch_counts`)."""
+    if tier != "jnp":
+        _M_DISPATCHES.labels(
+            kernel=kernel,
+            mode="interpret" if on_cpu() else "native").inc()
     with record_event(f"{tier}/{kernel}", kind="kernel"):
         yield
 
@@ -170,5 +214,6 @@ def kernel_span(tier, kernel):
 
 __all__ = [
     "AUTO_PALLAS", "resolve_tier", "use_pallas", "record_fallback",
-    "fallback_counts", "reset_fallback_counts", "kernel_span",
+    "fallback_counts", "reset_fallback_counts", "dispatch_counts",
+    "kernel_span",
 ]

@@ -1,10 +1,13 @@
 """Fused conv+bn(+relu) Pallas kernels for the ResNet block shapes.
 
-The flagship profile (bench.py roofline notes) shows the step HBM-bound
-through the conv→batch_norm→relu chains: XLA materializes the conv output
-to HBM, re-reads it for the statistics reduce, and re-reads the normalized
-activation for the elementwise tail. These kernels keep the activation
-VMEM-resident through the whole epilogue instead:
+The idea: keep the conv output VMEM-resident through batch statistics,
+normalize and activation instead of round-tripping it through HBM. On the
+chip it does not pay yet (TPU v5 lite, PR 21, tools/kernel_probe.py): the
+kernels lower natively and match their twins, but run at 0.2-0.65x of
+XLA's own conv+BN fusions at six of seven ResNet-50 shapes (1x1 64->256
+at 56x56 is the exception, 1.5x fwd / 1.36x bwd), and the fused flagship
+step takes 318.6 ms against 102.5 ms unfused. So ``conv_bn`` is NOT in
+``AUTO_PALLAS``; ``kernel_tier=pallas`` still reaches it. The kernels:
 
 * **forward (training)** — ONE kernel, grid ``(2, N)`` over a sequential
   TPU grid: pass 0 computes each image's conv block in VMEM and
@@ -12,8 +15,7 @@ VMEM-resident through the whole epilogue instead:
   HBM); at the pass boundary the batch mean/var and folded scale/shift
   land in scratch; pass 1 recomputes the conv and writes only the final
   normalized+activated y. The conv runs twice (trading MXU flops for HBM
-  round trips — the right trade for the HBM-bound 1x1/small-C shapes, see
-  ``supported()``), but the [N,H,W,C] intermediate never round-trips.
+  round trips), but the [N,H,W,C] intermediate never round-trips.
 * **forward (inference)** — single pass: conv + precomputed scale/shift
   (+relu), the classic folded-BN serving epilogue.
 * **backward (training)** — same two-pass shape: pass 0 recomputes the
@@ -45,13 +47,55 @@ from jax.experimental import pallas as pl
 from . import on_cpu as _on_cpu
 
 
-# conservative per-core VMEM budget for one program's working set (the
-# hardware has ~16 MB; pallas double-buffers the streamed blocks)
-_VMEM_BUDGET = 10 * 1024 * 1024
+# The scoped-VMEM limit every kernel here asks of Mosaic
+# (``vmem_limit_bytes``) and the budget ``supported()`` admits shapes
+# against — one number, so the predicate and the compiler agree. The
+# compiler's default scoped limit (16 MiB on a v5e) is far below the
+# chip's VMEM (128 MiB on a v5e), and at ResNet-50 shapes the backward
+# kernel's working set exceeds it (the 56x56 64->64 3x3 backward ran out
+# of VMEM on the chip under the default). 64 MiB is half a v5e's VMEM.
+_VMEM_LIMIT = 64 * 1024 * 1024
+
+
+def _compiler_params():
+    from jax.experimental.pallas import tpu as pltpu
+    return pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT)
 
 
 def _itemsize(dtype):
     return jnp.dtype(dtype).itemsize
+
+
+def _tile_bytes(rows, cols, itemsize):
+    """VMEM bytes of a [rows, cols] slab after tile padding: lanes pad to
+    128, sublanes to 8 (f32) / 16 (bf16)."""
+    sub = 32 // itemsize
+    return (-(-rows // sub) * sub) * (-(-cols // 128) * 128) * itemsize
+
+
+def _vmem_need(bn, hp, wp, ho, wo, cin, cout, taps, it, backward):
+    """Conservative working set of one grid step: streamed blocks double
+    buffered, scratch, the f32 temporaries of the epilogue, and — for
+    3x3 — the shifted tap slices (sublane-unaligned slices materialize
+    as copies). Measured against the chip it over-counts by ~2x (the
+    compiler fuses some temporaries), which only costs fallbacks."""
+    rows = ho * wo
+    x_b = bn * hp * _tile_bytes(wp, cin, it)
+    y_b = bn * ho * _tile_bytes(wo, cout, it)
+    wt_b = taps * _tile_bytes(cin, cout, it)
+    slices = taps * _tile_bytes(rows, cin, it) if taps > 1 else 0
+    if not backward:
+        return (2 * (x_b + y_b + wt_b) + slices
+                + bn * 3 * _tile_bytes(rows, cout, 4))      # acc, zf, y
+    prows = (ho + 2 * (hp - ho)) * (wo + 2 * (wp - wo))     # dzp rows
+    dw_b = taps * _tile_bytes(cin, cout, 4)
+    dzp_b = (ho + 2 * (hp - ho)) * _tile_bytes(wo + 2 * (wp - wo), cout, it)
+    return (2 * (x_b + y_b + x_b + 2 * wt_b + dw_b)         # x, dy, dx, w
+            + dw_b + dzp_b                                  # scratch
+            + 6 * _tile_bytes(rows, cout, 4)                # zf..dz
+            + 2 * _tile_bytes(hp * wp, cin, 4)              # dxp
+            + 2 * slices
+            + (taps * _tile_bytes(prows, cout, it) if taps > 1 else 0))
 
 
 def supported(x_shape, w_shape, strides, paddings, dilations, groups,
@@ -92,18 +136,8 @@ def supported(x_shape, w_shape, strides, paddings, dilations, groups,
     bn = int(block_n)
     if bn < 1 or (bn > 1 and (backward or n % bn != 0)):
         return False
-    it = _itemsize(x_dtype)
-    x_b = hp * wp * cin * it * bn
-    wt_b = kh * kw * cin * cout * it
-    z_b = ho * wo * cout * 4 * bn
-    if backward:
-        dy_b = ho * wo * cout * it
-        dzp_b = hp * wp * cout * it
-        dw_b = kh * kw * cin * cout * 4
-        need = 2 * x_b + 2 * dy_b + 2 * wt_b + dzp_b + dw_b + 2 * z_b
-    else:
-        need = 2 * x_b + wt_b + 2 * z_b
-    return need <= _VMEM_BUDGET
+    return _vmem_need(bn, hp, wp, ho, wo, cin, cout, kh * kw,
+                      _itemsize(x_dtype), backward) <= _VMEM_LIMIT
 
 
 def _prep(x, w, strides, paddings):
@@ -238,6 +272,7 @@ def conv_bn_train_pallas(x, w, scale, bias, eps, strides, paddings, act,
         scratch_shapes=[pltpu.VMEM((1, cout), jnp.float32),
                         pltpu.VMEM((1, cout), jnp.float32),
                         pltpu.VMEM((2, cout), jnp.float32)],
+        compiler_params=_compiler_params(),
         interpret=_on_cpu(),
     )(x, wt, sb)
     return y, sm[0], sv[0]
@@ -286,6 +321,7 @@ def conv_affine_pallas(x, w, a, b, strides, paddings, act, block_n=1):
         ],
         out_specs=pl.BlockSpec((bn, ho, wo, cout), lambda i: (i, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((n, ho, wo, cout), out_dtype),
+        compiler_params=_compiler_params(),
         interpret=_on_cpu(),
     )(x, wt, ab)
 
@@ -443,6 +479,7 @@ def conv_bn_bwd_pallas(x, w, dy, scale, bias, mean, var, eps, strides,
             pltpu.VMEM((ho + 2 * (kh - 1), wo + 2 * (kw - 1), cout),
                        x_dtype),
         ],
+        compiler_params=_compiler_params(),
         interpret=_on_cpu(),
     )(xp, wt, wtr, dy, aux)
     dw_oihw = dw.reshape(kh, kw, cin, cout).transpose(3, 2, 0, 1)

@@ -8,9 +8,11 @@ Pallas analogs go further than per-cell fusion: the LSTM/GRU run their
 WHOLE sequence as one kernel — grid over time, recurrent weight
 VMEM-resident across steps (lax.scan re-reads it from HBM every
 iteration), h/c carries in VMEM scratch, bf16 MXU gate matmuls with f32
-accumulation. Measured 1.22x vs the scan path on the v5e LSTM training
-lane (round 5); GRU 0.98-1.08x across sessions (kept out of the tier's
-AUTO_PALLAS set for that reason).
+accumulation. On a TPU v5 lite at the bench RNN-lane shape (bs64 len100
+hid512; PR 21, tools/kernel_probe.py): the LSTM recurrence runs 1.22x the
+scan twin (bitwise-equal output) and the lane's whole train step ties
+(3.386 vs 3.389 ms) — lstm is in AUTO_PALLAS; the GRU recurrence runs
+1.61x but its train step has not been measured, so gru is not.
 
 Numerics incl. all gradients are pinned against jnp twins
 (tests/test_pallas_kernels.py, interpret mode on CPU, native on TPU).

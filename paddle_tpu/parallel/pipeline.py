@@ -25,7 +25,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def shard_pipeline_params(stacked_params, mesh, axis="pp"):
@@ -100,9 +99,9 @@ def pipeline_apply(stage_fn, stacked_params, microbatches, mesh, axis="pp",
         raise ValueError(
             f"data_spec {dspec} must not partition the leading microbatch "
             "dim; shard the per-microbatch batch dim (e.g. P(None, 'dp'))")
-    fn = shard_map(per_device, mesh=mesh,
-                   in_specs=(spec_params, dspec), out_specs=dspec,
-                   check_rep=False)
+    fn = jax.shard_map(per_device, mesh=mesh,
+                       in_specs=(spec_params, dspec), out_specs=dspec,
+                       check_vma=False)
     return fn(stacked_params, microbatches)
 
 

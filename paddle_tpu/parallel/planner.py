@@ -91,14 +91,24 @@ _M_REJECTS = _METRICS.counter(
     "fresh search, never a failure",
     labels=("reason",))
 
-# cost-model machine constants: RELATIVE ranking is what matters (every
-# full-use candidate divides the same measured FLOPs by the same device
-# count), so these are deliberately round numbers — per-device peak
-# FLOP/s and per-device interconnect bytes/s. TPU numbers are v5e-class;
-# the CPU fallback only needs comm to be expensive relative to compute
-# in the same proportion (ICI-class fabric ~ 1e11 B/s vs ~ 1e14 FLOP/s).
-PEAK_FLOPS_S = {"tpu": 2.0e14, "cpu": 5.0e10}
-COLLECTIVE_BYTES_S = {"tpu": 9.0e10, "cpu": 2.0e7}
+# Per-device machine rates, keyed by jax's ``device_kind`` — the ONE table
+# of peaks in the repo. The cost model reads ``flops_s`` and
+# ``ici_bytes_s``; ``hbm_bytes_s`` is here so roofline arithmetic elsewhere
+# never grows a second table. A device that is not listed is an error
+# (:func:`machine_rates`), never a default: a plan costed with another
+# machine's rates ranks meshes for a machine that is not there.
+DEVICE_RATES = {
+    # TPU v5e. Source: Google Cloud documentation, "TPU v5e" — 197 TFLOP/s
+    # bf16, 819 GB/s HBM, 1,600 Gbit/s chip-to-chip interconnect (200 GB/s
+    # per chip, all links).
+    "TPU v5 lite": {"flops_s": 1.97e14, "hbm_bytes_s": 8.19e11,
+                    "ici_bytes_s": 2.0e11},
+    # The host CPU backend (tier-1's virtual-device mesh). Not a measured
+    # machine: round numbers chosen so collectives cost about as much
+    # relative to compute as on an ICI fabric, which is all the RELATIVE
+    # ranking of candidates needs. No HBM.
+    "cpu": {"flops_s": 5.0e10, "hbm_bytes_s": None, "ici_bytes_s": 2.0e7},
+}
 
 # default microbatch count for the pipeline bubble term
 # (bubble = (pp-1)/(micro+pp-1), the GPipe fill/drain fraction)
@@ -511,12 +521,20 @@ def enumerate_meshes(target, n_devices, moe_experts=None):
     return f, out
 
 
-def _machine_rates():
-    import jax
-    dev = jax.devices()[0]
-    platform = str(dev.platform)
-    return (PEAK_FLOPS_S.get(platform, PEAK_FLOPS_S["cpu"]),
-            COLLECTIVE_BYTES_S.get(platform, COLLECTIVE_BYTES_S["cpu"]))
+def machine_rates(device_kind=None):
+    """The :data:`DEVICE_RATES` entry for ``device_kind`` (default: the
+    first attached device's). Raises :class:`PlanError` for a device the
+    table does not list."""
+    if device_kind is None:
+        import jax
+        device_kind = jax.devices()[0].device_kind
+    try:
+        return DEVICE_RATES[str(device_kind)]
+    except KeyError:
+        raise PlanError(
+            f"no machine rates for device_kind {str(device_kind)!r} "
+            f"(known: {sorted(DEVICE_RATES)}); add its published peaks, "
+            "with their source, to parallel.planner.DEVICE_RATES") from None
 
 
 def cost_candidate(features, cand, microbatches=None, comm_scale=1.0,
@@ -529,7 +547,10 @@ def cost_candidate(features, cand, microbatches=None, comm_scale=1.0,
     f, s = features, cand.sizes
     dp, ep, pp, tp, sp = (s[a] for a in _AXIS_ORDER)
     shards = dp * ep * pp * tp * sp
-    flops_s, bytes_s = rates or _machine_rates()
+    if rates is None:
+        r = machine_rates()
+        rates = (r["flops_s"], r["ici_bytes_s"])
+    flops_s, bytes_s = rates
 
     compute_s = f.flops_estimate() / shards / flops_s
 
@@ -948,10 +969,11 @@ def plan(program, feed_example=None, n_devices=None, fetch_list=None,
 
 
 __all__ = [
-    "ARTIFACT_SUFFIX", "Candidate", "PLAN_DIRNAME", "PlanCost",
+    "ARTIFACT_SUFFIX", "Candidate", "DEVICE_RATES", "PLAN_DIRNAME",
+    "PlanCost",
     "PlanError", "PlanStore", "PlacementReport", "ProgramFeatures",
     "REJECT_REASONS", "apply_candidate", "cost_candidate",
     "enumerate_meshes", "extract_features", "fingerprint_key",
-    "manifest_plan_digests", "plan", "plan_fingerprint",
+    "machine_rates", "manifest_plan_digests", "plan", "plan_fingerprint",
     "program_signature", "resolve_store",
 ]

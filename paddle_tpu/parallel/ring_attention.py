@@ -27,10 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
-# this jax ships shard_map under jax.experimental only (the top-level
-# jax.shard_map export landed later); same signature modulo the
-# replication-check kwarg name (check_rep here, check_vma upstream)
-from jax.experimental.shard_map import shard_map
 
 
 def _block_attention(q, k, v, m_prev, l_prev, acc_prev, mask=None):
@@ -111,8 +107,8 @@ def _build_ring_fn(mesh, axis, causal, batch_axis=None):
         out = acc / l.transpose(0, 2, 1)[..., None]
         return out.astype(qb.dtype)
 
-    fn = shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
-                   out_specs=spec, check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
+                       out_specs=spec, check_vma=False)
     return jax.jit(fn), NamedSharding(mesh, spec)
 
 

@@ -26,8 +26,16 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 def make_mesh(n_devices=None, axes=("dp",), shape=None, devices=None):
     """Create a Mesh over the first n devices. axes like ("dp",) or
-    ("dp", "tp"); shape optionally fixes the per-axis sizes."""
-    devs = list(devices if devices is not None else jax.devices())[: n_devices]
+    ("dp", "tp"); shape optionally fixes the per-axis sizes. Asking for
+    more devices than exist raises — a silently smaller mesh would run
+    the plan on hardware nobody asked for."""
+    devs = list(devices if devices is not None else jax.devices())
+    if n_devices is not None:
+        if n_devices > len(devs):
+            raise ValueError(
+                f"make_mesh: {n_devices} devices asked for, "
+                f"{len(devs)} available ({devs[0].platform})")
+        devs = devs[:n_devices]
     n = len(devs)
     if shape is None:
         if len(axes) == 1:
@@ -268,6 +276,9 @@ def shard_program_step(executor, program, feed_example, fetch_list, plan,
     the multi-chip equivalent of Executor._compiled, with every state/feed
     leaf placed by the ShardingPlan. Run it in a loop, carrying state.
     """
+    from contextlib import nullcontext
+
+    from ..core.amp import amp_guard
     from ..core.executor import (_analyze_program, _run_ops, _RNG_KEY,
                                  _is_traceable)
     from ..core.scope import global_scope
@@ -310,7 +321,10 @@ def shard_program_step(executor, program, feed_example, fetch_list, plan,
         env.update(fd)
         executor._tracing = True
         try:
-            _run_ops(block, env, executor)
+            # the executor's AMP setting applies here as it does in
+            # Executor.run (an amp_guard the caller holds stays in force)
+            with amp_guard(True) if executor.amp else nullcontext():
+                _run_ops(block, env, executor)
         finally:
             executor._tracing = False
         # carry exactly the input keyset so the step iterates:

@@ -309,12 +309,14 @@ class ExecCache:
                        error=None if error is None
                        else f"{type(error).__name__}: {error}")
 
-    def load(self, fp):
-        """The warm path: the artifact for ``fp``, deserialized and
-        wrapped, or None (miss / reject — the caller compiles). Never
-        raises: corruption at ANY depth is a reject + compile fallback,
-        because a broken cache must only ever cost the compile it failed
-        to save."""
+    def load(self, fp, device):
+        """The warm path: the artifact for ``fp``, deserialized and loaded
+        onto ``device`` (the engine executor's — without it jax loads the
+        executable for EVERY device of the backend, and on a multi-device
+        host a one-device artifact then fails its first dispatch), or None
+        (miss / reject — the caller compiles). Never raises: corruption at
+        ANY depth is a reject + compile fallback, because a broken cache
+        must only ever cost the compile it failed to save."""
         path = self.artifact_path(fp)
         try:
             with open(path, "rb") as f:
@@ -358,7 +360,8 @@ class ExecCache:
             from jax.experimental.serialize_executable import \
                 deserialize_and_load
             compiled = deserialize_and_load(doc["payload"], doc["in_tree"],
-                                            doc["out_tree"])
+                                            doc["out_tree"],
+                                            execution_devices=[device])
         except Exception as e:
             self.note_reject(fp.get("tag", "?"), stage, error=e)
             return None
@@ -448,7 +451,7 @@ def acquire(cache, content_hash, tag, program, feed, fetch_names,
         donated = tuple(sorted(n for n in donate_feeds if n in prepared))
         fp = fingerprint(content_hash, tag, prepared, fetch_names,
                          donated=donated)
-        entry = cache.load(fp)
+        entry = cache.load(fp, executor.device)
         if entry is None and not cache.readonly:
             entry = compile_and_save(cache, fp, program, prepared,
                                      fetch_names, executor=executor,

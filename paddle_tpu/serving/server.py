@@ -754,6 +754,13 @@ class ModelServer:
         # reads a current number — json-safe, present on every backend
         # (CPU falls back to the live-arrays tally)
         out["memory"] = _perf.memory_section()
+        # accelerator identity of THIS process (a fleet's supervising
+        # parent never touches jax; it reads the fleet's identity here)
+        import jax
+        dev = jax.devices()[0]
+        out["device"] = {"platform": dev.platform,
+                         "kind": str(dev.device_kind),
+                         "count": jax.device_count()}
         # SLO verdicts on the same surface rollouts and routers already
         # health-gate on: this server's OWN monitor when it has one
         # (two servers in one process must not report each other's

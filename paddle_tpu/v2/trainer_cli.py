@@ -219,12 +219,6 @@ def main(argv=None):
                          "reference supplies at runtime)")
     args = ap.parse_args(argv)
 
-    import jax
-    try:
-        jax.config.update("jax_platforms", "cpu")
-    except Exception:
-        pass
-
     from .config_helpers import parse_config
     topo, main_prog, startup = parse_config(
         args.config, config_args=_parse_config_args(args.config_args),

@@ -635,3 +635,59 @@ def test_pserver_supervisor_rides_shared_helper():
     sig = inspect.signature(ChildSupervisor.__init__)
     assert sig.parameters["startup_grace_s"].default == 0.0
     assert sig.parameters["mp_start_method"].default == "fork"
+
+
+# ---------------------------------------------------------------------------
+# one process per chip: the supervising parent stays off JAX, and a replica
+# that cannot get its device fails loudly and promptly
+# ---------------------------------------------------------------------------
+
+_OFF_JAX_PARENT = """
+import sys, time
+from jax._src import xla_bridge
+from paddle_tpu.serving import FleetSupervisor
+
+root = sys.argv[1]
+# a fleet whose replicas are told to use a platform this host does not
+# have, next to a healthy one: both supervised from THIS process
+bad = FleetSupervisor(root, "mlp", n_replicas=1, jax_platform="tpu")
+good = FleetSupervisor(root, "mlp", n_replicas=1)
+try:
+    assert good.wait_ready(120)
+    fm = good.fleet_metrics()
+    assert fm["device_kind"] == "cpu" and fm["n_devices"] >= 1, fm
+    t0 = time.monotonic()
+    try:
+        bad.wait_ready(120)
+    except RuntimeError as e:
+        assert "could not get its accelerator" in str(e), e
+    else:
+        raise AssertionError("a replica without a device passed wait_ready")
+    assert bad.restarts == [0] and not bad.child_alive(0)
+    print("surfaced_after_s", round(time.monotonic() - t0, 1))
+finally:
+    good.stop()
+    bad.stop()
+assert not xla_bridge.backends_are_initialized(), "the parent touched jax"
+print("PARENT_OFF_JAX_OK")
+"""
+
+
+def test_fleet_parent_stays_off_jax_and_deviceless_replica_fails_fast(
+        tmp_path):
+    import subprocess
+    import sys
+
+    d, _, _ = _export_model(tmp_path)
+    root = str(tmp_path / "registry")
+    ModelRegistry(root).publish("mlp", d)
+    r = subprocess.run(
+        [sys.executable, "-c", _OFF_JAX_PARENT, root], capture_output=True,
+        text=True, timeout=300,
+        env=dict(os.environ, PYTHONPATH=os.path.dirname(
+            os.path.dirname(os.path.abspath(__file__)))))
+    assert r.returncode == 0, r.stderr[-3000:]
+    assert "PARENT_OFF_JAX_OK" in r.stdout
+    # the replica's own message names the cause; the supervisor repeats it
+    assert "cannot get its accelerator (platform=tpu)" in r.stderr
+    assert "exited code 75" in r.stderr

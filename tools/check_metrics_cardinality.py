@@ -41,7 +41,8 @@ BOUNDED_LABELS = {
                 "component constructed, bounded by process lifetime",
     "bucket": "engine batch/prompt buckets — a small parsed flag set",
     "phase": "generation phases: prefill/chunk/decode",
-    "mode": "executor modes: eager/jit",
+    "mode": "executor modes: eager/jit; Pallas lowering modes: "
+            "native/interpret",
     "op_type": "registered op types — the fixed op registry",
     "kind": "small code-site enums (retrace kinds, flight event kinds)",
     "role": "wire roles: client/server",

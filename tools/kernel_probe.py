@@ -1,0 +1,321 @@
+"""Per-family evidence for ``ops.pallas.AUTO_PALLAS`` membership, taken on
+the attached accelerator in ONE process.
+
+A kernel family belongs in the auto set only if, at the shapes the repo
+actually runs, its Pallas kernel (1) lowers natively (Mosaic, not the
+interpreter), (2) matches its jnp twin, and (3) is not slower than the
+twin in a same-process A/B. This tool takes those three observations per
+case and prints one JSON line each (also collected into ``--out``):
+
+    {"case", "family", "lowered", "error", "max_abs_err", "max_rel_err",
+     "jnp_ms", "pallas_ms", "speedup"}
+
+Cases: conv_bn forward and backward at ResNet-50 bottleneck shapes
+(batch 256, bf16 — what ``chip_smoke.py`` dispatches under
+``kernel_tier=pallas``); the fused momentum step over ResNet-50's real
+parameter census; lstm/gru at the ``bench.py`` RNN-lane shape; ctc and
+embedding_sgd at the shapes their parity tests pin, scaled to a workload
+size; paged_attention at the generation lane's decode shape.
+
+A kernel that fails to lower is a RESULT here (``lowered: false`` with the
+compiler's message), never a crash. A watchdog ends the process if one
+case blocks the device for longer than ``CASE_TIMEOUT_S``.
+
+Usage (on the chip): python tools/kernel_probe.py [--tiny] [--only FAMILY]
+``--tiny`` shrinks every case so the tool itself can be checked on the CPU
+(kernels then run interpreted and the timings mean nothing).
+"""
+
+import argparse
+import json
+import os
+import sys
+import threading
+import time
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import numpy as np
+
+CASE_TIMEOUT_S = 240.0
+
+
+def _leaves(out):
+    import jax
+    return [np.asarray(x, np.float32) for x in jax.tree_util.tree_leaves(out)]
+
+
+def _errs(got, want):
+    """(max abs err, max err relative to the twin's largest magnitude)."""
+    abs_e = rel_e = 0.0
+    for a, b in zip(_leaves(got), _leaves(want)):
+        d = float(np.max(np.abs(a - b))) if a.size else 0.0
+        abs_e = max(abs_e, d)
+        rel_e = max(rel_e, d / max(float(np.max(np.abs(b))), 1e-30))
+    return abs_e, rel_e
+
+
+def probe(case, family, runners, repeats=5, inner=4):
+    """Run one case: twin first (must work), then the Pallas runner (a
+    failure is recorded, not raised), then parity and the interleaved A/B
+    (ops.autotune.measure — the repo's one timing core)."""
+    import jax
+    from paddle_tpu.ops.autotune import measure
+
+    rec = {"case": case, "family": family, "lowered": False, "error": None,
+           "max_abs_err": None, "max_rel_err": None, "jnp_ms": None,
+           "pallas_ms": None, "speedup": None}
+    want = jax.block_until_ready(runners["jnp"]())
+    try:
+        got = jax.block_until_ready(runners["pallas"]())
+    except Exception as e:          # a kernel that cannot compile: a result
+        rec["error"] = f"{type(e).__name__}: {e}"[:1500]
+        return rec
+    rec["lowered"] = True
+    rec["max_abs_err"], rec["max_rel_err"] = _errs(got, want)
+    ms = measure(runners, repeats=repeats, inner=inner)
+    rec["jnp_ms"] = round(ms["jnp"], 4)
+    rec["pallas_ms"] = round(ms["pallas"], 4)
+    rec["speedup"] = round(ms["jnp"] / ms["pallas"], 4)
+    return rec
+
+
+# ---------------------------------------------------------------------------
+# case builders: each returns {"jnp": runner, "pallas": runner}
+# ---------------------------------------------------------------------------
+
+def conv_bn_case(n, h, cin, cout, k, stride, backward):
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops.conv_ops import _conv2d_compute
+    from paddle_tpu.ops.norm_ops import bn_forward_math, bn_backward_math
+    from paddle_tpu.ops.pallas import conv_bn as cbk
+
+    rng = np.random.RandomState(0)
+    dt = jnp.bfloat16
+    pad = ((k - 1) // 2,) * 2
+    strides = (stride, stride)
+    ho = -(-h // stride)
+    x = jnp.asarray(rng.normal(0, 1, (n, h, h, cin)), dt)
+    w = jnp.asarray(rng.normal(0, 0.05, (cout, cin, k, k)), dt)
+    scale = jnp.asarray(rng.uniform(0.5, 1.5, cout), jnp.float32)
+    bias = jnp.asarray(rng.normal(0, 0.2, cout), jnp.float32)
+    dy = jnp.asarray(rng.normal(0, 1, (n, ho, ho, cout)), dt)
+    rm, rv = jnp.zeros(cout), jnp.ones(cout)
+    eps = 1e-5
+
+    def conv(a, b):
+        return _conv2d_compute(a, b, strides, pad, (1, 1), 1, "NHWC")
+
+    def twin_fwd(x, w):
+        y, _m, _v, sm, sv = bn_forward_math(conv(x, w), scale, bias, rm, rv,
+                                            eps, 0.9, "NHWC", False)
+        return jnp.maximum(y, 0), sm, sv
+
+    def pallas_fwd(x, w):
+        return cbk.conv_bn_train_pallas(x, w, scale, bias, eps, strides,
+                                        pad, "relu")
+
+    if not backward:
+        tf, pf = jax.jit(twin_fwd), jax.jit(pallas_fwd)
+        return {"jnp": lambda: tf(x, w), "pallas": lambda: pf(x, w)}
+
+    y, sm, sv = jax.jit(twin_fwd)(x, w)
+
+    def twin_bwd(x, w, dy):
+        # the fused op's jnp backward (ops/fused_ops.py) verbatim
+        z, vjp = jax.vjp(conv, x, w)
+        dz, ds, db = bn_backward_math(z, scale, sm, sv, dy * (y > 0), eps,
+                                      "NHWC", False)
+        dx, dw = vjp(dz.astype(z.dtype))
+        return dx, dw, ds, db
+
+    def pallas_bwd(x, w, dy):
+        return cbk.conv_bn_bwd_pallas(x, w, dy, scale, bias, sm, sv, eps,
+                                      strides, pad, "relu")
+
+    tb, pb = jax.jit(twin_bwd), jax.jit(pallas_bwd)
+    return {"jnp": lambda: tb(x, w, dy), "pallas": lambda: pb(x, w, dy)}
+
+
+def registry_case(kernel, key):
+    """A case straight from the autotuner's variant registry (the same
+    runners the Tuner measures)."""
+    from paddle_tpu.ops.autotune import VARIANTS
+    specs = VARIANTS.variants(kernel)
+    return {"jnp": specs["jnp"].build(key),
+            "pallas": specs["pallas"].build(key)}
+
+
+def momentum_case(shapes):
+    """One fused-momentum step over ``shapes``: the arena megakernel (with
+    the concat/split the fused op pays) vs the per-param twin."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops.optimizer_ops import _momentum_dense
+    from paddle_tpu.ops.pallas import optimizer as opk
+
+    rng = np.random.RandomState(0)
+    ps = [jnp.asarray(rng.normal(0, 1, s), jnp.float32) for s in shapes]
+    gs = [jnp.asarray(rng.normal(0, 1e-3, s), jnp.float32) for s in shapes]
+    vs = [jnp.zeros(s, jnp.float32) for s in shapes]
+    lr, mu = 0.1, 0.9
+
+    def fused(ps, gs, vs):
+        pa, ga, va = (opk.flatten_arena(t)[0] for t in (ps, gs, vs))
+        po, vo = opk.momentum_arena_pallas(pa, ga, va, lr, mu)
+        return opk.split_arena(po, shapes), opk.split_arena(vo, shapes)
+
+    def twin(ps, gs, vs):
+        out = [_momentum_dense(p, g, v, lr, mu, False)
+               for p, g, v in zip(ps, gs, vs)]
+        return [o[0] for o in out], [o[1] for o in out]
+
+    ff, tf = jax.jit(fused), jax.jit(twin)
+    return {"jnp": lambda: tf(ps, gs, vs), "pallas": lambda: ff(ps, gs, vs)}
+
+
+def ctc_case(b, t, c, u):
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import ctc_ops
+
+    rng = np.random.RandomState(0)
+    logits = jnp.asarray(rng.normal(0, 1, (b, t, c)), jnp.float32)
+    labels = jnp.asarray(rng.randint(1, c, (b, u)), jnp.int32)
+    x_lens = jnp.asarray(rng.randint(t // 2, t + 1, b), jnp.int32)
+    y_lens = jnp.asarray(rng.randint(1, u + 1, b), jnp.int32)
+    scan = jax.jit(lambda l: ctc_ops._ctc_loss_scan(l, x_lens, labels,
+                                                    y_lens, 0))
+    pal = jax.jit(lambda l: ctc_ops._ctc_loss_pallas(l, x_lens, labels,
+                                                     y_lens, 0))
+    return {"jnp": lambda: scan(logits), "pallas": lambda: pal(logits)}
+
+
+def resnet50_param_shapes():
+    """The tensors the flagship's optimizer updates, read off the built
+    program (the PR-21 run also counted the 106 BN running statistics:
+    267 tensors, the same 25.6 M elements)."""
+    import bench
+    main, _startup, _loss = bench.build(8, 224, 1000)
+    return [tuple(p.shape) for p in main.global_block().all_parameters()
+            if p.trainable]
+
+
+def cases(tiny):
+    """Yield (case name, family, zero-arg builder)."""
+    from paddle_tpu.ops.autotune import make_key
+
+    n = 4 if tiny else 256
+    # (h, cin, cout, k, stride): one of each kind per ResNet-50 stage
+    convs = [(8, 8, 8, 3, 1), (8, 8, 16, 1, 1)] if tiny else [
+        (56, 64, 64, 3, 1), (56, 64, 256, 1, 1), (56, 256, 512, 1, 2),
+        (28, 128, 128, 3, 1), (14, 256, 256, 3, 1), (14, 1024, 256, 1, 1),
+        (7, 512, 512, 3, 1)]
+    for h, cin, cout, k, s in convs:
+        for bwd in (False, True):
+            yield (f"conv_bn_{'bwd' if bwd else 'fwd'}_{h}x{h}_{cin}to{cout}"
+                   f"_k{k}s{s}", "conv_bn",
+                   lambda a=(n, h, cin, cout, k, s, bwd): conv_bn_case(*a))
+    shapes = [(64, 16)] * 4 + [(16,)] * 4 if tiny \
+        else resnet50_param_shapes()
+    yield ("optimizer_momentum_resnet50", "optimizer",
+           lambda: momentum_case(shapes))
+    b, L, H = (4, 6, 128) if tiny else (64, 100, 512)
+    for cell, mult in (("lstm", 4), ("gru", 3)):
+        yield (f"{cell}_b{b}_len{L}_hid{H}", cell,
+               lambda c=cell, m=mult: registry_case("rnn", make_key(
+                   cell=c, x=(b, L, m * H), dtype="float32")))
+    yield ("ctc", "ctc", lambda: ctc_case(*((2, 6, 8, 2) if tiny
+                                            else (32, 128, 96, 24))))
+    rows, dim, nnz = (64, 128, 8) if tiny else (30000, 128, 6400)
+    yield ("embedding_sgd", "embedding_sgd",
+           lambda: registry_case("embedding", make_key(
+               rows=rows, dim=dim, nnz=nnz, dtype="float32")))
+    s, nb = (2, 8) if tiny else (8, 64)
+    yield ("paged_attention", "paged_attention",
+           lambda: registry_case("paged_attention", make_key(
+               q=(s, 4, 128), kc=(nb, 16, 4, 128), tables=4,
+               dtype="float32")))
+
+
+def lstm_lane_step(tiny, rounds=3):
+    """The bench.py LSTM text-cls lane's whole TRAINING step, scan path vs
+    the Pallas recurrence, by the lane's own runner, interleaved (jnp,
+    pallas, pallas, jnp) x ``rounds`` — the family's end-to-end A/B.
+    Reports the best of each and every run (``runs_ms``) so the spread is
+    on the record next to the difference."""
+    import bench
+    kw = dict(batch=4, seq_len=6, hidden=128, steps=2, warmup=1,
+              vocab=64) if tiny else {}
+    rec = {"case": "lstm_textcls_train_step", "family": "lstm",
+           "lowered": False, "error": None, "jnp_ms": None,
+           "pallas_ms": None, "speedup": None}
+    ms = {False: [], True: []}
+    try:
+        for use_pallas in (False, True, True, False) * rounds:
+            ms[use_pallas].append(
+                bench.run_lstm_lane(use_pallas=use_pallas, **kw))
+    except Exception as e:
+        rec["error"] = f"{type(e).__name__}: {e}"[:1500]
+        return rec
+    rec.update(lowered=True, jnp_ms=round(min(ms[False]), 4),
+               pallas_ms=round(min(ms[True]), 4),
+               speedup=round(min(ms[False]) / min(ms[True]), 4),
+               runs_ms={"jnp": [round(v, 4) for v in ms[False]],
+                        "pallas": [round(v, 4) for v in ms[True]]})
+    return rec
+
+
+def main():
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--tiny", action="store_true")
+    ap.add_argument("--only", default=None, help="one kernel family")
+    ap.add_argument("--out", default="chiprun_out/kernel_probe.json")
+    args = ap.parse_args()
+
+    import jax
+    from paddle_tpu.ops.pallas import on_cpu
+    dev = jax.devices()[0]
+    head = {"platform": dev.platform, "device_kind": dev.device_kind,
+            "count": len(jax.devices()), "interpret": on_cpu(),
+            "tiny": args.tiny}
+    print(json.dumps(head), flush=True)
+
+    current = {"case": None, "deadline": None}
+
+    def watchdog():
+        while True:
+            time.sleep(1.0)
+            d = current["deadline"]
+            if d is not None and time.monotonic() > d:
+                print(json.dumps({"case": current["case"],
+                                  "error": "watchdog: case blocked the "
+                                           "device past CASE_TIMEOUT_S"}),
+                      flush=True)
+                os._exit(4)
+
+    threading.Thread(target=watchdog, daemon=True).start()
+    results = []
+    todo = [(name, family,
+             lambda n=name, f=family, b=build: probe(n, f, b()))
+            for name, family, build in cases(args.tiny)]
+    todo.append(("lstm_textcls_train_step", "lstm",
+                 lambda: lstm_lane_step(args.tiny)))
+    for name, family, run in todo:
+        if args.only and family != args.only:
+            continue
+        current.update(case=name,
+                       deadline=time.monotonic() + CASE_TIMEOUT_S)
+        rec = run()
+        current["deadline"] = None
+        results.append(rec)
+        print(json.dumps(rec), flush=True)
+        os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
+        with open(args.out, "w") as f:
+            json.dump({"device": head, "results": results}, f, indent=1)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -53,8 +53,10 @@ def main():
     if args.hlo_match and os.path.exists(args.hlo_match):
         shapes, nbytes = load_hlo_annotations(args.hlo_match)
 
+    from paddle_tpu.core import compile_cache
     from paddle_tpu.obs import perf
 
+    compile_cache.enable()
     target = profile_common.build_target(args)
     print(f"target: {target.label}")
     with target.ctx():
@@ -72,8 +74,11 @@ def main():
         print(f"  {row['us_per_step']:10.1f} us {row['pct']:6.2f}% "
               f" {row['name']}")
 
+    from paddle_tpu.parallel.planner import machine_rates
+    hbm = machine_rates()["hbm_bytes_s"]
+    peak = f"{hbm / 1e9:.0f}" if hbm else "n/a"
     print(f"\ntop {args.top} instances (GB/s = static operand+result bytes "
-          f"over measured time; v5e HBM peak ~819):")
+          f"over measured time; this device's HBM peak {peak}):")
     print(f"{'us/step':>10s} {'%':>6s} {'GB/s':>6s}  name | hlo")
     for row in res["top"]:
         us_step = row["us_per_step"]
