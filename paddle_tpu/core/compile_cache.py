@@ -2,7 +2,7 @@
 
 The cache directory is part of the cache key's usefulness: a directory
 that moves between runs never hits. So the rule is fixed here and every
-checkout script (chip_smoke.py, bench.py, tools/profile_step.py,
+checkout script (chip_smoke.py, bench.py, benchmark/run.py,
 __graft_entry__.py) calls :func:`enable` instead of naming a directory:
 
 * ``JAX_COMPILATION_CACHE_DIR`` set — JAX reads the variable itself; this

@@ -32,7 +32,7 @@ import jax.numpy as jnp
 
 from . import registry
 from .amp import amp_guard
-from .profiler import profiler_enabled, record_event
+from .profiler import record_event
 from .lod import LoDArray, flat_to_lodarray, pack_sequences
 from .scope import Scope, global_scope
 from .types import np_dtype
@@ -238,6 +238,7 @@ def _run_ops(block, env, exec_state):
     """Run/trace every op of a block over ``env`` in order. This is both the
     eager interpreter and the function traced by jit."""
     from .flags import get_flag
+    scopes = _analyze_program(block.program).op_scopes(block)
     # dispatch-coverage recording happens per-op AFTER each forward below
     # (an op that raises must not mark the block's remaining ops as
     # dispatched); no-op lambda when disabled keeps the loops branch-free
@@ -256,16 +257,10 @@ def _run_ops(block, env, exec_state):
         bench = get_flag("benchmark")
         check = get_flag("check_nan_inf")
         opm = get_flag("obs_op_metrics")
-        prof = profiler_enabled()
-        for op in block.ops:
+        for op, op_scope in zip(block.ops, scopes):
             t0 = _time.perf_counter() if (bench or opm) else 0.0
             info = registry.get_op_info(op.type)
-            if prof:
-                # metering must not suppress the per-op profiler spans
-                # the plain branches below record
-                with record_event(op.type, kind="op"):
-                    info.forward(ExecContext(op, block, env, exec_state))
-            else:
+            with record_event(op.type, kind="op"), jax.named_scope(op_scope):
                 info.forward(ExecContext(op, block, env, exec_state))
             record(op.type)
             if opm:
@@ -286,24 +281,56 @@ def _run_ops(block, env, exec_state):
                       f"{(_time.perf_counter() - t0) * 1e3:.3f} ms",
                       flush=True)
         return
-    if profiler_enabled():
-        # per-op host spans, the reference's RecordEvent around op->Run
-        # (executor.cc:317, operator.cc:488). In eager mode these are real
-        # op times; under jit they are trace-time spans (still useful for
-        # finding slow-to-trace ops) while the compiled step is covered by
-        # the jit_compile/jit_step spans in Executor.run.
-        for op in block.ops:
-            with record_event(op.type, kind="op"):
-                info = registry.get_op_info(op.type)
-                ctx = ExecContext(op, block, env, exec_state)
-                info.forward(ctx)
-                record(op.type)
-        return
-    for op in block.ops:
+    # per-op host spans, the reference's RecordEvent around op->Run
+    # (executor.cc:317, operator.cc:488). In eager mode these are real op
+    # times; under jit they are trace-time spans (still useful for finding
+    # slow-to-trace ops) while the compiled step is covered by the
+    # executor.run spans in Executor.run. The named scope is what the
+    # compiled step's instructions carry (below).
+    for op, op_scope in zip(block.ops, scopes):
         info = registry.get_op_info(op.type)
         ctx = ExecContext(op, block, env, exec_state)
-        info.forward(ctx)
+        with record_event(op.type, kind="op"), jax.named_scope(op_scope):
+            info.forward(ctx)
         record(op.type)
+
+
+# ---------------------------------------------------------------------------
+# A named scope per Fluid op, with its phase. ``_run_ops`` traces every op
+# under ``jax.named_scope("<phase>/<op type>")``, so each instruction of the
+# compiled step carries its Fluid op in its ``op_name`` metadata and a device
+# trace can be summed by phase and by op (a fusion that spans two Fluid ops
+# carries its root's, and is credited to that op). Sub-blocks nest: a reader
+# takes the outermost pair. Trace time only; the instructions are unchanged.
+#
+# SCOPE_SCHEME names the scheme in the jitted step functions' names
+# (``jit_step_<scheme>``). JAX's persistent compile cache does not see
+# metadata, the module's name it does: without the tag a cache that holds
+# executables compiled before the scopes existed would hand those back,
+# scope-less, for ever. Change it only when the scheme below changes; every
+# existing cache then compiles each program once more.
+# ---------------------------------------------------------------------------
+
+SCOPE_SCHEME = "ps1"
+_GRAD_MARK = "@GRAD"            # fluid.framework.GRAD_SUFFIX
+
+
+def _scheme_named(fn, base):
+    fn.__name__ = f"{base}_{SCOPE_SCHEME}"
+    return fn
+
+
+def _op_phase(op):
+    """``opt``: the op updates a parameter from its gradient (``Param`` and
+    ``Grad`` input slots). ``bwd``: one of its outputs is a gradient
+    variable, which also takes the ``sum`` / ``fill_constant`` /
+    ``fill_zeros_like`` ops ``append_backward`` inserts and the clip and
+    regulariser ops that rewrite gradients. ``fwd``: the rest."""
+    if op.inputs.get("Param") and op.inputs.get("Grad"):
+        return "opt"
+    if any(_GRAD_MARK in n for n in op.output_arg_names()):
+        return "bwd"
+    return "fwd"
 
 
 class _ProgramAnalysis:
@@ -315,7 +342,8 @@ class _ProgramAnalysis:
     its ExecutorPrepareContext, framework/executor.cc:271)."""
 
     __slots__ = ("version", "free", "written", "persistable_written",
-                 "verified", "op_inventory", "_op_metric_children")
+                 "verified", "op_inventory", "_op_metric_children",
+                 "_op_scopes")
 
     def __init__(self, version, free, written, persistable_written,
                  op_inventory=()):
@@ -336,6 +364,16 @@ class _ProgramAnalysis:
         # surface verifies once; the steady-state hot path pays one set
         # lookup, and a version bump rebuilds the analysis and re-verifies.
         self.verified = set()
+        self._op_scopes = {}
+
+    def op_scopes(self, block):
+        """``"<phase>/<op type>"`` of each op of ``block`` (any block of the
+        program), decided from the op itself once per program version."""
+        names = self._op_scopes.get(block.idx)
+        if names is None or len(names) != len(block.ops):
+            names = self._op_scopes[block.idx] = tuple(
+                f"{_op_phase(op)}/{op.type}" for op in block.ops)
+        return names
 
 
 # program -> _ProgramAnalysis for block 0. Keyed by the program OBJECT via
@@ -573,101 +611,111 @@ class Executor:
         # tpu_jit). Scope arrays then carry compute-preferred layouts.
         self.auto_layout = auto_layout
         self._cache = {}
+        self._step_num = 0      # the step spans' step_num
 
     # ------------------------------------------------------------------
     def run(self, program=None, feed=None, fetch_list=None, scope=None,
             return_numpy=True, use_program_cache=True, donate_feeds=()):
+        """Run ``program`` once. The call is one step span, ``executor.run``,
+        whose children (``executor.feed`` / ``state`` / ``lookup`` /
+        ``enqueue`` / ``writeback``) lie in a ``jax.profiler`` trace while
+        one is taken; the same statements run with or without one. It
+        returns when the step is enqueued, not when the device is done."""
         from ..fluid.framework import default_main_program
-
-        program = program or default_main_program()
-        feed = dict(feed or {})
-        fetch_list = list(fetch_list or [])
-        scope = scope or global_scope()
-        fetch_names = [f if isinstance(f, str) else f.name for f in fetch_list]
-
-        block = program.global_block()
-        feed_vals = self._prepare_feed(block, feed)
-
-        if scope.find_var(_RNG_KEY) is None:
-            scope.set(_RNG_KEY, jax.random.PRNGKey(program.random_seed or 0))
-
-        # steady-state hot path: every per-program set below comes from the
-        # _ProgramAnalysis cache — no block walk after the first run. (A
-        # free name with no runtime value anywhere is produced by an earlier
-        # op, e.g. a fill; if an op truly reads it first, _run_ops raises a
-        # clean error.)
-        analysis = _analyze_program(program)
-        _maybe_verify(program, analysis, tuple(feed_vals), tuple(fetch_names),
-                      scope=scope)
         from .flags import get_flag
-        if get_flag("obs_op_metrics"):
-            # jit: per-step op-type counts from the cached inventory
-            # (eager dispatches are timed per op inside _run_ops instead)
-            _M_STEPS.labels(mode=self.mode).inc()
-            if self.mode != "eager" and use_program_cache:
-                _note_jit_ops(analysis)
-        state_in = [n for n in analysis.free
-                    if n not in feed_vals and scope.has_var(n)]
-        state_out = [n for n in analysis.written
-                     if n in analysis.persistable_written or scope.has_var(n)]
 
-        state = {n: scope.find_var(n) for n in state_in}
-        state[_RNG_KEY] = scope.find_var(_RNG_KEY)
+        self._step_num += 1
+        with record_event("executor.run", kind="stage",
+                          step_num=self._step_num):
+            program = program or default_main_program()
+            feed = dict(feed or {})
+            fetch_list = list(fetch_list or [])
+            scope = scope or global_scope()
+            fetch_names = [f if isinstance(f, str) else f.name
+                           for f in fetch_list]
+            block = program.global_block()
+            jit = self.mode != "eager" and use_program_cache
 
-        if self.mode == "eager" or not use_program_cache:
-            env = dict(state)
-            env.update(feed_vals)
-            with amp_guard(self.amp):
-                _run_ops(block, env, self)
-            new_state = {n: env[n] for n in state_out if n in env}
-            new_state[_RNG_KEY] = env[_RNG_KEY]
-            fetches = [env[n] for n in fetch_names]
-        else:
-            # donated feeds (KV-arena donation) split into a third jit
-            # argument AFTER the analysis above saw them as feeds; eager
-            # dispatch ignores the split (no buffers to alias there)
-            donated = {n: feed_vals.pop(n) for n in donate_feeds
-                       if n in feed_vals} if donate_feeds else {}
-            with record_event("executor.prepare", kind="stage"):
-                fn = self._compiled(program, tuple(sorted(feed_vals)),
-                                    tuple(fetch_names), tuple(state_in),
-                                    tuple(state_out),
-                                    tuple(sorted(donated)))
-                # non-traceable state (readers, rank tables) can't cross jit
-                trace_state = {k: v for k, v in state.items()
-                               if _is_traceable(v)}
-                if self.place is not None:
-                    # explicit place: commit state so jit follows the
-                    # operands
-                    trace_state = {k: jax.device_put(v, self.device)
-                                   for k, v in trace_state.items()}
-            args = (trace_state, feed_vals) \
-                + ((donated,) if donated else ())
-            # amp guard wraps dispatch because jax traces lazily (first call
-            # and any shape-driven retrace happen inside fn())
-            from .flags import get_flag
-            if profiler_enabled():
-                with record_event("jit_step_dispatch", kind="stage"):
-                    with amp_guard(self.amp):
-                        new_state, fetches = fn(*args)
-                with record_event("jit_step_device", kind="stage"):
-                    jax.block_until_ready(fetches)
-            elif get_flag("check_nan_inf"):
-                # the jit analog of the eager per-op sweep: jax re-runs the
-                # computation op-by-op and points at the offending
-                # primitive (reference --check_nan_inf covers BOTH NaN and
-                # Inf, hence debug_infs too)
-                with jax.debug_nans(True), jax.debug_infs(True):
-                    with amp_guard(self.amp):
-                        new_state, fetches = fn(*args)
-                        jax.block_until_ready(fetches)
-            else:
+            with record_event("executor.feed", kind="stage"):
+                feed_vals = self._prepare_feed(block, feed)
+
+            with record_event("executor.state", kind="stage"):
+                if scope.find_var(_RNG_KEY) is None:
+                    scope.set(_RNG_KEY,
+                              jax.random.PRNGKey(program.random_seed or 0))
+                # steady-state hot path: every per-program set below comes
+                # from the _ProgramAnalysis cache — no block walk after the
+                # first run. (A free name with no runtime value anywhere is
+                # produced by an earlier op, e.g. a fill; if an op truly
+                # reads it first, _run_ops raises a clean error.)
+                analysis = _analyze_program(program)
+                _maybe_verify(program, analysis, tuple(feed_vals),
+                              tuple(fetch_names), scope=scope)
+                if get_flag("obs_op_metrics"):
+                    # jit: per-step op-type counts from the cached inventory
+                    # (eager dispatches are timed per op inside _run_ops)
+                    _M_STEPS.labels(mode=self.mode).inc()
+                    if jit:
+                        _note_jit_ops(analysis)
+                state_in = [n for n in analysis.free
+                            if n not in feed_vals and scope.has_var(n)]
+                state_out = [n for n in analysis.written
+                             if n in analysis.persistable_written
+                             or scope.has_var(n)]
+                state = {n: scope.find_var(n) for n in state_in}
+                state[_RNG_KEY] = scope.find_var(_RNG_KEY)
+                if jit:
+                    # donated feeds (KV-arena donation) split into a third
+                    # jit argument AFTER the analysis above saw them as
+                    # feeds; eager dispatch ignores the split (no buffers to
+                    # alias there)
+                    donated = {n: feed_vals.pop(n) for n in donate_feeds
+                               if n in feed_vals} if donate_feeds else {}
+                    # non-traceable state (readers, rank tables) can't
+                    # cross jit
+                    state = {k: v for k, v in state.items()
+                             if _is_traceable(v)}
+                    if self.place is not None:
+                        # explicit place: commit state so jit follows the
+                        # operands
+                        state = {k: jax.device_put(v, self.device)
+                                 for k, v in state.items()}
+
+            if not jit:
+                env = dict(state)
+                env.update(feed_vals)
                 with amp_guard(self.amp):
-                    new_state, fetches = fn(*args)
+                    _run_ops(block, env, self)
+                new_state = {n: env[n] for n in state_out if n in env}
+                new_state[_RNG_KEY] = env[_RNG_KEY]
+                fetches = [env[n] for n in fetch_names]
+            else:
+                with record_event("executor.lookup", kind="stage"):
+                    fn = self._compiled(program, tuple(sorted(feed_vals)),
+                                        tuple(fetch_names), tuple(state_in),
+                                        tuple(state_out),
+                                        tuple(sorted(donated)))
+                args = (state, feed_vals) + ((donated,) if donated else ())
+                check = get_flag("check_nan_inf")
+                # amp guard wraps dispatch because jax traces lazily (first
+                # call and any shape-driven retrace happen inside fn())
+                with record_event("executor.enqueue", kind="stage"), \
+                        amp_guard(self.amp):
+                    if check:
+                        # the jit analog of the eager per-op sweep: jax
+                        # re-runs the computation op-by-op and points at the
+                        # offending primitive (reference --check_nan_inf
+                        # covers BOTH NaN and Inf, hence debug_infs too)
+                        with jax.debug_nans(True), jax.debug_infs(True):
+                            new_state, fetches = fn(*args)
+                            jax.block_until_ready(fetches)
+                    else:
+                        new_state, fetches = fn(*args)
 
-        for n, v in new_state.items():
-            scope.set(n, v)
-        return [self._fetch_value(v, return_numpy) for v in fetches]
+            with record_event("executor.writeback", kind="stage"):
+                for n, v in new_state.items():
+                    scope.set(n, v)
+                return [self._fetch_value(v, return_numpy) for v in fetches]
 
     # ------------------------------------------------------------------
     def prepare_steps(self, program=None, feeds=(), fetch_list=None,
@@ -738,20 +786,28 @@ class Executor:
         current carry state from the scope, runs the K-step scan, writes the
         new state back, and returns the per-step stacked fetches — the
         reference's RunPreparedContext (executor.cc:296)."""
-        scope = prepared.scope
-        state = {n: scope.find_var(n) for n in prepared.carry_keys}
         from .flags import get_flag
-        if get_flag("check_nan_inf"):
-            with jax.debug_nans(True), jax.debug_infs(True):
-                with amp_guard(self.amp):
+
+        self._step_num += 1
+        with record_event("executor.run_prepared", kind="stage",
+                          step_num=self._step_num):
+            scope = prepared.scope
+            state = {n: scope.find_var(n) for n in prepared.carry_keys}
+            check = get_flag("check_nan_inf")
+            with record_event("executor.enqueue", kind="stage"), \
+                    amp_guard(self.amp):
+                if check:
+                    with jax.debug_nans(True), jax.debug_infs(True):
+                        new_state, fetches = prepared.fn(state,
+                                                         prepared.stacked)
+                        jax.block_until_ready(fetches)
+                else:
                     new_state, fetches = prepared.fn(state, prepared.stacked)
-                    jax.block_until_ready(fetches)
-        else:
-            with amp_guard(self.amp):
-                new_state, fetches = prepared.fn(state, prepared.stacked)
-        for n, v in new_state.items():
-            scope.set(n, v)
-        return [np.asarray(v) if return_numpy else v for v in fetches]
+            with record_event("executor.writeback", kind="stage"):
+                for n, v in new_state.items():
+                    scope.set(n, v)
+                return [np.asarray(v) if return_numpy else v
+                        for v in fetches]
 
     def run_steps(self, program=None, feeds=(), fetch_list=None, scope=None,
                   steps=None, return_numpy=True):
@@ -806,8 +862,9 @@ class Executor:
             return jax.lax.scan(body, state, idx)
 
         donate = (0,) if self.donate else ()
-        fn = _InstrumentedFn(tpu_jit(multi, donate_argnums=donate),
-                             "jit_scan", program._version)
+        fn = _InstrumentedFn(
+            tpu_jit(_scheme_named(multi, "multi"), donate_argnums=donate),
+            "jit_scan", program._version)
         self._cache[key] = fn
         return fn
 
@@ -861,7 +918,8 @@ class Executor:
 
             donate = (0,) if self.donate else ()
         fn = _InstrumentedFn(
-            tpu_jit(step, auto_state_layout=self.auto_layout,
+            tpu_jit(_scheme_named(step, "step"),
+                    auto_state_layout=self.auto_layout,
                     donate_argnums=donate),
             "jit_step", program._version)
         self._cache[key] = fn

@@ -28,6 +28,18 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from .profiler import record_event
+from ..obs.metrics import REGISTRY as _METRICS
+
+_M_PACK = _METRICS.counter(
+    "paddle_tpu_lod_pack_elements",
+    "sequence positions through core.lod.pack_sequences: real = the "
+    "sequences' own lengths summed; padded = batch x padded length, what "
+    "the device is given to work on (real / padded is the packing's fill)",
+    labels=("kind",))
+_M_PACK_REAL = _M_PACK.labels(kind="real")
+_M_PACK_PADDED = _M_PACK.labels(kind="padded")
+
 
 @jax.tree_util.register_pytree_node_class
 class LoDArray:
@@ -131,19 +143,23 @@ def pack_sequences(seqs, dtype=None, max_len=None, pad_multiple=1):
     ``pad_multiple`` buckets max_len up to a multiple to bound the number of
     distinct compiled shapes (the bucketed-padding policy from SURVEY.md §5).
     """
-    lens = np.array([len(s) for s in seqs], dtype=np.int32)
-    ml = int(max_len if max_len is not None else (lens.max() if len(lens) else 0))
-    if pad_multiple > 1:
-        ml = ((ml + pad_multiple - 1) // pad_multiple) * pad_multiple
-    ml = max(ml, 1)
-    first = np.asarray(seqs[0])
-    feat = first.shape[1:]
-    dt = dtype or first.dtype
-    out = np.zeros((len(seqs), ml) + tuple(feat), dtype=dt)
-    for i, s in enumerate(seqs):
-        s = np.asarray(s, dtype=dt)
-        out[i, : len(s)] = s
-    return LoDArray(out, lens)
+    with record_event("lod.pack", kind="reader"):
+        lens = np.array([len(s) for s in seqs], dtype=np.int32)
+        ml = int(max_len if max_len is not None
+                 else (lens.max() if len(lens) else 0))
+        if pad_multiple > 1:
+            ml = ((ml + pad_multiple - 1) // pad_multiple) * pad_multiple
+        ml = max(ml, 1)
+        first = np.asarray(seqs[0])
+        feat = first.shape[1:]
+        dt = dtype or first.dtype
+        out = np.zeros((len(seqs), ml) + tuple(feat), dtype=dt)
+        for i, s in enumerate(seqs):
+            s = np.asarray(s, dtype=dt)
+            out[i, : len(s)] = s
+        _M_PACK_REAL.inc(int(lens.sum()))
+        _M_PACK_PADDED.inc(len(seqs) * ml)
+        return LoDArray(out, lens)
 
 
 def lod_from_lens(lens) -> list:

@@ -11,11 +11,15 @@ TPU-native redesign: there is no per-op device kernel to intercept — a block
 compiles to ONE fused XLA computation. So the host profiler records
   * per-op spans in eager mode (the interpreter path — true analog of the
     reference's per-op host events),
-  * trace/compile/dispatch/step spans in jit mode,
+  * the step's own spans in jit mode (``executor.run`` and its children,
+    the reader's feeder thread; PERF.md section 3 lists them),
 and device-side detail comes from ``jax.profiler`` xplane traces (the CUPTI
-analog), started/stopped by the same context manager. Chrome-trace JSON is
-written directly (no proto intermediary) with the same event schema
-timeline.py emits: ph="X" complete events with pid/tid/ts/dur.
+analog; ``fluid.profiler.device_tracer``). Every ``record_event`` is also a
+``jax.profiler.TraceAnnotation``, so while such a trace is taken the same
+spans lie in it, on the profiler's clock, beside the device's events, which
+carry each Fluid op's ``phase/op_type`` scope (``core/executor._run_ops``).
+Chrome-trace JSON is written directly (no proto intermediary) with the same
+event schema timeline.py emits: ph="X" complete events with pid/tid/ts/dur.
 """
 
 from __future__ import annotations
@@ -26,6 +30,9 @@ import threading
 import time
 import uuid
 from contextlib import contextmanager
+
+from jax.profiler import (StepTraceAnnotation as _StepTraceAnnotation,
+                          TraceAnnotation as _TraceAnnotation)
 
 _lock = threading.Lock()
 _enabled = False
@@ -121,23 +128,53 @@ def disable_profiler(sorted_key=None, profile_path=None):
     return summarize(events, sorted_key)
 
 
-@contextmanager
-def record_event(name, kind="op"):
-    """RAII span (reference RecordEvent, profiler.h:98). Near-zero cost when
-    profiling is off."""
-    if not _enabled:
-        yield
-        return
-    t0 = _now()
-    try:
-        yield
-    finally:
-        t1 = _now()
-        with _lock:
-            if _enabled:
-                _events.append(
-                    (kind, name, t0, t1, threading.get_ident(),
-                     _TRACE_ID.get()))
+class record_event:
+    """RAII span (reference RecordEvent, profiler.h:98), the program's one
+    span primitive, with two sinks that see the same spans and change
+    nothing of what the program executes:
+
+    * the ``jax.profiler`` trace: every entry makes a ``TraceAnnotation``
+      (made AT entry: one made earlier records nothing), so the span lies
+      on the profiler's clock beside the device's events while a
+      ``jax.profiler`` session is active and is a no-op otherwise.
+      ``step_num=n`` makes it a ``StepTraceAnnotation``, the outermost span
+      of a step; other ``ids`` (``batch=n`` on a feeder thread) become the
+      event's stats. Parentage is containment on the thread's line;
+    * the in-memory list behind ``enable_profiler`` / ``fluid.profiler``,
+      appended to only while that profiler is enabled.
+
+    A class with ``__slots__`` and not a generator: it runs some ten times
+    a step on the hot path with tracing off."""
+
+    __slots__ = ("name", "kind", "step_num", "ids", "_ann", "_t0")
+
+    def __init__(self, name, kind="op", step_num=None, **ids):
+        self.name = name
+        self.kind = kind
+        self.step_num = step_num
+        self.ids = ids
+
+    def __enter__(self):
+        if self.step_num is None:
+            self._ann = _TraceAnnotation(self.name, **self.ids)
+        else:
+            self._ann = _StepTraceAnnotation(
+                self.name, step_num=self.step_num, **self.ids)
+        self._ann.__enter__()
+        self._t0 = _now() if _enabled else None
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        t0 = self._t0
+        if t0 is not None:
+            t1 = _now()
+            with _lock:
+                if _enabled:
+                    _events.append(
+                        (self.kind, self.name, t0, t1,
+                         threading.get_ident(), _TRACE_ID.get()))
+        self._ann.__exit__(exc_type, exc, tb)
+        return False
 
 
 def events():
@@ -181,8 +218,8 @@ def print_summary(rows, file=None):
 
 def _percentile_sorted(vals, q):
     """q-th percentile of an already-sorted sample (linear interpolation,
-    numpy's default definition — hand-rolled so this module keeps its
-    stdlib-only import surface)."""
+    numpy's default definition — hand-rolled so this module needs no
+    numpy)."""
     if not vals:
         return 0.0
     if len(vals) == 1:

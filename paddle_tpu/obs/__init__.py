@@ -31,8 +31,7 @@ pieces:
   (``paddle_tpu_device_bytes_live``/``_peak``,
   :func:`~.perf.sample_device_memory` / ``MemorySampler``), and the
   cost-attribution API (:func:`~.perf.attribute` AOT HLO/cost-analysis
-  merge, :func:`~.perf.profile` device-trace aggregation) the profiling
-  CLIs are thin argument parsers over.
+  merge) ``tools/hlo_report.py`` is a thin argument parser over.
 * :func:`~.metrics.json_safe` — the wire-safety coercion every
   ``stats()``/``health()`` payload passes through.
 """

@@ -34,20 +34,17 @@ profile-driven kernel work needs:
   compile it, and merges the optimized HLO's static per-instruction
   operand+result bytes (:func:`hlo_shape_bytes`, extracted from
   ``tools/hlo_report.py`` and unit-tested) with the backend's
-  ``cost_analysis()`` totals into a top-N table. :func:`profile` wraps
-  ``jax.profiler.trace`` device-event aggregation (extracted from
-  ``tools/profile_step.py``) around ANY step callable. The two CLIs
-  are argument parsing over these entry points.
+  ``cost_analysis()`` totals into a top-N table; ``tools/hlo_report.py``
+  is argument parsing over it. Device TIME is a trace's to give:
+  ``fluid.profiler.device_tracer(dir)`` around a loop records the device's
+  operations, each under its Fluid op's ``phase/op_type`` scope, beside the
+  program's own spans (``core/profiler.record_event``).
 """
 
 from __future__ import annotations
 
-import glob
-import gzip
-import json
 import os
 import re
-import tempfile
 import threading
 import time
 from collections import deque
@@ -703,119 +700,10 @@ def per_op_rows(rows, total_flops=None):
     return out
 
 
-# ---------------------------------------------------------------------------
-# device-trace profiling (the profile_step.py aggregation, extracted)
-# ---------------------------------------------------------------------------
-
-def aggregate_device_trace(trace_dir):
-    """Aggregate the complete ('X') events of a ``jax.profiler.trace``
-    output directory by event name. Prefers device lanes (process names
-    mentioning TPU/GPU); without any (CPU backend) it aggregates host
-    lanes instead. Returns ``(per_name_us, per_name_count, on_device)``."""
-    files = glob.glob(os.path.join(trace_dir, "**", "*.trace.json.gz"),
-                      recursive=True)
-    per_name, per_name_n = {}, {}
-    on_device = False
-    for path in files:
-        with gzip.open(path) as f:
-            tr = json.load(f)
-        ev = tr.get("traceEvents", [])
-        device_pids = set()
-        for e in ev:
-            if e.get("ph") == "M" and e.get("name") == "process_name":
-                pname = e.get("args", {}).get("name", "")
-                if "TPU" in pname or "GPU" in pname:
-                    device_pids.add(e["pid"])
-        if device_pids:
-            on_device = True
-        for e in ev:
-            if e.get("ph") != "X":
-                continue
-            if device_pids and e.get("pid") not in device_pids:
-                continue
-            name = e["name"]
-            per_name[name] = per_name.get(name, 0) + e.get("dur", 0)
-            per_name_n[name] = per_name_n.get(name, 0) + 1
-    return per_name, per_name_n, on_device
-
-
-def profile(fn, steps=8, warmup=2, trace_dir=None, top=40):
-    """Per-kernel device timing of ANY step callable: run ``warmup``
-    un-traced dispatches, then ``steps`` under ``jax.profiler.trace``,
-    and aggregate the trace's device events by name (host events on
-    backends without device lanes — ``on_device`` says which you got).
-    ``fn`` dispatches one step (a program run, an engine infer, a
-    generation step — anything); its return value is block_until_ready'd
-    best-effort so the measured window is honest.
-
-    Returns ``{"steps", "wall_s_per_step", "on_device",
-    "busy_us_per_step", "by_kind": [...], "top": [...]}`` — ``by_kind``
-    groups trailing ``.N`` fusion indices."""
-    import jax
-
-    out = None
-    for _ in range(int(warmup)):
-        out = fn()
-    _block(out)
-    tmp = trace_dir or tempfile.mkdtemp(prefix="pdtpu_prof_")
-    t0 = time.perf_counter()
-    with jax.profiler.trace(tmp):
-        for _ in range(int(steps)):
-            out = fn()
-        _block(out)
-    wall = time.perf_counter() - t0
-    if not glob.glob(os.path.join(tmp, "**", "*.trace.json.gz"),
-                     recursive=True):
-        # a broken profiler setup (unwritable dir, profiler unavailable)
-        # must not read as a valid 0-ms measurement
-        raise RuntimeError(f"jax.profiler produced no trace under {tmp}")
-    per_name, per_name_n, on_device = aggregate_device_trace(tmp)
-    # drop the outer module/step spans: whole-step 'jit_*' events, bare
-    # numeric per-step spans nested under them, and (host fallback) the
-    # profiler's own '$file.py:line' python-frame events — what's left
-    # is executed kernels/executables
-    leaf = {n: us for n, us in per_name.items()
-            if not n.startswith("jit_") and not n.isdigit()
-            and not n.startswith("$")}
-    total_us = sum(leaf.values())
-    grouped = {}
-    for name, us in leaf.items():
-        base = re.sub(r"\.[0-9]+$", "", name)
-        grouped[base] = grouped.get(base, 0) + us
-    by_kind = [{"name": n, "us_per_step": us / steps,
-                "pct": 100.0 * us / max(total_us, 1)}
-               for n, us in sorted(grouped.items(), key=lambda kv: -kv[1])]
-    top_rows = [{"name": n, "us_per_step": us / steps,
-                 "pct": 100.0 * us / max(total_us, 1),
-                 "count": per_name_n.get(n, 0)}
-                for n, us in sorted(leaf.items(),
-                                    key=lambda kv: -kv[1])[:int(top)]]
-    return json_safe({
-        "steps": int(steps),
-        "wall_s_per_step": wall / max(int(steps), 1),
-        "on_device": on_device,
-        "busy_us_per_step": total_us / max(int(steps), 1),
-        "by_kind": by_kind,
-        "top": top_rows,
-    })
-
-
-def _block(out):
-    import jax
-    try:
-        jax.block_until_ready(out)
-    except Exception:
-        import numpy as np
-        try:
-            np.asarray(out)
-        except Exception:
-            pass
-
-
 __all__ = [
     "COMPILE_LOG", "CompileLog", "CompileRecord", "MemorySampler",
-    "aggregate_device_trace", "attribute", "compile_site", "cost_totals",
+    "attribute", "compile_site", "cost_totals",
     "current_site", "enabled", "harvest_cost", "hlo_entry_rows",
     "hlo_shape_bytes", "lower_program", "memory_section", "note_compile",
-    "per_op_rows", "profile", "sample_device_memory", "template_feed",
+    "per_op_rows", "sample_device_memory", "template_feed",
 ]

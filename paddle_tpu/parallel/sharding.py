@@ -280,7 +280,8 @@ def shard_program_step(executor, program, feed_example, fetch_list, plan,
 
     from ..core.amp import amp_guard
     from ..core.executor import (_analyze_program, _run_ops, _RNG_KEY,
-                                 _is_traceable)
+                                 _is_traceable, _scheme_named)
+    from ..core.profiler import record_event
     from ..core.scope import global_scope
 
     scope = scope or global_scope()
@@ -337,19 +338,27 @@ def shard_program_step(executor, program, feed_example, fetch_list, plan,
     # forwards the xla_compiler_options flag to the backend compiler
     from ..core.executor import tpu_jit
     jitted = tpu_jit(
-        step,
+        _scheme_named(step, "sharded_step"),
         in_shardings=(state_shardings, feed_shardings),
         out_shardings=(state_shardings, None),
         donate_argnums=(0,) if donate else (),
     )
 
+    step_num = 0
+
     def fn(st, fd):
+        # the same two span names Executor.run gives a one-chip step
+        nonlocal step_num
         from ..core.flags import get_flag
-        if get_flag("check_nan_inf"):
-            with jax.debug_nans(True), jax.debug_infs(True):
-                out = jitted(st, fd)
-                jax.block_until_ready(out)
-                return out
-        return jitted(st, fd)
+        step_num += 1
+        with record_event("sharding.step", kind="stage", step_num=step_num):
+            check = get_flag("check_nan_inf")
+            with record_event("executor.enqueue", kind="stage"):
+                if check:
+                    with jax.debug_nans(True), jax.debug_infs(True):
+                        out = jitted(st, fd)
+                        jax.block_until_ready(out)
+                        return out
+                return jitted(st, fd)
 
     return fn, state, feeds

@@ -21,6 +21,18 @@ import threading
 
 import jax
 
+from ..core.profiler import record_event
+from ..obs.metrics import REGISTRY as _METRICS
+
+_M_BATCHES = _METRICS.counter(
+    "paddle_tpu_reader_batches",
+    "batches through reader.prefetch.background_buffer: staged = pulled "
+    "and staged by the feeder thread; starved = handed to a consumer that "
+    "found the queue empty at least once (the step waited for the reader)",
+    labels=("event",))
+_M_STAGED = _M_BATCHES.labels(event="staged")
+_M_STARVED = _M_BATCHES.labels(event="starved")
+
 
 def background_buffer(reader, capacity=2, stage=None, register=None):
     """Record-agnostic bounded background prefetch: returns a creator whose
@@ -33,7 +45,14 @@ def background_buffer(reader, capacity=2, stage=None, register=None):
     (WorkerPool.background uses it to bookkeep stagers and cancel/join
     them at shutdown). One implementation for the feed-dict
     (DeviceFeedIterator), slot-tuple (reader-graph op), and pool-staging
-    flavors."""
+    flavors.
+
+    Spans (``core.profiler.record_event``; in a ``jax.profiler`` trace while
+    one is taken): on the feeder thread ``reader.pull`` (``next()`` on the
+    decorated reader) and ``reader.stage``, both with the batch's ordinal as
+    ``batch=n``, and ``reader.put_wait`` while the full queue blocks it (the
+    reader is ahead); on the consumer ``reader.get_wait`` while the empty
+    queue blocks it (the step is starved)."""
 
     def make():
         q = _queue.Queue(maxsize=max(1, int(capacity)))
@@ -45,20 +64,55 @@ def background_buffer(reader, capacity=2, stage=None, register=None):
             # stop check a `break` out of the consuming loop would leave the
             # feeder blocked forever on the full queue, pinning its staged
             # (device-resident) batches and the open readers
-            while True:
-                try:
-                    q.put(item, timeout=0.05)
-                    return True
-                except _queue.Full:
-                    if stop.is_set():
-                        return False
+            try:
+                q.put_nowait(item)
+                return True
+            except _queue.Full:
+                pass
+            with record_event("reader.put_wait", kind="reader"):
+                while True:
+                    try:
+                        q.put(item, timeout=0.05)
+                        return True
+                    except _queue.Full:
+                        if stop.is_set():
+                            return False
+
+        def get():
+            try:
+                return q.get_nowait()
+            except _queue.Empty:
+                pass
+            _M_STARVED.inc()
+            with record_event("reader.get_wait", kind="reader"):
+                while True:
+                    try:
+                        return q.get(timeout=0.05)
+                    except _queue.Empty:
+                        if stop.is_set():
+                            # cancelled externally (pool shutdown): the
+                            # feeder is gone and may not have managed to
+                            # enqueue the end sentinel — fail loudly
+                            # instead of hanging
+                            raise RuntimeError(
+                                "background reader cancelled mid-stream")
 
         def feed():
             try:
-                for item in reader():
-                    if not put(stage(item) if stage is not None else item) \
-                            or stop.is_set():
+                it, n = iter(reader()), 0
+                while True:
+                    with record_event("reader.pull", kind="reader", batch=n):
+                        item = next(it, end)
+                    if item is end:
                         return
+                    if stage is not None:
+                        with record_event("reader.stage", kind="reader",
+                                          batch=n):
+                            item = stage(item)
+                    _M_STAGED.inc()
+                    if not put(item) or stop.is_set():
+                        return
+                    n += 1
             except BaseException as e:   # surface in consumer
                 err.append(e)
             finally:
@@ -70,16 +124,7 @@ def background_buffer(reader, capacity=2, stage=None, register=None):
         t.start()
         try:
             while True:
-                try:
-                    item = q.get(timeout=0.05)
-                except _queue.Empty:
-                    if stop.is_set():
-                        # cancelled externally (pool shutdown): the feeder
-                        # is gone and may not have managed to enqueue the
-                        # end sentinel — fail loudly instead of hanging
-                        raise RuntimeError(
-                            "background reader cancelled mid-stream")
-                    continue
+                item = get()
                 if item is end:
                     if err:
                         raise err[0]

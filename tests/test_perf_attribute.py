@@ -1,7 +1,7 @@
 """Cost attribution (obs.perf): the extracted HLO shape-bytes estimator
 (hardened for scalar and tuple-nested shapes), ``attribute()`` over
-programs / bundles / engines, ``profile()`` device-trace aggregation,
-and the profiling CLIs' shared ``--bundle`` scaffolding.
+programs / bundles / engines, and ``tools/hlo_report.py``'s ``--bundle``
+scaffolding.
 """
 
 import json
@@ -121,46 +121,6 @@ def test_attribute_bundle_dir_and_engine(tmp_path):
     eng = InferenceEngine(d, buckets=[2])
     res2 = perf.attribute(eng, batch=2, top=5)
     assert res2["instructions"] > 0
-
-
-# ---------------------------------------------------------------------------
-# profile(): device-trace aggregation over any step callable
-# ---------------------------------------------------------------------------
-
-def test_profile_any_step_callable(tmp_path):
-    main, startup, loss = build_mlp()
-    exe = fluid.Executor()
-    scope = fluid.Scope()
-    exe.run(startup, scope=scope)
-    feed = mlp_feed(4)
-
-    def step():
-        return exe.run(main, feed=feed, fetch_list=[loss], scope=scope,
-                       return_numpy=False)
-
-    res = perf.profile(step, steps=2, warmup=1,
-                       trace_dir=str(tmp_path / "trace"))
-    json.dumps(res)
-    assert res["steps"] == 2
-    assert res["wall_s_per_step"] > 0
-    # CPU backend: no device lanes — the host fallback is flagged
-    assert res["on_device"] is False
-    assert isinstance(res["by_kind"], list)
-    assert isinstance(res["top"], list)
-
-
-def test_profile_raises_when_no_trace_produced(tmp_path, monkeypatch):
-    """A broken profiler setup must not read as a valid 0-ms
-    measurement (the old CLI asserted; the API raises typed)."""
-    import contextlib
-    import jax
-    monkeypatch.setattr(jax.profiler, "trace",
-                        lambda _d: contextlib.nullcontext())
-    with pytest.raises(RuntimeError, match="no trace"):
-        perf.profile(lambda: None, steps=1, warmup=0,
-                     trace_dir=str(tmp_path / "sub"))
-    # the parser itself stays tolerant: an empty dir aggregates empty
-    assert perf.aggregate_device_trace(str(tmp_path)) == ({}, {}, False)
 
 
 # ---------------------------------------------------------------------------

@@ -63,8 +63,8 @@ def test_profiler_jit_step_spans():
         exe.run(main, feed=_feed(rng), fetch_list=[loss])
     rows = core_prof.disable_profiler(sorted_key="calls")
     byname = {r["name"]: r for r in rows}
-    assert byname["jit_step_dispatch"]["calls"] == 2
-    assert byname["jit_step_device"]["calls"] == 2
+    assert byname["executor.run"]["calls"] == 2
+    assert byname["executor.enqueue"]["calls"] == 2
 
 
 def test_profiler_off_records_nothing():

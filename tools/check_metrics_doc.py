@@ -44,6 +44,7 @@ def registered_metrics():
     import paddle_tpu.ops.autotune          # noqa: F401
     import paddle_tpu.ops.pallas            # noqa: F401
     import paddle_tpu.parallel.planner      # noqa: F401
+    import paddle_tpu.reader.prefetch       # noqa: F401
     import paddle_tpu.serving.autoscale     # noqa: F401
     import paddle_tpu.serving.batcher       # noqa: F401
     import paddle_tpu.serving.engine        # noqa: F401

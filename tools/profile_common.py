@@ -1,13 +1,12 @@
-"""Shared build/feed scaffolding for the profiling CLIs.
+"""Build/feed scaffolding for ``tools/hlo_report.py``.
 
-``tools/profile_step.py`` and ``tools/hlo_report.py`` used to duplicate
-the flagship ResNet-50 build (program + pre-staged bf16 feeds + jit
-executor + startup under bf16 matmul precision); this module is the one
-copy, plus a ``--bundle`` target so ANY published model — a
+The flagship ResNet-50 build (program + pre-staged bf16 feeds + jit
+executor + startup under bf16 matmul precision), plus a ``--bundle``
+target so ANY published model — a
 ``save_inference_model`` export dir or a registry ``<model>/<version>``
 dir — can be profiled, not just the flagship.
 
-Both CLIs consume a :class:`Target`: the program, a rotating feed list,
+The CLI consumes a :class:`Target`: the program, a rotating feed list,
 the fetch names, the executor/scope that would dispatch it in
 production, and a ``ctx()`` context manager reproducing the numeric
 environment the target trains/serves under.
@@ -45,8 +44,7 @@ class Target:
             else contextlib.nullcontext()
 
     def step_fn(self):
-        """A zero-arg one-dispatch callable cycling the staged feeds —
-        what ``obs.perf.profile`` drives."""
+        """A zero-arg one-dispatch callable cycling the staged feeds."""
         i = [0]
 
         def step():
