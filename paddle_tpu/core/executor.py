@@ -590,6 +590,11 @@ class _InstrumentedFn:
         # AOT entry (obs.perf.lower_program, tools/hlo_report.py)
         return self._fn.lower(*args, **kwargs)
 
+    @property
+    def traceable(self):
+        # the jitted step itself (obs.perf.program_jaxpr)
+        return self._fn
+
 
 class Executor:
     """User-facing executor (reference python/paddle/fluid/executor.py Executor).

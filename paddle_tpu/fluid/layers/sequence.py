@@ -31,11 +31,17 @@ def dynamic_lstm(input, size, param_attr=None, bias_attr=None,
                                    is_bias=True)
     hidden_out = helper.create_tmp_variable(dtype, lod_level=input.lod_level)
     cell_out = helper.create_tmp_variable(dtype, lod_level=input.lod_level)
+    # saved for lstm_grad, like the reference's BatchGate/BatchCellPreAct
+    # (lstm_op.cc): the recurrence's carries as it ran, time-major
+    batch_hidden = helper.create_tmp_variable(dtype, stop_gradient=True)
+    batch_cell = helper.create_tmp_variable(dtype, stop_gradient=True)
     helper.append_op(
         "lstm",
         inputs={"Input": [input.name], "Weight": [weight.name],
                 "Bias": [bias.name]},
-        outputs={"Hidden": [hidden_out.name], "Cell": [cell_out.name]},
+        outputs={"Hidden": [hidden_out.name], "Cell": [cell_out.name],
+                 "BatchHidden": [batch_hidden.name],
+                 "BatchCell": [batch_cell.name]},
         attrs={"use_peepholes": use_peepholes, "is_reverse": is_reverse,
                "gate_activation": gate_activation,
                "cell_activation": cell_activation,

@@ -528,16 +528,11 @@ def template_feed(program, feed_names, batch=1):
     return feed
 
 
-def lower_program(program, feed, fetch_list, executor=None, scope=None,
-                  donate_feeds=()):
-    """AOT-lower one dispatch of ``program`` exactly as ``Executor.run``
-    would compile it (same state/feed surface resolution, same jit
-    wrapper) and compile it for the attached backend. ``donate_feeds``
-    names feeds that ride the donated third jit argument (the engine's
-    KV-arena donation) — the lowered signature must match how the engine
-    dispatches. Returns ``(lowered, compiled)``."""
+def _program_dispatch(program, feed, fetch_list, executor, scope,
+                      donate_feeds):
+    """(executor, compiled-step wrapper, its arguments) of one dispatch of
+    ``program``, resolved exactly as ``Executor.run`` resolves them."""
     import jax
-    from ..core.amp import amp_guard
     from ..core.executor import (Executor, _RNG_KEY, _collect_free_inputs,
                                  _written_names)
     from ..core.scope import global_scope
@@ -566,10 +561,36 @@ def lower_program(program, feed, fetch_list, executor=None, scope=None,
                        state_in, state_out, tuple(sorted(donated)))
     state = {n: scope.find_var(n) for n in state_in}
     state[_RNG_KEY] = scope.find_var(_RNG_KEY)
-    lower_args = (state, feed) + ((donated,) if donated else ())
+    return exe, fn, (state, feed) + ((donated,) if donated else ())
+
+
+def lower_program(program, feed, fetch_list, executor=None, scope=None,
+                  donate_feeds=()):
+    """AOT-lower one dispatch of ``program`` exactly as ``Executor.run``
+    would compile it (same state/feed surface resolution, same jit
+    wrapper) and compile it for the attached backend. ``donate_feeds``
+    names feeds that ride the donated third jit argument (the engine's
+    KV-arena donation) — the lowered signature must match how the engine
+    dispatches. Returns ``(lowered, compiled)``."""
+    from ..core.amp import amp_guard
+
+    exe, fn, args = _program_dispatch(program, feed, fetch_list, executor,
+                                      scope, donate_feeds)
     with amp_guard(exe.amp):
-        lowered = fn.lower(*lower_args)
+        lowered = fn.lower(*args)
     return lowered, lowered.compile()
+
+
+def program_jaxpr(program, feed, fetch_list, executor=None, scope=None):
+    """The jaxpr of the same dispatch: what the step holds before XLA sees
+    it (which primitives, how many of each), for structural tests."""
+    import jax
+    from ..core.amp import amp_guard
+
+    exe, fn, args = _program_dispatch(program, feed, fetch_list, executor,
+                                      scope, ())
+    with amp_guard(exe.amp):
+        return jax.make_jaxpr(fn.traceable)(*args)
 
 
 def cost_totals(compiled):
@@ -705,5 +726,5 @@ __all__ = [
     "attribute", "compile_site", "cost_totals",
     "current_site", "enabled", "harvest_cost", "hlo_entry_rows",
     "hlo_shape_bytes", "lower_program", "memory_section", "note_compile",
-    "per_op_rows", "sample_device_memory", "template_feed",
+    "per_op_rows", "program_jaxpr", "sample_device_memory", "template_feed",
 ]

@@ -49,8 +49,11 @@ from ...obs.metrics import REGISTRY as _METRICS
 # step was not slower than the twin's in a same-process A/B. Taken on a
 # TPU v5 lite, jax 0.9.0 (PR 21; full lines in CHANGES.md / PERF.md):
 #   lstm          IN   bitwise equal to the scan twin; recurrence 0.458 vs
-#                      0.558 ms (1.22x), LSTM-lane train step 3.386 vs
-#                      3.389 ms (a tie, spread +-0.6%)
+#                      0.558 ms (1.22x, PR 21). With the backward a kernel
+#                      too (lstm_bwd, PR 27) the benchmark's LSTM cells
+#                      read 41.0 ms a step at 256 x 512 (62.3 before) and
+#                      +45% samples/s over ragged lengths; the reverse
+#                      kernel is 1.9-2.7x its scan at L = 64..512
 #   conv_bn       out  lowers, but 0.2-0.65x of XLA's conv+BN fusions at
 #                      6 of 7 ResNet-50 shapes; fused flagship step 318.6
 #                      vs 102.5 ms unfused

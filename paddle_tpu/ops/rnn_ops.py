@@ -57,6 +57,45 @@ def _reverse_padded(data, lens):
     return jnp.take_along_axis(data, idx, axis=1)
 
 
+def _lstm_route(x, gate_act, cell_act, cand_act, has_peepholes):
+    """"pallas" or "jnp" for this call's recurrence; the forward op and its
+    grad op ask the same question and get the same answer."""
+    from .autotune import dispatch_variant, make_key
+
+    # the Pallas fused cell implements the standard activation set (the
+    # reference's hand-scheduled hl_cuda_lstm.cu does the same); other
+    # activations / peepholes fall back to the scan with a counter bump
+    supported = (not has_peepholes
+                 and (gate_act, cell_act, cand_act)
+                 == ("sigmoid", "tanh", "tanh"))
+    return dispatch_variant(
+        "rnn",
+        make_key(cell="lstm", x=tuple(x.shape), dtype=str(x.dtype)),
+        {"jnp": True, "pallas": supported}, tier_kernel="lstm")
+
+
+def _alive_mask(L, lens, dtype):
+    """[L, b, 1] prefix mask: 1 where step t is inside the row's length."""
+    return (jnp.arange(L)[:, None] < lens[None, :]).astype(dtype)[..., None]
+
+
+def _lstm_pallas(x, lens, w, h0, c0):
+    """The whole-recurrence kernel: ONE launch for the full sequence with
+    the recurrent weight VMEM-resident across steps (see
+    ops/pallas/rnn.lstm_seq_pallas). Returns the masked hidden/cell
+    [b, L, H] and the kernel's own carries [L, b, H], which lstm_grad
+    starts from."""
+    from .pallas import kernel_span
+    from .pallas.rnn import lstm_seq_pallas
+
+    with kernel_span("pallas", "lstm"):
+        xt = jnp.swapaxes(x, 0, 1)                   # [L, b, 4H]
+        alive = _alive_mask(x.shape[1], lens, x.dtype)
+        hs, cs = lstm_seq_pallas(xt, alive, w, h0, c0)
+        return (jnp.swapaxes(hs * alive, 0, 1),
+                jnp.swapaxes(cs * alive, 0, 1), (hs, cs))
+
+
 def _lstm_scan(x, lens, w, h0, c0, gate_act, cell_act, cand_act,
                peepholes=None):
     """x: [b, L, 4H] projected inputs (+bias already added); w: [H, 4H].
@@ -64,36 +103,18 @@ def _lstm_scan(x, lens, w, h0, c0, gate_act, cell_act, cand_act,
     diagonal cell->gate connections (math/detail/lstm_kernel.h:37-40:
     i/f see the PREVIOUS cell state, o sees the NEW one). Returns
     hidden [b, L, H], cell [b, L, H]."""
-    from .autotune import dispatch_variant, make_key
-    from .pallas import kernel_span
+    if _lstm_route(x, gate_act, cell_act, cand_act,
+                   peepholes is not None) == "pallas":
+        return _lstm_pallas(x, lens, w, h0, c0)[:2]
+    return _lstm_jnp_scan(x, lens, w, h0, c0, gate_act, cell_act, cand_act,
+                          peepholes)
 
-    b, L, H4 = x.shape
-    H = H4 // 4
+
+def _lstm_jnp_scan(x, lens, w, h0, c0, gate_act, cell_act, cand_act,
+                   peepholes):
+    """The jnp twin: one lax.scan over time, any activations, peepholes."""
+    H = x.shape[-1] // 4
     ga, ca, cda = _act(gate_act), _act(cell_act), _act(cand_act)
-    # the Pallas fused cell implements the standard activation set (the
-    # reference's hand-scheduled hl_cuda_lstm.cu does the same); other
-    # activations / peepholes fall back to the scan with a counter bump
-    supported = (peepholes is None
-                 and (gate_act, cell_act, cand_act)
-                 == ("sigmoid", "tanh", "tanh"))
-    choice = dispatch_variant(
-        "rnn",
-        make_key(cell="lstm", x=tuple(x.shape), dtype=str(x.dtype)),
-        {"jnp": True, "pallas": supported}, tier_kernel="lstm")
-
-    if choice == "pallas":
-        # whole-recurrence kernel: ONE launch for the full sequence with
-        # the recurrent weight VMEM-resident across steps (see
-        # ops/pallas/rnn.lstm_seq_pallas)
-        from .pallas.rnn import lstm_seq_pallas
-        with kernel_span("pallas", "lstm"):
-            xt = jnp.swapaxes(x, 0, 1)               # [L, b, 4H]
-            alive = (jnp.arange(L)[:, None] < lens[None, :]) \
-                .astype(x.dtype)[..., None]          # [L, b, 1]
-            hs, cs = lstm_seq_pallas(xt, alive, w, h0, c0)
-            hs = hs * alive
-            cs = cs * alive
-            return jnp.swapaxes(hs, 0, 1), jnp.swapaxes(cs, 0, 1)
 
     def step(carry, inp):
         h_prev, c_prev, t = carry
@@ -125,7 +146,16 @@ def _lstm_scan(x, lens, w, h0, c0, gate_act, cell_act, cand_act,
     return jnp.swapaxes(hs, 0, 1), jnp.swapaxes(cs, 0, 1)
 
 
-def _lstm_compute(x, lens, w, bias, h0, c0, attrs):
+def _lstm_acts(attrs):
+    return (attrs.get("gate_activation", "sigmoid"),
+            attrs.get("cell_activation", "tanh"),
+            attrs.get("candidate_activation", "tanh"))
+
+
+def _lstm_scan_inputs(x, lens, bias, h0, c0, attrs):
+    """What the recurrence itself consumes: x with the gate bias added and,
+    for is_reverse, each row's valid prefix reversed; zero initial states
+    where none were given; the peephole weights out of a 7H bias."""
     b, L, H4 = x.shape
     H = H4 // 4
     peepholes = None
@@ -140,25 +170,38 @@ def _lstm_compute(x, lens, w, bias, h0, c0, attrs):
         h0 = jnp.zeros((b, H), x.dtype)
     if c0 is None:
         c0 = jnp.zeros((b, H), x.dtype)
-    rev = attrs.get("is_reverse", False)
-    if rev:
+    if attrs.get("is_reverse", False):
         x = _reverse_padded(x, lens)
-    hs, cs = _lstm_scan(x, lens, w,
-                        h0, c0,
-                        attrs.get("gate_activation", "sigmoid"),
-                        attrs.get("cell_activation", "tanh"),
-                        attrs.get("candidate_activation", "tanh"),
-                        peepholes=peepholes)
-    if rev:
+    return x, h0, c0, peepholes
+
+
+def _lstm_compute(x, lens, w, bias, h0, c0, attrs):
+    """(hidden, cell, carries): the op's outputs [b, L, H] and, where the
+    kernel ran, its carries [L, b, H] in the recurrence's own order (rows
+    reversed for is_reverse); None on the jnp path."""
+    x, h0, c0, peepholes = _lstm_scan_inputs(x, lens, bias, h0, c0, attrs)
+    acts = _lstm_acts(attrs)
+    carries = None
+    if _lstm_route(x, *acts, peepholes is not None) == "pallas":
+        hs, cs, carries = _lstm_pallas(x, lens, w, h0, c0)
+    else:
+        hs, cs = _lstm_jnp_scan(x, lens, w, h0, c0, *acts, peepholes)
+    if attrs.get("is_reverse", False):
         hs = _reverse_padded(hs, lens)
         cs = _reverse_padded(cs, lens)
-    return hs, cs
+    return hs, cs, carries
 
 
 def _lstm_grad_maker(op):
     inputs = {"Input": op.input("Input"), "Weight": op.input("Weight"),
               "Hidden@GRAD": G(op.output("Hidden")),
               "Cell@GRAD": G(op.output("Cell"))}
+    # the carries the forward saved, where its op has the slots (the
+    # reference's lstm_grad takes BatchGate/BatchCellPreAct the same way,
+    # lstm_op.cc): the grad op then does not run the forward again
+    for slot in ("BatchHidden", "BatchCell"):
+        if op.output(slot):
+            inputs[slot] = op.output(slot)
     outputs = {"Input@GRAD": G(op.input("Input")),
                "Weight@GRAD": G(op.input("Weight"))}
     for slot in ("Bias", "H0", "C0"):
@@ -197,9 +240,40 @@ def lstm(ctx):
         bias = bias.reshape(-1)
     h0 = data_of(ctx.input("H0")) if ctx.has_input("H0") else None
     c0 = data_of(ctx.input("C0")) if ctx.has_input("C0") else None
-    hs, cs = _lstm_compute(x, lens, w, bias, h0, c0, ctx.op.attrs)
+    hs, cs, carries = _lstm_compute(x, lens, w, bias, h0, c0, ctx.op.attrs)
     ctx.set_output("Hidden", LoDArray(hs, lens))
     ctx.set_output("Cell", LoDArray(cs, lens))
+    if carries is not None:
+        ctx.set_output("BatchHidden", carries[0])
+        ctx.set_output("BatchCell", carries[1])
+
+
+def _lstm_grad_from_carries(x, lens, w, bias, h0, c0, carries, dhs, dcs,
+                            attrs):
+    """The Pallas path's gradients from the carries its forward saved
+    ([L, b, H], the recurrence's own order): the whole-sequence backward of
+    ops/pallas/rnn.py inside, the transposes of _lstm_compute's outer
+    pieces by hand around it. dhs/dcs are the gradients of Hidden/Cell
+    [b, L, H]. Returns (dx, dw, dbias, dh0, dc0), dbias for the 4H gate
+    bias."""
+    from .pallas.rnn import lstm_seq_bwd
+
+    x, h0, c0, _ = _lstm_scan_inputs(x, lens, bias, h0, c0, attrs)
+    rev = attrs.get("is_reverse", False)
+    if rev:
+        # _reverse_padded permutes each row onto itself and is its own
+        # inverse, hence its own transpose: the incoming gradients go back
+        # to the recurrence's order the way x went there
+        dhs, dcs = _reverse_padded(dhs, lens), _reverse_padded(dcs, lens)
+    alive = _alive_mask(x.shape[1], lens, x.dtype)
+    # the output mask's transpose is the same mask on the gradients
+    dx, dw, dh0, dc0 = lstm_seq_bwd(
+        jnp.swapaxes(x, 0, 1), alive, w, h0, c0, *carries,
+        jnp.swapaxes(dhs, 0, 1) * alive, jnp.swapaxes(dcs, 0, 1) * alive)
+    dx = jnp.swapaxes(dx, 0, 1)
+    if rev:
+        dx = _reverse_padded(dx, lens)
+    return dx, dw, dx.sum((0, 1)), dh0, dc0
 
 
 @register_op("lstm_grad")
@@ -224,24 +298,37 @@ def lstm_grad(ctx):
     if ctx.has_input("C0"):
         operands["C0"] = data_of(ctx.input("C0"))
     names = list(operands)
+    if ctx.has_input("BatchHidden") and ctx.has_input("BatchCell"):
+        # the forward ran the kernel: start from the carries it saved
+        grads = dict(zip(
+            ("Input", "Weight", "Bias", "H0", "C0"),
+            _lstm_grad_from_carries(
+                x, lens, w, operands.get("Bias"), operands.get("H0"),
+                operands.get("C0"),
+                (data_of(ctx.input("BatchHidden")),
+                 data_of(ctx.input("BatchCell"))),
+                gd("Hidden@GRAD"), gd("Cell@GRAD"), attrs)))
+    else:
+        # the jnp twin's path (peepholes, other activations, the CPU; an
+        # lstm op built without the two slots): jax.vjp over the scan,
+        # which saves its own residuals
+        def f(*args):
+            kw = dict(zip(names, args))
+            return _lstm_compute(kw["Input"], lens, kw["Weight"],
+                                 kw.get("Bias"), kw.get("H0"), kw.get("C0"),
+                                 attrs)[:2]
 
-    def f(*args):
-        kw = dict(zip(names, args))
-        return _lstm_compute(kw["Input"], lens, kw["Weight"], kw.get("Bias"),
-                             kw.get("H0"), kw.get("C0"), attrs)
-
-    _, vjp = jax.vjp(f, *operands.values())
-    grads = dict(zip(names, vjp((gd("Hidden@GRAD"), gd("Cell@GRAD")))))
+        _, vjp = jax.vjp(f, *operands.values())
+        grads = dict(zip(names, vjp((gd("Hidden@GRAD"), gd("Cell@GRAD")))))
     dx = grads["Input"]
     ctx.set_output("Input@GRAD",
                    LoDArray(dx, lens) if isinstance(xv, LoDArray) else dx)
     ctx.set_output("Weight@GRAD", grads["Weight"])
-    if "Bias" in grads:
-        ctx.set_output("Bias@GRAD", grads["Bias"].reshape(1, -1))
-    if "H0" in grads:
-        ctx.set_output("H0@GRAD", grads["H0"])
-    if "C0" in grads:
-        ctx.set_output("C0@GRAD", grads["C0"])
+    for slot in ("Bias", "H0", "C0"):
+        if slot in operands:
+            g = grads[slot]
+            ctx.set_output(slot + "@GRAD",
+                           g.reshape(1, -1) if slot == "Bias" else g)
 
 
 # ---------------------------------------------------------------------------
@@ -276,8 +363,7 @@ def _gru_compute(x, lens, w, bias, h0, attrs):
         from .pallas.rnn import gru_seq_pallas
         with kernel_span("pallas", "gru"):
             xs = jnp.swapaxes(x, 0, 1)               # [L, b, 3H]
-            alive = (jnp.arange(L)[:, None] < lens[None, :]) \
-                .astype(x.dtype)[..., None]          # [L, b, 1]
+            alive = _alive_mask(L, lens, x.dtype)
             hs = gru_seq_pallas(xs, alive, w, h0) * alive
             hs = jnp.swapaxes(hs, 0, 1)
         if rev:

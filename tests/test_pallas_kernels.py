@@ -145,6 +145,96 @@ def test_lstm_seq_kernel_matches_jnp_twin():
         np.testing.assert_allclose(a, b_, rtol=2e-4, atol=1e-5,
                                    err_msg=name)
 
+def _lstm_bwd_case(L, b, H, lens, with_state, seed=6):
+    """Inputs of the whole-sequence backward and what jax.grad of the jnp
+    twin scan says the gradients are, for cotangents on BOTH outputs."""
+    from paddle_tpu.ops.pallas.rnn import _lstm_step_jnp
+
+    rng = np.random.RandomState(seed)
+
+    def arr(*shape, scale=1.0):
+        return jnp.asarray(rng.normal(0, scale, shape).astype("float32"))
+
+    x = arr(L, b, 4 * H)
+    lens = jnp.asarray(lens, jnp.int32)
+    alive = (jnp.arange(L)[:, None] < lens[None, :]) \
+        .astype(jnp.float32)[..., None]
+    w = arr(H, 4 * H, scale=0.5 / np.sqrt(H / 8))
+    h0, c0 = (arr(b, H), arr(b, H)) if with_state \
+        else (jnp.zeros((b, H)), jnp.zeros((b, H)))
+    dhs, dcs = arr(L, b, H), arr(L, b, H)
+
+    def twin(x, w, h0, c0):
+        def step(carry, inp):
+            h, c = _lstm_step_jnp(inp[0], *carry, w, inp[1])
+            return (h, c), (h, c)
+        return jax.lax.scan(step, (h0, c0), (x, alive))[1]
+
+    (hs, cs), vjp = jax.vjp(twin, x, w, h0, c0)
+    dx, dw, dh0, dc0 = vjp((dhs, dcs))
+    return (x, alive, w, h0, c0, hs, cs, dhs, dcs), (dx, dw, dh0, dc0)
+
+
+def _assert_lstm_grads(got, exp):
+    for a, e, name in zip(got, exp, ("dx", "dw", "dh0", "dc0")):
+        np.testing.assert_allclose(a, e, rtol=2e-4, atol=1e-5,
+                                   err_msg=name)
+
+
+@pytest.mark.parametrize("masked", [False, True],
+                         ids=["carries", "masked_outputs"])
+@pytest.mark.parametrize("with_state", [False, True],
+                         ids=["zero_state", "h0_c0"])
+@pytest.mark.parametrize("path", ["kernel", "scan"])
+def test_lstm_seq_bwd_matches_grad_of_jnp_twin(path, with_state, masked):
+    """The backward from saved carries — the reverse kernel (interpret
+    mode) and the scan it falls back to, each with the weight gradient
+    taken after the loop — against jax.grad of the jnp twin scan: ragged
+    lengths with a length-1 and a full-length row, dhs and dcs both
+    non-zero, from the carries and from the op's masked outputs."""
+    from paddle_tpu.ops.pallas import rnn
+
+    L, b, H = 6, 4, 8
+    args, exp = _lstm_bwd_case(L, b, H, [6, 3, 5, 1], with_state)
+    x, alive, w, h0, c0, hs, cs, dhs, dcs = args
+    if masked:
+        hs, cs = hs * alive, cs * alive
+    if path == "kernel":
+        got = rnn.lstm_seq_bwd(x, alive, w, h0, c0, hs, cs, dhs, dcs)
+    else:
+        dx, dh0, dc0 = rnn._lstm_seq_bwd_scan(
+            x, alive, w.astype(jnp.bfloat16), h0, c0, hs, cs, dhs, dcs)
+        got = (dx, rnn._lstm_dw(h0, hs, dx).astype(w.dtype), dh0, dc0)
+    _assert_lstm_grads(got, exp)
+
+
+def test_lstm_seq_bwd_over_vmem_budget_falls_back_counted():
+    """A batch whose blocks exceed the kernel's VMEM budget (counted from
+    the shape alone) takes the scan, says so on the fallback counter, and
+    still gives the twin's gradients."""
+    from paddle_tpu.ops import pallas as tier
+    from paddle_tpu.ops.pallas import rnn
+
+    b, H = 1024, 512
+    assert rnn.lstm_bwd_fits(256, H) and not rnn.lstm_bwd_fits(b, H)
+    args, exp = _lstm_bwd_case(2, b, H, [2] * (b - 1) + [1], True)
+    tier.reset_fallback_counts()
+    before = tier.dispatch_counts().get("lstm_bwd", {})
+    try:
+        got = rnn.lstm_seq_bwd(*args)
+        assert tier.fallback_counts() == {"lstm_bwd": 1}
+        assert tier.dispatch_counts().get("lstm_bwd", {}) == before
+    finally:
+        tier.reset_fallback_counts()
+    # products over 2048 bf16 operands: where hand-written and autodiff
+    # arithmetic differ in a last float32 bit an operand rounds the other
+    # way, a 2**-9 step of one term; against each gradient's largest
+    # value that reads 3e-5..4.5e-4 over three seeds. The element-wise
+    # 2e-4 is held at the small shape above.
+    for a, e, name in zip(got, exp, ("dx", "dw", "dh0", "dc0")):
+        assert np.abs(a - e).max() <= 1.5e-3 * np.abs(e).max(), name
+
+
 def test_gru_op_parity_with_pallas_flag():
     layers = fluid.layers
 

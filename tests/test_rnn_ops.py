@@ -344,3 +344,152 @@ def test_simple_rnn_without_bias():
     assert np.isfinite(float(got))
     # grad restores the (size, size) parameter shape
     assert np.asarray(gb).shape == (4, 4)
+
+
+# ---------------------------------------------------------------------------
+# lstm_grad from the forward's saved Hidden/Cell (the Pallas tier's path)
+# ---------------------------------------------------------------------------
+
+def _two_layer_lstm(hidden=8):
+    """embedding -> fc -> lstm -> fc -> lstm(is_reverse) -> average pool
+    -> fc -> mse; both lstm ops carry a bias. Returns the programs, the loss
+    and, per lstm op, the names of its Input/Weight/Bias gradients."""
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.fluid import framework
+    layers = fluid.layers
+    framework.reset_unique_name()
+    main, startup = fluid.Program(), fluid.Program()
+    main.random_seed = startup.random_seed = 17
+    with fluid.program_guard(main, startup):
+        x = layers.data("x", shape=[1], dtype="int64", lod_level=1)
+        net = layers.embedding(x, size=[12, 6])
+        for rev in (False, True):
+            proj = layers.fc(net, size=hidden * 4)
+            net, _ = layers.dynamic_lstm(proj, size=hidden * 4,
+                                         is_reverse=rev)
+        # every step's output feeds the loss (the reversed layer's LAST
+        # step is its first and would not see the recurrent weight)
+        pred = layers.fc(layers.sequence_pool(net, "average"), size=1)
+        label = layers.data("y", shape=[1])
+        loss = layers.mean(layers.square(
+            layers.elementwise_sub(pred, label)))
+        fluid.optimizer.SGD(learning_rate=0.1).minimize(loss, startup)
+    grads = [n + "@GRAD" for op in main.global_block().ops
+             if op.type == "lstm"
+             for n in (op.input("Input")[0], op.input("Weight")[0],
+                       op.input("Bias")[0])]
+    return main, startup, loss, grads
+
+
+def _two_layer_feed():
+    rng = np.random.RandomState(5)
+    lens = (1, 6, 3, 4, 6)           # a length-1 row and full-length rows
+    return {"x": [rng.randint(0, 12, (n, 1)).astype("int64") for n in lens],
+            "y": rng.normal(0, 1, (len(lens), 1)).astype("float32")}
+
+
+@pytest.fixture
+def _tier_reset():
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.ops import pallas as tier
+    yield
+    fluid.set_flags({"kernel_tier": "auto"})
+    tier.reset_fallback_counts()
+
+
+def test_two_layer_lstm_grads_agree_across_tiers(_tier_reset):
+    """Weight@GRAD, Input@GRAD and Bias@GRAD of both layers (one reversed)
+    under kernel_tier=pallas — lstm_grad from the carries the forward op
+    saved, the outer pieces transposed by hand — against kernel_tier=jnp, jax.vjp over the
+    scan. The Pallas tier's products take bf16 operands where the CPU's
+    jnp scan multiplies in float32, so the two agree to bf16's resolution
+    (the kernel-level tests hold the tight 2e-4 against the bf16 twin);
+    a wrong mask, reversal, bias sum or transpose is off by its own size."""
+    import paddle_tpu.fluid as fluid
+
+    def run(tier_name):
+        fluid.set_flags({"kernel_tier": tier_name})
+        main, startup, loss, grads = _two_layer_lstm()
+        exe = fluid.Executor(fluid.CPUPlace())
+        scope = fluid.Scope()
+        exe.run(startup, scope=scope)
+        outs = exe.run(main, feed=_two_layer_feed(),
+                       fetch_list=[loss] + grads, scope=scope,
+                       return_numpy=False)
+        vals = [np.asarray(getattr(o, "data", o)) for o in outs]
+        return grads, vals[1:]
+
+    names, base = run("jnp")
+    _, got = run("pallas")
+    assert len(names) == 6
+    for name, a, b in zip(names, got, base):
+        assert a.shape == b.shape, name
+        scale = np.abs(b).max()
+        assert scale > 0, name
+        np.testing.assert_allclose(a, b, rtol=5e-3, atol=4e-3 * scale,
+                                   err_msg=name)
+
+
+@pytest.mark.parametrize("is_reverse", [False, True])
+@pytest.mark.parametrize("with_state", [False, True])
+def test_lstm_grad_from_carries_matches_vjp_of_compute(
+        _tier_reset, is_reverse, with_state):
+    """The outer pieces alone, tightly: the hand-written transposes around
+    lstm_seq_bwd against jax.vjp over _lstm_compute under the SAME tier
+    (both run the same whole-sequence backward inside), from the carries
+    the forward saved."""
+    import jax
+    import jax.numpy as jnp
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.ops import rnn_ops
+
+    fluid.set_flags({"kernel_tier": "pallas"})
+    rng = np.random.RandomState(7)
+    b, L, H = 4, 5, 8
+    lens = jnp.asarray([5, 1, 3, 5], jnp.int32)
+    x = jnp.asarray(rng.normal(0, 1, (b, L, 4 * H)), jnp.float32)
+    w = jnp.asarray(rng.normal(0, 0.4, (H, 4 * H)), jnp.float32)
+    bias = jnp.asarray(rng.normal(0, 0.3, (4 * H,)), jnp.float32)
+    h0 = c0 = None
+    if with_state:
+        h0 = jnp.asarray(rng.normal(0, 1, (b, H)), jnp.float32)
+        c0 = jnp.asarray(rng.normal(0, 1, (b, H)), jnp.float32)
+    dhs = jnp.asarray(rng.normal(0, 1, (b, L, H)), jnp.float32)
+    dcs = jnp.asarray(rng.normal(0, 1, (b, L, H)), jnp.float32)
+    attrs = {"is_reverse": is_reverse}
+
+    operands = [x, w, bias] + ([h0, c0] if with_state else [])
+
+    def f(x, w, bias, h0=None, c0=None):
+        return rnn_ops._lstm_compute(x, lens, w, bias, h0, c0, attrs)[:2]
+
+    _, vjp = jax.vjp(f, *operands)
+    exp = vjp((dhs, dcs))
+    carries = rnn_ops._lstm_compute(x, lens, w, bias, h0, c0, attrs)[2]
+    assert carries[0].shape == (L, b, H)
+    got = rnn_ops._lstm_grad_from_carries(x, lens, w, bias, h0, c0, carries,
+                                          dhs, dcs, attrs)
+    for a, e, name in zip(got, exp, ("dx", "dw", "dbias", "dh0", "dc0")):
+        np.testing.assert_allclose(a, e, rtol=1e-5, atol=1e-6, err_msg=name)
+
+
+def test_train_step_runs_each_lstm_kernel_once(_tier_reset):
+    """The jaxpr of the whole train step holds one forward pallas_call for
+    each lstm op and one backward one: lstm_grad starts from the saved
+    BatchHidden/BatchCell and does not run the forward again."""
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.obs.perf import program_jaxpr
+
+    fluid.set_flags({"kernel_tier": "pallas"})
+    main, startup, loss, _ = _two_layer_lstm()
+    n_lstm = sum(op.type == "lstm" for op in main.global_block().ops)
+    assert n_lstm == 2
+    exe = fluid.Executor(fluid.CPUPlace(), mode="jit")
+    scope = fluid.Scope()
+    exe.run(startup, scope=scope)
+    feed = exe._prepare_feed(main.global_block(), _two_layer_feed())
+    text = str(program_jaxpr(main, feed, [loss], executor=exe, scope=scope))
+    calls = text.count("pallas_call[")
+    backward = text.count("name=lstm_bwd")
+    assert backward == n_lstm, (backward, calls)
+    assert calls - backward == n_lstm, (backward, calls)
