@@ -9,6 +9,8 @@ ops lower to fused XLA rather than per-kernel dispatch.
 
 from __future__ import annotations
 
+import copy
+
 import numpy as np
 
 from ..framework import Variable, unique_name
@@ -965,19 +967,126 @@ def max_pool3d_with_index(input, pool_size, pool_stride=None, name=None):
     return out, mask
 
 
-def causal_self_attention(q, k, v, num_heads, name=None):
-    """Causal multi-head self-attention over dense [batch, seq, hidden]
+def causal_self_attention(q, k, v, num_heads, num_kv_heads=None, window=0,
+                          name=None):
+    """Causal self-attention over dense [batch, seq, heads * head_dim]
     Q/K/V (already projected, e.g. by ``fc(num_flatten_dims=2)``). One op
     per transformer layer — the attention site the generation serving
     engine (serving/generate) recognizes and rewrites into its
-    prefill/paged-decode phase ops over the KV arena."""
+    prefill/paged-decode phase ops over the KV arena. ``num_kv_heads``
+    (default ``num_heads``) key/value heads serve the query heads in
+    blocked groups (K and V are then [batch, seq, num_kv_heads *
+    head_dim]); ``window`` > 0 lets position i see j only where
+    0 <= i - j < window (0: every j <= i)."""
+    num_kv_heads = int(num_kv_heads or num_heads)
     if q.shape and q.shape[-1] is not None and q.shape[-1] % num_heads:
         raise ValueError(
             f"hidden size {q.shape[-1]} must divide num_heads {num_heads}")
+    if num_heads % num_kv_heads:
+        raise ValueError(f"num_heads {num_heads} must be a multiple of "
+                         f"num_kv_heads {num_kv_heads}")
     helper = LayerHelper("causal_self_attention", name=name)
     out = helper.create_tmp_variable(q.dtype, shape=q.shape)
+    lse = helper.create_tmp_variable("float32", stop_gradient=True)
+    attrs = {"num_heads": int(num_heads)}
+    if num_kv_heads != num_heads:
+        attrs["num_kv_heads"] = num_kv_heads
+    if window:
+        attrs["window"] = int(window)
     helper.append_op("causal_self_attention",
                      inputs={"Q": [q.name], "K": [k.name], "V": [v.name]},
-                     outputs={"Out": [out.name]},
-                     attrs={"num_heads": int(num_heads)})
+                     outputs={"Out": [out.name], "LogSumExp": [lse.name]},
+                     attrs=attrs)
     return out
+
+
+def rms_norm(input, epsilon=1e-6, param_attr=None, name=None):
+    """Root-mean-square norm over the last axis with a learned scale
+    (initialised to 1): ``scale * x * rsqrt(mean(x^2) + epsilon)``."""
+    helper = LayerHelper("rms_norm", name=name)
+    scale = helper.create_parameter(ParamAttr.to_attr(param_attr),
+                                    shape=(int(input.shape[-1]),),
+                                    dtype=input.dtype,
+                                    default_initializer=Constant(1.0))
+    out = helper.create_tmp_variable(input.dtype, shape=input.shape)
+    helper.append_op("rms_norm",
+                     inputs={"X": [input.name], "Scale": [scale.name]},
+                     outputs={"Y": [out.name]}, attrs={"epsilon": epsilon})
+    return out
+
+
+def rotary_embedding(q, k, head_dim, theta=10000.0, rope_type="default",
+                     factor=1.0, original_max_position=0, beta_fast=32.0,
+                     beta_slow=1.0, attention_factor=1.0, name=None):
+    """Rotary positions (the ``rotate_half`` convention) on projected Q and
+    K, [batch, seq, heads * head_dim], positions 0..seq-1. ``rope_type``
+    ``yarn`` blends each frequency with itself over ``factor`` by YaRN's
+    linear ramp (``original_max_position``, ``beta_fast``, ``beta_slow``)
+    and scales cos and sin by ``attention_factor``. Returns (q, k)."""
+    helper = LayerHelper("rotary_embedding", name=name)
+    q_out = helper.create_tmp_variable(q.dtype, shape=q.shape)
+    k_out = helper.create_tmp_variable(k.dtype, shape=k.shape)
+    helper.append_op(
+        "rotary_embedding", inputs={"Q": [q.name], "K": [k.name]},
+        outputs={"QOut": [q_out.name], "KOut": [k_out.name]},
+        attrs={"head_dim": int(head_dim), "theta": float(theta),
+               "rope_type": rope_type, "factor": float(factor),
+               "original_max_position": int(original_max_position),
+               "beta_fast": float(beta_fast), "beta_slow": float(beta_slow),
+               "attention_factor": float(attention_factor)})
+    return q_out, k_out
+
+
+def routed_experts(input, num_experts, top_k, expert_width,
+                   held_experts=None, expert_offset=0, norm_topk_prob=True,
+                   row_buffer_factor=2.0, router_task_gradient=True,
+                   param_attr=None, name=None):
+    """A mixture-of-experts MLP that is told which experts it holds
+    (ops/moe_ops.py): the router scores all ``num_experts`` and keeps the
+    ``top_k``; the layer holds the gated-SiLU experts ``expert_offset ..
+    expert_offset + held_experts - 1`` (default: all) of width
+    ``expert_width`` and returns their part of the result, dropping no
+    row. With ``router_task_gradient`` off the task loss does not reach
+    the router through the top k's weights (it learns from ``aux_loss``
+    alone: for a layer that holds a share of the experts). Returns (out,
+    expert_load [held] int32, aux_loss [1]: the load-balancing term over
+    all router outputs)."""
+    helper = LayerHelper("routed_experts", name=name)
+    hidden = int(input.shape[-1])
+    held = int(held_experts or num_experts)
+    if not 0 <= expert_offset <= num_experts - held:
+        raise ValueError(
+            f"experts {expert_offset}..{expert_offset + held - 1} are not "
+            f"among {num_experts}")
+
+    def weight(shape):
+        return helper.create_parameter(
+            copy.deepcopy(ParamAttr.to_attr(param_attr)), shape=shape,
+            dtype=input.dtype)
+
+    router = weight((hidden, num_experts))
+    w_gate = weight((held, hidden, expert_width))
+    w_up = weight((held, hidden, expert_width))
+    w_down = weight((held, expert_width, hidden))
+    out = helper.create_tmp_variable(input.dtype, shape=input.shape)
+    aux = helper.create_tmp_variable("float32", shape=(1,))
+    kept = {slot: helper.create_tmp_variable(dtype, stop_gradient=True)
+            for slot, dtype in (
+                ("ExpertLoad", "int32"), ("Gate", input.dtype),
+                ("Up", input.dtype), ("RowAssign", "int32"),
+                ("RowWeight", "float32"), ("TopIdx", "int32"),
+                ("Probs", "float32"))}
+    kept["ExpertLoad"].shape = (held,)
+    helper.append_op(
+        "routed_experts",
+        inputs={"X": [input.name], "RouterW": [router.name],
+                "WGate": [w_gate.name], "WUp": [w_up.name],
+                "WDown": [w_down.name]},
+        outputs={"Out": [out.name], "AuxLoss": [aux.name],
+                 **{slot: [v.name] for slot, v in kept.items()}},
+        attrs={"num_experts": int(num_experts), "top_k": int(top_k),
+               "norm_topk_prob": bool(norm_topk_prob),
+               "expert_offset": int(expert_offset),
+               "row_buffer_factor": float(row_buffer_factor),
+               "router_task_gradient": bool(router_task_gradient)})
+    return out, kept["ExpertLoad"], aux

@@ -51,9 +51,9 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ..core.registry import register_op
-from .common import data_of
-from .sequence_ops import _vjp_grad
+from ..core.amp import cast_compute
+from ..core.registry import OpSpec, register_op
+from .common import G, data_of
 
 
 def _split_heads(x, num_heads):
@@ -80,29 +80,208 @@ def _causal_mha(q, k, v, num_heads):
     return out.reshape(q.shape)
 
 
-@register_op("causal_self_attention",
-             grad=_vjp_grad("causal_self_attention", in_slots=("Q", "K", "V")))
+def _attention_route(q, num_heads):
+    """"pallas" or "jnp" for this call; the forward op and its grad op ask
+    the same question and get the same answer."""
+    from .pallas import use_pallas
+    from .pallas.attention import attention_supported
+
+    return "pallas" if use_pallas(
+        "attention", attention_supported(q, num_heads)) else "jnp"
+
+
+def _attention_attrs(ctx, q, k):
+    heads = int(ctx.attr("num_heads"))
+    kv_heads = int(ctx.attr("num_kv_heads", 0) or heads)
+    if heads % kv_heads or q.shape[-1] % heads \
+            or k.shape[-1] * heads != q.shape[-1] * kv_heads:
+        raise ValueError(
+            f"attention: {heads} query heads over {kv_heads} key/value "
+            f"heads do not fit Q {q.shape} and K {k.shape}")
+    return heads, kv_heads, int(ctx.attr("window", 0) or 0)
+
+
+def _attention_forward(q, k, v, heads, kv_heads, window):
+    """(out, residual) by the route's forward (ops/pallas/attention.py)."""
+    from .pallas import kernel_span
+    from .pallas import attention as att
+
+    q, k, v = cast_compute(q, k, v)
+    route = _attention_route(q, heads)
+    with kernel_span(route, "attention"):
+        fn = att.attention_pallas if route == "pallas" else att.attention_jnp
+        return fn(q, k, v, heads, kv_heads, window)
+
+
+def _attention_grad_maker(op):
+    inputs = {s: op.input(s) for s in ("Q", "K", "V")}
+    inputs["Out"] = op.output("Out")
+    if op.output("LogSumExp"):
+        inputs["LogSumExp"] = op.output("LogSumExp")
+    inputs["Out@GRAD"] = G(op.output("Out"))
+    return [OpSpec("causal_self_attention_grad", inputs,
+                   {s + "@GRAD": G(op.input(s)) for s in ("Q", "K", "V")},
+                   dict(op.attrs))]
+
+
+@register_op("causal_self_attention", grad=_attention_grad_maker)
 def causal_self_attention(ctx):
-    """Causal MHA over a [b, T, E] window — the training/export form the
-    generation engine's program split rewrites per phase."""
+    """Causal attention over a [b, T, heads*d] window — the training/export
+    form the generation engine's program split rewrites per phase.
+    ``num_kv_heads`` (default ``num_heads``) key/value heads serve the query
+    heads in blocked groups; ``window`` > 0 lets position i see j only
+    where i - j < window. Blocked (no [T, T] scores): the ``attention``
+    Pallas family or its jnp twin. ``LogSumExp`` is the forward's residual,
+    in the form its route keeps it, for the grad op."""
     q = data_of(ctx.input("Q"))
     k = data_of(ctx.input("K"))
     v = data_of(ctx.input("V"))
-    ctx.set_output("Out", _causal_mha(q, k, v, int(ctx.attr("num_heads"))))
+    out, lse = _attention_forward(q, k, v, *_attention_attrs(ctx, q, k))
+    ctx.set_output("Out", out)
+    ctx.set_output("LogSumExp", lse)
 
 
 @register_op("causal_self_attention_grad")
 def causal_self_attention_grad(ctx):
+    from .pallas import kernel_span
+    from .pallas import attention as att
+
     q = data_of(ctx.input("Q"))
     k = data_of(ctx.input("K"))
     v = data_of(ctx.input("V"))
-    h = int(ctx.attr("num_heads"))
-    d = data_of(ctx.input("Out@GRAD"))
-    _, vjp = jax.vjp(lambda a, b, c: _causal_mha(a, b, c, h), q, k, v)
-    dq, dk, dv = vjp(d)
-    ctx.set_output("Q@GRAD", dq)
-    ctx.set_output("K@GRAD", dk)
-    ctx.set_output("V@GRAD", dv)
+    heads, kv_heads, window = _attention_attrs(ctx, q, k)
+    if ctx.has_input("LogSumExp"):
+        out = data_of(ctx.input("Out"))
+        lse = data_of(ctx.input("LogSumExp"))
+    else:           # a program built before the op kept its residual
+        out, lse = _attention_forward(q, k, v, heads, kv_heads, window)
+    qc, kc, vc, out, d = cast_compute(q, k, v, out,
+                                      data_of(ctx.input("Out@GRAD")))
+    route = _attention_route(qc, heads)
+    with kernel_span(route, "attention"):
+        fn = (att.attention_pallas_bwd if route == "pallas"
+              else att.attention_jnp_bwd)
+        dq, dk, dv = fn(qc, kc, vc, out, lse, d.astype(qc.dtype), heads,
+                        kv_heads, window)
+    ctx.set_output("Q@GRAD", dq.astype(q.dtype))
+    ctx.set_output("K@GRAD", dk.astype(k.dtype))
+    ctx.set_output("V@GRAD", dv.astype(v.dtype))
+
+
+# ---------------------------------------------------------------------------
+# rotary_embedding — rotate Q and K by their positions' angles
+# ---------------------------------------------------------------------------
+
+def rotary_inv_freq(head_dim, theta, rope_type="default", factor=1.0,
+                    original_max_position=0, beta_fast=32.0, beta_slow=1.0):
+    """Per-frequency inverse wavelengths [head_dim / 2], float64 on the
+    host. ``default``: theta^(-2i/d). ``yarn``: each frequency blends
+    theta^(-2i/d) (kept: extrapolation) with the same over ``factor``
+    (interpolation) by a linear ramp between the two correction
+    dimensions, those whose wavelength fits ``beta_fast`` and
+    ``beta_slow`` times into ``original_max_position``."""
+    import math
+
+    import numpy as np
+
+    pos_freqs = float(theta) ** (np.arange(0, head_dim, 2, dtype=np.float64)
+                                 / head_dim)
+    if rope_type == "default":
+        return 1.0 / pos_freqs
+    if rope_type != "yarn":
+        raise ValueError(f"rotary_embedding: unknown rope_type {rope_type!r}")
+
+    def correction_dim(rotations):
+        return head_dim * math.log(original_max_position
+                                   / (rotations * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(head_dim // 2, dtype=np.float64) - low)
+                   / (high - low), 0.0, 1.0)
+    return (1.0 / (factor * pos_freqs)) * ramp + (1.0 / pos_freqs) * (1 - ramp)
+
+
+def _rotary_tables(ctx, length):
+    """(cos, sin) [T, head_dim] float32 for positions 0..T-1, the two
+    halves alike (the ``rotate_half`` convention), times
+    ``attention_factor``."""
+    inv = rotary_inv_freq(
+        int(ctx.attr("head_dim")), ctx.attr("theta", 10000.0),
+        ctx.attr("rope_type", "default"), ctx.attr("factor", 1.0),
+        ctx.attr("original_max_position", 0), ctx.attr("beta_fast", 32.0),
+        ctx.attr("beta_slow", 1.0))
+    angles = jnp.arange(length, dtype=jnp.float32)[:, None] \
+        * jnp.asarray(inv, jnp.float32)[None, :]
+    angles = jnp.concatenate([angles, angles], axis=-1)
+    scale = float(ctx.attr("attention_factor", 1.0))
+    return jnp.cos(angles) * scale, jnp.sin(angles) * scale
+
+
+def _rotate(x, cos, sin, head_dim):
+    """x * cos + rotate_half(x) * sin per head, in float32; rotate_half
+    maps a head's halves (a, b) to (-b, a). The halves change places in a
+    product with a signed permutation matrix: exact (one +-1 a column), a
+    few GFLOP on the MXU, where slicing and concatenating 64-lane halves
+    cost the rotation 20 x its HBM time on a v5e (PERF.md, PR 28)."""
+    b, t, e = x.shape
+    xh = x.reshape(b, t, e // head_dim, head_dim)
+    half = head_dim // 2
+    swap = jnp.zeros((head_dim, head_dim), x.dtype)
+    swap = swap.at[jnp.arange(half) + half, jnp.arange(half)].set(-1)
+    swap = swap.at[jnp.arange(half), jnp.arange(half) + half].set(1)
+    rot = jnp.einsum("bthd,de->bthe", xh, swap,
+                     precision=jax.lax.Precision.HIGHEST,
+                     preferred_element_type=jnp.float32)
+    out = xh.astype(jnp.float32) * cos[None, :, None, :] \
+        + rot * sin[None, :, None, :]
+    return out.reshape(x.shape).astype(x.dtype)
+
+
+def _rotary_grad_maker(op):
+    return [OpSpec("rotary_embedding_grad",
+                   {"QOut@GRAD": G(op.output("QOut")),
+                    "KOut@GRAD": G(op.output("KOut"))},
+                   {"Q@GRAD": G(op.input("Q")), "K@GRAD": G(op.input("K"))},
+                   dict(op.attrs))]
+
+
+def _rotary_infer(op, block):
+    for src, dst in (("Q", "QOut"), ("K", "KOut")):
+        x = block.var(op.input(src)[0])
+        for name in op.output(dst):
+            out = block.var(name)
+            out.shape, out.dtype = x.shape, out.dtype or x.dtype
+
+
+@register_op("rotary_embedding", infer_shape=_rotary_infer,
+             grad=_rotary_grad_maker)
+def rotary_embedding(ctx):
+    """Rotary positions on projected Q and K ([b, T, heads*head_dim]),
+    positions 0..T-1. Attrs: ``head_dim``, ``theta``, ``rope_type``
+    (``default`` | ``yarn``), and for YaRN ``factor``,
+    ``original_max_position``, ``beta_fast``, ``beta_slow``,
+    ``attention_factor`` (on cos and sin)."""
+    q, k = data_of(ctx.input("Q")), data_of(ctx.input("K"))
+    cos, sin = _rotary_tables(ctx, q.shape[1])
+    d = int(ctx.attr("head_dim"))
+    ctx.set_output("QOut", _rotate(q, cos, sin, d))
+    ctx.set_output("KOut", _rotate(k, cos, sin, d))
+
+
+@register_op("rotary_embedding_grad")
+def rotary_embedding_grad(ctx):
+    """The rotation is linear and its two sin halves are alike, so the
+    gradient is the rotation by the opposite angle."""
+    dq = data_of(ctx.input("QOut@GRAD"))
+    dk = data_of(ctx.input("KOut@GRAD"))
+    cos, sin = _rotary_tables(ctx, dq.shape[1])
+    d = int(ctx.attr("head_dim"))
+    ctx.set_output("Q@GRAD", _rotate(dq, cos, -sin, d))
+    ctx.set_output("K@GRAD", _rotate(dk, cos, -sin, d))
 
 
 def _scatter_rows(cache, slots, rows):

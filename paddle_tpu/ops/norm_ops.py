@@ -1,4 +1,4 @@
-"""Normalization ops: batch_norm, layer_norm, lrn.
+"""Normalization ops: batch_norm, layer_norm, rms_norm, lrn.
 
 Reference: /root/reference/paddle/fluid/operators/batch_norm_op.cc (NCHW,
 inputs X/Scale/Bias/Mean/Variance, outputs Y/MeanOut/VarianceOut/SavedMean/
@@ -269,6 +269,50 @@ def layer_norm_grad(ctx):
         ctx.set_output("Scale@GRAD", grads[1])
     if bias is not None:
         ctx.set_output("Bias@GRAD", grads[-1])
+
+
+# ---------------------------------------------------------------------------
+# rms_norm — y = scale * x * rsqrt(mean(x^2, last axis) + eps)
+# ---------------------------------------------------------------------------
+
+def _rms_grad_maker(op):
+    return [OpSpec("rms_norm_grad",
+                   {"X": op.input("X"), "Scale": op.input("Scale"),
+                    "Y@GRAD": G(op.output("Y"))},
+                   {"X@GRAD": G(op.input("X")),
+                    "Scale@GRAD": G(op.input("Scale"))},
+                   dict(op.attrs))]
+
+
+@register_op("rms_norm", infer_shape=same_shape("X", "Y"),
+             grad=_rms_grad_maker)
+def rms_norm(ctx):
+    """Root-mean-square norm over the last axis. The statistic and the
+    product are float32 whatever the activation type (a stability island
+    under AMP); the result takes the input's type."""
+    x = data_of(ctx.input("X"))
+    scale = data_of(ctx.input("Scale")).astype(jnp.float32)
+    xf = x.astype(jnp.float32)
+    r = jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True)
+                      + ctx.attr("epsilon", 1e-6))
+    ctx.set_output("Y", (xf * r * scale).astype(x.dtype))
+
+
+@register_op("rms_norm_grad")
+def rms_norm_grad(ctx):
+    """With r = rsqrt(mean(x^2) + eps) and g = dy * scale:
+    dx = r * (g - x * r^2 * mean(g * x)), dscale = sum(dy * x * r)."""
+    x = data_of(ctx.input("X"))
+    scale = data_of(ctx.input("Scale"))
+    xf = x.astype(jnp.float32)
+    dy = data_of(ctx.input("Y@GRAD")).astype(jnp.float32)
+    r = jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True)
+                      + ctx.attr("epsilon", 1e-6))
+    g = dy * scale.astype(jnp.float32)
+    dx = r * (g - xf * (r * r) * jnp.mean(g * xf, axis=-1, keepdims=True))
+    dscale = jnp.sum((dy * xf * r).reshape(-1, x.shape[-1]), axis=0)
+    ctx.set_output("X@GRAD", dx.astype(x.dtype))
+    ctx.set_output("Scale@GRAD", dscale.astype(scale.dtype))
 
 
 # ---------------------------------------------------------------------------

@@ -54,6 +54,20 @@ from ...obs.metrics import REGISTRY as _METRICS
 #                      read 41.0 ms a step at 256 x 512 (62.3 before) and
 #                      +45% samples/s over ragged lengths; the reverse
 #                      kernel is 1.9-2.7x its scan at L = 64..512
+#   attention     IN   (PR 28) banded grouped-query causal attention, three
+#                      kernels that skip key blocks outside the band. At
+#                      8192 x 32/4 heads of 128, bf16: forward 3.73 ms
+#                      (window 1024) / 9.74 (full) against the blocked twin's
+#                      2.84 / 20.87, backward 7.41 / 18.87 against 11.65 /
+#                      33.70; at the step (three window layers, one full)
+#                      191.6 ms against 214.3 on the twin, which also holds
+#                      each block's scores in HBM (19.3 GB peak for 11.6)
+#   grouped_matmul IN  (PR 28) a tile of rows meets one expert's resident
+#                      weights. 8192 rows over 8 experts of 2304 x 896:
+#                      0.80-1.20 ms a product against 1.28-1.59 for
+#                      XLA:TPU's own ragged-dot kernel (512 x 256 x 128
+#                      tiles), equal to the bit; at the step 191.6 ms
+#                      against 215.2
 #   conv_bn       out  lowers, but 0.2-0.65x of XLA's conv+BN fusions at
 #                      6 of 7 ResNet-50 shapes; fused flagship step 318.6
 #                      vs 102.5 ms unfused
@@ -64,7 +78,7 @@ from ...obs.metrics import REGISTRY as _METRICS
 # Not in the set and not admitted by this rule yet: gru (recurrence 1.61x,
 # step not measured), paged_attention (does not lower under this jax).
 # Every family stays reachable with kernel_tier=pallas.
-AUTO_PALLAS = frozenset({"lstm"})
+AUTO_PALLAS = frozenset({"lstm", "attention", "grouped_matmul"})
 
 # kernel family -> the deprecated flag that used to gate it
 _LEGACY_FLAGS = {
@@ -133,7 +147,8 @@ def use_pallas(kernel, supported=True):
     """Should this dispatch take the Pallas path?
 
     ``kernel`` names the kernel family ("conv_bn", "optimizer",
-    "embedding_sgd", "lstm", "gru", "ctc"); ``supported`` is the call
+    "embedding_sgd", "lstm", "gru", "ctc", "attention",
+    "grouped_matmul"); ``supported`` is the call
     site's shape/config predicate. Unsupported shapes under a Pallas tier
     fall back to the jnp twin with a counter bump (never an error).
     """

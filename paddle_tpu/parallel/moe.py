@@ -17,6 +17,13 @@ Routing uses a softmax gate; ``aux_loss`` is the standard load-balancing
 term (mean fraction * mean gate mass per expert, scaled by E) to train
 against expert collapse. Dropped tokens (over capacity) pass through the
 residual (output 0 for their expert contribution), the GShard policy.
+
+This is a ``jax``-level function with no ``fluid.Program`` behind it. The
+Program-level expert layer is the ``routed_experts`` op
+(``ops/moe_ops.py``, ``fluid.layers.routed_experts``): top-k routing, no
+dropped rows, told which experts it holds. It computes one chip's share;
+the exchange between chips (``shard_program_step`` over ``ep``) is not
+built yet, and until it is this module is the only expert-PARALLEL path.
 """
 
 from __future__ import annotations

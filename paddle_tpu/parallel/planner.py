@@ -152,7 +152,10 @@ class ProgramFeatures:
     expert count, batch/seq) and the cost inputs (measured or estimated
     FLOPs, parameter/activation bytes). Built from a Program by
     :func:`extract_features`; the moe/ring lanes — jax-level model
-    functions with no fluid Program — construct one directly."""
+    functions with no fluid Program — construct one directly. Programs
+    can hold experts now (the ``routed_experts`` op, ops/moe_ops.py), but
+    :func:`extract_features` does not read ``moe_experts`` from them yet:
+    it is still passed by hand."""
 
     def __init__(self, signature="", batch=None, param_shapes=None,
                  layer_chain=0, attention=False, seq_len=None,

@@ -337,6 +337,23 @@ class GenerationEngine:
         configs = set()
         for op in sites:
             heads = int(op.attr("num_heads"))
+            # the phase ops and the cache allocator know equal heads and
+            # full causal attention only: refuse the rest by name, before
+            # a rewritten program computes something else
+            kv_heads = int(op.attr("num_kv_heads", 0) or heads)
+            if kv_heads != heads:
+                raise ValueError(
+                    f"causal_self_attention with num_kv_heads={kv_heads} "
+                    f"!= num_heads={heads}: the paged KV arena is laid out "
+                    "for equal query and key/value heads; grouped-query "
+                    "programs cannot be served by GenerationEngine yet")
+            if int(op.attr("window", 0) or 0):
+                raise ValueError(
+                    f"causal_self_attention with window="
+                    f"{int(op.attr('window'))}: the cache allocator keeps "
+                    "every block of a sequence and the phase ops attend "
+                    "over all of them; sliding-window programs cannot be "
+                    "served by GenerationEngine yet")
             kvar = block.var(op.input("K")[0])
             hidden = int(kvar.shape[-1])
             configs.add((heads, hidden // heads))
@@ -376,6 +393,7 @@ class GenerationEngine:
                 continue
             inputs = dict(op.inputs)
             outputs = dict(op.outputs)
+            outputs.pop("LogSumExp", None)    # the training op's residual
             for kind in ("k", "v"):
                 _declare(_kv_name(kind, layer), "float32")
             inputs["KCache"] = [_kv_name("k", layer)]

@@ -175,3 +175,91 @@ def export_tiny_lm(dirname, scope=None, **kw):
     fluid.io.save_inference_model(dirname, ["tokens", "positions"],
                                   [logits], exe, main, scope=scope)
     return main, scope, logits
+
+
+def build_mellum2_lm(cfg, length, batch=1):
+    """The Mellum2 block stack (JetBrains/Mellum2-12B-A2.5B's published
+    ``config.json`` keys) as a Fluid program, and the chip's share of it:
+    ``num_hidden_layers`` pre-norm blocks — RMSNorm -> q/k/v ``fc`` (no
+    bias) -> rotary (plain on a ``sliding_attention`` layer, YaRN on a
+    ``full_attention`` one) -> grouped-query causal attention (window
+    ``sliding_window`` on a sliding layer) -> o ``fc`` -> residual; RMSNorm
+    -> ``routed_experts`` holding ``num_experts`` of the router's
+    ``num_experts_routed`` from ``expert_offset`` -> residual — then the
+    final RMSNorm and an untied head over ``vocab_size`` rows. The loss is
+    the mean next-token cross-entropy plus ``balance_loss_coef`` times the
+    layers' mean load-balancing term. The plain reference is
+    ``testing/reference/mellum2.py``; parameters are created in the order
+    its ``unpack`` reads. Feeds ``tokens`` and ``labels`` [batch, length,
+    1] int64. Returns (main, startup, loss, logits [batch, length, vocab],
+    [expert_load of each layer])."""
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.fluid.initializer import Normal
+    from paddle_tpu.fluid.param_attr import ParamAttr
+
+    layers = fluid.layers
+    hidden, d = cfg["hidden_size"], cfg["head_dim"]
+    heads, kv_heads = cfg["num_attention_heads"], cfg["num_key_value_heads"]
+
+    def init(std=cfg.get("init_std", 0.02)):
+        return ParamAttr(initializer=Normal(0.0, std))
+
+    def proj(x, size):
+        return layers.fc(x, size, num_flatten_dims=2, param_attr=init(),
+                         bias_attr=False)
+
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        tokens = layers.data("tokens", shape=[batch, length, 1],
+                             dtype="int64", append_batch_size=False)
+        labels = layers.data("labels", shape=[batch, length, 1],
+                             dtype="int64", append_batch_size=False)
+        x = layers.embedding(
+            tokens, size=(cfg["vocab_size"], hidden),
+            param_attr=init(cfg.get("embedding_init_std",
+                                    cfg.get("init_std", 0.02))))
+        aux, loads = [], []
+        for i in range(cfg["num_hidden_layers"]):
+            kind = cfg["layer_types"][i]
+            rope = cfg["rope_parameters"][kind]
+            xn = layers.rms_norm(x, epsilon=cfg["rms_norm_eps"])
+            q, k, v = (proj(xn, heads * d), proj(xn, kv_heads * d),
+                       proj(xn, kv_heads * d))
+            q, k = layers.rotary_embedding(
+                q, k, head_dim=d, theta=rope["rope_theta"],
+                rope_type=rope["rope_type"], factor=rope.get("factor", 1.0),
+                original_max_position=rope.get(
+                    "original_max_position_embeddings", 0),
+                beta_fast=rope.get("beta_fast", 32.0),
+                beta_slow=rope.get("beta_slow", 1.0),
+                attention_factor=rope.get("attention_factor", 1.0))
+            a = layers.causal_self_attention(
+                q, k, v, num_heads=heads, num_kv_heads=kv_heads,
+                window=cfg["sliding_window"]
+                if kind == "sliding_attention" else 0)
+            x = layers.elementwise_add(x, proj(a, hidden))
+            y, load, balance = layers.routed_experts(
+                layers.rms_norm(x, epsilon=cfg["rms_norm_eps"]),
+                num_experts=cfg["num_experts_routed"],
+                top_k=cfg["num_experts_per_tok"],
+                expert_width=cfg["moe_intermediate_size"],
+                held_experts=cfg["num_experts"],
+                expert_offset=cfg.get("expert_offset", 0),
+                norm_topk_prob=cfg.get("norm_topk_prob", True),
+                row_buffer_factor=cfg.get("row_buffer_factor", 2.0),
+                router_task_gradient=cfg.get("router_task_gradient", True),
+                param_attr=init())
+            x = layers.elementwise_add(x, y)
+            aux.append(balance)
+            loads.append(load)
+        logits = layers.fc(
+            layers.rms_norm(x, epsilon=cfg["rms_norm_eps"]),
+            cfg["vocab_size"], num_flatten_dims=2, bias_attr=False,
+            param_attr=init(cfg.get("head_init_std",
+                                    cfg.get("init_std", 0.02))))
+        loss = layers.mean(layers.softmax_with_cross_entropy(logits, labels))
+        coef = cfg.get("balance_loss_coef", 0.0)
+        if coef:
+            loss = layers.elementwise_add(loss, layers.scale(
+                layers.mean(layers.sums(aux)), scale=coef / len(aux)))
+    return main, startup, loss, logits, loads

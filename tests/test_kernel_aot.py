@@ -37,8 +37,10 @@ def native_lowering(monkeypatch):
     the CPU, so steer that one predicate to compile them natively. The
     persistent cache cannot read such an executable back: keep it off."""
     from jax.experimental.compilation_cache import compilation_cache
-    from paddle_tpu.ops.pallas import rnn
+    from paddle_tpu.ops.pallas import attention, grouped_matmul, rnn
     monkeypatch.setattr(rnn, "_on_cpu", lambda: False)
+    monkeypatch.setattr(attention, "on_cpu", lambda: False)
+    monkeypatch.setattr(grouped_matmul, "on_cpu", lambda: False)
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
@@ -85,3 +87,86 @@ def test_lstm_backward_whole_compiles_for_v5e(one_chip, native_lowering):
         s["x"], s["alive"], s["w"], s["h0"], s["c0"],
         s["seq"], s["seq"], s["seq"], s["seq"]).compile()
     assert "lstm_bwd" in compiled.as_text()
+
+
+# ---- the Mellum2 cell: 8192 tokens, 32 query / 4 key-value heads of 128
+ATT_T, ATT_HEADS, ATT_KV, ATT_D = 8192, 32, 4, 128
+
+
+def _attention_shapes(sharding):
+    def bf16(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=sharding)
+    return (bf16(1, ATT_T, ATT_HEADS * ATT_D), bf16(1, ATT_T, ATT_KV * ATT_D),
+            jax.ShapeDtypeStruct((1, ATT_HEADS, ATT_T, 128), jnp.float32,
+                                 sharding=sharding))
+
+
+@pytest.mark.parametrize("window", [1024, 0])
+def test_attention_kernels_compile_for_v5e(one_chip, native_lowering,
+                                           window):
+    from paddle_tpu.ops.pallas import attention as att
+    q, kv, lse = _attention_shapes(one_chip)
+    fwd = jax.jit(lambda q, k, v: att.attention_pallas(
+        q, k, v, ATT_HEADS, ATT_KV, window)).lower(q, kv, kv).compile()
+    assert "attention_fwd" in fwd.as_text()
+    bwd = jax.jit(lambda q, k, v, o, l, d: att.attention_pallas_bwd(
+        q, k, v, o, l, d, ATT_HEADS, ATT_KV, window)).lower(
+            q, kv, kv, q, lse, q).compile()
+    text = bwd.as_text()
+    assert "attention_dq" in text and "attention_dkv" in text
+
+
+def _expert_shapes(sharding):
+    from paddle_tpu.ops import moe_ops
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+    _, _, buffer = moe_ops.row_buffer(8192, 8, 8, 64, 2.0)
+    return (s((buffer, 2304)), s((buffer, 896)), s((8, 2304, 896)),
+            s((8, 896, 2304)), s((buffer // moe_ops.TILE,), jnp.int32),
+            s((1,), jnp.int32), s((8,), jnp.int32))
+
+
+def test_grouped_matmul_kernels_compile_for_v5e(one_chip, native_lowering):
+    """The three kernels at the cell's shapes: 18432 buffered rows, 8
+    experts of 2304 x 896 (up) and 896 x 2304 (down)."""
+    from paddle_tpu.ops.pallas import grouped_matmul as gm
+    wide, narrow, w_up, w_down, tile_expert, tiles, _ = _expert_shapes(
+        one_chip)
+    assert gm.supported(wide, w_up) and gm.supported(narrow, w_down)
+    for fn, args, name in (
+            (gm.gmm, (wide, w_up), "grouped_matmul"),
+            (gm.gmm, (narrow, w_down), "grouped_matmul"),
+            (gm.gmm_t, (narrow, w_up), "grouped_matmul_t"),
+            (gm.gmm_t, (wide, w_down), "grouped_matmul_t"),
+            (lambda a, b, e, n: gm.tgmm(a, b, 8, e, n), (wide, narrow),
+             "grouped_matmul_w"),
+            (lambda a, b, e, n: gm.tgmm(a, b, 8, e, n), (narrow, wide),
+             "grouped_matmul_w")):
+        compiled = jax.jit(fn).lower(*args, tile_expert, tiles).compile()
+        assert name in compiled.as_text()
+
+
+def test_ragged_dot_route_lowers_to_a_grouped_kernel_on_v5e(one_chip,
+                                                             native_lowering):
+    """Off the kernels routed_experts leans on XLA:TPU lowering
+    ``ragged_dot`` to its own grouped kernel (work follows the rows held,
+    not rows x experts) — which a ragged_dot_general that contracts the
+    weights' last axis does NOT get: hence the transposed weights."""
+    wide, narrow, w_up, _, _, _, sizes = _expert_shapes(one_chip)
+    dense = 2 * wide.shape[0] * 2304 * 896
+
+    def cost(fn, *args):
+        return jax.jit(fn).lower(*args).compile().cost_analysis()["flops"]
+
+    plain = lambda a, w, s: jax.lax.ragged_dot(   # noqa: E731
+        a, w, s, preferred_element_type=jnp.float32)
+    assert cost(plain, wide, w_up, sizes) < 1.1 * dense
+    assert cost(lambda a, w, s: plain(a, jnp.swapaxes(w, 1, 2), s),
+                narrow, w_up, sizes) < 1.1 * dense
+    contract_last = jax.lax.RaggedDotDimensionNumbers(
+        dot_dimension_numbers=(((1,), (2,)), ((), ())),
+        lhs_ragged_dimensions=[0], rhs_group_dimensions=[0])
+    assert cost(lambda a, w, s: jax.lax.ragged_dot_general(
+        a, w, s, contract_last, preferred_element_type=jnp.float32),
+        narrow, w_up, sizes) > 4 * dense
