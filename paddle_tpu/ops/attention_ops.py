@@ -53,7 +53,15 @@ import jax.numpy as jnp
 
 from ..core.amp import cast_compute
 from ..core.registry import OpSpec, register_op
+from ..obs.metrics import REGISTRY as _METRICS
 from .common import G, data_of
+
+_M_BLOCKS = _METRICS.gauge(
+    "paddle_tpu_attention_blocks",
+    "the attention kernels' grid a head, as last traced under each window: "
+    "kind=scheduled is the band's blocks, a grid step each; kind=skipped "
+    "the empty steps a rectangular grid would have walked besides",
+    labels=("window", "kind"))
 
 
 def _split_heads(x, num_heads):
@@ -80,14 +88,20 @@ def _causal_mha(q, k, v, num_heads):
     return out.reshape(q.shape)
 
 
-def _attention_route(q, num_heads):
+def _attention_route(q, heads, kv_heads, window):
     """"pallas" or "jnp" for this call; the forward op and its grad op ask
     the same question and get the same answer."""
     from .pallas import use_pallas
-    from .pallas.attention import attention_supported
+    from .pallas import attention as att
 
-    return "pallas" if use_pallas(
-        "attention", attention_supported(q, num_heads)) else "jnp"
+    if not use_pallas("attention", att.attention_supported(
+            q, heads, kv_heads, window)):
+        return "jnp"
+    T = q.shape[1]
+    sched = att.band_schedule(T, att.kernel_block(T), window)
+    _M_BLOCKS.labels(window=window, kind="scheduled").set(len(sched.q))
+    _M_BLOCKS.labels(window=window, kind="skipped").set(sched.skipped)
+    return "pallas"
 
 
 def _attention_attrs(ctx, q, k):
@@ -107,7 +121,7 @@ def _attention_forward(q, k, v, heads, kv_heads, window):
     from .pallas import attention as att
 
     q, k, v = cast_compute(q, k, v)
-    route = _attention_route(q, heads)
+    route = _attention_route(q, heads, kv_heads, window)
     with kernel_span(route, "attention"):
         fn = att.attention_pallas if route == "pallas" else att.attention_jnp
         return fn(q, k, v, heads, kv_heads, window)
@@ -157,7 +171,7 @@ def causal_self_attention_grad(ctx):
         out, lse = _attention_forward(q, k, v, heads, kv_heads, window)
     qc, kc, vc, out, d = cast_compute(q, k, v, out,
                                       data_of(ctx.input("Out@GRAD")))
-    route = _attention_route(qc, heads)
+    route = _attention_route(qc, heads, kv_heads, window)
     with kernel_span(route, "attention"):
         fn = (att.attention_pallas_bwd if route == "pallas"
               else att.attention_jnp_bwd)

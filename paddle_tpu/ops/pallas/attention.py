@@ -7,7 +7,10 @@ Query head ``j`` reads key/value head ``j // (num_heads // num_kv_heads)``
 Neither path holds a ``[T, T]`` tensor: queries go in blocks, a block
 meets only the key blocks its band touches (a window layer's work is
 ``T x window``, a full layer's ``T^2 / 2``), and the backward pass rebuilds
-each block's probabilities from the forward's saved log-sum-exp.
+each block's probabilities from the forward's saved log-sum-exp. The
+kernels' grid is the band itself: ``band_schedule`` lists the (query block,
+key block) pairs when the op is traced, the tables are prefetched into
+scalar memory, and a grid step is one pair.
 
 Arrays keep the model's layout, ``[b, T, heads * head_dim]``: a block
 spec's last index picks the head's columns, so nothing is transposed.
@@ -23,9 +26,11 @@ The twin is the CPU's path and the kernels' reference in the tests.
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -38,18 +43,60 @@ KERNEL_BLOCKS = (512, 256, 128)
 
 
 # ------------------------------------------------------------------ the band
-def band_first(q_lo, window, block):
-    """First key block a query block starting at row ``q_lo`` sees."""
-    if not window:
-        return 0 * q_lo
-    return jnp.maximum(q_lo - window + 1, 0) // block
-
-
 def _visible(qpos, kpos, window):
     mask = kpos <= qpos
     if window:
         mask = mask & (qpos - kpos < window)
     return mask
+
+
+FIRST, LAST = 1, 2                # bits of a schedule entry's flags
+MAX_ENTRIES = 32768     # of one kernel's schedule: its four int32 tables are
+                        # then half a v5e's scalar memory (1 MiB)
+
+
+class Schedule(NamedTuple):
+    """The blocks a band touches, one entry a grid step, in the order a
+    kernel walks them: int32 tables of the entry's query block, key block,
+    query head within its group (the ``key`` order; zeros otherwise) and
+    flags. ``FIRST`` / ``LAST``: the entry opens / closes its accumulator's
+    run. ``skipped`` is what a rectangular grid of (blocks x the longest
+    run) would have walked besides."""
+    q: np.ndarray
+    k: np.ndarray
+    head: np.ndarray
+    flags: np.ndarray
+    skipped: int
+
+
+def band_schedule(T, block, window, by="query", group=1):
+    """The one place that says what the band is. Block ``(i, j)`` holds the
+    differences ``qpos - kpos`` from ``lo`` to ``hi``; the visible ones
+    are an interval that starts at 0, so some pair of the block is visible
+    where the block's difference nearest 0 is.
+
+    ``by="query"`` (``attention_fwd``, ``attention_dq``): a run is a query
+    block's key blocks, ascending, so the diagonal comes last.
+    ``by="key"`` (``attention_dkv``): a run is a key block's query blocks,
+    ascending, once for each of the ``group`` query heads that share the
+    key/value head."""
+    n = T // block
+    i, j = np.indices((n, n))
+    lo, hi = (i - j) * block - (block - 1), (i - j) * block + (block - 1)
+    some = _visible(np.clip(0, lo, hi), 0, window)
+    runs = []
+    for a in range(n):
+        if by == "query":
+            runs.append([(a, b, 0) for b in np.flatnonzero(some[a])])
+        else:
+            runs.append([(b, a, g) for g in range(group)
+                         for b in np.flatnonzero(some[:, a])])
+    entries = [(qi, ki, g, (FIRST if e == 0 else 0)
+                | (LAST if e == len(run) - 1 else 0))
+               for run in runs for e, (qi, ki, g) in enumerate(run)]
+    q, k, head, flags = np.asarray(entries, np.int32).T
+    longest = max(len(run) for run in runs)
+    return Schedule(q, k, head, flags, n * longest - len(entries))
 
 
 # ------------------------------------------------------------ blocked twin
@@ -146,32 +193,15 @@ def kernel_block(T):
     return None
 
 
-def attention_supported(q, num_heads):
-    """The kernels take whole 128-lane heads and a length that 128
-    divides; anything else is the twin's."""
-    d = q.shape[-1] // num_heads
-    return (d % LANES == 0 and kernel_block(q.shape[1]) is not None
-            and q.dtype in (jnp.bfloat16, jnp.float32))
-
-
-def _last_query(kj, blk, window, n):
-    """Last query block that sees key block ``kj``."""
-    if not window:
-        return n - 1 + 0 * kj
-    return jnp.minimum((kj * blk + blk + window - 2) // blk, n - 1)
-
-
-def _span(T, blk, window):
-    """Static grid extents: the number of blocks, the most key blocks any
-    query block sees, and the most query blocks any key block is seen
-    by."""
-    n = T // blk
-    if not window:
-        return n, n, n
-    keys = max(i - max(i * blk - window + 1, 0) // blk + 1 for i in range(n))
-    queries = max(min((j * blk + blk + window - 2) // blk, n - 1) - j + 1
-                  for j in range(n))
-    return n, keys, queries
+def attention_supported(q, num_heads, num_kv_heads, window):
+    """The kernels take whole 128-lane heads, a length that 128 divides
+    and a band whose schedule fits scalar memory (the longest is
+    ``attention_dkv``'s, a group's heads through every pair); anything
+    else is the twin's."""
+    T, d, blk, group = _geometry(q, num_heads, num_kv_heads)
+    return (d % LANES == 0 and blk is not None
+            and q.dtype in (jnp.bfloat16, jnp.float32)
+            and len(band_schedule(T, blk, window).q) * group <= MAX_ENTRIES)
 
 
 def _scores(q, k, qi, ki, blk, window, scale):
@@ -182,204 +212,202 @@ def _scores(q, k, qi, ki, blk, window, scale):
     return jnp.where(_visible(qpos, kpos, window), s, NEG)
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-                *, blk, window, scale):
-    qi, j = pl.program_id(2), pl.program_id(3)
-    ki = band_first(qi * blk, window, blk) + j
+def _wide(stat, cols):
+    """A lane-replicated ``[rows, 128]`` row statistic against a ``[rows,
+    cols]`` block: whole copies side by side. The same numbers as
+    ``stat[:, :1]`` broadcast, which crosses lanes for every vreg of the
+    block and was two fifths of the forward kernel on a v5e (PERF.md,
+    PR 29)."""
+    return stat if cols == LANES else jnp.tile(stat, (1, cols // LANES))
 
-    @pl.when(j == 0)
+
+def _entry(q_tab, k_tab, flags_tab):
+    """(query block, key block, flags) of this grid step's entry."""
+    e = pl.program_id(2)
+    return q_tab[e], k_tab[e], flags_tab[e]
+
+
+def _fwd_kernel(q_tab, k_tab, flags_tab, q_ref, k_ref, v_ref, o_ref, lse_ref,
+                m_scr, l_scr, acc_scr, *, blk, window, scale):
+    qi, ki, flags = _entry(q_tab, k_tab, flags_tab)
+
+    @pl.when(flags & FIRST != 0)
     def _():
         m_scr[...] = jnp.full(m_scr.shape, NEG, jnp.float32)
         l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
         acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
 
-    @pl.when(ki <= qi)
-    def _():
-        v = v_ref[...]
-        s = _scores(q_ref[...], k_ref[...], qi, ki, blk, window, scale)
-        m_prev = m_scr[...]
-        m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        # a row wholly masked in this block adds exp(0) here; the diagonal
-        # block comes last, holds a real score for every row, and its
-        # alpha = exp(NEG - m) wipes that
-        p = jnp.exp(s - m_next[:, :1])
-        alpha = jnp.exp(m_prev - m_next)
-        l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha[:, :1] + jnp.dot(
-            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
-        m_scr[...] = m_next
+    v = v_ref[...]
+    s = _scores(q_ref[...], k_ref[...], qi, ki, blk, window, scale)
+    m_prev = m_scr[...]
+    m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    # a row wholly masked in this block adds exp(0) here; the diagonal
+    # block comes last, holds a real score for every row, and its
+    # alpha = exp(NEG - m) wipes that
+    p = jnp.exp(s - _wide(m_next, s.shape[1]))
+    alpha = jnp.exp(m_prev - m_next)
+    l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=1, keepdims=True)
+    acc_scr[...] = acc_scr[...] * _wide(alpha, v.shape[1]) + jnp.dot(
+        p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+    m_scr[...] = m_next
 
-    @pl.when(j == pl.num_programs(3) - 1)
+    @pl.when(flags & LAST != 0)
     def _():
         l = l_scr[...]
-        o_ref[...] = (acc_scr[...] / l[:, :1]).astype(o_ref.dtype)
+        o_ref[...] = (acc_scr[...]
+                      / _wide(l, acc_scr.shape[1])).astype(o_ref.dtype)
         lse_ref[...] = m_scr[...] + jnp.log(l)
 
 
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-               dq_scr, *, blk, window, scale):
-    qi, j = pl.program_id(2), pl.program_id(3)
-    ki = band_first(qi * blk, window, blk) + j
+def _dq_kernel(q_tab, k_tab, flags_tab, q_ref, k_ref, v_ref, do_ref, lse_ref,
+               delta_ref, dq_ref, dq_scr, *, blk, window, scale):
+    qi, ki, flags = _entry(q_tab, k_tab, flags_tab)
 
-    @pl.when(j == 0)
+    @pl.when(flags & FIRST != 0)
     def _():
         dq_scr[...] = jnp.zeros(dq_scr.shape, jnp.float32)
 
-    @pl.when(ki <= qi)
-    def _():
-        k = k_ref[...]
-        s = _scores(q_ref[...], k, qi, ki, blk, window, scale)
-        p = jnp.exp(s - lse_ref[...][:, :1])
-        dp = jax.lax.dot_general(do_ref[...], v_ref[...],
-                                 (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[...][:, :1])
-        dq_scr[...] += jnp.dot(ds.astype(k.dtype), k,
-                               preferred_element_type=jnp.float32)
+    k = k_ref[...]
+    s = _scores(q_ref[...], k, qi, ki, blk, window, scale)
+    p = jnp.exp(s - _wide(lse_ref[...], s.shape[1]))
+    dp = jax.lax.dot_general(do_ref[...], v_ref[...],
+                             (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+    ds = p * (dp - _wide(delta_ref[...], s.shape[1]))
+    dq_scr[...] += jnp.dot(ds.astype(k.dtype), k,
+                           preferred_element_type=jnp.float32)
 
-    @pl.when(j == pl.num_programs(3) - 1)
+    @pl.when(flags & LAST != 0)
     def _():
         dq_ref[...] = (dq_scr[...] * scale).astype(dq_ref.dtype)
 
 
-def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
-                dv_ref, dk_scr, dv_scr, *, blk, window, scale, n):
-    ki, g, j = pl.program_id(2), pl.program_id(3), pl.program_id(4)
-    qi = ki + j
+def _dkv_kernel(q_tab, k_tab, head_tab, flags_tab, q_ref, k_ref, v_ref,
+                do_ref, lse_ref, delta_ref, dk_ref, dv_ref, dk_scr, dv_scr,
+                *, blk, window, scale):
+    del head_tab                            # the index maps' alone
+    qi, ki, flags = _entry(q_tab, k_tab, flags_tab)
 
-    @pl.when((g == 0) & (j == 0))
+    @pl.when(flags & FIRST != 0)
     def _():
         dk_scr[...] = jnp.zeros(dk_scr.shape, jnp.float32)
         dv_scr[...] = jnp.zeros(dv_scr.shape, jnp.float32)
 
-    @pl.when(qi <= _last_query(ki, blk, window, n))
-    def _():
-        q, do = q_ref[...], do_ref[...]
-        s = _scores(q, k_ref[...], qi, ki, blk, window, scale)
-        p = jnp.exp(s - lse_ref[...][:, :1])
-        dv_scr[...] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v_ref[...], (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[...][:, :1])
-        dk_scr[...] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    q, do = q_ref[...], do_ref[...]
+    s = _scores(q, k_ref[...], qi, ki, blk, window, scale)
+    p = jnp.exp(s - _wide(lse_ref[...], s.shape[1]))
+    dv_scr[...] += jax.lax.dot_general(
+        p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    dp = jax.lax.dot_general(do, v_ref[...], (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+    ds = p * (dp - _wide(delta_ref[...], s.shape[1]))
+    dk_scr[...] += jax.lax.dot_general(
+        ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
 
-    @pl.when((g == pl.num_programs(3) - 1) & (j == pl.num_programs(4) - 1))
+    @pl.when(flags & LAST != 0)
     def _():
         dk_ref[...] = (dk_scr[...] * scale).astype(dk_ref.dtype)
         dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
 
 
-def _params(semantics):
-    return pltpu.CompilerParams(dimension_semantics=semantics)
+def _call(kernel, name, heads, tables, operands, in_specs, out_specs,
+          out_shape, scratch_shapes):
+    """One kernel over the grid (batch, ``heads``, the schedule's entries),
+    the schedule's ``tables`` prefetched into scalar memory, where the
+    index maps and the body read them."""
+    return pl.pallas_call(
+        kernel, name=name,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(tables),
+            grid=(operands[0].shape[0], heads, len(tables[0])),
+            in_specs=in_specs, out_specs=out_specs,
+            scratch_shapes=scratch_shapes),
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=on_cpu(),
+    )(*(jnp.asarray(t) for t in tables), *operands)
 
 
-def _geometry(q, num_heads, num_kv_heads, window):
-    b, T, _ = q.shape
-    d = q.shape[-1] // num_heads
-    blk = kernel_block(T)
-    n, keys, queries = _span(T, blk, window)
-    return b, T, d, blk, n, keys, queries, num_heads // num_kv_heads
+def _geometry(q, num_heads, num_kv_heads):
+    T = q.shape[1]
+    return (T, q.shape[-1] // num_heads, kernel_block(T),
+            num_heads // num_kv_heads)
 
 
-def _band_maps(blk, window, group):
-    """Index maps of the kernels whose grid is (batch, head, query block,
-    step in the band): the key block is clamped to the diagonal, so a step
-    past the band fetches nothing new and its body is skipped."""
-    def key_block(i, j):
-        return jnp.minimum(band_first(i * blk, window, blk) + j, i)
-
-    return {
-        "q": lambda b, h, i, j: (b, i, h),
-        "kv": lambda b, h, i, j: (b, key_block(i, j), h // group),
-        "row": lambda b, h, i, j: (b, h, i, 0),
-    }
+def _query_order(T, blk, d, window, group):
+    """What ``attention_fwd`` and ``attention_dq`` share: the schedule's
+    tables and the block specs of a query head's own blocks, its key/value
+    head's blocks and its rows' statistics, at grid step (batch, query
+    head, entry)."""
+    sched = band_schedule(T, blk, window)
+    own = pl.BlockSpec((None, blk, d),
+                       lambda b, h, e, qt, kt, fl: (b, qt[e], h))
+    kv = pl.BlockSpec((None, blk, d),
+                      lambda b, h, e, qt, kt, fl: (b, kt[e], h // group))
+    rows = pl.BlockSpec((None, None, blk, LANES),
+                        lambda b, h, e, qt, kt, fl: (b, h, qt[e], 0))
+    return (sched.q, sched.k, sched.flags), own, kv, rows
 
 
 def attention_pallas(q, k, v, num_heads, num_kv_heads, window):
     """(out [b, T, heads*d], lse [b, heads, T, 128]) by the forward
     kernel."""
-    b, T, d, blk, n, keys, _, group = _geometry(q, num_heads, num_kv_heads,
-                                                window)
-    maps = _band_maps(blk, window, group)
-    kernel = functools.partial(_fwd_kernel, blk=blk, window=window,
-                               scale=d ** -0.5)
-    return pl.pallas_call(
-        kernel, name="attention_fwd",
-        grid=(b, num_heads, n, keys),
-        in_specs=[pl.BlockSpec((None, blk, d), maps["q"]),
-                  pl.BlockSpec((None, blk, d), maps["kv"]),
-                  pl.BlockSpec((None, blk, d), maps["kv"])],
-        out_specs=[pl.BlockSpec((None, blk, d), maps["q"]),
-                   pl.BlockSpec((None, None, blk, LANES), maps["row"])],
+    T, d, blk, group = _geometry(q, num_heads, num_kv_heads)
+    tables, own, kv, rows = _query_order(T, blk, d, window, group)
+    return _call(
+        functools.partial(_fwd_kernel, blk=blk, window=window,
+                          scale=d ** -0.5),
+        "attention_fwd", num_heads, tables, (q, k, v),
+        in_specs=[own, kv, kv], out_specs=[own, rows],
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
-                   jax.ShapeDtypeStruct((b, num_heads, T, LANES),
+                   jax.ShapeDtypeStruct((q.shape[0], num_heads, T, LANES),
                                         jnp.float32)],
         scratch_shapes=[pltpu.VMEM((blk, LANES), jnp.float32),
                         pltpu.VMEM((blk, LANES), jnp.float32),
-                        pltpu.VMEM((blk, d), jnp.float32)],
-        compiler_params=_params(("parallel", "parallel", "parallel",
-                                 "arbitrary")),
-        interpret=on_cpu(),
-    )(q, k, v)
+                        pltpu.VMEM((blk, d), jnp.float32)])
 
 
 def attention_pallas_bwd(q, k, v, out, lse, dout, num_heads, num_kv_heads,
                          window):
     """(dq, dk, dv): one kernel over query blocks for ``dq``, one over key
     blocks for ``dk``/``dv`` that sums a group's query heads in VMEM."""
-    b, T, d, blk, n, keys, queries, group = _geometry(
-        q, num_heads, num_kv_heads, window)
+    T, d, blk, group = _geometry(q, num_heads, num_kv_heads)
+    b = q.shape[0]
     scale = d ** -0.5
     delta = jnp.sum((out.astype(jnp.float32) * dout.astype(jnp.float32))
                     .reshape(b, T, num_heads, d), axis=-1)
     delta = jnp.broadcast_to(jnp.swapaxes(delta, 1, 2)[..., None],
                              (b, num_heads, T, LANES))
-    maps = _band_maps(blk, window, group)
-    rows = pl.BlockSpec((None, None, blk, LANES), maps["row"])
-    dq = pl.pallas_call(
+    operands = (q, k, v, dout, lse, delta)
+    tables, own, kv, rows = _query_order(T, blk, d, window, group)
+    dq = _call(
         functools.partial(_dq_kernel, blk=blk, window=window, scale=scale),
-        name="attention_dq",
-        grid=(b, num_heads, n, keys),
-        in_specs=[pl.BlockSpec((None, blk, d), maps["q"]),
-                  pl.BlockSpec((None, blk, d), maps["kv"]),
-                  pl.BlockSpec((None, blk, d), maps["kv"]),
-                  pl.BlockSpec((None, blk, d), maps["q"]), rows, rows],
-        out_specs=pl.BlockSpec((None, blk, d), maps["q"]),
+        "attention_dq", num_heads, tables, operands,
+        in_specs=[own, kv, kv, own, rows, rows], out_specs=own,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        scratch_shapes=[pltpu.VMEM((blk, d), jnp.float32)],
-        compiler_params=_params(("parallel", "parallel", "parallel",
-                                 "arbitrary")),
-        interpret=on_cpu(),
-    )(q, k, v, dout, lse, delta)
+        scratch_shapes=[pltpu.VMEM((blk, d), jnp.float32)])
 
-    def query_block(i, j):
-        return jnp.minimum(i + j, _last_query(i, blk, window, n))
-
-    q_map = lambda b, h, i, g, j: (b, query_block(i, j), h * group + g)
-    kv_map = lambda b, h, i, g, j: (b, i, h)
-    row_map = lambda b, h, i, g, j: (b, h * group + g, query_block(i, j), 0)
-    rows = pl.BlockSpec((None, None, blk, LANES), row_map)
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, blk=blk, window=window, scale=scale,
-                          n=n),
-        name="attention_dkv",
-        grid=(b, num_kv_heads, n, group, queries),
-        in_specs=[pl.BlockSpec((None, blk, d), q_map),
-                  pl.BlockSpec((None, blk, d), kv_map),
-                  pl.BlockSpec((None, blk, d), kv_map),
-                  pl.BlockSpec((None, blk, d), q_map), rows, rows],
-        out_specs=[pl.BlockSpec((None, blk, d), kv_map),
-                   pl.BlockSpec((None, blk, d), kv_map)],
+    # grid step (batch, key/value head, entry): the entry names the query
+    # head of the group beside its blocks
+    sched = band_schedule(T, blk, window, by="key", group=group)
+    own = pl.BlockSpec(
+        (None, blk, d),
+        lambda b, h, e, qt, kt, gt, fl: (b, qt[e], h * group + gt[e]))
+    kv = pl.BlockSpec((None, blk, d),
+                      lambda b, h, e, qt, kt, gt, fl: (b, kt[e], h))
+    rows = pl.BlockSpec(
+        (None, None, blk, LANES),
+        lambda b, h, e, qt, kt, gt, fl: (b, h * group + gt[e], qt[e], 0))
+    dk, dv = _call(
+        functools.partial(_dkv_kernel, blk=blk, window=window, scale=scale),
+        "attention_dkv", num_kv_heads,
+        (sched.q, sched.k, sched.head, sched.flags), operands,
+        in_specs=[own, kv, kv, own, rows, rows], out_specs=[kv, kv],
         out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
         scratch_shapes=[pltpu.VMEM((blk, d), jnp.float32),
-                        pltpu.VMEM((blk, d), jnp.float32)],
-        compiler_params=_params(("parallel", "parallel", "parallel",
-                                 "arbitrary", "arbitrary")),
-        interpret=on_cpu(),
-    )(q, k, v, dout, lse, delta)
+                        pltpu.VMEM((blk, d), jnp.float32)])
     return dq, dk, dv

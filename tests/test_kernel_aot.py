@@ -93,26 +93,69 @@ def test_lstm_backward_whole_compiles_for_v5e(one_chip, native_lowering):
 ATT_T, ATT_HEADS, ATT_KV, ATT_D = 8192, 32, 4, 128
 
 
-def _attention_shapes(sharding):
+def _attention_shapes(sharding, T=ATT_T):
     def bf16(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=sharding)
-    return (bf16(1, ATT_T, ATT_HEADS * ATT_D), bf16(1, ATT_T, ATT_KV * ATT_D),
-            jax.ShapeDtypeStruct((1, ATT_HEADS, ATT_T, 128), jnp.float32,
+    return (bf16(1, T, ATT_HEADS * ATT_D), bf16(1, T, ATT_KV * ATT_D),
+            jax.ShapeDtypeStruct((1, ATT_HEADS, T, 128), jnp.float32,
                                  sharding=sharding))
 
 
-@pytest.mark.parametrize("window", [1024, 0])
+def _pallas_grids(fn, *args):
+    """{kernel name: (grid, scalar-prefetch operands)} of a traced call."""
+    return {e.params["name"]:
+            (tuple(e.params["grid_mapping"].grid),
+             e.params["grid_mapping"].num_index_operands)
+            for e in jax.make_jaxpr(fn)(*args).eqns
+            if e.primitive.name == "pallas_call"}
+
+
+# a head's schedule at 8192 in blocks of 512: the window layers' 45
+# entries, the full layer's 136; attention_dkv walks each of a group's 8
+# query heads through a key/value head's
+@pytest.mark.parametrize("window,entries", [(1024, 45), (0, 136)])
 def test_attention_kernels_compile_for_v5e(one_chip, native_lowering,
-                                           window):
+                                           window, entries):
+    """Both of the cell's layers, on the scalar-prefetch grid: its last
+    axis is the schedule's length, so no step's body is skipped."""
     from paddle_tpu.ops.pallas import attention as att
     q, kv, lse = _attention_shapes(one_chip)
-    fwd = jax.jit(lambda q, k, v: att.attention_pallas(
-        q, k, v, ATT_HEADS, ATT_KV, window)).lower(q, kv, kv).compile()
-    assert "attention_fwd" in fwd.as_text()
-    bwd = jax.jit(lambda q, k, v, o, l, d: att.attention_pallas_bwd(
-        q, k, v, o, l, d, ATT_HEADS, ATT_KV, window)).lower(
-            q, kv, kv, q, lse, q).compile()
-    text = bwd.as_text()
+    fwd = lambda q, k, v: att.attention_pallas(         # noqa: E731
+        q, k, v, ATT_HEADS, ATT_KV, window)
+    bwd = lambda q, k, v, o, l, d: att.attention_pallas_bwd(  # noqa: E731
+        q, k, v, o, l, d, ATT_HEADS, ATT_KV, window)
+    assert _pallas_grids(fwd, q, kv, kv) == {
+        "attention_fwd": ((1, ATT_HEADS, entries), 3)}
+    assert _pallas_grids(bwd, q, kv, kv, q, lse, q) == {
+        "attention_dq": ((1, ATT_HEADS, entries), 3),
+        "attention_dkv": ((1, ATT_KV, entries * ATT_HEADS // ATT_KV), 4)}
+    assert "attention_fwd" in jax.jit(fwd).lower(
+        q, kv, kv).compile().as_text()
+    text = jax.jit(bwd).lower(q, kv, kv, q, lse, q).compile().as_text()
+    assert "attention_dq" in text and "attention_dkv" in text
+
+
+def test_attention_schedule_fits_scalar_memory_at_the_longest_length(
+        one_chip, native_lowering):
+    """The schedule's tables live in scalar memory (1 MiB on a v5e) and
+    grow with the square of the length: the longest full layer that
+    attention_supported admits at the cell's heads (88 blocks of 512,
+    attention_dkv's 31328 entries) still compiles, and the next power of
+    two is the twin's."""
+    from paddle_tpu.ops.pallas import attention as att
+    T = 88 * 512
+    q, kv, lse = _attention_shapes(one_chip, T)
+    assert att.attention_supported(q, ATT_HEADS, ATT_KV, 0)
+    assert not att.attention_supported(
+        _attention_shapes(one_chip, 65536)[0], ATT_HEADS, ATT_KV, 0)
+    assert att.attention_supported(
+        _attention_shapes(one_chip, 65536)[0], ATT_HEADS, ATT_KV, 1024)
+    bwd = lambda q, k, v, o, l, d: att.attention_pallas_bwd(  # noqa: E731
+        q, k, v, o, l, d, ATT_HEADS, ATT_KV, 0)
+    entries = len(att.band_schedule(T, 512, 0, by="key",
+                                    group=ATT_HEADS // ATT_KV).q)
+    assert att.MAX_ENTRIES - 2048 < entries <= att.MAX_ENTRIES
+    text = jax.jit(bwd).lower(q, kv, kv, q, lse, q).compile().as_text()
     assert "attention_dq" in text and "attention_dkv" in text
 
 

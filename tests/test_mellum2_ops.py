@@ -207,22 +207,30 @@ def test_a_rigged_router_drops_no_row_and_an_overflow_is_loud():
     assert not np.asarray(parts[1][0]).any()
 
 
-@pytest.mark.parametrize("window", [0, 100])
-def test_attention_kernels_match_the_twin_through_the_op(window):
+# (T, window): the block follows from T (256, 256; then 256 under a window
+# no multiple of it and one equal to it, 128 under a window wider than T,
+# one block of 128, 512 with whole blocks under the diagonal)
+@pytest.mark.parametrize("length,window", [
+    (256, 0), (256, 100), (768, 300), (768, 256), (384, 4096), (128, 0),
+    (1536, 0)])
+def test_attention_kernels_match_the_twin_through_the_op(length, window):
     """kernel_tier=pallas runs the attention family's three kernels in the
-    interpreter; outputs and all three gradients match the blocked twin."""
+    interpreter; outputs, the log-sum-exp and all three gradients match
+    the blocked twin."""
     from paddle_tpu.ops.pallas import dispatch_counts
+
+    batch = 2 if length <= 256 else 1
 
     def run(tier):
         fluid.set_flags({"kernel_tier": tier})
         try:
             main, startup = fluid.Program(), fluid.Program()
             with fluid.program_guard(main, startup):
-                q = fluid.layers.data("q", shape=[2, 256, 512],
+                q = fluid.layers.data("q", shape=[batch, length, 512],
                                       append_batch_size=False)
-                k = fluid.layers.data("k", shape=[2, 256, 256],
+                k = fluid.layers.data("k", shape=[batch, length, 256],
                                       append_batch_size=False)
-                v = fluid.layers.data("v", shape=[2, 256, 256],
+                v = fluid.layers.data("v", shape=[batch, length, 256],
                                       append_batch_size=False)
                 for var in (q, k, v):
                     var.stop_gradient = False
@@ -231,21 +239,43 @@ def test_attention_kernels_match_the_twin_through_the_op(window):
                 loss = fluid.layers.mean(fluid.layers.elementwise_mul(
                     out, out))
                 fluid.backward.append_backward(loss)
+            lse, = main.global_block().ops[0].output("LogSumExp")
             rng = np.random.RandomState(1)
             feed = {n: rng.randn(*s).astype(np.float32) for n, s in (
-                ("q", (2, 256, 512)), ("k", (2, 256, 256)),
-                ("v", (2, 256, 256)))}
+                ("q", (batch, length, 512)), ("k", (batch, length, 256)),
+                ("v", (batch, length, 256)))}
             return fluid.Executor(mode="jit").run(
                 main, feed=feed, scope=fluid.Scope(),
-                fetch_list=[out.name, "q@GRAD", "k@GRAD", "v@GRAD"])
+                fetch_list=[out.name, lse, "q@GRAD", "k@GRAD", "v@GRAD"])
         finally:
             fluid.set_flags({"kernel_tier": "auto"})
 
     before = dispatch_counts().get("attention", {}).get("interpret", 0)
     kernel, twin = run("pallas"), run("jnp")
     assert dispatch_counts()["attention"]["interpret"] == before + 2
+    kernel[1] = np.asarray(kernel[1])[..., 0]       # lane-replicated
     for a, b in zip(kernel, twin):
         assert _err(a, b) < 1e-5
+
+
+def test_attention_blocks_gauge_reads_the_schedule_as_last_traced():
+    """paddle_tpu_attention_blocks after the op's dispatch at the Mellum2
+    cell's length (nothing runs): the full layer, then a window layer."""
+    from paddle_tpu.obs.metrics import REGISTRY
+    from paddle_tpu.ops import attention_ops
+    from paddle_tpu.ops.pallas import attention as att
+
+    q = jax.ShapeDtypeStruct((1, 8192, 4 * 128), jnp.bfloat16)
+    assert att.kernel_block(8192) == 512
+    fluid.set_flags({"kernel_tier": "pallas"})
+    try:
+        for window, blocks in ((0, [136, 120]), (1024, [45, 3])):
+            assert attention_ops._attention_route(q, 4, 1, window) == "pallas"
+            gauge = REGISTRY.get("paddle_tpu_attention_blocks")
+            assert [int(gauge.labels(window=window, kind=kind).value)
+                    for kind in ("scheduled", "skipped")] == blocks
+    finally:
+        fluid.set_flags({"kernel_tier": "auto"})
 
 
 def test_grouped_matmul_kernels_match_ragged_dot_through_the_op():

@@ -15,7 +15,9 @@ Cases: conv_bn forward and backward at ResNet-50 bottleneck shapes
 ``kernel_tier=pallas``); the fused momentum step over ResNet-50's real
 parameter census; lstm/gru at the ``bench.py`` RNN-lane shape; ctc and
 embedding_sgd at the shapes their parity tests pin, scaled to a workload
-size; paged_attention at the generation lane's decode shape.
+size; paged_attention at the generation lane's decode shape; banded
+attention at the Mellum2 cell's shape (8192 tokens, 32 / 4 heads of 128,
+bfloat16), forward and backward, a window layer and the full one.
 
 A kernel that fails to lower is a RESULT here (``lowered: false`` with the
 compiler's message), never a crash. A watchdog ends the process if one
@@ -192,6 +194,34 @@ def ctc_case(b, t, c, u):
     return {"jnp": lambda: scan(logits), "pallas": lambda: pal(logits)}
 
 
+def attention_case(T, heads, kv_heads, window, backward):
+    """Banded grouped-query attention: the kernels vs the blocked twin,
+    each route's backward from its own forward's residual."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops.pallas import attention as att
+
+    d = 128
+    keys = jax.random.split(jax.random.PRNGKey(window), 4)
+    q, dout = (jax.random.normal(k, (1, T, heads * d), jnp.bfloat16)
+               for k in keys[:2])
+    k, v = (jax.random.normal(k, (1, T, kv_heads * d), jnp.bfloat16)
+            for k in keys[2:])
+
+    def route(fwd, bwd):
+        forward = jax.jit(lambda q, k, v: fwd(q, k, v, heads, kv_heads,
+                                              window))
+        if not backward:
+            return lambda: forward(q, k, v)[0]
+        out, lse = forward(q, k, v)
+        grads = jax.jit(lambda q, k, v, dout: bwd(
+            q, k, v, out, lse, dout, heads, kv_heads, window))
+        return lambda: grads(q, k, v, dout)
+
+    return {"jnp": route(att.attention_jnp, att.attention_jnp_bwd),
+            "pallas": route(att.attention_pallas, att.attention_pallas_bwd)}
+
+
 def resnet50_param_shapes():
     """The tensors the flagship's optimizer updates, read off the built
     program (the PR-21 run also counted the 106 BN running statistics:
@@ -237,6 +267,12 @@ def cases(tiny):
            lambda: registry_case("paged_attention", make_key(
                q=(s, 4, 128), kc=(nb, 16, 4, 128), tables=4,
                dtype="float32")))
+    T, heads, kv, win = (384, 2, 1, 256) if tiny else (8192, 32, 4, 1024)
+    for window in (win, 0):
+        for bwd in (False, True):
+            yield (f"attention_{'bwd' if bwd else 'fwd'}_len{T}_window"
+                   f"{window}", "attention",
+                   lambda a=(T, heads, kv, window, bwd): attention_case(*a))
 
 
 def lstm_lane_step(tiny, rounds=3):
