@@ -57,13 +57,8 @@ def _rec(d):
     from paddle_tpu.core.flags import get_flag
     from paddle_tpu.obs import REGISTRY, json_safe, perf, recorder, slo
     from paddle_tpu.ops.pallas import resolve_tier
-    from paddle_tpu.ops.autotune import active_digest
     out = dict(d)
     out.setdefault("kernel_tier", resolve_tier())
-    # tuning-table stamp: the digest of the ATTACHED kernel-tuning table
-    # (None = static AUTO_PALLAS routing) — a row measured under tuned
-    # routing is attributable to the exact table that routed it
-    out.setdefault("tune_digest", active_digest())
     out.setdefault("executor_verify", bool(get_flag("executor_verify")))
     # backend stamp: which accelerator actually measured this row — a
     # CPU-smoke record must never be mistaken for a TPU measurement when
@@ -234,8 +229,8 @@ def _run_rnn_lane(build_fn, batch, seq_len, hidden, steps, warmup,
 
     scope = fluid.Scope()
     exe = fluid.Executor(mode="jit", donate=True)
-    prev = get_flag("use_pallas_rnn")
-    set_flags({"use_pallas_rnn": bool(use_pallas)})
+    prev = get_flag("kernel_tier")
+    set_flags({"kernel_tier": "pallas" if use_pallas else "jnp"})
     try:
         with jax.default_matmul_precision("bfloat16"):
             exe.run(startup, scope=scope)
@@ -252,7 +247,7 @@ def _run_rnn_lane(build_fn, batch, seq_len, hidden, steps, warmup,
             loss_v = np.asarray(v[0])
             elapsed = time.perf_counter() - t0
     finally:
-        set_flags({"use_pallas_rnn": prev})
+        set_flags({"kernel_tier": prev})
     assert np.isfinite(loss_v), f"non-finite rnn loss {loss_v}"
     return elapsed / steps * 1e3
 
@@ -292,8 +287,8 @@ def build_gru_textcls(batch, seq_len, hidden, vocab=30000, emb=128,
 def run_gru_lane(batch=64, seq_len=100, hidden=512, steps=48, warmup=4,
                  use_pallas=False, vocab=30000):
     """ms/batch for the GRU text-classification lane (--with-gru): the
-    whole-recurrence Pallas kernel's A/B surface (see
-    flags.use_pallas_rnn)."""
+    whole-recurrence Pallas kernel's A/B surface (``use_pallas`` picks
+    kernel_tier pallas or jnp for the run)."""
     return _run_rnn_lane(build_gru_textcls, batch, seq_len, hidden, steps,
                          warmup, use_pallas, vocab)
 
@@ -1621,149 +1616,6 @@ def run_fused_kernels_lane(smoke):
             out["conv_bn_relu"]["speedup"] >= 1.15
             and out["optimizer_step"]["speedup"] >= 1.15)
     return out
-
-
-def run_kernel_autotune_lane(smoke):
-    """End-to-end A/B for the kernel autotuner plane (ops/autotune.py):
-    one fused_conv2d_bn-bearing infer step measured under each STATIC
-    kernel tier and under ``kernel_tier=auto`` with a freshly tuned
-    table attached.
-
-    Flow: build the program once; trace it under ``capture()`` to learn
-    the REAL dispatch keys; ``Tuner``-measure every registered variant
-    per key; ``attach_table`` the winners; then time the identical step
-    under ``kernel_tier=jnp``, ``kernel_tier=pallas``, and tuned auto —
-    all three through the autotuner's shared measurement core
-    (``ops.autotune.measure``), one config at a time (the tier flags sit
-    in the jit key, so interleaving configs would retrace every window).
-
-    Gates, asserted in-lane on every backend:
-      * ZERO in-band tuning work in the tuned timed runs (the tunes
-        counter is flat across them — selection is a table lookup at
-        trace time);
-      * one fetched step under tuned routing is BITWISE the static tier
-        that compiles the same family (jnp for a jnp selection, pallas
-        for pallas/pallas_db — the double-buffered kernel accumulates in
-        the same order);
-      * tuned >= 1.0x the best static tier. When the tuned selection is
-        a variant some static tier also compiles (always true on CPU,
-        where interpret-mode Pallas loses to jnp by construction and the
-        tuned program IS the jnp program), the two configs time the
-        identical executable and the gate allows 5% same-program
-        run-to-run jitter; a selection no static tier can express
-        (pallas_db) must beat best-static outright.
-    """
-    import jax
-    import paddle_tpu.fluid as fluid
-    from paddle_tpu.core.flags import get_flag, set_flags
-    from paddle_tpu.fluid import framework
-    from paddle_tpu.obs import REGISTRY
-    from paddle_tpu.ops import autotune as at
-
-    if smoke:
-        n, hw, cin, cout = 2, 8, 8, 8
-        repeats, inner = 2, 2
-    else:
-        n, hw, cin, cout = 32, 28, 64, 64
-        repeats, inner = 3, 8
-
-    framework.reset_unique_name()
-    main, startup = fluid.Program(), fluid.Program()
-    main.random_seed = startup.random_seed = 7
-    with fluid.program_guard(main, startup):
-        img = fluid.layers.data("img", shape=[hw, hw, cin])
-        c1 = fluid.layers.conv2d(img, cout, 3, padding=1, bias_attr=False,
-                                 data_format="NHWC")
-        b1 = fluid.layers.batch_norm(c1, act="relu", data_layout="NHWC",
-                                     is_test=True)
-        out_var = fluid.layers.mean(b1)
-    n_fused = fluid.fuse_conv_bn(main)
-    assert n_fused == 1, f"expected 1 fused chain, got {n_fused}"
-
-    rng = np.random.RandomState(0)
-    feed = {"img": rng.normal(0, 1, (n, hw, hw, cin)).astype("float32")}
-
-    def make_runner():
-        scope = fluid.Scope()
-        exe = fluid.Executor(mode="jit")
-        exe.run(startup, scope=scope)
-
-        def run():
-            return exe.run(main, feed=feed, fetch_list=[out_var],
-                           scope=scope, return_numpy=False)[0]
-        return run
-
-    def _tunes():
-        return REGISTRY.totals().get("paddle_tpu_kernel_autotune_tunes", 0)
-
-    saved = {k: get_flag(k) for k in ("kernel_tier", "kernel_autotune")}
-    try:
-        # ---- capture the program's real dispatch keys, tune, attach ----
-        at.detach_table()
-        set_flags({"kernel_tier": "auto", "kernel_autotune": True})
-        with at.capture() as keys:
-            make_runner()()
-        assert any(k == "conv_bn" for k, _, _ in keys), \
-            "the fused program must dispatch through conv_bn"
-        table = at.Tuner(repeats=repeats, inner=inner).tune(keys)
-        digest = at.attach_table(table)
-        selections = {k: e["variant"]
-                      for (k, _), e in sorted(table.entries.items())}
-        sel = selections["conv_bn"]
-        # no assumption about WHICH variant wins: interpret-mode Pallas
-        # can beat jnp at tiny shapes — the parity reference and the
-        # speedup gate below both key off the actual selection
-
-        # ---- time the three configs through the shared measure core ----
-        ms, step_out = {}, {}
-        for name, tier, attach in (("jnp", "jnp", False),
-                                   ("pallas", "pallas", False),
-                                   ("tuned", "auto", True)):
-            if attach:
-                at.attach_table(table, merge=False)
-            else:
-                at.detach_table()
-            set_flags({"kernel_tier": tier})
-            runner = make_runner()
-            t0 = _tunes()
-            got = at.measure({name: runner}, repeats=repeats, inner=inner)
-            assert _tunes() == t0, \
-                f"in-band tuning work during the {name!r} timed run"
-            if name in got:
-                ms[name] = got[name]
-                step_out[name] = np.asarray(runner(), np.float32)
-
-        best_static = min(v for k, v in ms.items() if k != "tuned")
-        speedup = best_static / ms["tuned"]
-        # bitwise parity: tuned vs the static tier compiling the same
-        # kernel family (pallas_db accumulates in pallas order)
-        ref = "jnp" if sel == "jnp" else "pallas"
-        parity_ok = bool(ref in step_out
-                         and np.array_equal(step_out["tuned"],
-                                            step_out[ref]))
-        assert parity_ok, f"tuned step != static {ref} step bitwise"
-        same_program = sel in ms  # a static tier compiles this variant
-        gate_ok = bool(speedup >= (0.95 if same_program else 1.0))
-        assert gate_ok, \
-            f"tuned {ms['tuned']:.3f}ms lost to best static {best_static:.3f}ms"
-        return {
-            "jnp_ms": round(ms["jnp"], 3),
-            "pallas_ms": None if "pallas" not in ms
-            else round(ms["pallas"], 3),
-            "tuned_ms": round(ms["tuned"], 3),
-            "speedup": round(speedup, 4),
-            "selections": selections,
-            "tune_digest": digest,
-            "tuned_entries": len(table.entries),
-            "gate": 1.0,
-            "gate_applies": True,
-            "gate_ok": gate_ok,
-            "tunes_during_timing": 0,
-            "parity": "bitwise",
-        }
-    finally:
-        at.detach_table()
-        set_flags(saved)
 
 
 def run_placement_planner_lane(smoke):
@@ -3223,22 +3075,6 @@ def main():
                 "(interpret-mode parity only on CPU; gate applies on TPU)",
         "vs_baseline": fk["conv_bn_relu"]["speedup"],
         **fk,
-    })))
-
-    # ---- kernel autotuner lane (measured per-shape variant selection) ----
-    ka = run_kernel_autotune_lane(args.smoke)
-    print(json.dumps(_rec({
-        "metric": "kernel_autotune" + ("_smoke" if args.smoke else ""),
-        "value": ka["speedup"],
-        "unit": "x tuned-table auto routing vs best single static "
-                "kernel_tier, fused conv+bn infer step (gate >= 1.0x; "
-                "5% same-program jitter allowed when the tuned selection "
-                "is a variant a static tier also compiles; bitwise "
-                "parity + zero in-band tuning asserted in-lane)",
-        # higher-is-better speedup of tuned routing over the best static
-        # tier — the lane's own baseline
-        "vs_baseline": ka["speedup"],
-        **ka,
     })))
 
     # ---- placement planner lane (searched meshes over a measured cost

@@ -11,9 +11,8 @@ __graft_entry__.py) calls :func:`enable` instead of naming a directory:
 * unset — one fixed path inside the checkout, ``<repo>/.jax_cache``
   (gitignored). Never a tempdir, a pid or a timestamp.
 
-This is JAX's own XLA-executable cache. The ``.jexec`` / ``.jtune`` /
-``.jplan`` artifact caches (serving/execcache.py, ops/autotune.py,
-parallel/planner.py) are separate planes and are not placed here.
+This is JAX's own XLA-executable cache. The ``.jexec`` / ``.jplan``
+artifact caches (serving/execcache.py, parallel/planner.py) are separate planes and are not placed here.
 """
 
 from __future__ import annotations

@@ -464,12 +464,11 @@ def _written_names(program, block_idx):
 
 # the flag-tuple portion of the jit-cache key: revalidated against the flag
 # registry's version counter so a steady-state run() costs one compare, not
-# eight registry lookups per dispatch
-_JIT_KEY_FLAGS = ("xla_compiler_options", "use_pallas_rnn",
-                  "bn_fusion_barrier", "bn_fusion_barrier_fwd",
-                  "bn_fusion_barrier_bwd", "conv_space_to_depth",
-                  "conv_1x1_grad_as_dot", "use_pallas_ctc", "kernel_tier",
-                  "kernel_autotune", "kernel_autotune_digest")
+# a registry lookup per flag per dispatch
+_JIT_KEY_FLAGS = ("xla_compiler_options", "bn_fusion_barrier",
+                  "bn_fusion_barrier_fwd", "bn_fusion_barrier_bwd",
+                  "conv_space_to_depth", "conv_1x1_grad_as_dot",
+                  "kernel_tier")
 
 _JIT_FLAG_KEY = (None, ())
 

@@ -85,42 +85,21 @@ DEFINE_flag("benchmark", False,
 DEFINE_flag("kernel_tier", "auto",
             "which lowering tier the hot-op dispatch sites use: 'auto' "
             "(Pallas on TPU for the families in ops/pallas.AUTO_PALLAS — "
-            "today only lstm; membership needs an on-chip observation, "
-            "see there — jnp elsewhere, so CPU suites never pay "
-            "interpret-mode kernels), 'pallas' (Pallas everywhere it "
-            "has a lowering; interpret mode on CPU — the parity-test "
+            "lstm, attention, grouped_matmul; membership needs an on-chip "
+            "observation, see there — jnp elsewhere, so CPU suites never "
+            "pay interpret-mode kernels), 'pallas' (Pallas for every "
+            "family; interpret mode on CPU — the parity-test "
             "setting; on a TPU a kernel Mosaic cannot compile raises), "
             "or 'jnp' (the plain jax.numpy lowerings, bitwise "
             "the pre-tier behavior). Per-kernel fallback: an unsupported "
             "shape under a Pallas tier routes to the jnp twin silently "
             "and bumps ops.pallas.fallback_counts()")
 
-DEFINE_flag("use_pallas_rnn", False,
-            "DEPRECATED (use kernel_tier; still honored — True forces the "
-            "Pallas path for the RNN kernels, with a one-time warning): "
-            "use the Pallas whole-recurrence kernels (the hand-scheduled "
-            "hl_cuda_lstm.cu analogs): LSTM and GRU each run their WHOLE "
-            "sequence as one kernel with the recurrent weight VMEM-"
-            "resident across steps. On a TPU v5 lite (PR 21, "
-            "tools/kernel_probe.py, bs64 len100 hid512): LSTM "
-            "recurrence 1.22x and the LSTM-lane train step a tie "
-            "(3.386 vs 3.389 ms); GRU recurrence 1.61x, GRU train step "
-            "not measured. Default off so CPU test runs avoid "
-            "interpret-mode kernels; bench.py measures both paths and "
-            "reports the winner")
 DEFINE_flag("xla_compiler_options", "",
             "comma-separated k=v TPU compiler options forwarded to "
             "jit(compiler_options=...), e.g. "
             "xla_tpu_scoped_vmem_limit_kib=114688 — the analog of the "
             "reference's backend gflags (platform/gpu_info.cc)")
-
-DEFINE_flag("use_pallas_ctc", False,
-            "DEPRECATED (use kernel_tier; still honored — True forces the "
-            "Pallas CTC path, with a one-time warning): "
-            "use the Pallas whole-recurrence CTC forward (alpha kept "
-            "VMEM-resident across time, the warp-ctc shared-memory "
-            "pattern) inside warpctc; default off — numerics pinned "
-            "against the lax.scan path")
 
 DEFINE_flag("conv_space_to_depth", False,
             "rewrite eligible stem convs (NHWC, stride 2, C_in<=4, k>1 — "
@@ -510,37 +489,6 @@ DEFINE_flag("obs_incident_dir", "",
             "bundles (one JSON file per trigger: breach / canary_failed "
             "/ child_restart) into; empty (default) keeps bundles "
             "in-memory only (IncidentCollector.bundles, bounded)")
-
-DEFINE_flag("kernel_autotune", True,
-            "consult the attached kernel-tuning table (ops.autotune) "
-            "when routing tunable kernels under kernel_tier=auto; off "
-            "means pure static AUTO_PALLAS routing even with a table "
-            "attached. In the executor's _JIT_KEY_FLAGS: flipping it "
-            "retraces so jitted programs re-route")
-
-DEFINE_flag("kernel_autotune_dir", "",
-            "local directory of kernel-tuning-table artifacts "
-            "(.jtune) consulted read-only when an engine's bundle has "
-            "no published tune/ dir, and the write target for "
-            "tools/autotune.py --out; empty (default) disables the "
-            "local-dir fallback. Not in the jit key: the attached "
-            "table's identity is carried by kernel_autotune_digest")
-
-DEFINE_flag("kernel_autotune_digest", "",
-            "content digest of the ATTACHED kernel-tuning table; set "
-            "and cleared by ops.autotune.attach_table/detach_table, "
-            "not by hand. In the executor's _JIT_KEY_FLAGS so a table "
-            "swap retraces every jitted program and flows into "
-            "execcache fingerprints (a warm executable compiled under "
-            "table X never loads into a process routing by table Y)")
-
-DEFINE_flag("kernel_autotune_bf16", False,
-            "allow the tuner to consider, and tuned dispatch to "
-            "select, bf16-flagged kernel variants (value-changing "
-            "reduced-precision activations, e.g. conv_bn pallas_bf16). "
-            "Off (default) keeps every tunable selection bitwise "
-            "against static routing; a table entry naming a bf16 "
-            "variant is ignored without this opt-in")
 
 DEFINE_flag("plan_memory_budget_bytes", 0,
             "per-device memory budget the placement planner "

@@ -372,16 +372,6 @@ def chunked_prefill_attention(ctx):
     ctx.set_output("KCacheOut", kc)
     ctx.set_output("VCacheOut", vc)
 
-    # one lowering today, but the dispatch still registers the shape key
-    # (and capture records it), so a future pallas chunked-prefill
-    # variant tunes in with no dispatch-site change
-    from .autotune import dispatch_variant, make_key
-    dispatch_variant(
-        "chunked_prefill_attention",
-        make_key(q=tuple(q.shape), kc=tuple(kc.shape),
-                 tables=int(bt.shape[1]), heads=h, dtype=str(q.dtype)),
-        {"jnp": True})
-
     kctx = _gather_context(kc, bt)                             # [b, C, H, D]
     vctx = _gather_context(vc, bt)
     qh = _split_heads(q, h)                                    # [b, T, H, D]
@@ -429,19 +419,13 @@ def paged_attention(ctx):
     ctx.set_output("KCacheOut", kc)
     ctx.set_output("VCacheOut", vc)
 
-    from .autotune import dispatch_variant, make_key
-    from .pallas import kernel_span
+    from .pallas import kernel_span, use_pallas
     from .pallas import paged_attention as pa
 
     qh = _split_heads(q, h)[:, 0]                              # [b, H, D]
     b = bt.shape[0]
-    key = make_key(q=tuple(qh.shape), kc=tuple(kc.shape),
-                   tables=int(bt.shape[1]), dtype=str(qh.dtype))
-    choice = dispatch_variant("paged_attention", key, {
-        "jnp": True,
-        "pallas": pa.paged_attention_supported(qh, kc, bt),
-    })
-    if choice == "pallas":
+    if use_pallas("paged_attention",
+                  pa.paged_attention_supported(qh, kc, bt)):
         with kernel_span("pallas", "paged_attention"):
             out = pa.paged_attention_pallas(qh, kc, vc, bt, ctx_lens)
     else:

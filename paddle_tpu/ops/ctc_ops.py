@@ -28,9 +28,9 @@ _NEG = -1e30
 def _ctc_loss(logits, x_lens, labels, y_lens, blank):
     """logits [b, T, C] unnormalized; labels [b, U] int; returns [b, 1].
     Dispatches to the Pallas whole-recurrence kernel under the kernel
-    tier (legacy use_pallas_ctc still honored; backward always runs the
-    scan path via custom_vjp, like the RNN cells). T==1 sequences have no
-    recurrence to fuse and route to the scan path (counted fallback)."""
+    tier (backward always runs the scan path via custom_vjp). T==1
+    sequences have no recurrence to fuse and route to the scan path
+    (counted fallback)."""
     from .pallas import use_pallas, kernel_span
     if use_pallas("ctc", logits.shape[1] > 1):
         with kernel_span("pallas", "ctc"):

@@ -36,11 +36,10 @@ from .pallas import use_pallas, kernel_span
 
 
 def _fused_supported(x, w, strides, paddings, dilations, groups, df,
-                     backward=False, block_n=1, dtype=None):
+                     backward=False):
     from .pallas import conv_bn as cbk
     return cbk.supported(tuple(x.shape), tuple(w.shape), strides, paddings,
-                         dilations, groups, df, dtype or x.dtype,
-                         backward=backward, block_n=block_n)
+                         dilations, groups, df, x.dtype, backward=backward)
 
 
 def _fused_conv_bn_infer(op, block):
@@ -92,47 +91,23 @@ def fused_conv2d_bn(ctx):
     # construction: s2d needs k>1 at stride 2, the fused path takes
     # stride 2 only at k=1 — s2d-eligible convs always land on the jnp
     # twin, whose _conv2d_compute applies the rewrite itself
-    sup = _fused_supported(x, w, strides, paddings, dilations, groups, df)
-    from .autotune import dispatch_variant, make_key
-    key = make_key(x=tuple(x.shape), w=tuple(w.shape), dtype=str(x.dtype),
-                   strides=tuple(strides), paddings=tuple(paddings),
-                   dilations=tuple(dilations), groups=groups, df=df,
-                   act=act, is_test=is_test)
-    choice = dispatch_variant("conv_bn", key, {
-        "jnp": True,
-        "pallas": sup,
-        "pallas_db": _fused_supported(x, w, strides, paddings, dilations,
-                                      groups, df, block_n=2),
-        # bf16 activations (value-changing, tuner opt-in): only a cast
-        # AWAY from f32 is a distinct variant
-        "pallas_bf16": (x.dtype == jnp.float32
-                        and _fused_supported(x, w, strides, paddings,
-                                             dilations, groups, df,
-                                             dtype=jnp.bfloat16)),
-    })
-    if choice != "jnp":
+    if use_pallas("conv_bn", _fused_supported(x, w, strides, paddings,
+                                              dilations, groups, df)):
         from .pallas import conv_bn as cbk
-        out_dtype = x.dtype
-        block_n = 2 if choice == "pallas_db" else 1
-        if choice == "pallas_bf16":
-            x, w = x.astype(jnp.bfloat16), w.astype(jnp.bfloat16)
         if is_test:
             inv = jax.lax.rsqrt(rv.astype(jnp.float32) + eps)
             a = scale.astype(jnp.float32) * inv
             b = bias.astype(jnp.float32) - rm.astype(jnp.float32) * a
-            with kernel_span(choice, "conv_bn"):
+            with kernel_span("pallas", "conv_bn"):
                 y = cbk.conv_affine_pallas(x, w, a, b, strides, paddings,
-                                           act, block_n=block_n)
+                                           act)
             new_mean, new_var, sm, sv = rm, rv, rm, rv
         else:
-            with kernel_span(choice, "conv_bn"):
+            with kernel_span("pallas", "conv_bn"):
                 y, sm, sv = cbk.conv_bn_train_pallas(
-                    x, w, scale, bias, eps, strides, paddings, act,
-                    block_n=block_n)
+                    x, w, scale, bias, eps, strides, paddings, act)
             new_mean = momentum * rm + (1.0 - momentum) * sm
             new_var = momentum * rv + (1.0 - momentum) * sv
-        if choice == "pallas_bf16":
-            y = y.astype(out_dtype)
     else:
         with kernel_span("jnp", "conv_bn"):
             z = _conv2d_compute(x, w, strides, paddings, dilations, groups,

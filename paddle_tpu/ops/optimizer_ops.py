@@ -86,16 +86,9 @@ def _sgd_apply(p_v, g_v, lr):
     duplicate rows accumulate correctly)."""
     p = data_of(p_v)
     if is_sparse(g_v):
-        from .autotune import dispatch_variant, make_key
-        from .pallas import kernel_span
-        supported = p.ndim == 2 and g_v.values.ndim == 2
-        key = make_key(rows=int(p.shape[0]),
-                       dim=int(p.shape[1]) if p.ndim == 2 else 0,
-                       nnz=int(g_v.values.shape[0]), dtype=str(p.dtype))
-        choice = dispatch_variant("embedding", key,
-                                  {"jnp": True, "pallas": supported},
-                                  tier_kernel="embedding_sgd")
-        if choice == "pallas":
+        from .pallas import kernel_span, use_pallas
+        if use_pallas("embedding_sgd",
+                      p.ndim == 2 and g_v.values.ndim == 2):
             from .pallas.embedding import embedding_sgd_pallas
             m = merge_rows(g_v.astype(p.dtype))
             with kernel_span("pallas", "embedding_sgd"):
@@ -323,8 +316,7 @@ def _fused_apply(ctx, state_slots, out_slots, dense_fn, sparse_fn,
     return a (p_new, *state_news) tuple; arena_fn(*arenas) returns the
     updated arenas in the same order.
     """
-    from .autotune import dispatch_variant, make_key
-    from .pallas import kernel_span
+    from .pallas import kernel_span, use_pallas
 
     slots = ("Params", "Grads") + tuple(state_slots)
     entries = list(zip(*[ctx.inputs(s) for s in slots]))
@@ -345,13 +337,7 @@ def _fused_apply(ctx, state_slots, out_slots, dense_fn, sparse_fn,
             outs[j][i] = v
     # the dispatch runs even with no fusable params so an all-sparse op
     # under a Pallas tier is a counted fallback, not a silent miss
-    kind = {0: "sgd", 1: "momentum"}.get(len(state_slots), "adam")
-    elems = sum(int(data_of(entries[i][0]).size) for i in fusable)
-    choice = dispatch_variant(
-        "optimizer",
-        make_key(kind=kind, tensors=len(fusable), elems=elems),
-        {"jnp": True, "pallas": bool(fusable)})
-    if choice == "pallas":
+    if use_pallas("optimizer", bool(fusable)):
         from .pallas import optimizer as opk
         ps = [data_of(entries[i][0]) for i in fusable]
         gs = [data_of(entries[i][1]).astype(jnp.float32) for i in fusable]

@@ -14,16 +14,13 @@ Tier selection (the ``kernel_tier`` flag):
   :data:`AUTO_PALLAS` (admitted by an on-chip observation, see there), jnp
   everywhere else (CPU suites never pay interpret-mode kernels unless they
   opt in).
-* ``pallas`` — Pallas for every kernel with a lowering (interpret mode on
-  CPU: this is what the parity tests run). On a TPU a kernel that Mosaic
-  cannot compile raises its compile error: nothing between
-  :func:`use_pallas` and ``pallas_call`` catches it.
+* ``pallas`` — Pallas for every family (interpret mode on CPU: this is
+  what the parity tests run). On a TPU a kernel that Mosaic cannot compile
+  raises its compile error: nothing between :func:`use_pallas` and
+  ``pallas_call`` catches it (``ctc``, ``embedding_sgd`` and
+  ``paged_attention`` do not lower: PR 21).
 * ``jnp`` — the plain jax.numpy lowerings, bitwise-identical to the
   pre-tier behavior.
-
-The legacy ``use_pallas_rnn`` / ``use_pallas_ctc`` flags are deprecated but
-still honored: set to True they force the Pallas path for their kernels
-(with a one-time DeprecationWarning) regardless of ``kernel_tier``.
 
 Fallback contract: when the tier resolves to Pallas but a dispatch site
 reports the shape/config unsupported (``supported=False``), the call
@@ -36,7 +33,6 @@ per op.
 
 from __future__ import annotations
 
-import warnings
 from contextlib import contextmanager
 
 from ...core.flags import get_flag
@@ -44,10 +40,12 @@ from ...core.profiler import record_event
 from ...obs.metrics import REGISTRY as _METRICS
 
 # Families that default to Pallas under kernel_tier=auto on a TPU. A family
-# is admitted only by an on-chip observation (tools/kernel_probe.py) at the
-# shapes the repo runs: it lowered natively, matched its jnp twin, and its
-# step was not slower than the twin's in a same-process A/B. Taken on a
-# TPU v5 lite, jax 0.9.0 (PR 21; full lines in CHANGES.md / PERF.md):
+# is admitted only by an on-chip observation at the shapes the repo runs:
+# tools/kernel_probe.py on the chip (it lowered natively, matched its jnp
+# twin, and was not slower in a same-process A/B) AND a benchmark cell whose
+# step it does not slow. The next family's evidence must come from the same
+# two places. One entry a family, taken on a TPU v5 lite, jax 0.9.0 (PR 21
+# where no other PR is named; full lines in CHANGES.md / PERF.md):
 #   lstm          IN   bitwise equal to the scan twin; recurrence 0.458 vs
 #                      0.558 ms (1.22x, PR 21). With the backward a kernel
 #                      too (lstm_bwd, PR 27) the benchmark's LSTM cells
@@ -75,19 +73,10 @@ from ...obs.metrics import REGISTRY as _METRICS
 #                      per-step concat/split costs more than it saves
 #   ctc           out  does not lower: (1, S) block of a [b, S] array
 #   embedding_sgd out  does not lower: (1, D) block of a [R, D] array
-# Not in the set and not admitted by this rule yet: gru (recurrence 1.61x,
-# step not measured), paged_attention (does not lower under this jax).
-# Every family stays reachable with kernel_tier=pallas.
+#   paged_attention out does not lower under this jax
+#   gru           out  recurrence 1.61x its scan, but no step measured: no
+#                      cell runs a GRU
 AUTO_PALLAS = frozenset({"lstm", "attention", "grouped_matmul"})
-
-# kernel family -> the deprecated flag that used to gate it
-_LEGACY_FLAGS = {
-    "lstm": "use_pallas_rnn",
-    "gru": "use_pallas_rnn",
-    "ctc": "use_pallas_ctc",
-}
-
-_warned_legacy: set = set()
 
 # pallas->jnp silent-fallback counter, in the obs.metrics registry
 # (fallback_counts() derives its historical dict from this family)
@@ -106,21 +95,6 @@ _M_DISPATCHES = _METRICS.counter(
     labels=("kernel", "mode"))
 
 
-def _legacy_forced(kernel):
-    """True when the kernel's deprecated flag is set (warn once per flag)."""
-    name = _LEGACY_FLAGS.get(kernel)
-    if name is None or not get_flag(name):
-        return False
-    if name not in _warned_legacy:
-        _warned_legacy.add(name)
-        warnings.warn(
-            f"flag {name!r} is deprecated: use kernel_tier='pallas' (or "
-            "'auto', which picks Pallas on TPU) instead; the old flag is "
-            "still honored and forces the Pallas path for its kernels",
-            DeprecationWarning, stacklevel=3)
-    return True
-
-
 def on_cpu():
     """Shared interpret-mode predicate: every kernel module passes
     ``interpret=on_cpu()`` to pallas_call so CPU (tests, smoke benches)
@@ -129,40 +103,42 @@ def on_cpu():
     return jax.default_backend() == "cpu"
 
 
-def resolve_tier():
-    """The tier the ``kernel_tier`` flag resolves to: 'pallas' or 'jnp'
-    ('auto' = pallas on TPU, jnp elsewhere — per-kernel AUTO_PALLAS
-    membership is applied in :func:`use_pallas`, not here)."""
+def _tier():
     t = get_flag("kernel_tier")
-    if t == "auto":
-        import jax
-        return "pallas" if jax.default_backend() == "tpu" else "jnp"
-    if t not in ("pallas", "jnp"):
+    if t not in ("auto", "pallas", "jnp"):
         raise ValueError(
             f"kernel_tier must be auto|pallas|jnp, got {t!r}")
     return t
 
 
-def use_pallas(kernel, supported=True):
-    """Should this dispatch take the Pallas path?
+def _on_tpu():
+    import jax
+    return jax.default_backend() == "tpu"
 
-    ``kernel`` names the kernel family ("conv_bn", "optimizer",
-    "embedding_sgd", "lstm", "gru", "ctc", "attention",
-    "grouped_matmul"); ``supported`` is the call
-    site's shape/config predicate. Unsupported shapes under a Pallas tier
-    fall back to the jnp twin with a counter bump (never an error).
+
+def resolve_tier():
+    """The tier the ``kernel_tier`` flag resolves to: 'pallas' or 'jnp'
+    ('auto' = pallas on TPU, jnp elsewhere — per-kernel AUTO_PALLAS
+    membership is applied in :func:`use_pallas`, not here)."""
+    t = _tier()
+    if t == "auto":
+        return "pallas" if _on_tpu() else "jnp"
+    return t
+
+
+def use_pallas(kernel, supported=True):
+    """Should this dispatch take the Pallas path? THE routing rule of the
+    kernel tier: every dispatch site asks it and nothing else.
+
+    ``kernel`` names the kernel family ("lstm", "gru", "ctc", "conv_bn",
+    "optimizer", "embedding_sgd", "paged_attention", "attention",
+    "grouped_matmul"); ``supported`` is the call site's shape/config
+    predicate. Unsupported shapes under a Pallas tier fall back to the jnp
+    twin with a counter bump (never an error).
     """
-    t = get_flag("kernel_tier")
-    if t not in ("auto", "pallas", "jnp"):
-        raise ValueError(
-            f"kernel_tier must be auto|pallas|jnp, got {t!r}")
-    want = _legacy_forced(kernel)
-    if not want:
-        if t == "pallas":
-            want = True
-        elif t == "auto" and kernel in AUTO_PALLAS:
-            import jax
-            want = jax.default_backend() == "tpu"
+    t = _tier()
+    want = t == "pallas" or (t == "auto" and kernel in AUTO_PALLAS
+                             and _on_tpu())
     if want and not supported:
         record_fallback(kernel)
         return False
@@ -226,7 +202,7 @@ def kernel_span(tier, kernel):
         yield
 
 
-# kernel modules (conv_bn, optimizer, embedding, rnn, ctc) are imported
+# kernel modules (conv_bn, optimizer, embedding, rnn, ctc, ...) are imported
 # lazily by their dispatch sites: the tier layer itself must stay cheap to
 # import (it is pulled in at ops-package import time)
 

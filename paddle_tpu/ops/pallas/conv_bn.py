@@ -73,20 +73,20 @@ def _tile_bytes(rows, cols, itemsize):
     return (-(-rows // sub) * sub) * (-(-cols // 128) * 128) * itemsize
 
 
-def _vmem_need(bn, hp, wp, ho, wo, cin, cout, taps, it, backward):
+def _vmem_need(hp, wp, ho, wo, cin, cout, taps, it, backward):
     """Conservative working set of one grid step: streamed blocks double
     buffered, scratch, the f32 temporaries of the epilogue, and — for
     3x3 — the shifted tap slices (sublane-unaligned slices materialize
     as copies). Measured against the chip it over-counts by ~2x (the
     compiler fuses some temporaries), which only costs fallbacks."""
     rows = ho * wo
-    x_b = bn * hp * _tile_bytes(wp, cin, it)
-    y_b = bn * ho * _tile_bytes(wo, cout, it)
+    x_b = hp * _tile_bytes(wp, cin, it)
+    y_b = ho * _tile_bytes(wo, cout, it)
     wt_b = taps * _tile_bytes(cin, cout, it)
     slices = taps * _tile_bytes(rows, cin, it) if taps > 1 else 0
     if not backward:
         return (2 * (x_b + y_b + wt_b) + slices
-                + bn * 3 * _tile_bytes(rows, cout, 4))      # acc, zf, y
+                + 3 * _tile_bytes(rows, cout, 4))           # acc, zf, y
     prows = (ho + 2 * (hp - ho)) * (wo + 2 * (wp - wo))     # dzp rows
     dw_b = taps * _tile_bytes(cin, cout, 4)
     dzp_b = (ho + 2 * (hp - ho)) * _tile_bytes(wo + 2 * (wp - wo), cout, it)
@@ -99,12 +99,10 @@ def _vmem_need(bn, hp, wp, ho, wo, cin, cout, taps, it, backward):
 
 
 def supported(x_shape, w_shape, strides, paddings, dilations, groups,
-              data_format, x_dtype, backward=False, block_n=1):
+              data_format, x_dtype, backward=False):
     """Is this conv+bn shape fused-kernel eligible? (The op layer passes
     the verdict to ``use_pallas`` so ineligible shapes fall back to the
-    jnp twin with a counter bump.) ``block_n > 1`` asks about the
-    double-buffered forward variant — ``block_n`` images stream per grid
-    step, so the VMEM working set scales and N must tile evenly."""
+    jnp twin with a counter bump.)"""
     if data_format != "NHWC" or groups != 1:
         return False
     if tuple(dilations) != (1, 1):
@@ -133,10 +131,7 @@ def supported(x_shape, w_shape, strides, paddings, dilations, groups,
     ho, wo = hp - kh + 1, wp - kw + 1
     if ho <= 0 or wo <= 0:
         return False
-    bn = int(block_n)
-    if bn < 1 or (bn > 1 and (backward or n % bn != 0)):
-        return False
-    return _vmem_need(bn, hp, wp, ho, wo, cin, cout, kh * kw,
+    return _vmem_need(hp, wp, ho, wo, cin, cout, kh * kw,
                       _itemsize(x_dtype), backward) <= _VMEM_LIMIT
 
 
@@ -174,7 +169,7 @@ def _conv_taps(x, wt_ref, kh, kw, ho, wo):
 
 def _conv_bn_train_kernel(x_ref, wt_ref, sb_ref, y_ref, sm_ref, sv_ref,
                           sum_s, sq_s, ab_s, *, kh, kw, ho, wo, count, eps,
-                          act, out_dtype, block_n=1):
+                          act, out_dtype):
     t = pl.program_id(0)
     i = pl.program_id(1)
     n = pl.num_programs(1)
@@ -184,31 +179,23 @@ def _conv_bn_train_kernel(x_ref, wt_ref, sb_ref, y_ref, sm_ref, sv_ref,
         sum_s[...] = jnp.zeros_like(sum_s)
         sq_s[...] = jnp.zeros_like(sq_s)
 
-    # block_n > 1 is the double-buffered variant: each grid step streams
-    # a block of images so pallas's block double-buffering overlaps the
-    # next block's HBM→VMEM copy with this block's taps. The per-image
-    # loop is unrolled in-image-order, so the Σy/Σy² adds land in the
-    # SAME sequence as block_n=1 — bitwise-identical f32 statistics
-    for j in range(block_n):
-        # conv block in the COMPUTE dtype (bf16 under AMP): the jnp
-        # twin's lax.conv emits the input dtype, and the BN statistics
-        # accumulate in f32 FROM that — rounding here keeps the two
-        # paths aligned
-        z = _conv_taps(x_ref[j], wt_ref, kh, kw, ho, wo) \
-            .astype(x_ref.dtype)
-        zf = z.astype(jnp.float32)
+    # conv block in the COMPUTE dtype (bf16 under AMP): the jnp twin's
+    # lax.conv emits the input dtype, and the BN statistics accumulate in
+    # f32 FROM that — rounding here keeps the two paths aligned
+    z = _conv_taps(x_ref[0], wt_ref, kh, kw, ho, wo).astype(x_ref.dtype)
+    zf = z.astype(jnp.float32)
 
-        @pl.when(t == 0)
-        def _(zf=zf):
-            sum_s[0, :] += jnp.sum(zf, axis=0)
-            sq_s[0, :] += jnp.sum(zf * zf, axis=0)
+    @pl.when(t == 0)
+    def _():
+        sum_s[0, :] += jnp.sum(zf, axis=0)
+        sq_s[0, :] += jnp.sum(zf * zf, axis=0)
 
-        @pl.when(t == 1)
-        def _(zf=zf, j=j):
-            y = zf * ab_s[0, :][None, :] + ab_s[1, :][None, :]
-            if act == "relu":
-                y = jnp.maximum(y, 0.0)
-            y_ref[j] = y.reshape(ho, wo, -1).astype(out_dtype)
+    @pl.when(t == 1)
+    def _():
+        y = zf * ab_s[0, :][None, :] + ab_s[1, :][None, :]
+        if act == "relu":
+            y = jnp.maximum(y, 0.0)
+        y_ref[0] = y.reshape(ho, wo, -1).astype(out_dtype)
 
     @pl.when(jnp.logical_and(t == 0, i == n - 1))
     def _():
@@ -222,15 +209,13 @@ def _conv_bn_train_kernel(x_ref, wt_ref, sb_ref, y_ref, sm_ref, sv_ref,
         sv_ref[0, :] = v
 
 
-def conv_bn_train_pallas(x, w, scale, bias, eps, strides, paddings, act,
-                         block_n=1):
+def conv_bn_train_pallas(x, w, scale, bias, eps, strides, paddings, act):
     """Fused training-mode conv+bn(+act) forward.
 
     x [N,H,W,Cin] NHWC, w [Cout,Cin,kh,kw] OIHW (stride 1, or stride 2
     for 1x1), scale/bias [C]. Returns (y, batch_mean, batch_var) — the
     momentum blend into the running stats is [C]-cheap and stays in jnp
-    at the op layer. ``block_n`` streams that many images per grid step
-    (the autotuner's ``pallas_db`` variant; N must tile evenly)."""
+    at the op layer."""
     from jax.experimental.pallas import tpu as pltpu
 
     out_dtype = x.dtype
@@ -239,20 +224,17 @@ def conv_bn_train_pallas(x, w, scale, bias, eps, strides, paddings, act,
     cout = w.shape[0]
     ho, wo = hp - kh + 1, wp - kw + 1
     count = float(n * ho * wo)
-    bn = int(block_n)
-    if n % bn != 0:
-        raise ValueError(f"block_n={bn} does not tile batch {n}")
     sb = jnp.stack([scale.astype(jnp.float32).reshape(-1),
                     bias.astype(jnp.float32).reshape(-1)])
 
     kernel = functools.partial(
         _conv_bn_train_kernel, kh=kh, kw=kw, ho=ho, wo=wo, count=count,
-        eps=float(eps), act=act, out_dtype=out_dtype, block_n=bn)
+        eps=float(eps), act=act, out_dtype=out_dtype)
     y, sm, sv = pl.pallas_call(
         kernel,
-        grid=(2, n // bn),
+        grid=(2, n),
         in_specs=[
-            pl.BlockSpec((bn, hp, wp, cin), lambda t, i: (i, 0, 0, 0)),
+            pl.BlockSpec((1, hp, wp, cin), lambda t, i: (i, 0, 0, 0)),
             pl.BlockSpec((kh * kw, cin, cout), lambda t, i: (0, 0, 0)),
             pl.BlockSpec((2, cout), lambda t, i: (0, 0)),
         ],
@@ -260,7 +242,7 @@ def conv_bn_train_pallas(x, w, scale, bias, eps, strides, paddings, act,
             # t*i: every pass-0 step parks on block 0 (same block ⇒ the
             # write-back defers), pass 1 walks the real blocks — so the
             # unwritten stats pass never flushes garbage rows to HBM
-            pl.BlockSpec((bn, ho, wo, cout), lambda t, i: (t * i, 0, 0, 0)),
+            pl.BlockSpec((1, ho, wo, cout), lambda t, i: (t * i, 0, 0, 0)),
             pl.BlockSpec((1, cout), lambda t, i: (0, 0)),
             pl.BlockSpec((1, cout), lambda t, i: (0, 0)),
         ],
@@ -283,43 +265,35 @@ def conv_bn_train_pallas(x, w, scale, bias, eps, strides, paddings, act,
 # ---------------------------------------------------------------------------
 
 def _conv_affine_kernel(x_ref, wt_ref, ab_ref, y_ref, *, kh, kw, ho, wo,
-                        act, out_dtype, block_n=1):
-    for j in range(block_n):
-        z = _conv_taps(x_ref[j], wt_ref, kh, kw, ho, wo).astype(x_ref.dtype)
-        y = z.astype(jnp.float32) * ab_ref[0, :][None, :] \
-            + ab_ref[1, :][None, :]
-        if act == "relu":
-            y = jnp.maximum(y, 0.0)
-        y_ref[j] = y.reshape(ho, wo, -1).astype(out_dtype)
+                        act, out_dtype):
+    z = _conv_taps(x_ref[0], wt_ref, kh, kw, ho, wo).astype(x_ref.dtype)
+    y = z.astype(jnp.float32) * ab_ref[0, :][None, :] + ab_ref[1, :][None, :]
+    if act == "relu":
+        y = jnp.maximum(y, 0.0)
+    y_ref[0] = y.reshape(ho, wo, -1).astype(out_dtype)
 
 
-def conv_affine_pallas(x, w, a, b, strides, paddings, act, block_n=1):
+def conv_affine_pallas(x, w, a, b, strides, paddings, act):
     """Fused inference conv + y = conv*a + b (+act): the folded-BN serving
-    epilogue (a = scale·rsqrt(var+eps), b = bias − mean·a, precomputed).
-    ``block_n`` streams that many images per grid step (the autotuner's
-    ``pallas_db`` variant; N must tile evenly)."""
+    epilogue (a = scale·rsqrt(var+eps), b = bias − mean·a, precomputed)."""
     out_dtype = x.dtype
     x, wt, kh, kw = _prep(x, w, strides, paddings)
     n, hp, wp, cin = x.shape
     cout = w.shape[0]
     ho, wo = hp - kh + 1, wp - kw + 1
-    bn = int(block_n)
-    if n % bn != 0:
-        raise ValueError(f"block_n={bn} does not tile batch {n}")
     ab = jnp.stack([a.astype(jnp.float32).reshape(-1),
                     b.astype(jnp.float32).reshape(-1)])
     kernel = functools.partial(_conv_affine_kernel, kh=kh, kw=kw, ho=ho,
-                               wo=wo, act=act, out_dtype=out_dtype,
-                               block_n=bn)
+                               wo=wo, act=act, out_dtype=out_dtype)
     return pl.pallas_call(
         kernel,
-        grid=(n // bn,),
+        grid=(n,),
         in_specs=[
-            pl.BlockSpec((bn, hp, wp, cin), lambda i: (i, 0, 0, 0)),
+            pl.BlockSpec((1, hp, wp, cin), lambda i: (i, 0, 0, 0)),
             pl.BlockSpec((kh * kw, cin, cout), lambda i: (0, 0, 0)),
             pl.BlockSpec((2, cout), lambda i: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((bn, ho, wo, cout), lambda i: (i, 0, 0, 0)),
+        out_specs=pl.BlockSpec((1, ho, wo, cout), lambda i: (i, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((n, ho, wo, cout), out_dtype),
         compiler_params=_compiler_params(),
         interpret=_on_cpu(),

@@ -57,21 +57,17 @@ def _reverse_padded(data, lens):
     return jnp.take_along_axis(data, idx, axis=1)
 
 
-def _lstm_route(x, gate_act, cell_act, cand_act, has_peepholes):
-    """"pallas" or "jnp" for this call's recurrence; the forward op and its
-    grad op ask the same question and get the same answer."""
-    from .autotune import dispatch_variant, make_key
+def _lstm_on_pallas(gate_act, cell_act, cand_act, has_peepholes):
+    """Does this call's recurrence run the Pallas kernel? The forward op
+    and its grad op ask the same question and get the same answer."""
+    from .pallas import use_pallas
 
     # the Pallas fused cell implements the standard activation set (the
     # reference's hand-scheduled hl_cuda_lstm.cu does the same); other
     # activations / peepholes fall back to the scan with a counter bump
-    supported = (not has_peepholes
-                 and (gate_act, cell_act, cand_act)
-                 == ("sigmoid", "tanh", "tanh"))
-    return dispatch_variant(
-        "rnn",
-        make_key(cell="lstm", x=tuple(x.shape), dtype=str(x.dtype)),
-        {"jnp": True, "pallas": supported}, tier_kernel="lstm")
+    return use_pallas("lstm", not has_peepholes
+                      and (gate_act, cell_act, cand_act)
+                      == ("sigmoid", "tanh", "tanh"))
 
 
 def _alive_mask(L, lens, dtype):
@@ -103,8 +99,7 @@ def _lstm_scan(x, lens, w, h0, c0, gate_act, cell_act, cand_act,
     diagonal cell->gate connections (math/detail/lstm_kernel.h:37-40:
     i/f see the PREVIOUS cell state, o sees the NEW one). Returns
     hidden [b, L, H], cell [b, L, H]."""
-    if _lstm_route(x, gate_act, cell_act, cand_act,
-                   peepholes is not None) == "pallas":
+    if _lstm_on_pallas(gate_act, cell_act, cand_act, peepholes is not None):
         return _lstm_pallas(x, lens, w, h0, c0)[:2]
     return _lstm_jnp_scan(x, lens, w, h0, c0, gate_act, cell_act, cand_act,
                           peepholes)
@@ -182,7 +177,7 @@ def _lstm_compute(x, lens, w, bias, h0, c0, attrs):
     x, h0, c0, peepholes = _lstm_scan_inputs(x, lens, bias, h0, c0, attrs)
     acts = _lstm_acts(attrs)
     carries = None
-    if _lstm_route(x, *acts, peepholes is not None) == "pallas":
+    if _lstm_on_pallas(*acts, peepholes is not None):
         hs, cs, carries = _lstm_pallas(x, lens, w, h0, c0)
     else:
         hs, cs = _lstm_jnp_scan(x, lens, w, h0, c0, *acts, peepholes)
@@ -349,16 +344,10 @@ def _gru_compute(x, lens, w, bias, h0, attrs):
     if rev:
         x = _reverse_padded(x, lens)
 
-    from .autotune import dispatch_variant, make_key
-    from .pallas import kernel_span
-    supported = (attrs.get("gate_activation", "sigmoid") == "sigmoid"
-                 and attrs.get("activation", "tanh") == "tanh")
-    choice = dispatch_variant(
-        "rnn",
-        make_key(cell="gru", x=tuple(x.shape), dtype=str(x.dtype)),
-        {"jnp": True, "pallas": supported}, tier_kernel="gru")
-
-    if choice == "pallas":
+    from .pallas import kernel_span, use_pallas
+    if use_pallas("gru",
+                  attrs.get("gate_activation", "sigmoid") == "sigmoid"
+                  and attrs.get("activation", "tanh") == "tanh"):
         # whole-recurrence kernel (see ops/pallas/rnn.gru_seq_pallas)
         from .pallas.rnn import gru_seq_pallas
         with kernel_span("pallas", "gru"):

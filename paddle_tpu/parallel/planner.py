@@ -5,11 +5,9 @@ The sharding layer (sharding.py) makes multi-chip placement a
 compile-time annotation problem — but WHICH mesh to annotate with has so
 far been a hand decision encoded in each test/bench lane
 (``make_mesh(8, axes=("dp", "tp"))`` and friends). This module makes
-that decision a SEARCH, the placement-level twin of the kernel
-autotuner's "measure once, dispatch forever" (ops/autotune.py) and the
-shape argued by *Synthesizing Optimal Parallelism Placement and
-Reduction Strategies on Hierarchical Systems* (PAPERS.md): enumerate the
-legal (dp, pp, tp, sp) factorizations of the device count, cost each one
+that decision a SEARCH, the shape argued by *Synthesizing Optimal
+Parallelism Placement and Reduction Strategies on Hierarchical Systems*
+(PAPERS.md): enumerate the legal (dp, pp, tp, sp) factorizations of the device count, cost each one
 with measured compute plus an analytic collective model, and emit the
 winner through the existing ``shard_program_step`` path — bitwise the
 plan a hand would have built.
@@ -42,9 +40,9 @@ Four planes:
   ``ShardingPlan`` kwargs, so the compiled step is bitwise equal.
   ``tools/plan_parallel.py`` renders the report for any program or
   published bundle.
-* **persistence** — chosen plans serialize under the ops/autotune
-  artifact contract: content-addressed envelope (``MAGIC + sha256hex +
-  blob``), full identity fingerprint (program content hash x device
+* **persistence** — chosen plans serialize under the execcache
+  artifact contract (serving/execcache.py): content-addressed envelope
+  (``MAGIC + sha256hex + blob``), full identity fingerprint (program content hash x device
   count/kind x planner flags) in the filename, typed bounded rejects
   (:data:`REJECT_REASONS`) each a ``paddle_tpu_plan_rejects`` bump plus
   a flight event followed by a silent fall-back to fresh planning, and
@@ -68,7 +66,7 @@ PLAN_DIRNAME = "plan"
 ARTIFACT_SUFFIX = ".jplan"
 _MAGIC = b"PDTPUPLAN1\n"
 
-# typed bounded reject vocabulary (the ops.autotune shape — a plan is
+# typed bounded reject vocabulary (the execcache shape — a plan is
 # only ever read, never executed at load time):
 #   format       — bad magic / truncated / bit-flipped payload
 #   manifest     — raw bytes not certified by the version manifest
@@ -757,11 +755,11 @@ def apply_candidate(cand, executor, program, feed_example, fetch_list,
 
 
 # ---------------------------------------------------------------------------
-# persistence (the ops/autotune artifact contract)
+# persistence (the serving/execcache.py artifact contract)
 # ---------------------------------------------------------------------------
 
 class PlanStore:
-    """One directory of placement-plan artifacts under the autotune /
+    """One directory of placement-plan artifacts under the
     execcache discipline: content-addressed envelope, identity in the
     filename, typed bounded rejects, optional manifest pinning,
     tmp+replace writes. ``load`` and ``save`` never raise — a broken

@@ -152,8 +152,6 @@ class InferenceEngine:
         # matching full-identity fingerprint exists, and compiles+saves
         # the rest (writable caches only). None = compile always, the
         # pre-cache behavior.
-        self._model_dir = str(model_dir) if model_dir is not None else None
-        self._tune_digest = None       # set by warmup's attach_for_bundle
         self._exec_cache = _execcache.resolve_cache(model_dir, exec_cache)
         self._bundle_hash = _execcache.bundle_content_hash(model_dir) \
             if self._exec_cache is not None and model_dir else None
@@ -268,13 +266,6 @@ class InferenceEngine:
         before = sum(c.value for c in self._m_compiles.values())
         from ..ops.pallas import resolve_tier
         self._kernel_tier = resolve_tier()
-        # attach the bundle's published tuning table (if any) BEFORE the
-        # first trace: the table digest flag is in the jit key and every
-        # execcache fingerprint, so warm artifacts bind to the routing
-        # they were compiled under. Corruption downgrades to static
-        # routing with a typed reject — never a warmup failure.
-        from ..ops.autotune import attach_for_bundle
-        self._tune_digest = attach_for_bundle(self._model_dir)
         with record_event("serving/warmup", kind="stage"):
             for b in self.buckets:
                 if self._exec_cache is not None:
@@ -496,7 +487,6 @@ class InferenceEngine:
             "hot_recompiles": self.hot_recompiles,
             "warmed": self._warmed,
             "kernel_tier": self._kernel_tier,
-            "tune_digest": self._tune_digest,
             "exec_cache": self._exec_cache.stats()
             if self._exec_cache is not None else None,
             "warm_loaded": len(self._warm_loaded),

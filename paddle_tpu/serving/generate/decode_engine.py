@@ -217,8 +217,6 @@ class GenerationEngine:
         # (max_seqs, max_len, arena geometry, chunking) needs no explicit
         # key — it is fully determined by the warmup feed shapes the
         # fingerprint already covers.
-        self._model_dir = str(model_dir) if model_dir is not None else None
-        self._tune_digest = None       # set by warmup's attach_for_bundle
         self._bundle_hash = _execcache.bundle_content_hash(model_dir) \
             if model_dir else None
         self._exec_cache = _execcache.resolve_cache(model_dir, exec_cache) \
@@ -635,10 +633,6 @@ class GenerationEngine:
             before = self._compiles()
             from ...ops.pallas import resolve_tier
             self._kernel_tier = resolve_tier()
-            # bundle's published tuning table attaches BEFORE any trace:
-            # the digest flag keys every retrace and exec fingerprint
-            from ...ops.autotune import attach_for_bundle
-            self._tune_digest = attach_for_bundle(self._model_dir)
             with record_event("serving/gen_warmup", kind="stage"):
                 if self._exec_cache is not None:
                     # inert decode feed, shaped exactly like the
@@ -1133,7 +1127,6 @@ class GenerationEngine:
             "cache": self.cache.stats(),
             "prefill_chunk": self.prefill_chunk,
             "kernel_tier": self._kernel_tier,
-            "tune_digest": self._tune_digest,
             "exec_cache": self._exec_cache.stats()
             if self._exec_cache is not None else None,
             "kv_store": self._kv_store.stats()

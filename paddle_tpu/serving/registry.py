@@ -105,7 +105,7 @@ class ModelRegistry:
 
     def publish(self, model, src_dir, version=None, kernel_tier=None,
                 model_kind="feedforward", lineage=None, warm_cache=False,
-                warm_kwargs=None, kv_prompts=None, tune=False, plan=False):
+                warm_kwargs=None, kv_prompts=None, plan=False):
         """Copy the bundle at ``src_dir`` in as ``version`` (next integer
         when None) and make it visible by writing the manifest LAST,
         atomically. Returns the published version number. Versions are
@@ -147,15 +147,6 @@ class ModelRegistry:
         serving/generate/kvstore.py): replicas that serve this version
         attach those prefixes with ZERO prefill steps. Passing it
         implies a warm pass even without ``warm_cache=True``.
-
-        ``tune=True`` (or a dict of Tuner options, e.g.
-        ``{"repeats": 3, "inner": 2}``) additionally runs the kernel
-        autotuner at publish time against the engine's REAL warmup
-        shapes and ships the winning-variant table under
-        ``<version>/tune/`` (ops/autotune.py), manifest-pinned like
-        ``warm_files`` — replicas that serve this version route tunable
-        kernels by measurement with zero in-band tuning work. Implies a
-        warm pass.
 
         ``plan=True`` additionally runs the auto-parallelism placement
         planner (parallel/planner.py) at publish time and ships the
@@ -240,12 +231,10 @@ class ModelRegistry:
         with open(tmp, "w") as f:
             json.dump(manifest, f, indent=1, sort_keys=True)
         os.replace(tmp, os.path.join(dst, VERSION_MANIFEST))
-        if warm_cache or kv_prompts or tune or plan:
+        if warm_cache or kv_prompts or plan:
             wk = dict(warm_kwargs or {})
             if kv_prompts is not None:
                 wk.setdefault("kv_prompts", kv_prompts)
-            if tune:
-                wk.setdefault("tune", tune)
             if plan:
                 wk.setdefault("plan", plan)
             self.warm(model, version, **wk)
@@ -253,7 +242,7 @@ class ModelRegistry:
 
     # ------------------------------------------------------------------
     def warm(self, model, version="latest", buckets=None, sample_feed=None,
-             gen_opts=None, kv_prompts=None, tune=False, plan=False):
+             gen_opts=None, kv_prompts=None, plan=False):
         """Build (or complete) the version's persistent compiled-
         executable artifacts under ``<version>/warm/`` so replicas LOAD
         instead of compile (serving/execcache.py): an engine of the
@@ -293,19 +282,6 @@ class ModelRegistry:
         None an existing ``kv/`` dir is left untouched — warm-cache
         refreshes must not prune KV artifacts they didn't rebuild.
 
-        ``tune=True`` (or a Tuner-option dict: ``repeats``/``inner``)
-        runs the kernel autotuner FIRST: a throwaway engine (no exec
-        cache) is warmed under ``ops.autotune.capture`` to learn the
-        real dispatch keys, the tuner measures each key's registered
-        variants, and the winning table lands under ``<version>/tune/``
-        with ``tune_files`` certified into the manifest BEFORE the warm
-        engine is built — so the warm pass attaches the manifest-pinned
-        table and every persisted executable's fingerprint already
-        carries the table digest (a replica loading warm/ under the
-        same table hits; one without the table recompiles instead of
-        loading mismatched routing). When ``tune`` is falsy an existing
-        ``tune/`` dir is left untouched, like ``kv/``.
-
         ``plan=True`` runs the publish-time placement search
         (parallel/planner.py): the bundle is loaded into a throwaway
         scope, the planner enumerates and cost-models the legal meshes
@@ -323,18 +299,6 @@ class ModelRegistry:
         m = self.manifest(model, v)
         from .execcache import ARTIFACT_SUFFIX, ExecCache, WARM_DIRNAME
         from .generate import kvstore as _kvs
-        if tune:
-            tune_files = self._tune(path, m, buckets=buckets,
-                                    sample_feed=sample_feed,
-                                    gen_opts=gen_opts,
-                                    tune_opts=tune if isinstance(tune, dict)
-                                    else None)
-            if m.get("tune_files") != tune_files:
-                m["tune_files"] = tune_files
-                tmp = os.path.join(path, VERSION_MANIFEST + ".tmp")
-                with open(tmp, "w") as f:
-                    json.dump(m, f, indent=1, sort_keys=True)
-                os.replace(tmp, os.path.join(path, VERSION_MANIFEST))
         if plan:
             plan_files = self._plan(path, m)
             if m.get("plan_files") != plan_files:
@@ -400,60 +364,7 @@ class ModelRegistry:
                 json.dump(m, f, indent=1, sort_keys=True)
             os.replace(tmp, os.path.join(path, VERSION_MANIFEST))
         return sorted(warm_files) + sorted(kv_files or {}) \
-            + sorted(m.get("tune_files", {}) if tune else {}) \
             + sorted(m.get("plan_files", {}) if plan else {})
-
-    def _tune(self, path, m, buckets=None, sample_feed=None, gen_opts=None,
-              tune_opts=None):
-        """Run the publish-time autotune pass: capture the real warmup's
-        dispatch keys on a THROWAWAY engine (no exec cache — loading
-        warm artifacts would skip the traced dispatches whose keys this
-        pass exists to learn), measure each captured key's registered
-        variants, and persist the winning table under ``tune/``. Keys an
-        existing valid table already covers are NOT re-measured (re-
-        warming is idempotent: same table bytes, same digest, nothing
-        downstream recompiles). Returns the ``tune_files`` digest map."""
-        from ..ops import autotune as _at
-        if m.get("model_kind", "feedforward") == "generative":
-            from .generate import GenerationEngine
-            engine = GenerationEngine(path, exec_cache=False,
-                                      **dict(gen_opts or {}))
-            with _at.capture() as keys:
-                engine.warmup()
-        else:
-            from .engine import InferenceEngine
-            engine = InferenceEngine(path, buckets=buckets,
-                                     exec_cache=False)
-            with _at.capture() as keys:
-                engine.warmup(sample_feed)
-        store = _at.TuneStore(os.path.join(path, _at.TUNE_DIRNAME))
-        existing = store.load()
-        missing = keys if existing is None else \
-            [c for c in keys
-             if (c[0], _at.key_str(c[1])) not in existing.entries]
-        table = existing
-        if missing or existing is None:
-            tuner = _at.Tuner(**(tune_opts or {}))
-            table = tuner.tune(missing, table=existing)
-        store.save(table)
-        touched = set(store.touched())
-        tune_dir = os.path.join(path, _at.TUNE_DIRNAME)
-        tune_files = {}
-        for name in sorted(os.listdir(tune_dir)):
-            fpath = os.path.join(tune_dir, name)
-            if not os.path.isfile(fpath) or name.endswith(".tmp"):
-                continue
-            if name in touched:
-                tune_files[f"{_at.TUNE_DIRNAME}/{name}"] = \
-                    _sha256_file(fpath)
-            elif name.endswith(_at.ARTIFACT_SUFFIX):
-                # a table another toolchain/backend measured: its
-                # filename fingerprint can never match here — prune
-                try:
-                    os.unlink(fpath)
-                except OSError:
-                    pass
-        return tune_files
 
     def _plan(self, path, m):
         """Run the publish-time placement search: load the bundle into a
@@ -702,8 +613,9 @@ class ModelRegistry:
         # same way: verify is the offline check, the engine's
         # manifest-pinned load reject is the runtime one
         listed.update(m.get("kv_files", {}))
-        # tune_files (publish-time kernel-tuning tables, tune/) too:
-        # ops.autotune.TuneStore pins loads to these digests at runtime
+        # tune_files (tune/): versions published before PR 30 may list
+        # kernel-tuning tables. Nothing reads them any more, but what a
+        # manifest lists is certified, so they re-hash like the rest
         listed.update(m.get("tune_files", {}))
         # plan_files (publish-time placement plans, plan/) the same:
         # parallel.planner.PlanStore pins loads to these digests

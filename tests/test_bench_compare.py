@@ -217,28 +217,6 @@ def test_reload_storm_lane_is_lower_is_better():
     better = {"reload_storm_serving": dict(rec, value=0.9)}
     assert bench_compare.compare_records(old, better, 5.0)["ok"]
 
-def test_kernel_autotune_lane_is_higher_is_better():
-    """The kernel_autotune lane's tuned-vs-best-static speedup unit (the
-    exact string bench.py emits) keeps the higher-is-better default: a
-    SMALLER speedup means tuned routing lost ground to static tiers."""
-    rec = {"metric": "kernel_autotune", "value": 1.02,
-           "unit": "x tuned-table auto routing vs best single static "
-                   "kernel_tier, fused conv+bn infer step (gate >= 1.0x; "
-                   "5% same-program jitter allowed when the tuned "
-                   "selection is a variant a static tier also compiles; "
-                   "bitwise parity + zero in-band tuning asserted "
-                   "in-lane)"}
-    assert not bench_compare.lower_is_better(rec)
-    assert not bench_compare.lower_is_better(
-        dict(rec, metric="kernel_autotune_smoke"))
-    old = {"kernel_autotune": rec}
-    worse = {"kernel_autotune": dict(rec, value=0.9)}
-    res = bench_compare.compare_records(old, worse, 5.0)
-    assert res["regressions"] == ["kernel_autotune"]
-    better = {"kernel_autotune": dict(rec, value=1.2)}
-    assert bench_compare.compare_records(old, better, 5.0)["ok"]
-
-
 def test_placement_planner_lane_is_higher_is_better():
     """The placement_planner lane's planned-vs-all-dp speedup unit (the
     exact string bench.py emits) keeps the higher-is-better default: a

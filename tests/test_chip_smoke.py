@@ -144,19 +144,29 @@ def test_planner_rates_are_keyed_by_device_kind():
         planner.machine_rates("TPU v9")
 
 
-def test_autotune_records_why_a_variant_was_dropped():
-    from paddle_tpu.obs.recorder import RECORDER
-    from paddle_tpu.ops import autotune
+def test_probe_records_why_a_kernel_was_dropped():
+    """tools/kernel_probe.py: a kernel that cannot compile is a result with
+    the compiler's text in it, at its first call or in the A/B's warm-up."""
+    sys.path.insert(0, os.path.join(REPO, "tools"))
+    try:
+        import kernel_probe
+    finally:
+        sys.path.pop(0)
+    calls = []
 
-    def broken():
-        raise ValueError("Mosaic says no")
+    def broken_after(n):
+        def run():
+            calls.append(n)
+            if len(calls) > n:
+                raise ValueError("Mosaic says no")
+            return 1.0
+        return run
 
-    kind = {"kernel_autotune_variant_failed"}
-    before = len(RECORDER.events(kinds=kind))
-    ms = autotune.measure({"jnp": lambda: 1, "pallas": broken}, repeats=1,
-                          inner=1, kernel="conv_bn")
-    assert set(ms) == {"jnp"}
-    (ev,) = RECORDER.events(kinds=kind)[before:]
-    assert ev["detail"] == {"kernel": "conv_bn", "variant": "pallas",
-                            "stage": "warmup",
-                            "error": "ValueError: Mosaic says no"}
+    for n, error in ((0, "ValueError: Mosaic says no"),
+                     (1, "pallas: ValueError: Mosaic says no")):
+        del calls[:]
+        rec = kernel_probe.probe("case", "conv_bn", {
+            "jnp": lambda: 1.0, "pallas": broken_after(n)}, repeats=1,
+            inner=1)
+        assert rec["error"] == error
+        assert rec["lowered"] == (n > 0) and rec["pallas_ms"] is None

@@ -32,7 +32,6 @@ def test_wheel_builds_with_all_subpackages(tmp_path):
                 "paddle_tpu/fluid/analysis/__init__.py",
                 "paddle_tpu/v2/__init__.py", "paddle_tpu/ops/__init__.py",
                 "paddle_tpu/ops/pallas/__init__.py",
-                "paddle_tpu/ops/autotune.py",
                 "paddle_tpu/parallel/__init__.py",
                 "paddle_tpu/parallel/planner.py",
                 "paddle_tpu/distributed/__init__.py",
@@ -69,7 +68,7 @@ def test_tools_scripts_compile():
     operator's box otherwise."""
     import py_compile
 
-    for name in ("autotune.py", "plan_parallel.py"):
+    for name in ("kernel_probe.py", "plan_parallel.py"):
         path = os.path.join(REPO, "tools", name)
         assert os.path.exists(path), path
         py_compile.compile(path, doraise=True)

@@ -20,13 +20,13 @@ import paddle_tpu.fluid as fluid
 @pytest.fixture(autouse=True)
 def _reset_flags():
     yield
-    fluid.set_flags({"use_pallas_rnn": False})
+    fluid.set_flags({"kernel_tier": "auto"})
 
 
 def test_gru_seq_kernel_matches_jnp_twin():
     """Whole-recurrence GRU kernel vs its jnp twin (same bf16-matmul
     recipe): carries and grads (dx, dw, dh0) must match tightly."""
-    from paddle_tpu.ops.pallas_kernels import gru_seq_pallas, _gru_step_jnp
+    from paddle_tpu.ops.pallas.rnn import gru_seq_pallas, _gru_step_jnp
 
     rng = np.random.RandomState(2)
     L, b, H = 5, 4, 8
@@ -64,7 +64,7 @@ def test_lstm_op_parity_with_pallas_flag():
     layers = fluid.layers
 
     def run(use_pallas):
-        fluid.set_flags({"use_pallas_rnn": use_pallas})
+        fluid.set_flags({"kernel_tier": "pallas" if use_pallas else "jnp"})
         from paddle_tpu.fluid import framework
         from paddle_tpu.core import scope as scope_mod
         framework.reset_unique_name()
@@ -104,7 +104,7 @@ def test_lstm_op_parity_with_pallas_flag():
 def test_lstm_seq_kernel_matches_jnp_twin():
     """Whole-recurrence kernel vs its jnp twin (same bf16-matmul recipe):
     carries AND gradients (dx, dw, dh0, dc0) must match tightly."""
-    from paddle_tpu.ops.pallas_kernels import (lstm_seq_pallas,
+    from paddle_tpu.ops.pallas.rnn import (lstm_seq_pallas,
                                                _lstm_step_jnp)
 
     rng = np.random.RandomState(4)
@@ -239,7 +239,7 @@ def test_gru_op_parity_with_pallas_flag():
     layers = fluid.layers
 
     def run(use_pallas):
-        fluid.set_flags({"use_pallas_rnn": use_pallas})
+        fluid.set_flags({"kernel_tier": "pallas" if use_pallas else "jnp"})
         from paddle_tpu.fluid import framework
         framework.reset_unique_name()
         main, startup = fluid.Program(), fluid.Program()
@@ -294,13 +294,13 @@ def test_pallas_ctc_matches_scan_path():
     ref, ref_grad = jax.value_and_grad(
         lambda lg: jnp.sum(_ctc_loss(lg, x_lens, labels, y_lens, 0)))(logits)
 
-    set_flags({"use_pallas_ctc": True})
+    set_flags({"kernel_tier": "pallas"})
     try:
         got, got_grad = jax.value_and_grad(
             lambda lg: jnp.sum(_ctc_loss(lg, x_lens, labels, y_lens, 0)))(
                 logits)
     finally:
-        set_flags({"use_pallas_ctc": False})
+        set_flags({"kernel_tier": "auto"})
 
     np.testing.assert_allclose(float(got), float(ref), rtol=1e-5)
     np.testing.assert_allclose(np.asarray(got_grad), np.asarray(ref_grad),

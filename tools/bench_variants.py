@@ -8,9 +8,9 @@ Variants:
                 the substitute has no per-channel affine traffic at all
   bnfrozen    — BN with is_test=True (running stats; no reduction pass)
 
-Timing rides the kernel autotuner's shared measurement core
-(paddle_tpu.ops.autotune.measure): interleaved best-of-N windows across
-all requested variants.
+Timing rides the kernel probe's measurement core
+(tools/kernel_probe.measure): interleaved best-of-N windows across all
+requested variants.
 
 Usage: python tools/bench_variants.py [--steps 8] [--windows 3]
        [--batch 256] [--which all]
@@ -65,7 +65,7 @@ def build_variant(batch, image_size, class_dim, variant):
 
 def build_runner(variant, batch):
     """Zero-arg timed step closure for one variant — what the shared
-    measurement core (paddle_tpu.ops.autotune.measure) times. Startup
+    measurement core (tools/kernel_probe.measure) times. Startup
     runs here, once; the first measured call absorbs the jit compile as
     the measurement core's per-runner warmup call."""
     import jax
@@ -111,19 +111,18 @@ def main():
     ap.add_argument("--which", default="all")
     args = ap.parse_args()
 
-    # timing rides the autotuner's measurement core: ONE interleaved
-    # best-of-N implementation in the tree (ops/autotune.measure), so
-    # drift hits every variant's windows equally instead of biasing
-    # whichever variant ran last
-    from paddle_tpu.ops.autotune import measure
+    # ONE interleaved best-of-N implementation in the tree, so drift hits
+    # every variant's windows equally instead of biasing whichever ran last
+    from kernel_probe import measure
 
     variants = ["full", "fwd", "bnfrozen", "nobn"] if args.which == "all" \
         else args.which.split(",")
     runners = {v: build_runner(v, args.batch) for v in variants}
-    times = measure(runners, repeats=args.windows, inner=args.steps)
+    times, dropped = measure(runners, repeats=args.windows,
+                             inner=args.steps)
     for v in variants:
-        if v not in times:
-            print(f"{v:10s} failed to run", flush=True)
+        if v in dropped:
+            print(f"{v:10s} failed to run: {dropped[v]}", flush=True)
             continue
         dt = times[v] / 1e3
         print(f"{v:10s} {times[v]:8.2f} ms/step  "

@@ -65,14 +65,9 @@ BOUNDED_LABELS = {
               "serving.execcache.REJECT_REASONS (format/manifest/"
               "fingerprint/deserialize/run_failed), "
               "serving.generate.kvstore.REJECT_REASONS (format/"
-              "manifest/fingerprint/deserialize), "
-              "ops.autotune.REJECT_REASONS (format/manifest/"
-              "fingerprint/deserialize) and "
+              "manifest/fingerprint/deserialize) and "
               "parallel.planner.REJECT_REASONS (format/manifest/"
               "fingerprint/deserialize)",
-    "variant": "registered kernel variant names — the fixed code-site "
-               "set ops.autotune.VARIANTS registers (jnp/pallas/"
-               "pallas_db/pallas_bf16)",
     "device": "local jax devices (platform:id) — bounded by the "
               "attached hardware",
     "tenant": "tenant ids — wire-origin, funneled past "
@@ -111,7 +106,6 @@ def registered_families():
     import paddle_tpu.online.pool           # noqa: F401
     import paddle_tpu.online.rollout        # noqa: F401
     import paddle_tpu.online.trainer        # noqa: F401
-    import paddle_tpu.ops.autotune          # noqa: F401
     import paddle_tpu.ops.pallas            # noqa: F401
     import paddle_tpu.parallel.planner      # noqa: F401
     import paddle_tpu.serving.autoscale     # noqa: F401

@@ -41,7 +41,6 @@ def registered_metrics():
     import paddle_tpu.online.pool           # noqa: F401
     import paddle_tpu.online.rollout        # noqa: F401
     import paddle_tpu.online.trainer        # noqa: F401
-    import paddle_tpu.ops.autotune          # noqa: F401
     import paddle_tpu.ops.pallas            # noqa: F401
     import paddle_tpu.parallel.planner      # noqa: F401
     import paddle_tpu.reader.prefetch       # noqa: F401

@@ -17,7 +17,9 @@ parameter census; lstm/gru at the ``bench.py`` RNN-lane shape; ctc and
 embedding_sgd at the shapes their parity tests pin, scaled to a workload
 size; paged_attention at the generation lane's decode shape; banded
 attention at the Mellum2 cell's shape (8192 tokens, 32 / 4 heads of 128,
-bfloat16), forward and backward, a window layer and the full one.
+bfloat16), forward and backward, a window layer and the full one; the
+grouped product at that cell's expert shape (8 experts of 2304 x 896).
+Every family with a dispatch site in ``paddle_tpu/ops/`` has a case.
 
 A kernel that fails to lower is a RESULT here (``lowered: false`` with the
 compiler's message), never a crash. A watchdog ends the process if one
@@ -34,6 +36,7 @@ import os
 import sys
 import threading
 import time
+from contextlib import contextmanager
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -57,12 +60,46 @@ def _errs(got, want):
     return abs_e, rel_e
 
 
+def measure(runners, repeats=3, inner=2):
+    """Time each runner: ``repeats`` interleaved windows of ``inner`` calls
+    each, best window kept; interleaved across runners so drift (thermal, a
+    noisy neighbor) hits every one equally instead of biasing whichever ran
+    last. One untimed warmup call per runner absorbs trace+compile. Returns
+    ``({name: best ms/call}, {name: "ExcType: text"})``: a runner that
+    raises in its warmup call is dropped from the timings (a variant that
+    cannot run cannot win) and its exception's text is returned, never
+    swallowed."""
+    import jax
+
+    repeats, inner = max(1, int(repeats)), max(1, int(inner))
+    order, dropped = [], {}
+    for name in sorted(runners):
+        try:
+            jax.block_until_ready(runners[name]())
+        except Exception as e:
+            dropped[name] = f"{type(e).__name__}: {e}"[:1500]
+            continue
+        order.append(name)
+    best = {}
+    for _ in range(repeats):
+        for name in order:
+            fn = runners[name]
+            t0 = time.perf_counter()
+            out = None
+            for _i in range(inner):
+                out = fn()
+            jax.block_until_ready(out)
+            ms = (time.perf_counter() - t0) * 1e3 / inner
+            if name not in best or ms < best[name]:
+                best[name] = ms
+    return best, dropped
+
+
 def probe(case, family, runners, repeats=5, inner=4):
     """Run one case: twin first (must work), then the Pallas runner (a
     failure is recorded, not raised), then parity and the interleaved A/B
-    (ops.autotune.measure — the repo's one timing core)."""
+    (:func:`measure`)."""
     import jax
-    from paddle_tpu.ops.autotune import measure
 
     rec = {"case": case, "family": family, "lowered": False, "error": None,
            "max_abs_err": None, "max_rel_err": None, "jnp_ms": None,
@@ -75,7 +112,10 @@ def probe(case, family, runners, repeats=5, inner=4):
         return rec
     rec["lowered"] = True
     rec["max_abs_err"], rec["max_rel_err"] = _errs(got, want)
-    ms = measure(runners, repeats=repeats, inner=inner)
+    ms, dropped = measure(runners, repeats=repeats, inner=inner)
+    if dropped:
+        rec["error"] = "; ".join(f"{n}: {e}" for n, e in dropped.items())
+        return rec
     rec["jnp_ms"] = round(ms["jnp"], 4)
     rec["pallas_ms"] = round(ms["pallas"], 4)
     rec["speedup"] = round(ms["jnp"] / ms["pallas"], 4)
@@ -140,13 +180,103 @@ def conv_bn_case(n, h, cin, cout, k, stride, backward):
     return {"jnp": lambda: tb(x, w, dy), "pallas": lambda: pb(x, w, dy)}
 
 
-def registry_case(kernel, key):
-    """A case straight from the autotuner's variant registry (the same
-    runners the Tuner measures)."""
-    from paddle_tpu.ops.autotune import VARIANTS
-    specs = VARIANTS.variants(kernel)
-    return {"jnp": specs["jnp"].build(key),
-            "pallas": specs["pallas"].build(key)}
+@contextmanager
+def _tier(name):
+    """Trace what runs inside under ``kernel_tier=name``."""
+    from paddle_tpu.core.flags import get_flag, set_flags
+    prev = get_flag("kernel_tier")
+    set_flags({"kernel_tier": name})
+    try:
+        yield
+    finally:
+        set_flags({"kernel_tier": prev})
+
+
+def rnn_case(cell, b, L, H):
+    """The whole recurrence through the op's own compute function, which
+    routes by ``kernel_tier`` when it is traced."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import rnn_ops
+
+    rng = np.random.RandomState(0)
+    hx = (4 if cell == "lstm" else 3) * H
+    x = jnp.asarray(rng.normal(0, 0.1, (b, L, hx)), jnp.float32)
+    w = jnp.asarray(rng.normal(0, 0.1, (H, hx)), jnp.float32)
+    lens = jnp.full((b,), L, jnp.int32)
+    zeros = jnp.zeros((b, H), x.dtype)
+
+    def route(tier):
+        # a function of its own per route: jit's cache is keyed on it
+        def compute(x, lens, w):
+            if cell == "lstm":
+                return rnn_ops._lstm_scan(x, lens, w, zeros, zeros,
+                                          "sigmoid", "tanh", "tanh")
+            return rnn_ops._gru_compute(x, lens, w, None, None, {})
+        fn = jax.jit(compute)
+
+        def run():
+            # the first call traces inside the context and pins the route
+            # into the jaxpr; later calls are cache hits
+            with _tier(tier):
+                return fn(x, lens, w)
+        return run
+
+    return {"jnp": route("jnp"), "pallas": route("pallas")}
+
+
+def embedding_sgd_case(rows, dim, nnz):
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops.pallas import embedding as emb
+
+    rng = np.random.RandomState(0)
+    p = jnp.asarray(rng.normal(0, 1, (rows, dim)), jnp.float32)
+    vals = jnp.asarray(rng.normal(0, 1, (nnz, dim)), jnp.float32)
+    # Knuth-hash row ids: distinct (the op merges rows before the kernel)
+    # and spread like a minibatch's
+    idx = jnp.asarray((np.arange(nnz) * 2654435761) % rows, jnp.int32)
+    lr = jnp.asarray(0.01, jnp.float32)
+    tf, pf = jax.jit(emb.embedding_sgd_jnp), jax.jit(emb.embedding_sgd_pallas)
+    return {"jnp": lambda: tf(p, idx, vals, lr),
+            "pallas": lambda: pf(p, idx, vals, lr)}
+
+
+def paged_attention_case(s, h, d, nb, bs, p):
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops.pallas import paged_attention as pa
+
+    rng = np.random.RandomState(0)
+    qh = jnp.asarray(rng.normal(0, 1, (s, h, d)), jnp.float32)
+    kc, vc = (jnp.asarray(rng.normal(0, 1, (nb, bs, h, d)), jnp.float32)
+              for _ in range(2))
+    bt = jnp.asarray((np.arange(s * p) % nb).reshape(s, p), jnp.int32)
+    ctx = jnp.full((s,), min(p, nb) * bs, jnp.int32)
+    tf = jax.jit(pa.paged_attention_jnp)
+    pf = jax.jit(pa.paged_attention_pallas)
+    return {"jnp": lambda: tf(qh, kc, vc, bt, ctx),
+            "pallas": lambda: pf(qh, kc, vc, bt, ctx)}
+
+
+def grouped_matmul_case(rows_per_expert, held, a, b):
+    """``rows [R, a] x w [held, a, b]``: the kernel vs ``ragged_dot`` over
+    the same tile-aligned groups (what ops/moe_ops.py runs off the tier)."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import moe_ops
+    from paddle_tpu.ops.pallas import grouped_matmul as gm
+
+    keys = jax.random.split(jax.random.PRNGKey(0), 2)
+    R = rows_per_expert * held
+    rows = jax.random.normal(keys[0], (R, a), jnp.bfloat16)
+    w = jax.random.normal(keys[1], (held, a, b), jnp.bfloat16) * 0.05
+    lay = moe_ops.layout(jnp.full((held,), rows_per_expert, jnp.int32), R)
+    tf = jax.jit(lambda rows, w: jax.lax.ragged_dot(
+        rows, w, lay["sizes"], preferred_element_type=jnp.float32))
+    pf = jax.jit(lambda rows, w: gm.gmm(rows, w, lay["tile_expert"],
+                                        lay["tiles"]))
+    return {"jnp": lambda: tf(rows, w), "pallas": lambda: pf(rows, w)}
 
 
 def momentum_case(shapes):
@@ -234,8 +364,6 @@ def resnet50_param_shapes():
 
 def cases(tiny):
     """Yield (case name, family, zero-arg builder)."""
-    from paddle_tpu.ops.autotune import make_key
-
     n = 4 if tiny else 256
     # (h, cin, cout, k, stride): one of each kind per ResNet-50 stage
     convs = [(8, 8, 8, 3, 1), (8, 8, 16, 1, 1)] if tiny else [
@@ -252,27 +380,26 @@ def cases(tiny):
     yield ("optimizer_momentum_resnet50", "optimizer",
            lambda: momentum_case(shapes))
     b, L, H = (4, 6, 128) if tiny else (64, 100, 512)
-    for cell, mult in (("lstm", 4), ("gru", 3)):
+    for cell in ("lstm", "gru"):
         yield (f"{cell}_b{b}_len{L}_hid{H}", cell,
-               lambda c=cell, m=mult: registry_case("rnn", make_key(
-                   cell=c, x=(b, L, m * H), dtype="float32")))
+               lambda a=(cell, b, L, H): rnn_case(*a))
     yield ("ctc", "ctc", lambda: ctc_case(*((2, 6, 8, 2) if tiny
                                             else (32, 128, 96, 24))))
     rows, dim, nnz = (64, 128, 8) if tiny else (30000, 128, 6400)
     yield ("embedding_sgd", "embedding_sgd",
-           lambda: registry_case("embedding", make_key(
-               rows=rows, dim=dim, nnz=nnz, dtype="float32")))
+           lambda a=(rows, dim, nnz): embedding_sgd_case(*a))
     s, nb = (2, 8) if tiny else (8, 64)
     yield ("paged_attention", "paged_attention",
-           lambda: registry_case("paged_attention", make_key(
-               q=(s, 4, 128), kc=(nb, 16, 4, 128), tables=4,
-               dtype="float32")))
+           lambda a=(s, 4, 128, nb, 16, 4): paged_attention_case(*a))
     T, heads, kv, win = (384, 2, 1, 256) if tiny else (8192, 32, 4, 1024)
     for window in (win, 0):
         for bwd in (False, True):
             yield (f"attention_{'bwd' if bwd else 'fwd'}_len{T}_window"
                    f"{window}", "attention",
                    lambda a=(T, heads, kv, window, bwd): attention_case(*a))
+    gm = (256, 2, 128, 128) if tiny else (1024, 8, 2304, 896)
+    yield ("grouped_matmul_{1}x{0}rows_{2}x{3}".format(*gm),
+           "grouped_matmul", lambda a=gm: grouped_matmul_case(*a))
 
 
 def lstm_lane_step(tiny, rounds=3):
