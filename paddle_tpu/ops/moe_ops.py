@@ -18,7 +18,12 @@ rows, and each weight meets the buffer in ONE grouped product: the
 ``grouped_matmul`` Pallas family (ops/pallas/grouped_matmul.py: a tile of
 rows meets one expert's resident weights), or ``jax.lax.ragged_dot`` over
 the same aligned groups (XLA:TPU's own grouped kernel; the twin on the CPU).
-The work follows the rows held, not rows x experts. The buffer holds
+The work follows the rows held, not rows x experts. The rows go back to
+their tokens in ``combine``, forward and backward: the ``moe_combine``
+Pallas family (ops/pallas/moe_combine.py), which leans on the sort being
+STABLE (tokens ascend inside a group, so a block of tokens owns one range of
+rows in each) and reads no row outside those ranges, or a scatter-add over
+the whole buffer, which a TPU runs one update after another. The buffer holds
 ``row_buffer_factor`` times the expectation ``tokens * top_k * held /
 num_experts`` (and a tile of padding per expert); a step whose held rows
 pass that makes the whole output NaN: loud, never a silent drop.
@@ -203,6 +208,35 @@ class _Products:
                 preferred_element_type=jnp.float32)
 
 
+def combine_jnp(rows, weight, token, n):
+    """``combine``'s twin: a scatter-add over the whole buffer, rows of
+    padding masked out."""
+    y = jnp.where((token >= 0)[:, None], rows, 0.0) * weight[:, None]
+    return jnp.zeros((n, rows.shape[1]), jnp.float32).at[
+        jnp.maximum(token, 0)].add(y)
+
+
+def combine(rows, weight, assign, top_k, lay, n):
+    """Rows -> tokens: for each of ``n`` tokens the float32 sum of the
+    buffer's rows that hold one of its assignments (``assign`` [R]: token *
+    top_k + slot, or -1 on a row of padding, which is left out), each times
+    its ``weight``. The ``moe_combine`` Pallas family
+    (ops/pallas/moe_combine.py: a gather and a sum over blocks of tokens
+    that reads only the rows in use), or a scatter-add over the whole
+    buffer (the twin on the CPU; one update after another on a TPU)."""
+    from .pallas import moe_combine as mc
+
+    token = jnp.where(assign >= 0, assign // top_k, -1)
+    route = "pallas" if use_pallas(
+        "moe_combine",
+        mc.supported(rows, n, lay["starts"].shape[0])) else "jnp"
+    with kernel_span(route, "moe_combine"):
+        if route == "pallas":
+            return mc.combine(rows, weight, token, lay["starts"],
+                              lay["tile_expert"], n)
+        return combine_jnp(rows, weight, token, n)
+
+
 def _infer(op, block):
     x = block.var(op.input("X")[0])
     if x.shape is None:
@@ -244,8 +278,8 @@ def routed_experts(ctx):
     up = jnp.where(keep, dot.rows_by(rows, wu, xc.dtype), 0)
     act = (jax.nn.silu(gate.astype(jnp.float32))
            * up.astype(jnp.float32)).astype(xc.dtype)
-    y = jnp.where(keep, dot.rows_by(act, wd), 0.0) * r["weight"][:, None]
-    out = jnp.zeros(x.shape, jnp.float32).at[token].add(y)
+    out = combine(dot.rows_by(act, wd), r["weight"], r["assign"],
+                  a["top_k"], r["layout"], x.shape[0])
     out = jnp.where(r["overflow"], jnp.nan, out)
 
     counts = _count(r["top_i"], a["num_experts"]).astype(jnp.float32)
@@ -289,7 +323,8 @@ def routed_experts_grad(ctx):
     keep = (assign >= 0)[:, None]
     token = jnp.maximum(assign, 0) // k
     rows, drows = xc[token], jnp.where(keep, dc[token], 0)
-    dot = _Products(load, layout(load, rows.shape[0]), rows, wgc)
+    lay = layout(load, rows.shape[0])
+    dot = _Products(load, lay, rows, wgc)
     g32, u32 = gate.astype(jnp.float32), up.astype(jnp.float32)
     sig = jax.nn.sigmoid(g32)
     silu = g32 * sig
@@ -303,9 +338,9 @@ def routed_experts_grad(ctx):
     dup = (dact * silu).astype(xc.dtype)
     d_wg = dot.weights_grad(rows, dgate)
     d_wu = dot.weights_grad(rows, dup)
-    drow_x = jnp.where(keep, dot.rows_by_transposed(dgate, wgc)
-                       + dot.rows_by_transposed(dup, wuc), 0.0)
-    dx = jnp.zeros(x.shape, jnp.float32).at[token].add(drow_x)
+    dx = combine(dot.rows_by_transposed(dgate, wgc)
+                 + dot.rows_by_transposed(dup, wuc), jnp.ones_like(weight),
+                 assign, k, lay, n)
 
     # the router: rows' weights back to their (token, slot), through the
     # renormalisation, the selection, the balance term and the softmax

@@ -66,6 +66,18 @@ from ...obs.metrics import REGISTRY as _METRICS
 #                      XLA:TPU's own ragged-dot kernel (512 x 256 x 128
 #                      tiles), equal to the bit; at the step 191.6 ms
 #                      against 215.2
+#   moe_combine   IN   (PR 32) routed_experts' rows -> tokens, forward and
+#                      backward: a gather and a sum over blocks of tokens
+#                      that reads only the rows in use. A family of its own
+#                      and not a fourth grouped_matmul kernel: its shape
+#                      predicate is about tokens and scalar memory, not an
+#                      expert's weights, so either falls back without the
+#                      other, and the counters say apart that it engaged.
+#                      18432 rows of 2304 float32 (8.5 k in use) to 8192
+#                      tokens: 0.37 ms on the device against 2.69 for the
+#                      scatter-add with its mask and weights (0.51 / 2.84 by
+#                      the probe's host clock), equal to the bit; at the
+#                      step 142.7 ms against 164.6, six of six pairs
 #   conv_bn       out  lowers, but 0.2-0.65x of XLA's conv+BN fusions at
 #                      6 of 7 ResNet-50 shapes; fused flagship step 318.6
 #                      vs 102.5 ms unfused
@@ -76,7 +88,8 @@ from ...obs.metrics import REGISTRY as _METRICS
 #   paged_attention out does not lower under this jax
 #   gru           out  recurrence 1.61x its scan, but no step measured: no
 #                      cell runs a GRU
-AUTO_PALLAS = frozenset({"lstm", "attention", "grouped_matmul"})
+AUTO_PALLAS = frozenset({"lstm", "attention", "grouped_matmul",
+                         "moe_combine"})
 
 # pallas->jnp silent-fallback counter, in the obs.metrics registry
 # (fallback_counts() derives its historical dict from this family)
@@ -132,9 +145,9 @@ def use_pallas(kernel, supported=True):
 
     ``kernel`` names the kernel family ("lstm", "gru", "ctc", "conv_bn",
     "optimizer", "embedding_sgd", "paged_attention", "attention",
-    "grouped_matmul"); ``supported`` is the call site's shape/config
-    predicate. Unsupported shapes under a Pallas tier fall back to the jnp
-    twin with a counter bump (never an error).
+    "grouped_matmul", "moe_combine"); ``supported`` is the call site's
+    shape/config predicate. Unsupported shapes under a Pallas tier fall
+    back to the jnp twin with a counter bump (never an error).
     """
     t = _tier()
     want = t == "pallas" or (t == "auto" and kernel in AUTO_PALLAS

@@ -37,10 +37,11 @@ def native_lowering(monkeypatch):
     the CPU, so steer that one predicate to compile them natively. The
     persistent cache cannot read such an executable back: keep it off."""
     from jax.experimental.compilation_cache import compilation_cache
-    from paddle_tpu.ops.pallas import attention, grouped_matmul, rnn
+    from paddle_tpu.ops.pallas import (attention, grouped_matmul,
+                                       moe_combine, rnn)
     monkeypatch.setattr(rnn, "_on_cpu", lambda: False)
-    monkeypatch.setattr(attention, "on_cpu", lambda: False)
-    monkeypatch.setattr(grouped_matmul, "on_cpu", lambda: False)
+    for module in attention, grouped_matmul, moe_combine:
+        monkeypatch.setattr(module, "on_cpu", lambda: False)
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
@@ -188,6 +189,89 @@ def test_grouped_matmul_kernels_compile_for_v5e(one_chip, native_lowering):
              "grouped_matmul_w")):
         compiled = jax.jit(fn).lower(*args, tile_expert, tiles).compile()
         assert name in compiled.as_text()
+
+
+def test_moe_combine_kernel_compiles_for_v5e(one_chip, native_lowering):
+    """``combine`` at the cell's shape: 18432 buffered float32 rows of 2304
+    (72 tiles, 8 held experts) to 8192 tokens; the whole walk and both row
+    vectors fit scalar memory."""
+    from paddle_tpu.ops.pallas import moe_combine as mc
+    wide, _, _, _, tile_expert, _, held = _expert_shapes(one_chip)
+
+    def s(dtype):
+        return jax.ShapeDtypeStruct(wide.shape[:1], dtype,
+                                    sharding=one_chip)
+    rows = jax.ShapeDtypeStruct(wide.shape, jnp.float32, sharding=one_chip)
+    assert wide.shape == (18432, 2304) and tile_expert.shape == (72,)
+    assert mc.supported(rows, 8192, 8)
+    compiled = mc.combine.lower(rows, s(jnp.float32), s(jnp.int32), held,
+                                tile_expert, n=8192).compile()
+    assert "moe_combine" in compiled.as_text()
+
+
+def _primitives(jaxpr):
+    """Every primitive's name in a jaxpr, those of its sub-jaxprs (jit,
+    loops, a kernel's body) among them."""
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _primitives(sub)
+
+
+@pytest.mark.parametrize("tier,scatters", [("pallas", False), ("jnp", True)])
+def test_routed_experts_on_the_kernel_route_has_no_scatter_add(tier,
+                                                               scatters):
+    """The forward's combine and the backward's rows -> tokens are the
+    ``moe_combine`` kernel on the kernel route: with
+    ``router_task_gradient`` off neither traced op holds a ``scatter-add``
+    (one update after another on a TPU), so the serial path cannot come
+    back unnoticed. The twin's route is the control: it holds one each."""
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.core.registry import get_op_info
+
+    class Ctx:
+        attrs = dict(num_experts=8, top_k=2, router_task_gradient=False)
+
+        def __init__(self, **env):
+            self.env, self.out = env, {}
+
+        def input(self, slot):
+            return self.env[slot]
+
+        def attr(self, name, default=None):
+            return self.attrs.get(name, default)
+
+        def set_output(self, slot, value):
+            self.out[slot] = value
+
+    def forward(**env):
+        ctx = Ctx(**env)
+        get_op_info("routed_experts").forward(ctx)
+        return ctx.out
+
+    def backward(env, kept, dout):
+        ctx = Ctx(**env, **{k: kept[k] for k in (
+            "Gate", "Up", "RowAssign", "RowWeight", "ExpertLoad", "TopIdx",
+            "Probs")}, **{"Out@GRAD": dout,
+                          "AuxLoss@GRAD": jnp.ones((1,), jnp.float32)})
+        get_op_info("routed_experts_grad").forward(ctx)
+        return ctx.out
+
+    f32 = jnp.zeros
+    env = dict(X=f32((1, 256, 128)), RouterW=f32((128, 8)),
+               WGate=f32((4, 128, 128)), WUp=f32((4, 128, 128)),
+               WDown=f32((4, 128, 128)))
+    fluid.set_flags({"kernel_tier": tier})
+    try:
+        fwd = jax.make_jaxpr(lambda env: forward(**env))(env)
+        kept = jax.eval_shape(lambda env: forward(**env), env)
+        bwd = jax.make_jaxpr(backward)(env, kept, env["X"])
+    finally:
+        fluid.set_flags({"kernel_tier": "auto"})
+    for traced in fwd, bwd:
+        names = set(_primitives(traced.jaxpr))
+        assert ("scatter-add" in names) == scatters, sorted(names)
+        assert ("pallas_call" in names) == (not scatters)
 
 
 def test_ragged_dot_route_lowers_to_a_grouped_kernel_on_v5e(one_chip,

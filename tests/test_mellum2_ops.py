@@ -278,49 +278,139 @@ def test_attention_blocks_gauge_reads_the_schedule_as_last_traced():
         fluid.set_flags({"kernel_tier": "auto"})
 
 
+def _experts_step(tier, x_value, router, num_experts=8, top_k=2, **layer):
+    """``routed_experts`` (width 128) and its gradient under ``tier`` on
+    one input and one router: Out, ExpertLoad, X@GRAD and the parameters'
+    gradients."""
+    fluid.set_flags({"kernel_tier": tier})
+    try:
+        main, startup = fluid.Program(), fluid.Program()
+        main.random_seed = startup.random_seed = 4
+        with fluid.program_guard(main, startup):
+            x = fluid.layers.data("x", shape=list(x_value.shape),
+                                  append_batch_size=False)
+            x.stop_gradient = False
+            out, load, aux = fluid.layers.routed_experts(
+                x, num_experts, top_k, 128,
+                **dict(dict(row_buffer_factor=2.0), **layer))
+            loss = fluid.layers.elementwise_add(
+                fluid.layers.mean(fluid.layers.elementwise_mul(out, out)),
+                fluid.layers.mean(aux))
+            pairs = fluid.backward.append_backward(loss)
+        exe, scope = fluid.Executor(mode="jit"), fluid.Scope()
+        exe.run(startup, scope=scope)
+        names = [p.name for p in main.global_block().all_parameters()]
+        scope.set(names[0], jnp.asarray(router))
+        return exe.run(main, feed={"x": x_value}, scope=scope,
+                       fetch_list=[out, load, "x@GRAD"]
+                       + [g for _, g in pairs])
+    finally:
+        fluid.set_flags({"kernel_tier": "auto"})
+
+
+def _interpreted(family):
+    from paddle_tpu.ops.pallas import dispatch_counts
+    return dispatch_counts().get(family, {}).get("interpret", 0)
+
+
 def test_grouped_matmul_kernels_match_ragged_dot_through_the_op():
     """kernel_tier=pallas runs the grouped_matmul family's three kernels in
     the interpreter (hidden and width of 128 lanes, 512 tokens, one expert
     without a row): the layer's output and every gradient match the
     ragged_dot route over the same aligned groups."""
-    from paddle_tpu.ops.pallas import dispatch_counts
-
     rng = np.random.RandomState(2)
     router = rng.randn(128, 8).astype(np.float32) * 0.3
     router[:, 3] = -1.0
     x_value = np.abs(rng.randn(1, 512, 128)).astype(np.float32)
 
-    def run(tier):
-        fluid.set_flags({"kernel_tier": tier})
-        try:
-            main, startup = fluid.Program(), fluid.Program()
-            main.random_seed = startup.random_seed = 4
-            with fluid.program_guard(main, startup):
-                x = fluid.layers.data("x", shape=[1, 512, 128],
-                                      append_batch_size=False)
-                x.stop_gradient = False
-                out, load, aux = fluid.layers.routed_experts(
-                    x, 8, 2, 128, row_buffer_factor=2.0)
-                loss = fluid.layers.elementwise_add(
-                    fluid.layers.mean(fluid.layers.elementwise_mul(out,
-                                                                    out)),
-                    fluid.layers.mean(aux))
-                pairs = fluid.backward.append_backward(loss)
-            exe, scope = fluid.Executor(mode="jit"), fluid.Scope()
-            exe.run(startup, scope=scope)
-            names = [p.name for p in main.global_block().all_parameters()]
-            scope.set(names[0], jnp.asarray(router))
-            return exe.run(main, feed={"x": x_value}, scope=scope,
-                           fetch_list=[out, load, "x@GRAD"]
-                           + [g for _, g in pairs])
-        finally:
-            fluid.set_flags({"kernel_tier": "auto"})
-
-    before = dispatch_counts().get("grouped_matmul", {}).get("interpret", 0)
-    kernel, twin = run("pallas"), run("jnp")
-    assert dispatch_counts()["grouped_matmul"]["interpret"] == before + 9
+    before = _interpreted("grouped_matmul")
+    kernel = _experts_step("pallas", x_value, router)
+    twin = _experts_step("jnp", x_value, router)
+    assert _interpreted("grouped_matmul") == before + 9
     assert int(np.asarray(twin[1])[3]) == 0         # an expert with no tile
     for a, b in zip(kernel, twin):
+        assert _err(a, b) < 1e-5
+
+
+def _combine_case(name):
+    """(x [1, 256 or 512, 128], router, layer attributes, what the loads
+    must read): routings that reach each edge of the combine's walk."""
+    rng = np.random.RandomState(5)
+    x = np.abs(rng.randn(1, 256, 128)).astype(np.float32)
+    share = dict(num_experts=64, top_k=8, held_experts=8, expert_offset=8)
+    if name == "share":                     # the cell's geometry: 8 of 64
+        router = rng.randn(128, 64).astype(np.float32) * 0.3
+        return x, router, share, lambda load: load.sum() > 0
+    if name == "whole":                     # held == num_experts, top 2
+        router = rng.randn(128, 8).astype(np.float32) * 0.3
+        return x, router, {}, lambda load: load.sum() == 512
+    if name == "all_eight_and_none":
+        # feature 0 is token 0's alone and names the held experts 8..15,
+        # feature 1 token 1's and names eight others
+        router = rng.randn(128, 64).astype(np.float32) * 0.05
+        x[0, :, :2] = 0.0
+        x[0, 0, 0] = x[0, 1, 1] = 50.0
+        router[0, 8:16], router[1, 24:32] = 1.0, 1.0
+        return x, router, dict(share, router_task_gradient=False), None
+    if name == "an_expert_without_a_row":
+        router = rng.randn(128, 8).astype(np.float32) * 0.3
+        router[:, 3] = -1.0
+        return x, router, {}, lambda load: load[3] == 0 and load[2] > 0
+    if name == "one_row_in_a_last_tile":
+        # 257 tokens carry feature 0, which names expert 5; no other token
+        # comes near it: its group is a tile and one row
+        x = np.abs(rng.randn(1, 512, 128)).astype(np.float32)
+        router = rng.randn(128, 8).astype(np.float32) * 0.3
+        x[0, :, 0] = 0.0
+        x[0, :257, 0] = 50.0
+        router[0, 5], router[1:, 5] = 10.0, -1.0
+        return x, router, {}, lambda load: load[5] == 257
+    assert name == "overflow"
+    # 512 tokens, experts 0 and 1 held, every token routed to both: 1024
+    # rows against a buffer of 2 x the expectation of 256
+    x = np.abs(rng.randn(1, 512, 128)).astype(np.float32)
+    router = rng.randn(128, 8).astype(np.float32) * 0.003
+    router[:, 0], router[:, 1] = 1.0, 0.9
+    return (x, router, dict(held_experts=2),
+            lambda load: load.tolist() == [512, 512])
+
+
+@pytest.mark.parametrize("case", [
+    "share", "whole", "all_eight_and_none", "an_expert_without_a_row",
+    "one_row_in_a_last_tile", "overflow"])
+def test_combine_kernel_matches_the_scatter_add_through_the_op(case):
+    """kernel_tier=pallas runs ``moe_combine`` in the interpreter, once in
+    the forward and once in the backward: ``Out`` and ``X@GRAD`` (and every
+    other gradient) match the route that scatter-adds over the whole
+    buffer; an overflow is NaN on both."""
+    from paddle_tpu.ops.pallas import fallback_counts
+
+    x_value, router, layer, loads_ok = _combine_case(case)
+    before = _interpreted("moe_combine")
+    kernel = _experts_step("pallas", x_value, router, **layer)
+    assert _interpreted("moe_combine") == before + 2
+    assert "moe_combine" not in fallback_counts()
+    twin = _experts_step("jnp", x_value, router, **layer)
+    assert _interpreted("moe_combine") == before + 2
+    load = np.asarray(twin[1])
+    np.testing.assert_array_equal(np.asarray(kernel[1]), load)
+    if case == "overflow":
+        assert loads_ok(load)
+        for got in kernel[0], kernel[2], twin[0]:
+            assert np.isnan(np.asarray(got)).all()
+        return
+    if case == "all_eight_and_none":
+        # token 0 has a row in every held group, token 1 in none: its
+        # output is zeros and its input's gradient the balance term's
+        top = np.argsort(-(x_value[0] @ router), axis=-1)[:2, :8]
+        assert sorted(top[0]) == list(range(8, 16))
+        assert not set(top[1]) & set(range(8, 16))
+        assert np.abs(np.asarray(kernel[0])[0, 0]).max() > 0
+        assert not np.asarray(kernel[0])[0, 1].any()
+    else:
+        assert loads_ok(load)
+    for a, b in zip(kernel, twin):
+        assert np.isfinite(np.asarray(a)).all()
         assert _err(a, b) < 1e-5
 
 

@@ -18,7 +18,8 @@ embedding_sgd at the shapes their parity tests pin, scaled to a workload
 size; paged_attention at the generation lane's decode shape; banded
 attention at the Mellum2 cell's shape (8192 tokens, 32 / 4 heads of 128,
 bfloat16), forward and backward, a window layer and the full one; the
-grouped product at that cell's expert shape (8 experts of 2304 x 896).
+grouped product at that cell's expert shape (8 experts of 2304 x 896) and
+the experts' combine at its buffer (18432 rows of 2304 to 8192 tokens).
 Every family with a dispatch site in ``paddle_tpu/ops/`` has a case.
 
 A kernel that fails to lower is a RESULT here (``lowered: false`` with the
@@ -279,6 +280,33 @@ def grouped_matmul_case(rows_per_expert, held, a, b):
     return {"jnp": lambda: tf(rows, w), "pallas": lambda: pf(rows, w)}
 
 
+def moe_combine_case(n, top_k, held, num_experts, h):
+    """``rows [R, h]`` float32 -> ``[n, h]``: the gather-and-sum kernel vs
+    the scatter-add over the whole buffer (what ops/moe_ops.py runs off the
+    tier), with the weights and the mask of padding folded into both, on a
+    routing of random tokens by a random router."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import moe_ops
+    from paddle_tpu.ops.pallas import moe_combine as mc
+
+    keys = jax.random.split(jax.random.PRNGKey(0), 3)
+    r = moe_ops.route(jax.random.normal(keys[0], (n, 64)),
+                      jax.random.normal(keys[1], (64, num_experts)), held,
+                      num_experts, top_k, True, 0, 2.0)
+    token = jnp.where(r["assign"] >= 0, r["assign"] // top_k, -1)
+    rows = jax.random.normal(keys[2], (token.shape[0], h), jnp.float32)
+    assert mc.supported(rows, n, held)
+    lay = r["layout"]
+
+    tf = jax.jit(lambda rows, weight: moe_ops.combine_jnp(rows, weight,
+                                                          token, n))
+    pf = jax.jit(lambda rows, weight: mc.combine(
+        rows, weight, token, lay["starts"], lay["tile_expert"], n))
+    return {"jnp": lambda: tf(rows, r["weight"]),
+            "pallas": lambda: pf(rows, r["weight"])}
+
+
 def momentum_case(shapes):
     """One fused-momentum step over ``shapes``: the arena megakernel (with
     the concat/split the fused op pays) vs the per-param twin."""
@@ -400,6 +428,9 @@ def cases(tiny):
     gm = (256, 2, 128, 128) if tiny else (1024, 8, 2304, 896)
     yield ("grouped_matmul_{1}x{0}rows_{2}x{3}".format(*gm),
            "grouped_matmul", lambda a=gm: grouped_matmul_case(*a))
+    mc = (256, 2, 4, 8, 128) if tiny else (8192, 8, 8, 64, 2304)
+    yield ("moe_combine_{0}tokens_top{1}_{2}of{3}_width{4}".format(*mc),
+           "moe_combine", lambda a=mc: moe_combine_case(*a))
 
 
 def lstm_lane_step(tiny, rounds=3):
