@@ -47,6 +47,22 @@ class Normal(Initializer):
                                "seed": self._seed})
 
 
+class Mapped(Initializer):
+    """``base``'s draw passed through unary ops of the registry, in place,
+    in the start-up program: ``Mapped(Uniform(1, 16), "log")`` draws log
+    U(1, 16). An entry is an op type or (op type, attrs)."""
+
+    def __init__(self, base, *ops):
+        self._base, self._ops = base, ops
+
+    def __call__(self, var, block):
+        self._base(var, block)
+        for op in self._ops:
+            op_type, attrs = (op, {}) if isinstance(op, str) else op
+            block.append_op(op_type, inputs={"X": [var.name]},
+                            outputs={"Out": [var.name]}, attrs=dict(attrs))
+
+
 def _fan_in_out(var):
     shape = var.shape
     if len(shape) == 0:

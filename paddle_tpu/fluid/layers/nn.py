@@ -16,7 +16,8 @@ import numpy as np
 from ..framework import Variable, unique_name
 from ..layer_helper import LayerHelper
 from ..param_attr import ParamAttr
-from ..initializer import Constant, Normal, Xavier
+from ..initializer import Constant, Mapped, Normal, Uniform, Xavier
+from . import ops, tensor
 
 
 def fc(input, size, num_flatten_dims=1, param_attr=None, bias_attr=None,
@@ -977,7 +978,8 @@ def causal_self_attention(q, k, v, num_heads, num_kv_heads=None, window=0,
     (default ``num_heads``) key/value heads serve the query heads in
     blocked groups (K and V are then [batch, seq, num_kv_heads *
     head_dim]); ``window`` > 0 lets position i see j only where
-    0 <= i - j < window (0: every j <= i)."""
+    0 <= i - j < window (0: every j <= i). V's heads may be of another size
+    than Q's and K's; the result has V's."""
     num_kv_heads = int(num_kv_heads or num_heads)
     if q.shape and q.shape[-1] is not None and q.shape[-1] % num_heads:
         raise ValueError(
@@ -986,7 +988,11 @@ def causal_self_attention(q, k, v, num_heads, num_kv_heads=None, window=0,
         raise ValueError(f"num_heads {num_heads} must be a multiple of "
                          f"num_kv_heads {num_kv_heads}")
     helper = LayerHelper("causal_self_attention", name=name)
-    out = helper.create_tmp_variable(q.dtype, shape=q.shape)
+    shape = q.shape
+    if shape and v.shape and v.shape[-1] is not None:
+        shape = tuple(shape[:-1]) + (
+            int(v.shape[-1]) // num_kv_heads * int(num_heads),)
+    out = helper.create_tmp_variable(q.dtype, shape=shape)
     lse = helper.create_tmp_variable("float32", stop_gradient=True)
     attrs = {"num_heads": int(num_heads)}
     if num_kv_heads != num_heads:
@@ -1037,20 +1043,217 @@ def rotary_embedding(q, k, head_dim, theta=10000.0, rope_type="default",
     return q_out, k_out
 
 
+def _project(x, size, param_attr):
+    """``fc`` over the last axis of [batch, seq, width], no bias, with a
+    copy of ``param_attr`` (one attr may serve several projections)."""
+    return fc(x, size, num_flatten_dims=2, bias_attr=False,
+              param_attr=copy.deepcopy(ParamAttr.to_attr(param_attr)))
+
+
+def latent_kv_heads(kv, k_rope, num_heads, nope_dim, name=None):
+    """Per-head keys and values of latent (MLA) attention: ``kv`` [batch,
+    seq, num_heads * (nope_dim + v)], each head [k_nope | v], and the one
+    rope key ``k_rope`` [batch, seq, rope] that all heads share -> (k
+    [batch, seq, num_heads * (nope_dim + rope)], v [batch, seq, num_heads *
+    v])."""
+    helper = LayerHelper("latent_kv_heads", name=name)
+    k = helper.create_tmp_variable(kv.dtype)
+    v = helper.create_tmp_variable(kv.dtype)
+    helper.append_op(
+        "latent_kv_heads", inputs={"KV": [kv.name], "KRope": [k_rope.name]},
+        outputs={"K": [k.name], "V": [v.name]},
+        attrs={"num_heads": int(num_heads), "nope_dim": int(nope_dim)})
+    return k, v
+
+
+def latent_attention(input, num_heads, kv_lora_rank, qk_nope_head_dim,
+                     qk_rope_head_dim, v_head_dim, q_lora_rank=None,
+                     rope_theta=None, epsilon=1e-6, param_attr=None,
+                     down_attr=None, up_attr=None, name=None):
+    """Causal multi-head latent attention (MLA) in its expanded, training
+    form, over an already normed ``input`` [batch, seq, hidden]; no bias
+    anywhere. Queries: ``num_heads`` heads of [nope | rope], projected whole
+    (``q_lora_rank`` None) or through ``RMSNorm(x W_qa)`` of that rank. Keys
+    and values: ``x W_kva`` is [latent (``kv_lora_rank``) | ONE rope key];
+    the normed latent goes up to ``num_heads`` heads of [k_nope | v], and
+    every head's key is [k_nope | the shared rope key]
+    (``latent_kv_heads``). With ``rope_theta`` the rope slices of the
+    queries and the rope key are rotated by their positions; with None
+    nothing is rotated (NoPE: the slice is 64 more coordinates of a key
+    that all heads share). Scores are scaled by (nope + rope)^-0.5 and the
+    values' heads may be of another size than the keys'
+    (``causal_self_attention``); the heads' outputs go back to ``hidden``
+    through ``W_o``. Parameters in the order W_q (or W_qa, the query
+    latent's norm, W_qb), W_kva, the key/value latent's norm, W_kvb, W_o.
+    ``down_attr`` (W_qa, W_kva) and ``up_attr`` (W_q / W_qb, W_kvb) default
+    to ``param_attr``."""
+    if rope_theta is not None:
+        raise NotImplementedError(
+            "latent_attention: a rotated slice needs rotary_embedding over "
+            "the last qk_rope_head_dim coordinates of a head, which the op "
+            "does not have; pass rope_theta=None (NoPE)")
+    head = int(qk_nope_head_dim) + int(qk_rope_head_dim)
+
+    def proj(x, size, attr=None):
+        return _project(x, size, attr or param_attr)
+
+    if q_lora_rank:
+        q = proj(rms_norm(proj(input, q_lora_rank, down_attr),
+                          epsilon=epsilon), num_heads * head, up_attr)
+    else:
+        q = proj(input, num_heads * head, up_attr)
+    c_kv, k_rope = tensor.split(
+        proj(input, kv_lora_rank + qk_rope_head_dim, down_attr),
+        [int(kv_lora_rank), int(qk_rope_head_dim)], dim=-1)
+    kv = proj(rms_norm(c_kv, epsilon=epsilon),
+              num_heads * (qk_nope_head_dim + v_head_dim), up_attr)
+    k, v = latent_kv_heads(kv, k_rope, num_heads, qk_nope_head_dim)
+    out = causal_self_attention(q, k, v, num_heads=num_heads, name=name)
+    return proj(out, int(input.shape[-1]))
+
+
+def gated_mlp(input, width, param_attr=None):
+    """The gated-SiLU MLP ``W_down(silu(W_gate x) * W_up x)`` of ``width``
+    over [batch, seq, hidden], no bias: three ``fc``, ``swish`` and a
+    product. Parameters in the order gate, up, down."""
+    gate = _project(input, width, param_attr)
+    up = _project(input, width, param_attr)
+    return _project(elementwise_mul(ops.swish(gate), up),
+                    int(input.shape[-1]), param_attr)
+
+
+def causal_conv1d(input, taps, param_attr=None, name=None):
+    """A causal depthwise convolution over time on [batch, seq, channels],
+    then SiLU: each channel's own filter of ``taps`` over the current and
+    the ``taps - 1`` earlier tokens (zeros before the first). The filter
+    parameter is [taps, channels], the last tap on the current token."""
+    helper = LayerHelper("causal_conv1d", name=name)
+    w = helper.create_parameter(
+        ParamAttr.to_attr(param_attr), shape=(int(taps),
+                                              int(input.shape[-1])),
+        dtype="float32")
+    out = helper.create_tmp_variable(input.dtype, shape=input.shape)
+    helper.append_op("causal_conv1d",
+                     inputs={"X": [input.name], "Filter": [w.name]},
+                     outputs={"Out": [out.name]})
+    return out
+
+
+def kda_decay_gate(input, num_heads, name=None):
+    """The gated delta rule's log-decay, one per key channel, from a
+    projection ``input`` [batch, seq, num_heads * head_dim]: ``-exp(A_log
+    [head]) * softplus(input + dt_bias)``, float32. Parameters ``A_log``
+    [num_heads], initialised log U(1, 16), and ``dt_bias`` [num_heads *
+    head_dim], the inverse softplus of a step drawn log-uniformly in [0.001,
+    0.1] (the family's convention)."""
+    helper = LayerHelper("kda_decay_gate", name=name)
+    a_log = helper.create_parameter(
+        ParamAttr(initializer=Mapped(Uniform(1.0, 16.0), "log")),
+        shape=(int(num_heads),), dtype="float32")
+    lo, hi = float(np.log(0.001)), float(np.log(0.1))
+    dt_bias = helper.create_parameter(
+        ParamAttr(initializer=Mapped(       # softplus^-1(e^u) = log(e^e^u - 1)
+            Uniform(lo, hi), "exp", "exp", ("scale", {"bias": -1.0}),
+            "log")),
+        shape=(int(input.shape[-1]),), dtype="float32")
+    out = helper.create_tmp_variable("float32", shape=input.shape)
+    helper.append_op("kda_decay_gate",
+                     inputs={"X": [input.name], "ALog": [a_log.name],
+                             "DtBias": [dt_bias.name]},
+                     outputs={"Out": [out.name]})
+    return out
+
+
+def gated_delta_rule(q, k, v, g, beta, num_heads, chunk_size=64, name=None):
+    """Linear attention by the gated delta rule (ops/
+    linear_attention_ops.py): per head a state ``S`` [key, value] from
+    zero, ``S_t = (I - beta_t k_t k_t^T) Diag(exp(g_t)) S_{t-1} + beta_t
+    k_t v_t^T``, ``o_t = S_t^T q_t``, computed in chunks of ``chunk_size``
+    tokens. ``q``, ``k`` [batch, seq, num_heads * dk] are L2-normalised per
+    head inside the op (``q`` times dk^-0.5); ``v``
+    [batch, seq, num_heads * dv]; ``g`` the log-decay per key channel (<=
+    0); ``beta`` [batch, seq, num_heads]. Returns o, of v's shape."""
+    helper = LayerHelper("gated_delta_rule", name=name)
+    out = helper.create_tmp_variable(v.dtype, shape=v.shape)
+    states = helper.create_tmp_variable("float32", stop_gradient=True)
+    helper.append_op(
+        "gated_delta_rule",
+        inputs={"Q": [q.name], "K": [k.name], "V": [v.name], "G": [g.name],
+                "Beta": [beta.name]},
+        outputs={"Out": [out.name], "States": [states.name]},
+        attrs={"num_heads": int(num_heads), "chunk_size": int(chunk_size)})
+    return out
+
+
+def gated_rms_norm(input, gate, head_dim, epsilon=1e-6, param_attr=None,
+                   name=None):
+    """RMSNorm over each ``head_dim`` slice of [batch, seq, heads *
+    head_dim] with one learned scale [head_dim] (initialised to 1), times
+    ``sigmoid(gate)``."""
+    helper = LayerHelper("gated_rms_norm", name=name)
+    scale = helper.create_parameter(
+        ParamAttr.to_attr(param_attr), shape=(int(head_dim),),
+        dtype="float32", default_initializer=Constant(1.0))
+    out = helper.create_tmp_variable(input.dtype, shape=input.shape)
+    helper.append_op("gated_rms_norm",
+                     inputs={"X": [input.name], "Gate": [gate.name],
+                             "Scale": [scale.name]},
+                     outputs={"Out": [out.name]},
+                     attrs={"epsilon": float(epsilon)})
+    return out
+
+
+def kda_attention(input, num_heads, head_dim, conv_size=4, gate_rank=None,
+                  chunk_size=64, epsilon=1e-6, param_attr=None,
+                  conv_attr=None, name=None):
+    """Kimi Delta Attention over an already normed ``input`` [batch, seq,
+    hidden]; no bias but the gate's. ``q, k, v = silu(conv(x W))`` (causal
+    depthwise convolutions of ``conv_size`` taps), ``num_heads`` heads of
+    ``head_dim`` each; the log-decay ``kda_decay_gate(x W_fa W_fb)`` through
+    a rank of ``gate_rank`` (default ``head_dim``); the step size
+    ``sigmoid(x W_b)`` per head; ``gated_delta_rule``; the output
+    ``gated_rms_norm`` per head with the gate ``x W_ga W_gb``; then ``W_o``
+    back to hidden. Parameters in the order W_q, W_k, W_v, the three
+    filters (q, k, v), W_fa, W_fb, A_log, dt_bias, W_b, W_ga, W_gb, the
+    output norm's scale, W_o."""
+    width = int(num_heads) * int(head_dim)
+    rank = int(gate_rank or head_dim)
+    q, k, v = (_project(input, width, param_attr) for _ in range(3))
+    q, k, v = (causal_conv1d(x, conv_size, param_attr=copy.deepcopy(
+        ParamAttr.to_attr(conv_attr or param_attr))) for x in (q, k, v))
+    g = kda_decay_gate(
+        _project(_project(input, rank, param_attr), width, param_attr),
+        num_heads)
+    beta = ops.sigmoid(_project(input, num_heads, param_attr))
+    o = gated_delta_rule(q, k, v, g, beta, num_heads, chunk_size, name=name)
+    gate = _project(_project(input, rank, param_attr), width, param_attr)
+    o = gated_rms_norm(o, gate, head_dim, epsilon=epsilon)
+    return _project(o, int(input.shape[-1]), param_attr)
+
+
 def routed_experts(input, num_experts, top_k, expert_width,
                    held_experts=None, expert_offset=0, norm_topk_prob=True,
                    row_buffer_factor=2.0, router_task_gradient=True,
-                   param_attr=None, name=None):
+                   scoring_func="softmax", routed_scaling_factor=1.0,
+                   selection_bias=False, bias_update_rate=0.0,
+                   bias_attr=None, param_attr=None, name=None):
     """A mixture-of-experts MLP that is told which experts it holds
-    (ops/moe_ops.py): the router scores all ``num_experts`` and keeps the
-    ``top_k``; the layer holds the gated-SiLU experts ``expert_offset ..
-    expert_offset + held_experts - 1`` (default: all) of width
-    ``expert_width`` and returns their part of the result, dropping no
-    row. With ``router_task_gradient`` off the task loss does not reach
-    the router through the top k's weights (it learns from ``aux_loss``
-    alone: for a layer that holds a share of the experts). Returns (out,
-    expert_load [held] int32, aux_loss [1]: the load-balancing term over
-    all router outputs)."""
+    (ops/moe_ops.py): the router scores all ``num_experts``
+    (``scoring_func``: ``softmax`` over all of them, or ``sigmoid`` of each)
+    and keeps the ``top_k``, whose weights are the scores' own, renormalised
+    where ``norm_topk_prob``, times ``routed_scaling_factor``; the layer
+    holds the gated-SiLU experts ``expert_offset .. expert_offset +
+    held_experts - 1`` (default: all) of width ``expert_width`` and returns
+    their part of the result, dropping no row. With ``selection_bias`` a
+    non-trainable parameter [num_experts] (zeros, or ``bias_attr``'s
+    initialiser) is added to the scores for the SELECTION only, and with
+    ``bias_update_rate`` > 0 each step moves it by that much against the
+    step's loads (``expert_bias_update``: no gradient, no optimizer). With
+    ``router_task_gradient`` off the task loss does not reach the router
+    through the top k's weights (it learns from ``aux_loss`` alone: for a
+    layer that holds a share of the experts). Returns (out, expert_load
+    [held] int32, aux_loss [1]: the load-balancing term over all router
+    outputs)."""
     helper = LayerHelper("routed_experts", name=name)
     hidden = int(input.shape[-1])
     held = int(held_experts or num_experts)
@@ -1065,9 +1268,33 @@ def routed_experts(input, num_experts, top_k, expert_width,
             dtype=input.dtype)
 
     router = weight((hidden, num_experts))
+    inputs = {"X": [input.name], "RouterW": [router.name]}
+    attrs = {"num_experts": int(num_experts), "top_k": int(top_k),
+             "norm_topk_prob": bool(norm_topk_prob),
+             "expert_offset": int(expert_offset),
+             "row_buffer_factor": float(row_buffer_factor),
+             "router_task_gradient": bool(router_task_gradient)}
+    # the defaults stay out of the op, which is then the one every program
+    # built before them holds
+    if scoring_func != "softmax":
+        attrs["scoring_func"] = str(scoring_func)
+    if routed_scaling_factor != 1.0:
+        attrs["routed_scaling_factor"] = float(routed_scaling_factor)
+    bias = None
+    if selection_bias:
+        attr = copy.deepcopy(ParamAttr.to_attr(bias_attr))
+        attr.trainable = False
+        bias = helper.create_parameter(
+            attr, shape=(int(num_experts),), dtype="float32",
+            default_initializer=Constant(0.0))
+        inputs["SelectBias"] = [bias.name]
+    elif bias_update_rate:
+        raise ValueError("bias_update_rate without selection_bias")
     w_gate = weight((held, hidden, expert_width))
     w_up = weight((held, hidden, expert_width))
     w_down = weight((held, expert_width, hidden))
+    inputs.update({"WGate": [w_gate.name], "WUp": [w_up.name],
+                   "WDown": [w_down.name]})
     out = helper.create_tmp_variable(input.dtype, shape=input.shape)
     aux = helper.create_tmp_variable("float32", shape=(1,))
     kept = {slot: helper.create_tmp_variable(dtype, stop_gradient=True)
@@ -1078,15 +1305,14 @@ def routed_experts(input, num_experts, top_k, expert_width,
                 ("Probs", "float32"))}
     kept["ExpertLoad"].shape = (held,)
     helper.append_op(
-        "routed_experts",
-        inputs={"X": [input.name], "RouterW": [router.name],
-                "WGate": [w_gate.name], "WUp": [w_up.name],
-                "WDown": [w_down.name]},
+        "routed_experts", inputs=inputs,
         outputs={"Out": [out.name], "AuxLoss": [aux.name],
                  **{slot: [v.name] for slot, v in kept.items()}},
-        attrs={"num_experts": int(num_experts), "top_k": int(top_k),
-               "norm_topk_prob": bool(norm_topk_prob),
-               "expert_offset": int(expert_offset),
-               "row_buffer_factor": float(row_buffer_factor),
-               "router_task_gradient": bool(router_task_gradient)})
+        attrs=attrs)
+    if bias is not None and bias_update_rate:
+        helper.append_op(
+            "expert_bias_update",
+            inputs={"Bias": [bias.name], "TopIdx": [kept["TopIdx"].name]},
+            outputs={"BiasOut": [bias.name]},
+            attrs={"rate": float(bias_update_rate)})
     return out, kept["ExpertLoad"], aux

@@ -19,6 +19,7 @@ from . import (  # noqa: F401
     sequence_ops,
     rnn_ops,
     attention_ops,
+    linear_attention_ops,
     moe_ops,
     control_flow_ops,
     crf_ops,

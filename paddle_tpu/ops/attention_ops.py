@@ -88,14 +88,18 @@ def _causal_mha(q, k, v, num_heads):
     return out.reshape(q.shape)
 
 
-def _attention_route(q, heads, kv_heads, window):
+def _attention_route(q, heads, kv_heads, window, v=None):
     """"pallas" or "jnp" for this call; the forward op and its grad op ask
-    the same question and get the same answer."""
+    the same question and get the same answer. The kernels are asked about
+    the query/key heads as ``_lane_padded`` would hand them over."""
     from .pallas import use_pallas
     from .pallas import attention as att
 
+    d = q.shape[-1] // heads
+    wide = jax.ShapeDtypeStruct(
+        q.shape[:-1] + (heads * (d + -d % att.LANES),), q.dtype)
     if not use_pallas("attention", att.attention_supported(
-            q, heads, kv_heads, window)):
+            wide, heads, kv_heads, window, v)):
         return "jnp"
     T = q.shape[1]
     sched = att.band_schedule(T, att.kernel_block(T), window)
@@ -104,15 +108,39 @@ def _attention_route(q, heads, kv_heads, window):
     return "pallas"
 
 
-def _attention_attrs(ctx, q, k):
+def _attention_attrs(ctx, q, k, v):
     heads = int(ctx.attr("num_heads"))
     kv_heads = int(ctx.attr("num_kv_heads", 0) or heads)
     if heads % kv_heads or q.shape[-1] % heads \
-            or k.shape[-1] * heads != q.shape[-1] * kv_heads:
+            or k.shape[-1] * heads != q.shape[-1] * kv_heads \
+            or v.shape[-1] % kv_heads:
         raise ValueError(
             f"attention: {heads} query heads over {kv_heads} key/value "
-            f"heads do not fit Q {q.shape} and K {k.shape}")
+            f"heads do not fit Q {q.shape}, K {k.shape} and V {v.shape}")
     return heads, kv_heads, int(ctx.attr("window", 0) or 0)
+
+
+def _lane_padded(x, heads):
+    """``x`` [b, T, heads * d] with each head filled up with zeros to whole
+    128-lane tiles (192 -> 256). Zeros add nothing to a score, so the
+    product is the same; the scale stays the true head size's."""
+    from .pallas.attention import LANES
+    b, t, e = x.shape
+    d = e // heads
+    if d % LANES == 0:
+        return x
+    return jnp.pad(x.reshape(b, t, heads, d),
+                   ((0, 0), (0, 0), (0, 0), (0, -d % LANES))) \
+        .reshape(b, t, -1)
+
+
+def _cut_heads(padded, like, heads):
+    """``_lane_padded``'s inverse for a gradient: each head's leading
+    coordinates, in ``like``'s shape."""
+    if padded.shape == like.shape:
+        return padded
+    b, t, e = like.shape
+    return padded.reshape(b, t, heads, -1)[..., :e // heads].reshape(b, t, e)
 
 
 def _attention_forward(q, k, v, heads, kv_heads, window):
@@ -121,10 +149,13 @@ def _attention_forward(q, k, v, heads, kv_heads, window):
     from .pallas import attention as att
 
     q, k, v = cast_compute(q, k, v)
-    route = _attention_route(q, heads, kv_heads, window)
+    route = _attention_route(q, heads, kv_heads, window, v)
     with kernel_span(route, "attention"):
-        fn = att.attention_pallas if route == "pallas" else att.attention_jnp
-        return fn(q, k, v, heads, kv_heads, window)
+        if route == "jnp":
+            return att.attention_jnp(q, k, v, heads, kv_heads, window)
+        return att.attention_pallas(
+            _lane_padded(q, heads), _lane_padded(k, kv_heads), v, heads,
+            kv_heads, window, scale=(q.shape[-1] // heads) ** -0.5)
 
 
 def _attention_grad_maker(op):
@@ -144,13 +175,19 @@ def causal_self_attention(ctx):
     form the generation engine's program split rewrites per phase.
     ``num_kv_heads`` (default ``num_heads``) key/value heads serve the query
     heads in blocked groups; ``window`` > 0 lets position i see j only
-    where i - j < window. Blocked (no [T, T] scores): the ``attention``
-    Pallas family or its jnp twin. ``LogSumExp`` is the forward's residual,
-    in the form its route keeps it, for the grad op."""
+    where i - j < window. V's heads may be of another size than Q's and
+    K's (latent attention: 192 / 128); ``Out`` has V's head size and the
+    scores are scaled by Q's. Blocked (no [T, T] scores): the
+    ``attention`` Pallas family or its jnp twin. The kernels take whole
+    128-lane heads: query/key heads of another size are padded with zeros
+    to the next multiple of 128 on the way in (exact: a zero adds nothing
+    to a score) and their gradients cut back on the way out. ``LogSumExp``
+    is the forward's residual, in the form its route keeps it, for the
+    grad op."""
     q = data_of(ctx.input("Q"))
     k = data_of(ctx.input("K"))
     v = data_of(ctx.input("V"))
-    out, lse = _attention_forward(q, k, v, *_attention_attrs(ctx, q, k))
+    out, lse = _attention_forward(q, k, v, *_attention_attrs(ctx, q, k, v))
     ctx.set_output("Out", out)
     ctx.set_output("LogSumExp", lse)
 
@@ -163,7 +200,7 @@ def causal_self_attention_grad(ctx):
     q = data_of(ctx.input("Q"))
     k = data_of(ctx.input("K"))
     v = data_of(ctx.input("V"))
-    heads, kv_heads, window = _attention_attrs(ctx, q, k)
+    heads, kv_heads, window = _attention_attrs(ctx, q, k, v)
     if ctx.has_input("LogSumExp"):
         out = data_of(ctx.input("Out"))
         lse = data_of(ctx.input("LogSumExp"))
@@ -171,12 +208,18 @@ def causal_self_attention_grad(ctx):
         out, lse = _attention_forward(q, k, v, heads, kv_heads, window)
     qc, kc, vc, out, d = cast_compute(q, k, v, out,
                                       data_of(ctx.input("Out@GRAD")))
-    route = _attention_route(qc, heads, kv_heads, window)
+    route = _attention_route(qc, heads, kv_heads, window, vc)
+    d = d.astype(qc.dtype)
     with kernel_span(route, "attention"):
-        fn = (att.attention_pallas_bwd if route == "pallas"
-              else att.attention_jnp_bwd)
-        dq, dk, dv = fn(qc, kc, vc, out, lse, d.astype(qc.dtype), heads,
-                        kv_heads, window)
+        if route == "jnp":
+            dq, dk, dv = att.attention_jnp_bwd(qc, kc, vc, out, lse, d, heads,
+                                               kv_heads, window)
+        else:
+            dq, dk, dv = att.attention_pallas_bwd(
+                _lane_padded(qc, heads), _lane_padded(kc, kv_heads), vc, out,
+                lse, d, heads, kv_heads, window,
+                scale=(q.shape[-1] // heads) ** -0.5)
+            dq, dk = _cut_heads(dq, q, heads), _cut_heads(dk, k, kv_heads)
     ctx.set_output("Q@GRAD", dq.astype(q.dtype))
     ctx.set_output("K@GRAD", dk.astype(k.dtype))
     ctx.set_output("V@GRAD", dv.astype(v.dtype))
@@ -296,6 +339,77 @@ def rotary_embedding_grad(ctx):
     d = int(ctx.attr("head_dim"))
     ctx.set_output("Q@GRAD", _rotate(dq, cos, -sin, d))
     ctx.set_output("K@GRAD", _rotate(dk, cos, -sin, d))
+
+
+# ---------------------------------------------------------------------------
+# latent_kv_heads — per-head keys and values of latent (MLA) attention
+# ---------------------------------------------------------------------------
+
+def _latent_dims(ctx, kv):
+    """(heads, nope): ``KV`` holds ``heads`` heads of [nope | value]."""
+    heads, nope = int(ctx.attr("num_heads")), int(ctx.attr("nope_dim"))
+    if kv.shape[-1] % heads or kv.shape[-1] // heads <= nope:
+        raise ValueError(
+            f"latent_kv_heads: {heads} heads of [{nope} | value] do not "
+            f"fit KV {kv.shape}")
+    return heads, nope
+
+
+def _latent_grad_maker(op):
+    return [OpSpec("latent_kv_heads_grad",
+                   {"K@GRAD": G(op.output("K")), "V@GRAD": G(op.output("V"))},
+                   {"KV@GRAD": G(op.input("KV")),
+                    "KRope@GRAD": G(op.input("KRope"))},
+                   dict(op.attrs))]
+
+
+def _latent_infer(op, block):
+    kv = block.var(op.input("KV")[0])
+    rope = block.var(op.input("KRope")[0])
+    if kv.shape is None or rope.shape is None:
+        return
+    heads, nope = int(op.attrs["num_heads"]), int(op.attrs["nope_dim"])
+    for slot, width in (("K", heads * (nope + rope.shape[-1])),
+                        ("V", kv.shape[-1] - heads * nope)):
+        for name in op.output(slot):
+            out = block.var(name)
+            out.shape = tuple(kv.shape[:-1]) + (width,)
+            out.dtype = out.dtype or kv.dtype
+
+
+@register_op("latent_kv_heads", infer_shape=_latent_infer,
+             grad=_latent_grad_maker)
+def latent_kv_heads(ctx):
+    """The keys and values a latent-attention (MLA) layer trains on, from
+    the up-projected latent ``KV`` [b, T, heads * (nope_dim + v)], each
+    head [k_nope | v], and the ONE rope key ``KRope`` [b, T, rope]
+    that every head shares: ``K`` [b, T, heads * (nope_dim + rope)], head i
+    [k_nope_i | k_rope], and ``V`` [b, T, heads * v]. Attrs ``num_heads``,
+    ``nope_dim``."""
+    kv, rope = data_of(ctx.input("KV")), data_of(ctx.input("KRope"))
+    heads, nope = _latent_dims(ctx, kv)
+    b, t, _ = kv.shape
+    kvh = kv.reshape(b, t, heads, -1)
+    shared = jnp.broadcast_to(rope.astype(kv.dtype)[:, :, None, :],
+                              (b, t, heads, rope.shape[-1]))
+    ctx.set_output("K", jnp.concatenate([kvh[..., :nope], shared], -1)
+                   .reshape(b, t, -1))
+    ctx.set_output("V", kvh[..., nope:].reshape(b, t, -1))
+
+
+@register_op("latent_kv_heads_grad")
+def latent_kv_heads_grad(ctx):
+    """The pieces go back where they came from; the shared rope key's
+    gradient is the sum over the heads, in float32."""
+    dk, dv = data_of(ctx.input("K@GRAD")), data_of(ctx.input("V@GRAD"))
+    heads = int(ctx.attr("num_heads"))
+    nope = int(ctx.attr("nope_dim"))
+    b, t, _ = dk.shape
+    dkh, dvh = dk.reshape(b, t, heads, -1), dv.reshape(b, t, heads, -1)
+    ctx.set_output("KV@GRAD", jnp.concatenate(
+        [dkh[..., :nope], dvh.astype(dk.dtype)], -1).reshape(b, t, -1))
+    ctx.set_output("KRope@GRAD", jnp.sum(
+        dkh[..., nope:].astype(jnp.float32), axis=2).astype(dk.dtype))
 
 
 def _scatter_rows(cache, slots, rows):

@@ -2,9 +2,14 @@
 holds.
 
 The router keeps its published width: logits ``x @ RouterW`` over all
-``num_experts`` in float32 at ``highest`` precision, softmax over all of
-them, the ``top_k`` largest, renormalised to sum 1 where
-``norm_topk_prob``. The expert weights are ``[held, ...]``: the experts
+``num_experts`` in float32 at ``highest`` precision, scored by
+``scoring_func`` — a softmax over all of them (the default) or a sigmoid of
+each — the ``top_k`` largest, renormalised to sum 1 where
+``norm_topk_prob``, times ``routed_scaling_factor``. With the input
+``SelectBias`` ([num_experts], float32) the top k are those of ``score +
+bias``: the bias selects and does not weigh (the weights are the scores'
+own). It is no gradient's business: ``expert_bias_update`` moves it by the
+step's loads. The expert weights are ``[held, ...]``: the experts
 ``expert_offset .. expert_offset + held - 1``. The op computes, for every
 token, the part of ``y = sum_k w_k WDown[e_k](silu(WGate[e_k] x) *
 WUp[e_k] x)`` that the held experts give; nothing stands in for the absent
@@ -35,7 +40,7 @@ outputs. ``ExpertLoad`` ([held] int32, rows per held expert) and the other
 integer outputs take no gradient. ``AuxLoss`` is the load-balancing term of
 the source's family, ``num_experts * sum_e f_e P_e`` over all router
 outputs (f_e the share of assignments, no gradient; P_e the mean
-probability).
+probability; under sigmoid scores the mean of ``s_e / sum(s)``).
 
 With the attr ``router_task_gradient`` off (default on: the layer's whole
 gradient) the weights of the top k are constants in the backward pass and
@@ -58,7 +63,7 @@ import jax
 import jax.numpy as jnp
 
 from ..core.amp import cast_compute
-from ..core.registry import OpSpec, register_op
+from ..core.registry import OpSpec, register_op, same_shape
 from ..obs.metrics import REGISTRY as _METRICS
 from .common import G, data_of
 from .pallas import kernel_span, use_pallas
@@ -86,11 +91,19 @@ def row_buffer(tokens, top_k, held, num_experts, factor):
 
 
 def _attrs(ctx):
+    scoring = ctx.attr("scoring_func", "softmax")
+    if scoring not in ("softmax", "sigmoid"):
+        raise ValueError(f"routed_experts: unknown scoring_func {scoring!r}")
     return dict(num_experts=int(ctx.attr("num_experts")),
                 top_k=int(ctx.attr("top_k")),
                 norm_topk_prob=bool(ctx.attr("norm_topk_prob", True)),
                 expert_offset=int(ctx.attr("expert_offset", 0)),
-                factor=float(ctx.attr("row_buffer_factor", 2.0)))
+                factor=float(ctx.attr("row_buffer_factor", 2.0)),
+                scoring=scoring,
+                scale=float(ctx.attr("routed_scaling_factor", 1.0)))
+
+
+SIGMOID_EPS = 1e-20     # in the renormalisation of sigmoid scores' top k
 
 
 def _count(ids, n):
@@ -100,21 +113,40 @@ def _count(ids, n):
                    axis=0, dtype=jnp.int32)
 
 
+def _chosen(top_i, num_experts):
+    """[n, k, num_experts] bool: slot k of token n chose expert e."""
+    return top_i[:, :, None] == jnp.arange(num_experts, dtype=top_i.dtype)
+
+
+def _scores_of(scores, top_i):
+    """``scores`` [n, e] at ``top_i`` [n, k], as a compare and a sum (a
+    gather takes its elements one after another on a TPU)."""
+    return jnp.sum(jnp.where(_chosen(top_i, scores.shape[-1]),
+                             scores[:, None, :], 0.0), axis=-1)
+
+
 def route(x, router_w, held, num_experts, top_k, norm_topk_prob,
-          expert_offset, factor):
-    """The routing of ``x`` [n, h] (float32): probabilities, the top k and
-    their weights, and the row buffer: for each row the assignment
-    (token * top_k + slot) it holds, or -1, and its weight; rows per held
-    expert; whether they passed the capacity. The buffer is sorted by
-    expert (a stable sort: tokens ascend inside a group) and each group
-    starts on a tile of ``TILE`` rows."""
+          expert_offset, factor, scoring="softmax", scale=1.0, bias=None):
+    """The routing of ``x`` [n, h] (float32): scores (``probs``: softmax
+    probabilities or sigmoids), the top k (of ``scores + bias`` where a
+    selection bias is given) and their weights, and the row buffer: for
+    each row the assignment (token * top_k + slot) it holds, or -1, and
+    its weight; rows per held expert; whether they passed the capacity.
+    The buffer is sorted by expert (a stable sort: tokens ascend inside a
+    group) and each group starts on a tile of ``TILE`` rows."""
     n = x.shape[0]
     logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_i = jax.lax.top_k(probs, top_k)
-    top_w = top_p / jnp.sum(top_p, -1, keepdims=True) if norm_topk_prob \
-        else top_p
+    probs = jax.nn.sigmoid(logits) if scoring == "sigmoid" \
+        else jax.nn.softmax(logits, axis=-1)
+    if bias is None:
+        top_p, top_i = jax.lax.top_k(probs, top_k)
+    else:
+        _, top_i = jax.lax.top_k(probs + bias.astype(jnp.float32), top_k)
+        top_p = _scores_of(probs, top_i)
+    top_w = top_p / _top_total(top_p, scoring) if norm_topk_prob else top_p
+    if scale != 1.0:
+        top_w = top_w * scale
     local = top_i.reshape(-1) - expert_offset
     key = jnp.where((local >= 0) & (local < held), local, held)
     load = _count(key, held)
@@ -134,6 +166,19 @@ def route(x, router_w, held, num_experts, top_k, norm_topk_prob,
     return dict(probs=probs, top_i=top_i.astype(jnp.int32), load=load,
                 assign=assign, weight=weight, layout=lay,
                 overflow=jnp.sum(load) > cap)
+
+
+def _top_total(top_p, scoring):
+    total = jnp.sum(top_p, -1, keepdims=True)
+    return total + SIGMOID_EPS if scoring == "sigmoid" else total
+
+
+def _balance(probs, scoring):
+    """P [n, e] of the balance term: the scores as a distribution over the
+    experts."""
+    if scoring == "sigmoid":
+        return probs / jnp.sum(probs, -1, keepdims=True)
+    return probs
 
 
 def layout(load, buffer):
@@ -266,7 +311,9 @@ def routed_experts(ctx):
     wg, wu, wd = (data_of(ctx.input(s)) for s in ("WGate", "WUp", "WDown"))
     a = _attrs(ctx)
     x = xv.reshape(-1, xv.shape[-1])
-    r = route(x, data_of(ctx.input("RouterW")), wg.shape[0], **a)
+    bias = data_of(ctx.input("SelectBias")) \
+        if ctx.has_input("SelectBias") else None
+    r = route(x, data_of(ctx.input("RouterW")), wg.shape[0], bias=bias, **a)
     xc, wg, wu, wd = cast_compute(x, wg, wu, wd)
     keep = (r["assign"] >= 0)[:, None]
     token = jnp.maximum(r["assign"], 0) // a["top_k"]
@@ -284,7 +331,8 @@ def routed_experts(ctx):
 
     counts = _count(r["top_i"], a["num_experts"]).astype(jnp.float32)
     aux = a["num_experts"] * jnp.sum(
-        counts / x.shape[0] * jnp.mean(r["probs"], axis=0))
+        counts / x.shape[0]
+        * jnp.mean(_balance(r["probs"], a["scoring"]), axis=0))
 
     ctx.set_output("Out", out.reshape(xv.shape).astype(xv.dtype))
     ctx.set_output("AuxLoss", aux.reshape(1))
@@ -301,8 +349,9 @@ def routed_experts(ctx):
 def routed_experts_grad(ctx):
     """By hand, from the forward's kept rows: the three products' input
     and weight gradients as grouped products over the same groups, then the
-    router's through the renormalised top k, the softmax and the balance
-    term."""
+    router's through the scaled, renormalised top k, the balance term and
+    the score function (softmax or sigmoid). The selection bias takes no
+    gradient: it moved the selection, which has none."""
     xv = data_of(ctx.input("X"))
     router_w = data_of(ctx.input("RouterW"))
     wg, wu, wd = (data_of(ctx.input(s)) for s in ("WGate", "WUp", "WDown"))
@@ -353,18 +402,31 @@ def routed_experts_grad(ctx):
             dweight, mode="drop", unique_indices=True).reshape(n, k)
     else:
         dtop_w = jnp.zeros((n, k), jnp.float32)
-    top_p = jnp.take_along_axis(probs, top_i, axis=-1)
+    sigmoid = a["scoring"] == "sigmoid"
+    if a["scale"] != 1.0:
+        dtop_w = dtop_w * a["scale"]
+    top_p = _scores_of(probs, top_i) if sigmoid \
+        else jnp.take_along_axis(probs, top_i, axis=-1)
     if a["norm_topk_prob"]:
-        total = jnp.sum(top_p, -1, keepdims=True)
+        total = _top_total(top_p, a["scoring"])
         dtop_p = (dtop_w - jnp.sum(dtop_w * top_p / total, -1,
                                    keepdims=True)) / total
     else:
         dtop_p = dtop_w
     counts = _count(top_i, n_exp).astype(jnp.float32)
-    chosen = top_i[:, :, None] == jnp.arange(n_exp, dtype=top_i.dtype)
-    dprobs = jnp.sum(jnp.where(chosen, dtop_p[:, :, None], 0.0), axis=1) \
-        + daux * n_exp * counts[None, :] / (n * n)
-    dlogits = probs * (dprobs - jnp.sum(dprobs * probs, -1, keepdims=True))
+    dprobs = jnp.sum(jnp.where(_chosen(top_i, n_exp), dtop_p[:, :, None],
+                               0.0), axis=1)
+    dbalance = daux * n_exp * counts[None, :] / (n * n)
+    if sigmoid:
+        # P = s / sum(s); then each score's own slope s (1 - s)
+        total = jnp.sum(probs, -1, keepdims=True)
+        dprobs = dprobs + (dbalance - jnp.sum(dbalance * probs / total, -1,
+                                              keepdims=True)) / total
+        dlogits = dprobs * probs * (1.0 - probs)
+    else:
+        dprobs = dprobs + dbalance
+        dlogits = probs * (dprobs - jnp.sum(dprobs * probs, -1,
+                                            keepdims=True))
     hi = jax.lax.Precision.HIGHEST
     x32, rw32 = x.astype(jnp.float32), router_w.astype(jnp.float32)
     dx = dx + jnp.dot(dlogits, rw32.T, precision=hi)
@@ -376,3 +438,19 @@ def routed_experts_grad(ctx):
     ctx.set_output("WGate@GRAD", d_wg.astype(wg.dtype))
     ctx.set_output("WUp@GRAD", d_wu.astype(wu.dtype))
     ctx.set_output("WDown@GRAD", d_wd.astype(wd.dtype))
+
+
+@register_op("expert_bias_update", infer_shape=same_shape("Bias", "BiasOut"))
+def expert_bias_update(ctx):
+    """The selection bias's own rule, no gradient step (the source family's
+    ``noaux_tc``): after a step's routing, ``bias_e += rate * sign(mean(c) -
+    c_e)`` with ``c_e`` the step's assignments to expert e over ALL router
+    outputs (``TopIdx`` of the layer's ``routed_experts``): an expert that
+    got more than its share is chosen less readily at the next step. It
+    writes ``BiasOut`` under ``Bias``'s own name, as batch-norm's moving
+    statistics are written."""
+    bias = data_of(ctx.input("Bias"))
+    counts = _count(data_of(ctx.input("TopIdx")),
+                    bias.shape[0]).astype(jnp.float32)
+    step = float(ctx.attr("rate")) * jnp.sign(jnp.mean(counts) - counts)
+    ctx.set_output("BiasOut", bias + step.astype(bias.dtype))

@@ -124,10 +124,12 @@ def _heads(x, kv_heads):
 
 
 def attention_jnp(q, k, v, num_heads, num_kv_heads, window):
-    """(out [b, T, heads*d], lse [b, heads, T]) — query blocks of
+    """(out [b, T, heads*dv], lse [b, heads, T]) — query blocks of
     ``TWIN_BLOCK`` rows, each against the static slice of keys its band
     reaches, softmax in float32, probabilities in the values' type for the
-    second product (as the kernel has them)."""
+    second product (as the kernel has them). The values' heads may be of
+    another size (``dv``) than the queries' and keys' (``d``, which scales
+    the scores)."""
     b, T, _ = q.shape
     g = num_heads // num_kv_heads
     d = q.shape[-1] // num_heads
@@ -156,8 +158,8 @@ def attention_jnp_bwd(q, k, v, out, lse, dout, num_heads, num_kv_heads,
     g = num_heads // num_kv_heads
     d = q.shape[-1] // num_heads
     scale = d ** -0.5
-    shape5 = (b, T, num_kv_heads, g, d)
-    qh, oh, doh = (x.reshape(shape5) for x in (q, out, dout))
+    qh, oh, doh = (x.reshape(b, T, num_kv_heads, g, -1)
+                   for x in (q, out, dout))
     kh, vh = _heads(k, num_kv_heads), _heads(v, num_kv_heads)
     delta = jnp.sum(oh.astype(jnp.float32) * doh.astype(jnp.float32), -1)
     lse = lse.reshape(b, num_kv_heads, g, T)
@@ -193,13 +195,15 @@ def kernel_block(T):
     return None
 
 
-def attention_supported(q, num_heads, num_kv_heads, window):
-    """The kernels take whole 128-lane heads, a length that 128 divides
-    and a band whose schedule fits scalar memory (the longest is
-    ``attention_dkv``'s, a group's heads through every pair); anything
-    else is the twin's."""
+def attention_supported(q, num_heads, num_kv_heads, window, v=None):
+    """The kernels take whole 128-lane heads (the values' ``v``, where
+    given, may be of another size than the queries' and keys'), a length
+    that 128 divides and a band whose schedule fits scalar memory (the
+    longest is ``attention_dkv``'s, a group's heads through every pair);
+    anything else is the twin's."""
     T, d, blk, group = _geometry(q, num_heads, num_kv_heads)
-    return (d % LANES == 0 and blk is not None
+    dv = d if v is None else v.shape[-1] // num_kv_heads
+    return (d % LANES == 0 and dv % LANES == 0 and blk is not None
             and q.dtype in (jnp.bfloat16, jnp.float32)
             and len(band_schedule(T, blk, window).q) * group <= MAX_ENTRIES)
 
@@ -337,77 +341,87 @@ def _geometry(q, num_heads, num_kv_heads):
             num_heads // num_kv_heads)
 
 
-def _query_order(T, blk, d, window, group):
+def _query_order(T, blk, widths, window, group):
     """What ``attention_fwd`` and ``attention_dq`` share: the schedule's
-    tables and the block specs of a query head's own blocks, its key/value
-    head's blocks and its rows' statistics, at grid step (batch, query
-    head, entry)."""
+    tables and, for each head size of ``widths``, the block specs of a
+    query head's own blocks and of its key/value head's blocks, then that
+    of its rows' statistics, at grid step (batch, query head, entry)."""
     sched = band_schedule(T, blk, window)
-    own = pl.BlockSpec((None, blk, d),
-                       lambda b, h, e, qt, kt, fl: (b, qt[e], h))
-    kv = pl.BlockSpec((None, blk, d),
-                      lambda b, h, e, qt, kt, fl: (b, kt[e], h // group))
+    own = [pl.BlockSpec((None, blk, d),
+                        lambda b, h, e, qt, kt, fl: (b, qt[e], h))
+           for d in widths]
+    kv = [pl.BlockSpec((None, blk, d),
+                       lambda b, h, e, qt, kt, fl: (b, kt[e], h // group))
+          for d in widths]
     rows = pl.BlockSpec((None, None, blk, LANES),
                         lambda b, h, e, qt, kt, fl: (b, h, qt[e], 0))
     return (sched.q, sched.k, sched.flags), own, kv, rows
 
 
-def attention_pallas(q, k, v, num_heads, num_kv_heads, window):
-    """(out [b, T, heads*d], lse [b, heads, T, 128]) by the forward
-    kernel."""
+def attention_pallas(q, k, v, num_heads, num_kv_heads, window, scale=None):
+    """(out [b, T, heads*dv], lse [b, heads, T, 128]) by the forward
+    kernel; ``scale`` defaults to the query heads' size ^-0.5 (a caller
+    that padded them gives the true one)."""
     T, d, blk, group = _geometry(q, num_heads, num_kv_heads)
-    tables, own, kv, rows = _query_order(T, blk, d, window, group)
+    dv = v.shape[-1] // num_kv_heads
+    tables, (own, own_v), (kv, kv_v), rows = _query_order(
+        T, blk, (d, dv), window, group)
+    b = q.shape[0]
     return _call(
         functools.partial(_fwd_kernel, blk=blk, window=window,
-                          scale=d ** -0.5),
+                          scale=scale or d ** -0.5),
         "attention_fwd", num_heads, tables, (q, k, v),
-        in_specs=[own, kv, kv], out_specs=[own, rows],
-        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
-                   jax.ShapeDtypeStruct((q.shape[0], num_heads, T, LANES),
+        in_specs=[own, kv, kv_v], out_specs=[own_v, rows],
+        out_shape=[jax.ShapeDtypeStruct((b, T, num_heads * dv), q.dtype),
+                   jax.ShapeDtypeStruct((b, num_heads, T, LANES),
                                         jnp.float32)],
         scratch_shapes=[pltpu.VMEM((blk, LANES), jnp.float32),
                         pltpu.VMEM((blk, LANES), jnp.float32),
-                        pltpu.VMEM((blk, d), jnp.float32)])
+                        pltpu.VMEM((blk, dv), jnp.float32)])
 
 
 def attention_pallas_bwd(q, k, v, out, lse, dout, num_heads, num_kv_heads,
-                         window):
+                         window, scale=None):
     """(dq, dk, dv): one kernel over query blocks for ``dq``, one over key
     blocks for ``dk``/``dv`` that sums a group's query heads in VMEM."""
     T, d, blk, group = _geometry(q, num_heads, num_kv_heads)
+    dv = v.shape[-1] // num_kv_heads
     b = q.shape[0]
-    scale = d ** -0.5
+    scale = scale or d ** -0.5
     delta = jnp.sum((out.astype(jnp.float32) * dout.astype(jnp.float32))
-                    .reshape(b, T, num_heads, d), axis=-1)
+                    .reshape(b, T, num_heads, dv), axis=-1)
     delta = jnp.broadcast_to(jnp.swapaxes(delta, 1, 2)[..., None],
                              (b, num_heads, T, LANES))
     operands = (q, k, v, dout, lse, delta)
-    tables, own, kv, rows = _query_order(T, blk, d, window, group)
+    tables, (own, own_v), (kv, kv_v), rows = _query_order(
+        T, blk, (d, dv), window, group)
     dq = _call(
         functools.partial(_dq_kernel, blk=blk, window=window, scale=scale),
         "attention_dq", num_heads, tables, operands,
-        in_specs=[own, kv, kv, own, rows, rows], out_specs=own,
+        in_specs=[own, kv, kv_v, own_v, rows, rows], out_specs=own,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((blk, d), jnp.float32)])
 
     # grid step (batch, key/value head, entry): the entry names the query
     # head of the group beside its blocks
     sched = band_schedule(T, blk, window, by="key", group=group)
-    own = pl.BlockSpec(
-        (None, blk, d),
+    own, own_v = (pl.BlockSpec(
+        (None, blk, w),
         lambda b, h, e, qt, kt, gt, fl: (b, qt[e], h * group + gt[e]))
-    kv = pl.BlockSpec((None, blk, d),
-                      lambda b, h, e, qt, kt, gt, fl: (b, kt[e], h))
+        for w in (d, dv))
+    kv, kv_v = (pl.BlockSpec((None, blk, w),
+                             lambda b, h, e, qt, kt, gt, fl: (b, kt[e], h))
+                for w in (d, dv))
     rows = pl.BlockSpec(
         (None, None, blk, LANES),
         lambda b, h, e, qt, kt, gt, fl: (b, h * group + gt[e], qt[e], 0))
-    dk, dv = _call(
+    dk, dv_out = _call(
         functools.partial(_dkv_kernel, blk=blk, window=window, scale=scale),
         "attention_dkv", num_kv_heads,
         (sched.q, sched.k, sched.head, sched.flags), operands,
-        in_specs=[own, kv, kv, own, rows, rows], out_specs=[kv, kv],
+        in_specs=[own, kv, kv_v, own_v, rows, rows], out_specs=[kv, kv_v],
         out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
         scratch_shapes=[pltpu.VMEM((blk, d), jnp.float32),
-                        pltpu.VMEM((blk, d), jnp.float32)])
-    return dq, dk, dv
+                        pltpu.VMEM((blk, dv), jnp.float32)])
+    return dq, dk, dv_out

@@ -263,3 +263,118 @@ def build_mellum2_lm(cfg, length, batch=1):
             loss = layers.elementwise_add(loss, layers.scale(
                 layers.mean(layers.sums(aux)), scale=coef / len(aux)))
     return main, startup, loss, logits, loads
+
+
+def build_kimi_linear_lm(cfg, length, batch=1):
+    """The Kimi-Linear block stack (``model_type`` ``kimi_linear``, its
+    published ``config.json`` keys) as a Fluid program, and the chip's share
+    of it: ``num_hidden_layers`` pre-norm blocks whose attention is, by
+    ``linear_attn_config`` (``kda_layers`` / ``full_attn_layers``, counted
+    from 1), Kimi Delta Attention (``layers.kda_attention``: the gated delta
+    rule in chunks, ``num_heads`` heads of ``head_dim``, convolutions of
+    ``short_conv_kernel_size`` taps) or latent attention without positions
+    (``layers.latent_attention``: ``q_lora_rank`` null, ``kv_lora_rank``,
+    heads of ``qk_nope_head_dim`` + ``qk_rope_head_dim`` for queries and
+    keys and ``v_head_dim`` for values, one shared key slice), and whose MLP
+    is a dense gated-SiLU one of ``intermediate_size`` in the first
+    ``first_k_dense_replace`` blocks and ``routed_experts`` after (scores by
+    ``moe_router_activation_func``, a selection bias with its own update,
+    top ``num_experts_per_token`` renormalised times
+    ``routed_scaling_factor``) holding ``num_experts`` of the router's
+    ``num_experts_routed`` from ``expert_offset``, beside
+    ``num_shared_experts`` shared experts as one gated MLP. Then the final
+    RMSNorm and an untied head over ``vocab_size`` rows. The loss is the
+    mean next-token cross-entropy plus ``balance_loss_coef`` times the
+    sparse layers' mean balance term. The plain reference is
+    ``testing/reference/kimi_linear.py``; parameters are created in the
+    order its ``unpack`` reads. Feeds ``tokens`` and ``labels`` [batch,
+    length, 1] int64. Returns (main, startup, loss, logits [batch, length,
+    vocab], [expert_load of each sparse layer])."""
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.fluid.initializer import Normal
+    from paddle_tpu.fluid.param_attr import ParamAttr
+    from paddle_tpu.testing.reference.kimi_linear import layer_kinds
+
+    layers = fluid.layers
+    hidden, eps = cfg["hidden_size"], cfg["rms_norm_eps"]
+    lin = cfg["linear_attn_config"]
+    std = cfg.get("init_std", 0.02)
+    if not cfg.get("mla_use_nope", True):
+        raise ValueError("build_kimi_linear_lm: latent attention with "
+                         "rotated positions is not built (mla_use_nope)")
+
+    def init(scale=std):
+        return ParamAttr(initializer=Normal(0.0, scale))
+
+    aux, loads = [], []
+
+    def block(x, attention, mlp):
+        a = layers.rms_norm(x, epsilon=eps)
+        if attention == "kda":
+            a = layers.kda_attention(
+                a, num_heads=lin["num_heads"], head_dim=lin["head_dim"],
+                conv_size=lin["short_conv_kernel_size"],
+                gate_rank=cfg.get("kda_gate_rank"),
+                chunk_size=cfg.get("kda_chunk_size", 64), epsilon=eps,
+                param_attr=init(cfg.get("kda_init_std", std)),
+                conv_attr=init(cfg.get("conv_init_std", std)))
+        else:
+            a = layers.latent_attention(
+                a, num_heads=cfg["num_attention_heads"],
+                q_lora_rank=cfg.get("q_lora_rank"),
+                kv_lora_rank=cfg["kv_lora_rank"],
+                qk_nope_head_dim=cfg["qk_nope_head_dim"],
+                qk_rope_head_dim=cfg["qk_rope_head_dim"],
+                v_head_dim=cfg["v_head_dim"], epsilon=eps, param_attr=init(),
+                down_attr=init(cfg.get("latent_down_init_std", std)),
+                up_attr=init(cfg.get("latent_up_init_std", std)))
+        x = layers.elementwise_add(x, a)
+        b = layers.rms_norm(x, epsilon=eps)
+        if mlp == "dense":
+            return layers.elementwise_add(
+                x, layers.gated_mlp(b, cfg["intermediate_size"], init()))
+        y, load, balance = layers.routed_experts(
+            b, num_experts=cfg["num_experts_routed"],
+            top_k=cfg["num_experts_per_token"],
+            expert_width=cfg["moe_intermediate_size"],
+            held_experts=cfg["num_experts"],
+            expert_offset=cfg.get("expert_offset", 0),
+            norm_topk_prob=cfg.get("moe_renormalize", True),
+            row_buffer_factor=cfg.get("row_buffer_factor", 2.0),
+            router_task_gradient=cfg.get("router_task_gradient", True),
+            scoring_func=cfg.get("moe_router_activation_func", "sigmoid"),
+            routed_scaling_factor=cfg.get("routed_scaling_factor", 1.0),
+            selection_bias=True,
+            bias_update_rate=cfg.get("bias_update_rate", 0.0),
+            bias_attr=ParamAttr(initializer=Normal(
+                cfg.get("selection_bias_init_mean", 0.0),
+                cfg.get("selection_bias_init_std", 0.0))),
+            param_attr=init(cfg.get("expert_init_std", std)))
+        aux.append(balance)
+        loads.append(load)
+        x = layers.elementwise_add(x, y)
+        return layers.elementwise_add(x, layers.gated_mlp(
+            b, cfg["num_shared_experts"] * cfg["moe_intermediate_size"],
+            init(cfg.get("expert_init_std", std))))
+
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        tokens = layers.data("tokens", shape=[batch, length, 1],
+                             dtype="int64", append_batch_size=False)
+        labels = layers.data("labels", shape=[batch, length, 1],
+                             dtype="int64", append_batch_size=False)
+        x = layers.embedding(
+            tokens, size=(cfg["vocab_size"], hidden),
+            param_attr=init(cfg.get("embedding_init_std", std)))
+        for attention, mlp in layer_kinds(cfg):
+            x = block(x, attention, mlp)
+        logits = layers.fc(
+            layers.rms_norm(x, epsilon=eps), cfg["vocab_size"],
+            num_flatten_dims=2, bias_attr=False,
+            param_attr=init(cfg.get("head_init_std", std)))
+        loss = layers.mean(layers.softmax_with_cross_entropy(logits, labels))
+        coef = cfg.get("balance_loss_coef", 0.0)
+        if coef and aux:
+            loss = layers.elementwise_add(loss, layers.scale(
+                layers.mean(layers.sums(aux)), scale=coef / len(aux)))
+    return main, startup, loss, logits, loads

@@ -160,6 +160,70 @@ def test_attention_schedule_fits_scalar_memory_at_the_longest_length(
     assert "attention_dq" in text and "attention_dkv" in text
 
 
+# ---- the Kimi-Linear cell: latent attention trains expanded, 32 query AND
+# 32 key/value heads, queries and keys of 192 (padded by the op to 256
+# lanes), values of 128, full causal attention over 4096 tokens
+MLA_HEADS, MLA_QK, MLA_V, MLA_T = 32, 256, 128, 4096
+
+
+def _kernel_vmem(text):
+    """{kernel name: bytes of VMEM (memory space 1) Mosaic's allocation
+    asks for it}, read off a compiled module's text."""
+    import re
+    out = {}
+    for line in text.splitlines():
+        if "tpu_custom_call" not in line:
+            continue
+        (name,) = set(re.findall(r"attention_(?:fwd|dq|dkv)", line))
+        out[name] = sum(int(n) for n in re.findall(
+            r'"memory_space":"1","offset":"0","size":"(\d+)"', line))
+    return out
+
+
+def test_attention_kernels_compile_for_v5e_at_heads_of_192_and_128(
+        one_chip, native_lowering):
+    """The op pads 192 to 256 lanes and asks the kernels about that: two
+    head sizes in one call, the block from the length as before (512), a
+    head's blocks, scratch and scores under Mosaic's default scoped limit
+    (16 MiB on a v5e) by the compile's own count, and with a group of 1
+    ``attention_dkv``'s schedule is a head's own."""
+    from paddle_tpu.ops import attention_ops
+    from paddle_tpu.ops.pallas import attention as att
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    narrow = s((1, MLA_T, MLA_HEADS * 192))
+    v = s((1, MLA_T, MLA_HEADS * MLA_V))
+    assert not att.attention_supported(narrow, MLA_HEADS, MLA_HEADS, 0, v)
+    assert jax.eval_shape(
+        lambda x: attention_ops._lane_padded(x, MLA_HEADS),
+        narrow).shape == (1, MLA_T, MLA_HEADS * MLA_QK)
+    q = s((1, MLA_T, MLA_HEADS * MLA_QK))
+    lse = s((1, MLA_HEADS, MLA_T, 128), jnp.float32)
+    assert att.attention_supported(q, MLA_HEADS, MLA_HEADS, 0, v)
+    assert att.kernel_block(MLA_T) == 512
+    entries = (MLA_T // 512) * (MLA_T // 512 + 1) // 2
+    fwd = lambda q, k, v: att.attention_pallas(         # noqa: E731
+        q, k, v, MLA_HEADS, MLA_HEADS, 0, scale=192 ** -0.5)
+    bwd = lambda q, k, v, o, l, d: att.attention_pallas_bwd(  # noqa: E731
+        q, k, v, o, l, d, MLA_HEADS, MLA_HEADS, 0, scale=192 ** -0.5)
+    assert _pallas_grids(fwd, q, q, v) == {
+        "attention_fwd": ((1, MLA_HEADS, entries), 3)}
+    assert _pallas_grids(bwd, q, q, v, v, lse, v) == {
+        "attention_dq": ((1, MLA_HEADS, entries), 3),
+        "attention_dkv": ((1, MLA_HEADS, entries), 4)}
+    assert jax.eval_shape(fwd, q, q, v)[0].shape == v.shape
+    assert [x.shape for x in jax.eval_shape(bwd, q, q, v, v, lse, v)] == [
+        q.shape, q.shape, v.shape]
+    vmem = _kernel_vmem(jax.jit(fwd).lower(q, q, v).compile().as_text())
+    vmem.update(_kernel_vmem(
+        jax.jit(bwd).lower(q, q, v, v, lse, v).compile().as_text()))
+    assert sorted(vmem) == ["attention_dkv", "attention_dq", "attention_fwd"]
+    # the blocks alone (double-buffered bfloat16 q, k of 512 x 256 and v,
+    # out or d out of 512 x 128) are 1.5 MiB
+    assert all(3 << 19 < n < 16 << 20 for n in vmem.values()), vmem
+
+
 def _expert_shapes(sharding):
     from paddle_tpu.ops import moe_ops
 
@@ -237,6 +301,9 @@ def test_routed_experts_on_the_kernel_route_has_no_scatter_add(tier,
 
         def input(self, slot):
             return self.env[slot]
+
+        def has_input(self, slot):
+            return slot in self.env
 
         def attr(self, name, default=None):
             return self.attrs.get(name, default)

@@ -207,43 +207,50 @@ def test_a_rigged_router_drops_no_row_and_an_overflow_is_loud():
     assert not np.asarray(parts[1][0]).any()
 
 
-# (T, window): the block follows from T (256, 256; then 256 under a window
-# no multiple of it and one equal to it, 128 under a window wider than T,
-# one block of 128, 512 with whole blocks under the diagonal)
-@pytest.mark.parametrize("length,window", [
-    (256, 0), (256, 100), (768, 300), (768, 256), (384, 4096), (128, 0),
-    (1536, 0)])
-def test_attention_kernels_match_the_twin_through_the_op(length, window):
+# (T, window, query heads, key/value heads, query/key head size, value head
+# size): the block follows from T (256, 256; then 256 under a window no
+# multiple of it and one equal to it, 128 under a window wider than T, one
+# block of 128, 512 with whole blocks under the diagonal); the last is latent
+# attention's expanded form, as many key/value heads as query heads, queries
+# and keys of 192 (the op pads them to 256 lanes) and values of 128
+@pytest.mark.parametrize("length,window,heads,kv_heads,d,dv", [
+    (256, 0, 4, 2, 128, 128), (256, 100, 4, 2, 128, 128),
+    (768, 300, 4, 2, 128, 128), (768, 256, 4, 2, 128, 128),
+    (384, 4096, 4, 2, 128, 128), (128, 0, 4, 2, 128, 128),
+    (1536, 0, 4, 2, 128, 128), (512, 0, 2, 2, 192, 128)])
+def test_attention_kernels_match_the_twin_through_the_op(length, window,
+                                                         heads, kv_heads, d,
+                                                         dv):
     """kernel_tier=pallas runs the attention family's three kernels in the
     interpreter; outputs, the log-sum-exp and all three gradients match
     the blocked twin."""
     from paddle_tpu.ops.pallas import dispatch_counts
 
     batch = 2 if length <= 256 else 1
+    shapes = {"q": (batch, length, heads * d),
+              "k": (batch, length, kv_heads * d),
+              "v": (batch, length, kv_heads * dv)}
 
     def run(tier):
         fluid.set_flags({"kernel_tier": tier})
         try:
             main, startup = fluid.Program(), fluid.Program()
             with fluid.program_guard(main, startup):
-                q = fluid.layers.data("q", shape=[batch, length, 512],
-                                      append_batch_size=False)
-                k = fluid.layers.data("k", shape=[batch, length, 256],
-                                      append_batch_size=False)
-                v = fluid.layers.data("v", shape=[batch, length, 256],
-                                      append_batch_size=False)
+                q, k, v = (fluid.layers.data(n, shape=list(shapes[n]),
+                                             append_batch_size=False)
+                           for n in "qkv")
                 for var in (q, k, v):
                     var.stop_gradient = False
                 out = fluid.layers.causal_self_attention(
-                    q, k, v, num_heads=4, num_kv_heads=2, window=window)
+                    q, k, v, num_heads=heads, num_kv_heads=kv_heads,
+                    window=window)
                 loss = fluid.layers.mean(fluid.layers.elementwise_mul(
                     out, out))
                 fluid.backward.append_backward(loss)
             lse, = main.global_block().ops[0].output("LogSumExp")
             rng = np.random.RandomState(1)
-            feed = {n: rng.randn(*s).astype(np.float32) for n, s in (
-                ("q", (batch, length, 512)), ("k", (batch, length, 256)),
-                ("v", (batch, length, 256)))}
+            feed = {n: rng.randn(*shapes[n]).astype(np.float32)
+                    for n in "qkv"}
             return fluid.Executor(mode="jit").run(
                 main, feed=feed, scope=fluid.Scope(),
                 fetch_list=[out.name, lse, "q@GRAD", "k@GRAD", "v@GRAD"])
