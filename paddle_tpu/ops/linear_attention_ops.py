@@ -26,22 +26,40 @@ W_k]`` one unit-lower-triangular solve a chunk, everything that does not
 read ``S`` computed for all chunks at once as batched products, and a scan
 over the chunks that carries ``S`` alone (two products a step). The pairwise
 decay ``e^(G_i - G_j)`` is never formed as ``e^(G_i) * e^(-G_j)``, which
-overflows float32 at a decay of 1.6 nats a token over 64 tokens: pairs
-inside a sub-block of ``SUB`` tokens take the difference first, channel by
-channel; pairs across sub-blocks factor through the later sub-block's first
-row ``r``, where both ``G_i - r`` and ``r - G_j`` are <= 0. No exponent is
-ever positive, so the op is exact at any decay (a channel wiped at every
-token included).
+overflows float32 at a decay of 1.6 nats a token over 64 tokens: a pair
+either takes the difference first, channel by channel, or factors through a
+row ``r`` between them, where both ``G_i - r`` and ``r - G_j`` are <= 0
+(the jnp twin: differences inside a sub-block of ``SUB`` tokens, the later
+sub-block's first row across them; the kernels: a row between the pair at
+every scale, by halves). No exponent is ever positive, so the op is exact
+at any decay (a channel wiped at every token included).
 
 Products take the operands' compute type (bfloat16 under AMP) and
 accumulate in float32, but the two pairwise-decay products, which are
 float32 at every pass; the cumulative sums, the exponentials, the
 triangular solve and the state are float32. The forward keeps the chunks'
 starting states (``States`` [b, chunks, heads, key, value] float32: 134 MB
-a layer at 4096 tokens of 32 heads of 128); the grad op rebuilds what does
-not read the state, walks the chunks backwards with ``jax.vjp`` of one
-chunk's step at its kept state, and differentiates the rest with
-``jax.vjp`` of the same chunked forward: no hand-derived formula.
+a layer at 4096 tokens of 32 heads of 128) and nothing else.
+
+Two paths compute this, chosen at ONE site (``_route``) by the kernel
+tier's rule, ``use_pallas("delta_rule", supported(shapes))``, the same
+answer for the op and its grad op:
+
+* the Pallas family ``delta_rule`` (ops/pallas/delta_rule.py; heads of
+  whole 128-lane widths, float32 ``G``): a forward and a backward kernel
+  that keep a chunk's terms in VMEM and carry the state (or its gradient)
+  in scratch from chunk to chunk. The backward rebuilds a chunk's terms
+  once from the inputs and the kept state and applies HAND-DERIVED
+  gradients of the read, the state update, the solve (``dR = T^-T dW``,
+  ``dT = -dR W^T`` on the strict triangle), the pairwise decays and the L2
+  norms; ``jax.vjp`` of the twin is its test oracle.
+* the jnp twin below (the CPU, ``kernel_tier=jnp``, any other shape, which
+  under a Pallas tier bumps ``paddle_tpu_pallas_fallbacks{kernel=
+  delta_rule}``): the chunk terms of all chunks as batched products and a
+  scan that carries the state; its grad rebuilds what does not read the
+  state, walks the chunks backwards with ``jax.vjp`` of one chunk's step at
+  its kept state, and differentiates the rest with ``jax.vjp`` of the same
+  chunked forward: no hand-derived formula there.
 
 Beside it: ``causal_conv1d`` (a causal depthwise convolution over the
 current and the earlier tokens, one filter a channel, then SiLU),
@@ -57,6 +75,7 @@ import jax.numpy as jnp
 
 from ..core.registry import OpSpec, register_op, same_shape
 from .common import G, data_of
+from .pallas import kernel_span, use_pallas
 
 L2_EPS = 1e-6           # in the L2 norm of queries and keys
 SUB = 16                # rows of a sub-block: pairs inside one take
@@ -207,9 +226,47 @@ def _terms_of(q, k, v, g, beta, heads, chunk, scale, ct):
     return {name: join(x) for name, x in terms.items()}
 
 
+def _route(q, v, g, heads, chunk):
+    """(the kernel module, "pallas" | "jnp"): ONE question for the forward
+    and the backward, so they never disagree. The module is imported here,
+    at the first dispatch, and not with the ops package."""
+    from .pallas import delta_rule as dr
+    return dr, "pallas" if use_pallas(
+        "delta_rule", dr.supported(q, v, g, heads, chunk)) else "jnp"
+
+
 def chunked_delta_rule(q, k, v, g, beta, heads, chunk, scale):
     """(out [b, T, heads * dv] in v's type, states [b, N, heads, dk, dv]
-    float32: each chunk's starting state)."""
+    float32: each chunk's starting state): the ``delta_rule`` Pallas
+    family (ops/pallas/delta_rule.py) where the tier and the shapes allow
+    it, the jnp twin below anywhere else."""
+    dr, route = _route(q, v, g, heads, chunk)
+    with kernel_span(route, "delta_rule"):
+        if route == "jnp":
+            return chunked_delta_rule_jnp(q, k, v, g, beta, heads, chunk,
+                                          scale)
+        out, states = dr.delta_rule_fwd(*_padded(chunk, q, k, v, g, beta),
+                                        heads, chunk, scale)
+        return out[:, :q.shape[1]], states
+
+
+def chunked_delta_rule_bwd(q, k, v, g, beta, states, dout, heads, chunk,
+                           scale):
+    """Gradients of ``chunked_delta_rule``'s ``out`` to (q, k, v, g, beta)
+    from the kept ``states``, by the route the forward took."""
+    dr, route = _route(q, v, g, heads, chunk)
+    with kernel_span(route, "delta_rule"):
+        if route == "jnp":
+            return chunked_delta_rule_bwd_jnp(q, k, v, g, beta, states, dout,
+                                              heads, chunk, scale)
+        grads = dr.delta_rule_bwd(*_padded(chunk, q, k, v, g, beta), states,
+                                  *_padded(chunk, dout), heads, chunk, scale)
+        return tuple(dx[:, :q.shape[1]] for dx in grads)
+
+
+def chunked_delta_rule_jnp(q, k, v, g, beta, heads, chunk, scale):
+    """The twin of ``delta_rule_fwd``: the chunk terms of all chunks as
+    batched products, then a scan that carries the state alone."""
     ct = v.dtype
     t = q.shape[1]
     terms = _terms_of(q, k, v, g, beta, heads, chunk, scale, ct)
@@ -224,10 +281,11 @@ def chunked_delta_rule(q, k, v, g, beta, heads, chunk, scale):
     return out.astype(v.dtype), jnp.swapaxes(states, 0, 1)
 
 
-def chunked_delta_rule_bwd(q, k, v, g, beta, states, dout, heads, chunk,
-                           scale):
-    """Gradients of ``chunked_delta_rule``'s ``out`` to (q, k, v, g, beta)
-    from the kept ``states``."""
+def chunked_delta_rule_bwd_jnp(q, k, v, g, beta, states, dout, heads, chunk,
+                               scale):
+    """The twin of ``delta_rule_bwd``: the terms rebuilt, the chunks walked
+    backwards with ``jax.vjp`` of one chunk's step at its kept state, the
+    rest by ``jax.vjp`` of the same chunked forward."""
     ct = v.dtype
     # the terms are rebuilt HERE: without the barrier the compiler finds the
     # forward op's own and keeps them alive from there to here instead

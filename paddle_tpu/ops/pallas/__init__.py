@@ -78,6 +78,17 @@ from ...obs.metrics import REGISTRY as _METRICS
 #                      scatter-add with its mask and weights (0.51 / 2.84 by
 #                      the probe's host clock), equal to the bit; at the
 #                      step 142.7 ms against 164.6, six of six pairs
+#   delta_rule    IN   (PR 35) gated_delta_rule's chunked core, forward
+#                      and backward: a chunk's terms live in VMEM, the
+#                      [128, 128] state (or its gradient) is carried in
+#                      scratch, the gradients are hand-derived. At 4096
+#                      tokens x 32 heads of 128, chunks of 64, bfloat16
+#                      q / k / v, the Kimi-Linear configuration's decays:
+#                      forward 3.82 ms against the chunked jnp scan's 9.00
+#                      (2.36x), backward 7.01 against 40.70 (5.80x), apart
+#                      by one bfloat16 rounding of the outputs (4.5e-3 /
+#                      7.8e-3 of the largest); at the step (four layers)
+#                      172.3 ms against 336.8, six of six pairs
 #   conv_bn       out  lowers, but 0.2-0.65x of XLA's conv+BN fusions at
 #                      6 of 7 ResNet-50 shapes; fused flagship step 318.6
 #                      vs 102.5 ms unfused
@@ -89,7 +100,7 @@ from ...obs.metrics import REGISTRY as _METRICS
 #   gru           out  recurrence 1.61x its scan, but no step measured: no
 #                      cell runs a GRU
 AUTO_PALLAS = frozenset({"lstm", "attention", "grouped_matmul",
-                         "moe_combine"})
+                         "moe_combine", "delta_rule"})
 
 # pallas->jnp silent-fallback counter, in the obs.metrics registry
 # (fallback_counts() derives its historical dict from this family)
@@ -145,7 +156,8 @@ def use_pallas(kernel, supported=True):
 
     ``kernel`` names the kernel family ("lstm", "gru", "ctc", "conv_bn",
     "optimizer", "embedding_sgd", "paged_attention", "attention",
-    "grouped_matmul", "moe_combine"); ``supported`` is the call site's
+    "grouped_matmul", "moe_combine", "delta_rule"); ``supported`` is the
+    call site's
     shape/config predicate. Unsupported shapes under a Pallas tier fall
     back to the jnp twin with a counter bump (never an error).
     """

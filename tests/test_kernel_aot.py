@@ -37,10 +37,10 @@ def native_lowering(monkeypatch):
     the CPU, so steer that one predicate to compile them natively. The
     persistent cache cannot read such an executable back: keep it off."""
     from jax.experimental.compilation_cache import compilation_cache
-    from paddle_tpu.ops.pallas import (attention, grouped_matmul,
-                                       moe_combine, rnn)
+    from paddle_tpu.ops.pallas import (attention, delta_rule,
+                                       grouped_matmul, moe_combine, rnn)
     monkeypatch.setattr(rnn, "_on_cpu", lambda: False)
-    for module in attention, grouped_matmul, moe_combine:
+    for module in attention, delta_rule, grouped_matmul, moe_combine:
         monkeypatch.setattr(module, "on_cpu", lambda: False)
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
@@ -271,6 +271,31 @@ def test_moe_combine_kernel_compiles_for_v5e(one_chip, native_lowering):
     compiled = mc.combine.lower(rows, s(jnp.float32), s(jnp.int32), held,
                                 tile_expert, n=8192).compile()
     assert "moe_combine" in compiled.as_text()
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_delta_rule_kernels_compile_for_v5e(one_chip, native_lowering,
+                                            direction):
+    """``delta_rule_fwd`` / ``delta_rule_bwd`` at the Kimi-Linear cell's
+    shape: one stream of 4096 tokens, 32 heads of 128, chunks of 64,
+    bfloat16 q / k / v / beta, float32 log-decays and kept states."""
+    from paddle_tpu.ops.pallas import delta_rule as dr
+    T, heads, d, chunk = 4096, 32, 128, 64
+
+    def s(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    x = s(jnp.bfloat16, 1, T, heads * d)
+    args = [x, x, x, s(jnp.float32, 1, T, heads * d),
+            s(jnp.bfloat16, 1, T, heads)]
+    assert dr.supported(x, x, args[3], heads, chunk)
+    if direction == "fwd":
+        lowered = dr.delta_rule_fwd.lower(*args, heads=heads, chunk=chunk,
+                                          scale=d ** -0.5)
+    else:
+        lowered = dr.delta_rule_bwd.lower(
+            *args, s(jnp.float32, 1, T // chunk, heads, d, d), x,
+            heads=heads, chunk=chunk, scale=d ** -0.5)
+    assert f"delta_rule_{direction}" in lowered.compile().as_text()
 
 
 def _primitives(jaxpr):

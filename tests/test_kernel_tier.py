@@ -97,6 +97,12 @@ with fluid.program_guard(main, startup):
     prob = fluid.layers.fc(bn, size=10, act="softmax")
     loss = fluid.layers.mean(fluid.layers.cross_entropy(prob, label))
     fluid.optimizer.Momentum(0.01, 0.9).minimize(loss, startup)
+    # a linear-attention layer too: its delta_rule kernels load at the
+    # first dispatch, not here
+    tokens = fluid.layers.data("tokens", shape=[1, 64, 32],
+                               append_batch_size=False)
+    kda = fluid.layers.kda_attention(tokens, 2, 16, conv_size=4,
+                                     chunk_size=16)
 print(sorted(m for m in sys.modules
              if m.startswith(("jax.experimental.pallas",
                               "paddle_tpu.ops.pallas."))))
@@ -351,6 +357,20 @@ def _moe_combine_site(supported):
     return _routed_experts_site(256 if supported else 252, 128)
 
 
+def _delta_rule_site(supported):
+    # 64 tokens, 128 lanes: one head of 128 is the kernels' shape, two heads
+    # of 64 are the twin's
+    rng = np.random.RandomState(3)
+    q, k, v = (rng.randn(1, 64, 128).astype(np.float32) for _ in range(3))
+    g = -0.3 * rng.rand(1, 64, 128).astype(np.float32)
+    heads = 1 if supported else 2
+    beta = rng.rand(1, 64, heads).astype(np.float32)
+    return _run_op("gated_delta_rule",
+                   {"Q": q, "K": k, "V": v, "G": g, "Beta": beta},
+                   {"Out": 0, "States": 0},
+                   {"num_heads": heads, "chunk_size": 32})
+
+
 # family -> (the op at a tiny shape, run(supported) -> outputs; Pallas
 # dispatches one supported run traces)
 SITES = {
@@ -364,6 +384,7 @@ SITES = {
     "attention": (_attention_site, 1),
     "grouped_matmul": (_grouped_matmul_site, 3),    # gate, up, down
     "moe_combine": (_moe_combine_site, 1),
+    "delta_rule": (_delta_rule_site, 1),
 }
 # the other family's dispatches in each run of a site whose op holds two
 # (routed_experts: its products and its combine), whatever the site's own
@@ -511,6 +532,7 @@ AOT_ENTRY_POINTS = {
     "attention": ("attention", ("attention_pallas", "attention_pallas_bwd")),
     "grouped_matmul": ("grouped_matmul", ("gmm", "gmm_t", "tgmm")),
     "moe_combine": ("moe_combine", ("combine",)),
+    "delta_rule": ("delta_rule", ("delta_rule_fwd", "delta_rule_bwd")),
 }
 
 

@@ -323,6 +323,155 @@ def test_the_strongest_assumed_decay_overflows_the_naive_factoring():
     assert float(jnp.min(g)) < -1.5
 
 
+@pytest.fixture
+def kernel_tier():
+    """``kernel_tier=pallas`` for one test (the kernels then run interpreted
+    on the CPU), the fallback counters zeroed before and after."""
+    from paddle_tpu.ops import pallas as tier
+    tier.reset_fallback_counts()
+    fluid.set_flags({"kernel_tier": "pallas"})
+    yield tier
+    fluid.set_flags({"kernel_tier": "auto"})
+    tier.reset_fallback_counts()
+
+
+def _interpreted(tier):
+    return tier.dispatch_counts().get("delta_rule", {}).get("interpret", 0)
+
+
+def _kernel_inputs(t, rate, beta=(0.0, 1.0), dtype=jnp.float32, seed=0,
+                   heads=2):
+    """The core's inputs at the kernels' shape: heads of 128 (two are one
+    group of the kernels, whose systems are inverted as one), step sizes
+    uniform in ``beta``, the largest decay ``rate`` nats a token."""
+    rng = np.random.RandomState(seed)
+    (q, k, v, g, _), heads = _core_inputs(t, rate, seed, b=1, heads=heads,
+                                          d=128, dv=128)
+    lo, hi = beta
+    step = jnp.asarray(lo + (hi - lo) * rng.rand(1, t, heads), jnp.float32)
+    return tuple(x.astype(dtype) for x in (q, k, v)) + (g, step.astype(dtype))
+
+
+# (tokens, the largest decay in nats a token, the step sizes' range, heads):
+# whole chunks of 64; a length that is no multiple of 64 (padded around the
+# kernels) at three heads (no pair: each head a group of its own); the
+# strongest assumed decay and thirty times it (no exponent of
+# the kernels is ever positive); steps near 0 (nothing is written) and near
+# 1 (every key's old value is replaced: the triangular system at its
+# strongest)
+@pytest.mark.parametrize("t,rate,beta,heads", [
+    (192, 0.3, (0.0, 1.0), 2), (100, 0.3, (0.0, 1.0), 3),
+    (128, 1.6, (0.0, 1.0), 2), (128, 50.0, (0.0, 1.0), 2),
+    (128, 0.3, (0.0, 0.02), 2), (128, 0.3, (0.98, 1.0), 2)])
+def test_the_kernels_are_the_recurrence_and_the_twin(kernel_tier, t, rate,
+                                                     beta, heads):
+    """``delta_rule_fwd`` / ``delta_rule_bwd`` interpreted on the CPU, through
+    the op's own dispatch: ``Out``, the kept states and all five gradients
+    against the token-by-token recurrence AND against the jnp twin."""
+    args, scale = _kernel_inputs(t, rate, beta, heads=heads), 128 ** -0.5
+    dout = jnp.asarray(np.random.RandomState(1).randn(1, t, heads * 128),
+                       jnp.float32)
+    before = _interpreted(kernel_tier)
+    out, states = la.chunked_delta_rule(*args, heads, 64, scale)
+    grads = la.chunked_delta_rule_bwd(*args, states, dout, heads, 64, scale)
+    assert _interpreted(kernel_tier) == before + 2
+    assert kernel_tier.fallback_counts() == {}
+
+    loose = rate > 10
+    want = _recurrence(*args, heads)
+    assert out.shape == want.shape and states.shape == (
+        1, -(-t // 64), heads, 128, 128)
+    assert _err(out, want) < (1e-4 if loose else 1e-5)
+    wants = jax.grad(lambda *a: jnp.sum(_recurrence(*a, heads) * dout),
+                     argnums=(0, 1, 2, 3, 4))(*args)
+    for a, b in zip(grads, wants):
+        assert a.shape == b.shape and bool(jnp.isfinite(a).all())
+        assert _err(a, b) < (1e-3 if loose else 1e-4)
+
+    twin_out, twin_states = la.chunked_delta_rule_jnp(*args, heads, 64, scale)
+    twin_grads = la.chunked_delta_rule_bwd_jnp(*args, twin_states, dout,
+                                               heads, 64, scale)
+    assert _err(out, twin_out) < (1e-4 if loose else 1e-5)
+    assert _err(states, twin_states) < (1e-3 if loose else 1e-5)
+    for a, b in zip(grads, twin_grads):
+        assert _err(a, b) < (1e-3 if loose else 1e-4)
+
+
+def test_the_kernels_take_bfloat16_as_the_twin_does(kernel_tier):
+    """bfloat16 q, k, v and steps (the AMP types), float32 log-decays: the
+    kernels round where the twin rounds (the four products with the state
+    and with U), so the two agree far inside bfloat16's own error against
+    the float32 core, and the gradients leave in their inputs' types."""
+    t, heads, scale = 128, 2, 128 ** -0.5
+    args = _kernel_inputs(t, 0.3, dtype=jnp.bfloat16)
+    dout = jnp.asarray(np.random.RandomState(1).randn(1, t, heads * 128),
+                       jnp.bfloat16)
+    out, states = la.chunked_delta_rule(*args, heads, 64, scale)
+    grads = la.chunked_delta_rule_bwd(*args, states, dout, heads, 64, scale)
+    twin_out, twin_states = la.chunked_delta_rule_jnp(*args, heads, 64, scale)
+    twin_grads = la.chunked_delta_rule_bwd_jnp(*args, twin_states, dout,
+                                               heads, 64, scale)
+    exact = [x.astype(jnp.float32) for x in args]
+    exact_out, exact_states = la.chunked_delta_rule_jnp(*exact, heads, 64,
+                                                        scale)
+    exact_grads = la.chunked_delta_rule_bwd_jnp(
+        *exact, exact_states, dout.astype(jnp.float32), heads, 64, scale)
+    assert out.dtype == jnp.bfloat16 and states.dtype == jnp.float32
+    assert _err(out, twin_out) < 4e-3 and _err(states, twin_states) < 1e-3
+    for x, a, b, c in zip(args, grads, twin_grads, exact_grads):
+        assert a.dtype == (jnp.float32 if x is args[3] else jnp.bfloat16)
+        # no further from the float32 core than the twin is, and near it
+        assert _err(a, c) < max(1.5 * _err(b, c), 8e-3)
+        assert _err(a, b) < 1.2e-2
+
+
+def test_the_op_in_a_program_runs_the_kernels_and_fills_every_grad_slot(
+        kernel_tier):
+    """``gated_delta_rule`` and its grad op through the executor under
+    ``kernel_tier=pallas``: one dispatch a direction, every ``@GRAD`` slot
+    equal to the same program on the twin."""
+    t, heads = 100, 2
+    names = ("q", "k", "v", "g", "beta")
+    feed = {n: np.asarray(x)
+            for n, x in zip(names, _kernel_inputs(t, 0.5, seed=5))}
+
+    def run():
+        return _run_ops(lambda v: fluid.layers.gated_delta_rule(
+            *(v[n] for n in names), heads, chunk_size=64), feed, names)
+    before = _interpreted(kernel_tier)
+    out, grads, _, _ = run()
+    assert _interpreted(kernel_tier) == before + 2
+    assert kernel_tier.fallback_counts() == {}
+    fluid.set_flags({"kernel_tier": "jnp"})
+    twin_out, twin_grads, _, _ = run()
+    assert _interpreted(kernel_tier) == before + 2
+    assert out.shape == (1, t, heads * 128) and _err(out, twin_out) < 1e-5
+    for n, a, b in zip(names, grads, twin_grads):
+        assert a.shape == feed[n].shape and _err(a, b) < 1e-4
+
+
+def test_heads_the_kernels_do_not_take_run_the_twin_and_are_counted(
+        kernel_tier):
+    """Heads of 16 x 8 under ``kernel_tier=pallas``: the predicate reads the
+    shapes, the twin runs to the bit, and the ``delta_rule`` fallback counter
+    moves once a direction; no kernel is dispatched."""
+    args, heads = _core_inputs(70, 0.5)
+    scale = 16 ** -0.5
+    dout = jnp.ones((2, 70, heads * 8), jnp.float32)
+    before = _interpreted(kernel_tier)
+    out, states = la.chunked_delta_rule(*args, heads, 32, scale)
+    assert kernel_tier.fallback_counts() == {"delta_rule": 1}
+    grads = la.chunked_delta_rule_bwd(*args, states, dout, heads, 32, scale)
+    assert kernel_tier.fallback_counts() == {"delta_rule": 2}
+    assert _interpreted(kernel_tier) == before
+    twin_out, twin_states = la.chunked_delta_rule_jnp(*args, heads, 32, scale)
+    assert np.array_equal(out, twin_out)
+    assert np.array_equal(states, twin_states)
+    for a, b in zip(grads, la.chunked_delta_rule_bwd_jnp(
+            *args, twin_states, dout, heads, 32, scale)):
+        assert np.array_equal(a, b)
+
+
 def _run_ops(build, feed, wanted):
     """Build a small program around ``build(vars) -> out``, take the loss
     ``sum(out * out)``, and fetch ``wanted`` (names)."""

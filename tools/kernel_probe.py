@@ -19,7 +19,9 @@ size; paged_attention at the generation lane's decode shape; banded
 attention at the Mellum2 cell's shape (8192 tokens, 32 / 4 heads of 128,
 bfloat16), forward and backward, a window layer and the full one; the
 grouped product at that cell's expert shape (8 experts of 2304 x 896) and
-the experts' combine at its buffer (18432 rows of 2304 to 8192 tokens).
+the experts' combine at its buffer (18432 rows of 2304 to 8192 tokens);
+the gated delta rule at the Kimi-Linear cell's shape (4096 tokens, 32 heads
+of 128, chunks of 64), forward and backward.
 Every family with a dispatch site in ``paddle_tpu/ops/`` has a case.
 
 A kernel that fails to lower is a RESULT here (``lowered: false`` with the
@@ -307,6 +309,46 @@ def moe_combine_case(n, top_k, held, num_experts, h):
             "pallas": lambda: pf(rows, r["weight"])}
 
 
+def delta_rule_case(T, heads, d, chunk, backward):
+    """The gated delta rule's chunked core: the two kernels vs the chunked
+    jnp scan, bfloat16 q / k / v, float32 log-decays drawn as the Kimi-Linear
+    configuration's initial state draws them (``-A softplus(x + dt_bias)``,
+    A in U(1, 16) a head, the step log-uniform in [0.001, 0.1] a channel),
+    the backward of either route from the twin's kept states."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import linear_attention_ops as la
+    from paddle_tpu.ops.pallas import delta_rule as dr
+
+    keys = jax.random.split(jax.random.PRNGKey(chunk), 8)
+    q, k, v, dout = (jax.random.normal(key, (1, T, heads * d), jnp.bfloat16)
+                     for key in keys[:4])
+    rate = jax.random.uniform(keys[4], (heads, 1), minval=1.0, maxval=16.0)
+    step = jnp.exp(jax.random.uniform(
+        keys[5], (heads, d), minval=np.log(0.001), maxval=np.log(0.1)))
+    x = jax.random.normal(keys[6], (1, T, heads, d))
+    g = (-rate * jax.nn.softplus(x + jnp.log(jnp.expm1(step)))) \
+        .reshape(1, T, heads * d).astype(jnp.float32)
+    beta = jax.nn.sigmoid(jax.random.normal(keys[7], (1, T, heads))) \
+        .astype(jnp.bfloat16)
+    assert dr.supported(q, v, g, heads, chunk)
+    scale = d ** -0.5
+    twin = jax.jit(lambda *a: la.chunked_delta_rule_jnp(*a, heads, chunk,
+                                                        scale))
+    states = twin(q, k, v, g, beta)[1] if backward else None
+
+    def route(fwd, bwd):
+        if not backward:
+            forward = jax.jit(lambda *a: fwd(*a, heads, chunk, scale))
+            return lambda: forward(q, k, v, g, beta)
+        grads = jax.jit(lambda *a: bwd(*a, heads, chunk, scale))
+        return lambda: grads(q, k, v, g, beta, states, dout)
+
+    return {"jnp": route(la.chunked_delta_rule_jnp,
+                         la.chunked_delta_rule_bwd_jnp),
+            "pallas": route(dr.delta_rule_fwd, dr.delta_rule_bwd)}
+
+
 def momentum_case(shapes):
     """One fused-momentum step over ``shapes``: the arena megakernel (with
     the concat/split the fused op pays) vs the per-param twin."""
@@ -431,6 +473,11 @@ def cases(tiny):
     mc = (256, 2, 4, 8, 128) if tiny else (8192, 8, 8, 64, 2304)
     yield ("moe_combine_{0}tokens_top{1}_{2}of{3}_width{4}".format(*mc),
            "moe_combine", lambda a=mc: moe_combine_case(*a))
+    T, heads = (128, 2) if tiny else (4096, 32)
+    for bwd in (False, True):
+        yield (f"delta_rule_{'bwd' if bwd else 'fwd'}_len{T}_{heads}x128"
+               "_chunk64", "delta_rule",
+               lambda a=(T, heads, 128, 64, bwd): delta_rule_case(*a))
 
 
 def lstm_lane_step(tiny, rounds=3):
