@@ -86,9 +86,9 @@ _SPECS = {
     "rotary_embedding": ({"Q": "1", "K": "1"}, {"QOut": "1", "KOut": "1"}),
     "rms_norm": ({"X": "1", "Scale": "1"}, {"Y": "1"}),
     "routed_experts": (
-        {"X": "1", "RouterW": "1", "WGate": "1", "WUp": "1", "WDown": "1",
+        {"X": "1", "RouterW": "1", "WGate": "?", "WUp": "1", "WDown": "1",
          "SelectBias": "?"},
-        {"Out": "1", "AuxLoss": "1", "ExpertLoad": "1", "Gate": "1",
+        {"Out": "1", "AuxLoss": "1", "ExpertLoad": "1", "Gate": "?",
          "Up": "1", "RowAssign": "1", "RowWeight": "1", "TopIdx": "1",
          "Probs": "1"}),
     "prefill_attention": (

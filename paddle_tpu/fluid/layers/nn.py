@@ -1122,19 +1122,28 @@ def gated_mlp(input, width, param_attr=None):
                     int(input.shape[-1]), param_attr)
 
 
-def causal_conv1d(input, taps, param_attr=None, name=None):
+def causal_conv1d(input, taps, param_attr=None, bias_attr=False, name=None):
     """A causal depthwise convolution over time on [batch, seq, channels],
     then SiLU: each channel's own filter of ``taps`` over the current and
     the ``taps - 1`` earlier tokens (zeros before the first). The filter
-    parameter is [taps, channels], the last tap on the current token."""
+    parameter is [taps, channels], the last tap on the current token. With
+    ``bias_attr`` (True, or a ParamAttr) a bias [channels], zeros by
+    default, is added before the SiLU."""
     helper = LayerHelper("causal_conv1d", name=name)
     w = helper.create_parameter(
         ParamAttr.to_attr(param_attr), shape=(int(taps),
                                               int(input.shape[-1])),
         dtype="float32")
+    inputs = {"X": [input.name], "Filter": [w.name]}
+    if bias_attr:
+        bias = helper.create_parameter(
+            ParamAttr.to_attr(None if bias_attr is True
+                              else copy.deepcopy(bias_attr)),
+            shape=(int(input.shape[-1]),), dtype="float32",
+            default_initializer=Constant(0.0))
+        inputs["Bias"] = [bias.name]
     out = helper.create_tmp_variable(input.dtype, shape=input.shape)
-    helper.append_op("causal_conv1d",
-                     inputs={"X": [input.name], "Filter": [w.name]},
+    helper.append_op("causal_conv1d", inputs=inputs,
                      outputs={"Out": [out.name]})
     return out
 
@@ -1186,20 +1195,26 @@ def gated_delta_rule(q, k, v, g, beta, num_heads, chunk_size=64, name=None):
 
 
 def gated_rms_norm(input, gate, head_dim, epsilon=1e-6, param_attr=None,
-                   name=None):
+                   gate_first=False, name=None):
     """RMSNorm over each ``head_dim`` slice of [batch, seq, heads *
     head_dim] with one learned scale [head_dim] (initialised to 1), times
-    ``sigmoid(gate)``."""
+    ``sigmoid(gate)``. With ``gate_first`` (the Mamba form) the input is
+    multiplied by ``silu(gate)`` BEFORE the norm, the norm is over groups
+    of ``head_dim`` channels and the scale is one a channel."""
     helper = LayerHelper("gated_rms_norm", name=name)
+    attrs = {"epsilon": float(epsilon)}
+    width = int(head_dim)
+    if gate_first:      # the default form's op stays as it was built before
+        attrs.update(gate_first=True, group_size=int(head_dim))
+        width = int(input.shape[-1])
     scale = helper.create_parameter(
-        ParamAttr.to_attr(param_attr), shape=(int(head_dim),),
+        ParamAttr.to_attr(param_attr), shape=(width,),
         dtype="float32", default_initializer=Constant(1.0))
     out = helper.create_tmp_variable(input.dtype, shape=input.shape)
     helper.append_op("gated_rms_norm",
                      inputs={"X": [input.name], "Gate": [gate.name],
                              "Scale": [scale.name]},
-                     outputs={"Out": [out.name]},
-                     attrs={"epsilon": float(epsilon)})
+                     outputs={"Out": [out.name]}, attrs=attrs)
     return out
 
 
@@ -1231,12 +1246,96 @@ def kda_attention(input, num_heads, head_dim, conv_size=4, gate_rank=None,
     return _project(o, int(input.shape[-1]), param_attr)
 
 
+def ssd_scan(x, dt, b, c, num_heads, n_groups=1, chunk_size=128,
+             time_step_min=0.001, time_step_max=0.1, time_step_floor=1e-4,
+             name=None):
+    """The Mamba-2 state-space core (ops/state_space_ops.py): per head a
+    state ``S`` [head_dim, state] from zero, ``S_t = a_t S_{t-1} + dt_t x_t
+    B_t^T``, ``y_t = S_t C_t + D x_t`` with ``dt_t = softplus(dt + dt_bias)``
+    and ``a_t = exp(-exp(A_log) dt_t)`` one scalar a head, computed in chunks
+    of ``chunk_size`` tokens. ``x`` [batch, seq, num_heads * head_dim]; the
+    raw step ``dt`` [batch, seq, num_heads]; ``b``, ``c`` [batch, seq,
+    n_groups * state], head h reading group ``h // (num_heads / n_groups)``.
+    Parameters, each [num_heads]: ``A_log`` (log U(1, 16)), ``dt_bias`` (the
+    inverse softplus of a step drawn log-uniformly in [``time_step_min``,
+    ``time_step_max``] and floored at ``time_step_floor``: the family's
+    convention) and the skip ``D`` (ones). Returns y, of x's shape."""
+    helper = LayerHelper("ssd_scan", name=name)
+    heads = (int(num_heads),)
+    a_log = helper.create_parameter(
+        ParamAttr(initializer=Mapped(Uniform(1.0, 16.0), "log")),
+        shape=heads, dtype="float32")
+    lo, hi = float(np.log(time_step_min)), float(np.log(time_step_max))
+    dt_bias = helper.create_parameter(
+        ParamAttr(initializer=Mapped(       # softplus^-1(s) = log(e^s - 1)
+            Uniform(lo, hi), "exp",
+            ("clip", {"min": float(time_step_floor), "max": 3.4e38}),
+            "exp", ("scale", {"bias": -1.0}), "log")),
+        shape=heads, dtype="float32")
+    skip = helper.create_parameter(
+        ParamAttr(initializer=Constant(1.0)), shape=heads, dtype="float32")
+    out = helper.create_tmp_variable(x.dtype, shape=x.shape)
+    states = helper.create_tmp_variable("float32", stop_gradient=True)
+    helper.append_op(
+        "ssd_scan",
+        inputs={"X": [x.name], "Dt": [dt.name], "B": [b.name],
+                "C": [c.name], "ALog": [a_log.name],
+                "DtBias": [dt_bias.name], "D": [skip.name]},
+        outputs={"Out": [out.name], "States": [states.name]},
+        attrs={"num_heads": int(num_heads), "n_groups": int(n_groups),
+               "chunk_size": int(chunk_size)})
+    return out
+
+
+def mamba2_mixer(input, num_heads, head_dim, n_groups, state_size,
+                 conv_size=4, chunk_size=128, epsilon=1e-5, conv_bias=True,
+                 time_step_min=0.001, time_step_max=0.1,
+                 time_step_floor=1e-4, param_attr=None, conv_attr=None,
+                 conv_bias_attr=None, out_attr=None, name=None):
+    """A Mamba-2 mixer over an already normed ``input`` [batch, seq,
+    hidden]; no bias but the convolution's. ``[z | xBC | dt] = x W_in``
+    (widths inner | inner + 2 n_groups state_size | num_heads, inner =
+    num_heads * head_dim); ``xBC = silu(conv(xBC) + b)`` (ONE causal
+    depthwise convolution of ``conv_size`` taps over x, B and C together);
+    ``ssd_scan`` over the split x, B, C and dt; ``gated_rms_norm`` in its
+    Mamba form (``y * silu(z)``, then RMSNorm over each of ``n_groups``
+    groups of channels, a scale a channel); then ``W_out`` back to hidden.
+    Parameters in the order W_in, the filter (``conv_attr``), its bias
+    (``conv_bias_attr``, zeros by default), A_log, dt_bias, D, the norm's
+    scale, W_out (``out_attr``, default ``param_attr``)."""
+    inner = int(num_heads) * int(head_dim)
+    bc = int(n_groups) * int(state_size)
+    proj = _project(input, 2 * inner + 2 * bc + int(num_heads), param_attr)
+    z, xbc, dt = tensor.split(proj, [inner, inner + 2 * bc, int(num_heads)],
+                              dim=-1)
+    xbc = causal_conv1d(
+        xbc, conv_size,
+        bias_attr=conv_bias and (conv_bias_attr or True),
+        param_attr=copy.deepcopy(ParamAttr.to_attr(conv_attr or param_attr)))
+    x, b, c = tensor.split(xbc, [inner, bc, bc], dim=-1)
+    y = ssd_scan(x, dt, b, c, num_heads, n_groups, chunk_size, time_step_min,
+                 time_step_max, time_step_floor, name=name)
+    y = gated_rms_norm(y, z, inner // int(n_groups), epsilon=epsilon,
+                       gate_first=True)
+    return _project(y, int(input.shape[-1]), out_attr or param_attr)
+
+
+def relu2_mlp(input, width, param_attr=None):
+    """The un-gated MLP ``W_down relu(W_up x)^2`` of ``width`` over [batch,
+    seq, hidden], no bias: two ``fc``, ``relu`` and ``square``. Parameters
+    in the order up, down."""
+    up = _project(input, width, param_attr)
+    return _project(ops.square(ops.relu(up)), int(input.shape[-1]),
+                    param_attr)
+
+
 def routed_experts(input, num_experts, top_k, expert_width,
                    held_experts=None, expert_offset=0, norm_topk_prob=True,
                    row_buffer_factor=2.0, router_task_gradient=True,
                    scoring_func="softmax", routed_scaling_factor=1.0,
                    selection_bias=False, bias_update_rate=0.0,
-                   bias_attr=None, param_attr=None, name=None):
+                   bias_attr=None, param_attr=None, expert_form="gated_silu",
+                   name=None):
     """A mixture-of-experts MLP that is told which experts it holds
     (ops/moe_ops.py): the router scores all ``num_experts``
     (``scoring_func``: ``softmax`` over all of them, or ``sigmoid`` of each)
@@ -1249,11 +1348,12 @@ def routed_experts(input, num_experts, top_k, expert_width,
     initialiser) is added to the scores for the SELECTION only, and with
     ``bias_update_rate`` > 0 each step moves it by that much against the
     step's loads (``expert_bias_update``: no gradient, no optimizer). With
-    ``router_task_gradient`` off the task loss does not reach the router
-    through the top k's weights (it learns from ``aux_loss`` alone: for a
-    layer that holds a share of the experts). Returns (out, expert_load
-    [held] int32, aux_loss [1]: the load-balancing term over all router
-    outputs)."""
+    ``expert_form`` ``relu2`` an expert is ``W_down relu(W_up x)^2`` and has
+    no gate matrix. With ``router_task_gradient`` off the task loss does not
+    reach the router through the top k's weights (it learns from
+    ``aux_loss`` alone: for a layer that holds a share of the experts).
+    Returns (out, expert_load [held] int32, aux_loss [1]: the load-balancing
+    term over all router outputs)."""
     helper = LayerHelper("routed_experts", name=name)
     hidden = int(input.shape[-1])
     held = int(held_experts or num_experts)
@@ -1290,11 +1390,17 @@ def routed_experts(input, num_experts, top_k, expert_width,
         inputs["SelectBias"] = [bias.name]
     elif bias_update_rate:
         raise ValueError("bias_update_rate without selection_bias")
-    w_gate = weight((held, hidden, expert_width))
+    relu2 = expert_form == "relu2"
+    if relu2:
+        attrs["expert_form"] = "relu2"
+    elif expert_form != "gated_silu":
+        raise ValueError(f"routed_experts: unknown expert_form "
+                         f"{expert_form!r}")
+    else:
+        inputs["WGate"] = [weight((held, hidden, expert_width)).name]
     w_up = weight((held, hidden, expert_width))
     w_down = weight((held, expert_width, hidden))
-    inputs.update({"WGate": [w_gate.name], "WUp": [w_up.name],
-                   "WDown": [w_down.name]})
+    inputs.update({"WUp": [w_up.name], "WDown": [w_down.name]})
     out = helper.create_tmp_variable(input.dtype, shape=input.shape)
     aux = helper.create_tmp_variable("float32", shape=(1,))
     kept = {slot: helper.create_tmp_variable(dtype, stop_gradient=True)
@@ -1302,7 +1408,7 @@ def routed_experts(input, num_experts, top_k, expert_width,
                 ("ExpertLoad", "int32"), ("Gate", input.dtype),
                 ("Up", input.dtype), ("RowAssign", "int32"),
                 ("RowWeight", "float32"), ("TopIdx", "int32"),
-                ("Probs", "float32"))}
+                ("Probs", "float32")) if not (relu2 and slot == "Gate")}
     kept["ExpertLoad"].shape = (held,)
     helper.append_op(
         "routed_experts", inputs=inputs,

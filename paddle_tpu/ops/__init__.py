@@ -20,6 +20,7 @@ from . import (  # noqa: F401
     rnn_ops,
     attention_ops,
     linear_attention_ops,
+    state_space_ops,
     moe_ops,
     control_flow_ops,
     crf_ops,

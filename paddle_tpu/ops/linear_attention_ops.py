@@ -61,11 +61,11 @@ answer for the op and its grad op:
   its kept state, and differentiates the rest with ``jax.vjp`` of the same
   chunked forward: no hand-derived formula there.
 
-Beside it: ``causal_conv1d`` (a causal depthwise convolution over the
-current and the earlier tokens, one filter a channel, then SiLU),
-``kda_decay_gate`` (``g = -exp(A_log) * softplus(x + dt_bias)``, float32)
-and ``gated_rms_norm`` (RMSNorm per head times a sigmoid gate); their grad
-ops are ``jax.vjp`` of their forwards.
+Beside it: ``causal_conv1d`` (a causal depthwise convolution, one filter a
+channel, a bias where the op has one, then SiLU), ``kda_decay_gate`` (``g =
+-exp(A_log) * softplus(x + dt_bias)``, float32) and ``gated_rms_norm``
+(RMSNorm per head times a sigmoid gate; ``gate_first``: the Mamba form);
+their grad ops are ``jax.vjp`` of their forwards.
 """
 
 from __future__ import annotations
@@ -375,28 +375,31 @@ def gated_delta_rule_grad(ctx):
 def _register_with_vjp(op_type, slots, out_slot, fn, doc, out_dtype=None):
     """Register ``op_type`` (inputs ``slots`` -> ``out_slot``, of the first
     input's shape and, but for ``out_dtype``, type) and its grad op,
-    ``jax.vjp`` of the same ``fn(ctx, *inputs)``."""
+    ``jax.vjp`` of the same ``fn(ctx, *inputs)``. A slot the op was built
+    without is left out of the call, and of the grad op."""
     def maker(op):
-        inputs = {s: op.input(s) for s in slots}
+        held = [s for s in slots if op.input(s)]
+        inputs = {s: op.input(s) for s in held}
         inputs[out_slot + "@GRAD"] = G(op.output(out_slot))
         return [OpSpec(op_type + "_grad", inputs,
-                       {s + "@GRAD": G(op.input(s)) for s in slots},
+                       {s + "@GRAD": G(op.input(s)) for s in held},
                        dict(op.attrs))]
 
     def forward(ctx):
-        ctx.set_output(out_slot,
-                       fn(ctx, *(data_of(ctx.input(s)) for s in slots)))
+        ctx.set_output(out_slot, fn(ctx, *(
+            data_of(ctx.input(s)) for s in slots if ctx.op.input(s))))
 
     def backward(ctx):
         # the barrier keeps the compiler from finding the forward op's own
         # float32 intermediates and holding them from there to here, where
         # rebuilding them from the (bfloat16) inputs costs one fused pass
+        held = [s for s in slots if ctx.op.input(s)]
         args, dout = jax.lax.optimization_barrier((
-            [data_of(ctx.input(s)) for s in slots],
+            [data_of(ctx.input(s)) for s in held],
             data_of(ctx.input(out_slot + "@GRAD"))))
         out, back = jax.vjp(lambda *a: fn(ctx, *a), *args)
         grads = back(dout.astype(out.dtype))
-        for slot, dx in zip(slots, grads):
+        for slot, dx in zip(held, grads):
             ctx.set_output(slot + "@GRAD", dx)
 
     def infer(op, block):
@@ -411,21 +414,24 @@ def _register_with_vjp(op_type, slots, out_slot, fn, doc, out_dtype=None):
     register_op(op_type + "_grad")(backward)
 
 
-def _causal_conv1d(ctx, x, w):
+def _causal_conv1d(ctx, x, w, bias=None):
     taps = w.shape[0]
     xf, wf = x.astype(jnp.float32), w.astype(jnp.float32)
     t = x.shape[1]
     back = jnp.pad(xf, ((0, 0), (taps - 1, 0), (0, 0)))
     y = sum(back[:, j:j + t] * wf[j] for j in range(taps))
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
     return jax.nn.silu(y).astype(x.dtype)
 
 
 _register_with_vjp(
-    "causal_conv1d", ("X", "Filter"), "Out", _causal_conv1d,
+    "causal_conv1d", ("X", "Filter", "Bias"), "Out", _causal_conv1d,
     """A causal depthwise convolution over time: ``X`` [b, T, channels],
     ``Filter`` [taps, channels] (one filter a channel; the LAST tap meets
     the current token, the first the token ``taps - 1`` back; before the
-    first token lie zeros), then SiLU. Float32 inside, X's type out.""")
+    first token lie zeros), plus ``Bias`` [channels] where the op has one,
+    then SiLU. Float32 inside, X's type out.""")
 
 
 def _kda_decay_gate(ctx, x, a_log, dt_bias):
@@ -446,7 +452,20 @@ _register_with_vjp(
     type.""", out_dtype="float32")
 
 
+def _gate_first_group_norm(ctx, x, gate, scale):
+    """The Mamba form: ``x * silu(gate)``, RMSNorm over each group of
+    ``group_size`` channels, times a scale per CHANNEL."""
+    size = int(ctx.attr("group_size"))
+    xf = x.astype(jnp.float32) * jax.nn.silu(gate.astype(jnp.float32))
+    g = xf.reshape(x.shape[:-1] + (-1, size))
+    g = g * jax.lax.rsqrt(jnp.mean(g * g, -1, keepdims=True)
+                          + ctx.attr("epsilon", 1e-6))
+    return (g.reshape(x.shape) * scale.astype(jnp.float32)).astype(x.dtype)
+
+
 def _gated_rms_norm(ctx, x, gate, scale):
+    if ctx.attr("gate_first", False):
+        return _gate_first_group_norm(ctx, x, gate, scale)
     d = scale.shape[0]
     xf = x.astype(jnp.float32).reshape(x.shape[:-1] + (-1, d))
     r = jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True)
@@ -459,4 +478,7 @@ _register_with_vjp(
     "gated_rms_norm", ("X", "Gate", "Scale"), "Out", _gated_rms_norm,
     """RMSNorm over each head of ``X`` [b, T, heads * d] with ONE learned
     ``Scale`` [d] for all heads, times ``sigmoid(Gate)`` (Gate of X's
-    shape). Float32 inside, X's type out.""")
+    shape). With the attr ``gate_first`` (the Mamba form) the gate is
+    ``silu(Gate)`` and multiplies BEFORE the norm, the norm is over groups
+    of ``group_size`` channels and ``Scale`` is one a channel [heads * d].
+    Float32 inside, X's type out.""")

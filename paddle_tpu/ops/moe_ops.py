@@ -13,8 +13,12 @@ step's loads. The expert weights are ``[held, ...]``: the experts
 ``expert_offset .. expert_offset + held - 1``. The op computes, for every
 token, the part of ``y = sum_k w_k WDown[e_k](silu(WGate[e_k] x) *
 WUp[e_k] x)`` that the held experts give; nothing stands in for the absent
-ones. With ``held == num_experts`` it is the whole layer; across chips it is
-what runs between the two exchanges of an expert-parallel step.
+ones. With the attr ``expert_form`` ``relu2`` an expert is ``WDown[e]
+relu(WUp[e] x)^2`` with NO gate: the op then has no ``WGate`` input and no
+``Gate`` output, and a layer is six grouped products forward and backward
+where the gated form has nine. With ``held == num_experts`` it is the whole
+layer; across chips it is what runs between the two exchanges of an
+expert-parallel step.
 
 No token routed to a held expert is dropped. The ``tokens * top_k``
 assignments are sorted by expert (absent ones last) into a row buffer of
@@ -72,7 +76,8 @@ _M_ROW_BUFFER = _METRICS.gauge(
     "paddle_tpu_moe_row_buffer",
     "routed_experts' row buffer as last traced: kind=rows is the "
     "expectation tokens*top_k*held/num_experts, kind=capacity the static "
-    "buffer a step's held rows must fit",
+    "buffer a step's held rows must fit, kind=products the grouped "
+    "products over it forward and backward (9: gated experts, 6: relu2)",
     labels=("kind",))
 
 ROW_ALIGN = 512
@@ -88,6 +93,19 @@ def row_buffer(tokens, top_k, held, num_experts, factor):
     cap = int(math.ceil(factor * expected / ROW_ALIGN) * ROW_ALIGN)
     cap = min(cap, tokens * top_k)
     return expected, cap, (-(-cap // TILE) + held) * TILE
+
+
+EXPERT_FORMS = ("gated_silu", "relu2")
+
+
+def _relu2(ctx):
+    """Whether the experts are ``WDown relu(WUp x)^2`` without a gate; the
+    form goes on the row buffer's gauge as the products it costs."""
+    form = ctx.attr("expert_form", "gated_silu")
+    if form not in EXPERT_FORMS:
+        raise ValueError(f"routed_experts: unknown expert_form {form!r}")
+    _M_ROW_BUFFER.labels(kind="products").set(6 if form == "relu2" else 9)
+    return form == "relu2"
 
 
 def _attrs(ctx):
@@ -292,41 +310,53 @@ def _infer(op, block):
 
 
 def _grad_maker(op):
-    inputs = {s: op.input(s) for s in ("X", "RouterW", "WGate", "WUp",
-                                       "WDown")}
+    weights = [s for s in ("X", "RouterW", "WGate", "WUp", "WDown")
+               if op.input(s)]                  # no WGate under relu2
+    inputs = {s: op.input(s) for s in weights}
     for s in ("Gate", "Up", "RowAssign", "RowWeight", "ExpertLoad", "TopIdx",
               "Probs"):
-        inputs[s] = op.output(s)
+        if op.output(s):
+            inputs[s] = op.output(s)
     inputs["Out@GRAD"] = G(op.output("Out"))
     inputs["AuxLoss@GRAD"] = G(op.output("AuxLoss"))
     return [OpSpec("routed_experts_grad", inputs,
-                   {s + "@GRAD": G(op.input(s))
-                    for s in ("X", "RouterW", "WGate", "WUp", "WDown")},
+                   {s + "@GRAD": G(op.input(s)) for s in weights},
                    dict(op.attrs))]
+
+
+def _weights(ctx, relu2):
+    """The experts' weights in the order of the products: [WGate,] WUp,
+    WDown."""
+    slots = ("WUp", "WDown") if relu2 else ("WGate", "WUp", "WDown")
+    return [data_of(ctx.input(s)) for s in slots]
 
 
 @register_op("routed_experts", infer_shape=_infer, grad=_grad_maker)
 def routed_experts(ctx):
+    relu2 = _relu2(ctx)
     xv = data_of(ctx.input("X"))
-    wg, wu, wd = (data_of(ctx.input(s)) for s in ("WGate", "WUp", "WDown"))
+    ws = _weights(ctx, relu2)
     a = _attrs(ctx)
     x = xv.reshape(-1, xv.shape[-1])
     bias = data_of(ctx.input("SelectBias")) \
         if ctx.has_input("SelectBias") else None
-    r = route(x, data_of(ctx.input("RouterW")), wg.shape[0], bias=bias, **a)
-    xc, wg, wu, wd = cast_compute(x, wg, wu, wd)
+    r = route(x, data_of(ctx.input("RouterW")), ws[0].shape[0], bias=bias,
+              **a)
+    xc, *ws = cast_compute(x, *ws)
     keep = (r["assign"] >= 0)[:, None]
     token = jnp.maximum(r["assign"], 0) // a["top_k"]
     rows = xc[token]
-    dot = _Products(r["load"], r["layout"], rows, wg)
+    dot = _Products(r["load"], r["layout"], rows, ws[0])
     # a row of padding holds some token's data and whatever the product
     # makes of it: kept out of everything that sums over rows
-    gate = jnp.where(keep, dot.rows_by(rows, wg, xc.dtype), 0)
-    up = jnp.where(keep, dot.rows_by(rows, wu, xc.dtype), 0)
-    act = (jax.nn.silu(gate.astype(jnp.float32))
-           * up.astype(jnp.float32)).astype(xc.dtype)
-    out = combine(dot.rows_by(act, wd), r["weight"], r["assign"],
-                  a["top_k"], r["layout"], x.shape[0])
+    *pre, up = (jnp.where(keep, dot.rows_by(rows, w, xc.dtype), 0)
+                for w in ws[:-1])
+    if relu2:
+        act = jnp.square(jax.nn.relu(up.astype(jnp.float32)))
+    else:
+        act = jax.nn.silu(pre[0].astype(jnp.float32)) * up.astype(jnp.float32)
+    out = combine(dot.rows_by(act.astype(xc.dtype), ws[-1]), r["weight"],
+                  r["assign"], a["top_k"], r["layout"], x.shape[0])
     out = jnp.where(r["overflow"], jnp.nan, out)
 
     counts = _count(r["top_i"], a["num_experts"]).astype(jnp.float32)
@@ -337,7 +367,8 @@ def routed_experts(ctx):
     ctx.set_output("Out", out.reshape(xv.shape).astype(xv.dtype))
     ctx.set_output("AuxLoss", aux.reshape(1))
     ctx.set_output("ExpertLoad", r["load"])
-    ctx.set_output("Gate", gate)
+    if pre:
+        ctx.set_output("Gate", pre[0])
     ctx.set_output("Up", up)
     ctx.set_output("RowAssign", r["assign"])
     ctx.set_output("RowWeight", r["weight"])
@@ -347,19 +378,21 @@ def routed_experts(ctx):
 
 @register_op("routed_experts_grad")
 def routed_experts_grad(ctx):
-    """By hand, from the forward's kept rows: the three products' input
-    and weight gradients as grouped products over the same groups, then the
-    router's through the scaled, renormalised top k, the balance term and
-    the score function (softmax or sigmoid). The selection bias takes no
-    gradient: it moved the selection, which has none."""
+    """By hand, from the forward's kept rows: the three products' (two
+    under ``relu2``) input and weight gradients as grouped products over
+    the same groups, then the router's through the scaled, renormalised top
+    k, the balance term and the score function (softmax or sigmoid). The
+    selection bias takes no gradient: it moved the selection, which has
+    none."""
+    relu2 = _relu2(ctx)
     xv = data_of(ctx.input("X"))
     router_w = data_of(ctx.input("RouterW"))
-    wg, wu, wd = (data_of(ctx.input(s)) for s in ("WGate", "WUp", "WDown"))
+    ws = _weights(ctx, relu2)
     a = _attrs(ctx)
     k, n_exp = a["top_k"], a["num_experts"]
     x = xv.reshape(-1, xv.shape[-1])
     n = x.shape[0]
-    gate, up = data_of(ctx.input("Gate")), data_of(ctx.input("Up"))
+    up = data_of(ctx.input("Up"))
     assign = data_of(ctx.input("RowAssign"))
     weight = data_of(ctx.input("RowWeight"))
     load = data_of(ctx.input("ExpertLoad"))
@@ -368,28 +401,41 @@ def routed_experts_grad(ctx):
     dout = data_of(ctx.input("Out@GRAD")).reshape(x.shape)
     daux = data_of(ctx.input("AuxLoss@GRAD")).reshape(()).astype(jnp.float32)
 
-    xc, wgc, wuc, wdc, dc = cast_compute(x, wg, wu, wd, dout)
+    xc, *wc, dc = cast_compute(x, *ws, dout)
     keep = (assign >= 0)[:, None]
     token = jnp.maximum(assign, 0) // k
     rows, drows = xc[token], jnp.where(keep, dc[token], 0)
     lay = layout(load, rows.shape[0])
-    dot = _Products(load, lay, rows, wgc)
-    g32, u32 = gate.astype(jnp.float32), up.astype(jnp.float32)
-    sig = jax.nn.sigmoid(g32)
-    silu = g32 * sig
-    act = silu * u32
+    dot = _Products(load, lay, rows, wc[0])
+    # (the gate's convert stays ahead of Up's: the gated form traces what
+    # it traced before the forms parted)
+    g32 = None if relu2 \
+        else data_of(ctx.input("Gate")).astype(jnp.float32)
+    u32 = up.astype(jnp.float32)
+    if relu2:
+        relu = jax.nn.relu(u32)
+        act = relu * relu
+    else:
+        sig = jax.nn.sigmoid(g32)
+        silu = g32 * sig
+        act = silu * u32
 
-    dact = jnp.where(keep, dot.rows_by_transposed(drows, wdc), 0.0)
+    dact = jnp.where(keep, dot.rows_by_transposed(drows, wc[-1]), 0.0)
     dweight = jnp.sum(dact * act, axis=-1)               # of the unweighted
     dact = dact * weight[:, None]
     d_wd = dot.weights_grad((act * weight[:, None]).astype(xc.dtype), drows)
-    dgate = (dact * u32 * sig * (1.0 + g32 * (1.0 - sig))).astype(xc.dtype)
-    dup = (dact * silu).astype(xc.dtype)
-    d_wg = dot.weights_grad(rows, dgate)
-    d_wu = dot.weights_grad(rows, dup)
-    dx = combine(dot.rows_by_transposed(dgate, wgc)
-                 + dot.rows_by_transposed(dup, wuc), jnp.ones_like(weight),
-                 assign, k, lay, n)
+    if relu2:
+        dup = (dact * 2.0 * relu).astype(xc.dtype)
+        d_pre = [dot.weights_grad(rows, dup)]
+        drows_in = dot.rows_by_transposed(dup, wc[0])
+    else:
+        dgate = (dact * u32 * sig
+                 * (1.0 + g32 * (1.0 - sig))).astype(xc.dtype)
+        dup = (dact * silu).astype(xc.dtype)
+        d_pre = [dot.weights_grad(rows, dgate), dot.weights_grad(rows, dup)]
+        drows_in = dot.rows_by_transposed(dgate, wc[0]) \
+            + dot.rows_by_transposed(dup, wc[1])
+    dx = combine(drows_in, jnp.ones_like(weight), assign, k, lay, n)
 
     # the router: rows' weights back to their (token, slot), through the
     # renormalisation, the selection, the balance term and the softmax
@@ -435,9 +481,10 @@ def routed_experts_grad(ctx):
     ctx.set_output("RouterW@GRAD",
                    jnp.dot(x32.T, dlogits, precision=hi)
                    .astype(router_w.dtype))
-    ctx.set_output("WGate@GRAD", d_wg.astype(wg.dtype))
-    ctx.set_output("WUp@GRAD", d_wu.astype(wu.dtype))
-    ctx.set_output("WDown@GRAD", d_wd.astype(wd.dtype))
+    for slot, w, dw in zip(("WUp",) if relu2 else ("WGate", "WUp"), ws,
+                           d_pre):
+        ctx.set_output(slot + "@GRAD", dw.astype(w.dtype))
+    ctx.set_output("WDown@GRAD", d_wd.astype(ws[-1].dtype))
 
 
 @register_op("expert_bias_update", infer_shape=same_shape("Bias", "BiasOut"))

@@ -324,8 +324,39 @@ class GenerationEngine:
     # ------------------------------------------------------------------
     # program split
     # ------------------------------------------------------------------
+    # ops that carry a state from token to token which the engine does not
+    # keep: a decode step would start every token from a zero state
+    _STATE_OPS = {
+        "ssd_scan": "a state-space layer's recurrent state",
+        "gated_delta_rule": "a linear-attention layer's recurrent state",
+        "causal_conv1d": "a short convolution's last taps",
+    }
+
+    @classmethod
+    def _refuse_unserved(cls, block):
+        """Ops whose decoding needs what the engine does not keep: refused
+        by name, before a rewritten program computes something else."""
+        for op in block.ops:
+            if op.type in cls._STATE_OPS:
+                raise ValueError(
+                    f"{op.type}: {cls._STATE_OPS[op.type]} is carried from "
+                    "token to token; GenerationEngine keeps a paged KV "
+                    "arena and no recurrent-state cache, so a decode step "
+                    "would start every token from nothing; programs with "
+                    "state layers cannot be served yet")
+            if op.type == "routed_experts" \
+                    and op.attr("expert_form", "gated_silu") != "gated_silu":
+                raise ValueError(
+                    f"routed_experts with expert_form="
+                    f"{op.attr('expert_form')!r}: the un-gated expert form "
+                    "comes with single-mixer state-space hybrids, whose "
+                    "layers need a recurrent-state cache that "
+                    "GenerationEngine does not have; no phase program has "
+                    "decoded it and it cannot be served yet")
+
     def _attention_config(self, program):
         block = program.global_block()
+        self._refuse_unserved(block)
         sites = [op for op in block.ops if op.type == ATTENTION_OP]
         if not sites:
             raise ValueError(

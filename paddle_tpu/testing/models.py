@@ -378,3 +378,128 @@ def build_kimi_linear_lm(cfg, length, batch=1):
             loss = layers.elementwise_add(loss, layers.scale(
                 layers.mean(layers.sums(aux)), scale=coef / len(aux)))
     return main, startup, loss, logits, loads
+
+
+def build_nemotron_h_lm(cfg, length, batch=1):
+    """The NemotronH layer stack (``model_type`` ``nemotron_h``, its
+    published ``config.json`` keys) as a Fluid program, and the chip's share
+    of it: ``num_hidden_layers`` layers ``x + mixer(rms_norm(x))``, ONE
+    mixer each, its kind the layer's letter in ``hybrid_override_pattern``:
+    ``M`` a Mamba-2 mixer (``layers.mamba2_mixer``: ``mamba_num_heads`` heads
+    of ``mamba_head_dim``, ``n_groups`` groups of ``ssm_state_size``, a
+    convolution of ``conv_kernel`` taps with a bias, the state-space core in
+    chunks of ``chunk_size``), ``*`` grouped-query attention without
+    positions (``num_attention_heads`` / ``num_key_value_heads`` heads of
+    ``head_dim``, nothing rotated), ``E`` ``routed_experts`` (sigmoid scores,
+    a selection bias with its own update, top ``num_experts_per_tok``
+    renormalised times ``routed_scaling_factor``, un-gated squared-ReLU
+    experts of ``moe_intermediate_size``) holding ``n_routed_experts`` of
+    the router's ``num_experts_routed`` from ``expert_offset``, beside one
+    shared squared-ReLU expert of ``moe_shared_expert_intermediate_size``.
+    Then the final RMSNorm and an untied head over ``vocab_size`` rows. The
+    out-projections of the Mamba and attention mixers are drawn at
+    ``init_std / sqrt(published num_hidden_layers)``
+    (``rescale_prenorm_residual``). The loss is the mean next-token
+    cross-entropy plus ``balance_loss_coef`` times the expert layers' mean
+    balance term. The plain reference is ``testing/reference/nemotron_h.py``;
+    parameters are created in the order its ``unpack`` reads. Feeds
+    ``tokens`` and ``labels`` [batch, length, 1] int64. Returns (main,
+    startup, loss, logits [batch, length, vocab], [expert_load of each
+    expert layer])."""
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.fluid.initializer import Normal, Uniform
+    from paddle_tpu.fluid.param_attr import ParamAttr
+    from paddle_tpu.testing.reference.nemotron_h import eps_of, layer_kinds
+
+    layers = fluid.layers
+    hidden, eps = cfg["hidden_size"], eps_of(cfg)
+    std = cfg.get("init_std", 0.02)
+    depth = cfg.get("published", {}).get("num_hidden_layers",
+                                         cfg["num_hidden_layers"])
+    out_scale = depth ** -0.5 if cfg.get("rescale_prenorm_residual", True) \
+        else 1.0
+
+    def init(scale=std):
+        return ParamAttr(initializer=Normal(0.0, scale))
+
+    def project(x, size, scale=std):
+        return layers.fc(x, size, num_flatten_dims=2, bias_attr=False,
+                         param_attr=init(scale))
+
+    # the filter and its bias as the family's framework draws a depthwise
+    # convolution: uniform in +-1 / sqrt(taps)
+    bound = cfg["conv_kernel"] ** -0.5
+    conv = ParamAttr(initializer=Uniform(-bound, bound))
+    aux, loads = [], []
+
+    def mixer(kind, u):
+        if kind == "mamba":
+            return layers.mamba2_mixer(
+                u, num_heads=cfg["mamba_num_heads"],
+                head_dim=cfg["mamba_head_dim"], n_groups=cfg["n_groups"],
+                state_size=cfg["ssm_state_size"],
+                conv_size=cfg["conv_kernel"],
+                chunk_size=cfg.get("chunk_size", 128), epsilon=eps,
+                conv_bias=cfg.get("use_conv_bias", True),
+                time_step_min=cfg.get("time_step_min", 0.001),
+                time_step_max=cfg.get("time_step_max", 0.1),
+                time_step_floor=cfg.get("time_step_floor", 1e-4),
+                param_attr=init(cfg.get("mamba_init_std", std)),
+                conv_attr=conv, conv_bias_attr=conv,
+                out_attr=init(cfg.get("mamba_out_init_std", std) * out_scale))
+        if kind == "attention":
+            heads, kv, d = (cfg["num_attention_heads"],
+                            cfg["num_key_value_heads"], cfg["head_dim"])
+            qk = cfg.get("attention_init_std", std)
+            q, k = project(u, heads * d, qk), project(u, kv * d, qk)
+            v = project(u, kv * d)
+            a = layers.causal_self_attention(q, k, v, num_heads=heads,
+                                             num_kv_heads=kv)
+            return project(
+                a, hidden, cfg.get("attention_out_init_std", std) * out_scale)
+        expert = init(cfg.get("expert_init_std", std))
+        y, load, balance = layers.routed_experts(
+            u, num_experts=cfg["num_experts_routed"],
+            top_k=cfg["num_experts_per_tok"],
+            expert_width=cfg["moe_intermediate_size"],
+            held_experts=cfg["n_routed_experts"],
+            expert_offset=cfg.get("expert_offset", 0),
+            norm_topk_prob=cfg.get("norm_topk_prob", True),
+            row_buffer_factor=cfg.get("row_buffer_factor", 2.0),
+            router_task_gradient=cfg.get("router_task_gradient", True),
+            scoring_func="sigmoid",
+            routed_scaling_factor=cfg.get("routed_scaling_factor", 1.0),
+            selection_bias=True,
+            bias_update_rate=cfg.get("bias_update_rate", 0.0),
+            bias_attr=ParamAttr(initializer=Normal(
+                cfg.get("selection_bias_init_mean", 0.0),
+                cfg.get("selection_bias_init_std", 0.0))),
+            param_attr=expert, expert_form="relu2")
+        aux.append(balance)
+        loads.append(load)
+        return layers.elementwise_add(y, layers.relu2_mlp(
+            u, cfg.get("n_shared_experts", 1)
+            * cfg["moe_shared_expert_intermediate_size"], expert))
+
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        tokens = layers.data("tokens", shape=[batch, length, 1],
+                             dtype="int64", append_batch_size=False)
+        labels = layers.data("labels", shape=[batch, length, 1],
+                             dtype="int64", append_batch_size=False)
+        x = layers.embedding(
+            tokens, size=(cfg["vocab_size"], hidden),
+            param_attr=init(cfg.get("embedding_init_std", std)))
+        for kind in layer_kinds(cfg):
+            x = layers.elementwise_add(
+                x, mixer(kind, layers.rms_norm(x, epsilon=eps)))
+        logits = layers.fc(
+            layers.rms_norm(x, epsilon=eps), cfg["vocab_size"],
+            num_flatten_dims=2, bias_attr=False,
+            param_attr=init(cfg.get("head_init_std", std)))
+        loss = layers.mean(layers.softmax_with_cross_entropy(logits, labels))
+        coef = cfg.get("balance_loss_coef", 0.0)
+        if coef and aux:
+            loss = layers.elementwise_add(loss, layers.scale(
+                layers.mean(layers.sums(aux)), scale=coef / len(aux)))
+    return main, startup, loss, logits, loads

@@ -224,24 +224,41 @@ def test_attention_kernels_compile_for_v5e_at_heads_of_192_and_128(
     assert all(3 << 19 < n < 16 << 20 for n in vmem.values()), vmem
 
 
-def _expert_shapes(sharding):
+# (tokens, top k, held, experts, buffer factor, hidden, expert width): the
+# Mellum2 cell's experts, and the Nemotron-3-Nano cell's, whose width is 14.5
+# lane tiles (every block of the kernels spans a whole width, so
+# ``supported`` admits whole HALVES of a tile)
+MELLUM2_EXPERTS = (8192, 8, 8, 64, 2.0, 2304, 896)
+NEMOTRON_EXPERTS = (4096, 6, 8, 128, 3.0, 2688, 1856)
+
+
+def _expert_shapes(sharding, case=MELLUM2_EXPERTS):
     from paddle_tpu.ops import moe_ops
+    tokens, top_k, held, experts, factor, hidden, width = case
 
     def s(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
-    _, _, buffer = moe_ops.row_buffer(8192, 8, 8, 64, 2.0)
-    return (s((buffer, 2304)), s((buffer, 896)), s((8, 2304, 896)),
-            s((8, 896, 2304)), s((buffer // moe_ops.TILE,), jnp.int32),
-            s((1,), jnp.int32), s((8,), jnp.int32))
+    _, _, buffer = moe_ops.row_buffer(tokens, top_k, held, experts, factor)
+    return (s((buffer, hidden)), s((buffer, width)),
+            s((held, hidden, width)), s((held, width, hidden)),
+            s((buffer // moe_ops.TILE,), jnp.int32),
+            s((1,), jnp.int32), s((held,), jnp.int32))
 
 
-def test_grouped_matmul_kernels_compile_for_v5e(one_chip, native_lowering):
-    """The three kernels at the cell's shapes: 18432 buffered rows, 8
-    experts of 2304 x 896 (up) and 896 x 2304 (down)."""
+@pytest.mark.parametrize("case", [MELLUM2_EXPERTS, NEMOTRON_EXPERTS],
+                         ids=["2304x896", "2688x1856"])
+def test_grouped_matmul_kernels_compile_for_v5e(one_chip, native_lowering,
+                                                case):
+    """The three kernels at a cell's shapes: 18432 buffered rows over 8
+    experts of 2304 x 896 (up) and 896 x 2304 (down); 6656 rows over 8 of
+    2688 x 1856, the last lane tile half full; a width that is no whole
+    half of a tile is not theirs."""
     from paddle_tpu.ops.pallas import grouped_matmul as gm
     wide, narrow, w_up, w_down, tile_expert, tiles, _ = _expert_shapes(
-        one_chip)
+        one_chip, case)
     assert gm.supported(wide, w_up) and gm.supported(narrow, w_down)
+    assert not gm.supported(wide, jax.ShapeDtypeStruct(
+        (8, case[5], case[6] + 32), jnp.bfloat16))
     for fn, args, name in (
             (gm.gmm, (wide, w_up), "grouped_matmul"),
             (gm.gmm, (narrow, w_down), "grouped_matmul"),
@@ -389,3 +406,72 @@ def test_ragged_dot_route_lowers_to_a_grouped_kernel_on_v5e(one_chip,
     assert cost(lambda a, w, s: jax.lax.ragged_dot_general(
         a, w, s, contract_last, preferred_element_type=jnp.float32),
         narrow, w_up, sizes) > 4 * dense
+
+
+# ---- the Nemotron-3-Nano cell: 4096 tokens; the Mamba-2 core at 64 heads of
+# 64 in 8 groups of state 128, chunks of 128; attention at 32 query and 2
+# key/value heads of 128
+SSD_T, SSD_HEADS, SSD_P, SSD_GROUPS, SSD_N, SSD_CHUNK = 4096, 64, 64, 8, 128, 128
+
+
+def test_the_ssd_core_compiles_for_v5e_inside_a_gigabyte(one_chip):
+    """``ssd_scan`` and its grad op at the cell's shape, bfloat16 x / B / C
+    and raw step (the AMP types): the chunked program has no loop (the pass
+    over the 32 chunk states is one product), keeps the states it says it
+    keeps, and its temporaries (the [32, 64, 128, 128] pairwise decays and
+    what the backward rebuilds of them) stay under a gigabyte by the
+    compiler's own count."""
+    from paddle_tpu.ops import state_space_ops as ss
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    x = s((1, SSD_T, SSD_HEADS * SSD_P))
+    bc = s((1, SSD_T, SSD_GROUPS * SSD_N))
+    dt = s((1, SSD_T, SSD_HEADS))
+    head = s((SSD_HEADS,), jnp.float32)
+    args = (x, dt, bc, bc, head, head, head)
+    chunks = SSD_T // SSD_CHUNK
+    states = s((1, chunks, SSD_HEADS, SSD_P, SSD_N), jnp.float32)
+    fwd = lambda *a: ss.ssd_chunked(                    # noqa: E731
+        *a, SSD_HEADS, SSD_GROUPS, SSD_CHUNK)
+    bwd = lambda *a: ss.ssd_chunked_bwd(                # noqa: E731
+        *a, SSD_HEADS, SSD_GROUPS, SSD_CHUNK)
+    out, kept = jax.eval_shape(fwd, *args)
+    assert (out.shape, out.dtype) == (x.shape, x.dtype)
+    assert (kept.shape, kept.dtype) == (states.shape, states.dtype)
+    grads = jax.eval_shape(bwd, *args, states, x)
+    assert [g.shape for g in grads[:4]] == [a.shape for a in args[:4]]
+    for fn, operands in ((fwd, args), (bwd, args + (states, x))):
+        compiled = jax.jit(fn).lower(*operands).compile()
+        assert " while(" not in compiled.as_text()
+        assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+def test_attention_at_32_and_2_heads_reaches_the_kernels(one_chip,
+                                                         native_lowering):
+    """The cell's one attention layer: sixteen query heads a key/value head
+    is a shape the three kernels take natively (no fallback), on the
+    full-causal schedule of 4096 tokens in blocks of 512."""
+    from paddle_tpu.ops.pallas import attention as att
+
+    def s(heads):
+        return jax.ShapeDtypeStruct((1, SSD_T, heads * 128), jnp.bfloat16,
+                                    sharding=one_chip)
+    q, kv = s(32), s(2)
+    lse = jax.ShapeDtypeStruct((1, 32, SSD_T, 128), jnp.float32,
+                               sharding=one_chip)
+    assert att.attention_supported(q, 32, 2, 0, kv)
+    entries = (SSD_T // 512) * (SSD_T // 512 + 1) // 2
+    fwd = lambda q, k, v: att.attention_pallas(         # noqa: E731
+        q, k, v, 32, 2, 0)
+    bwd = lambda q, k, v, o, l, d: att.attention_pallas_bwd(  # noqa: E731
+        q, k, v, o, l, d, 32, 2, 0)
+    assert _pallas_grids(fwd, q, kv, kv) == {
+        "attention_fwd": ((1, 32, entries), 3)}
+    assert _pallas_grids(bwd, q, kv, kv, q, lse, q) == {
+        "attention_dq": ((1, 32, entries), 3),
+        "attention_dkv": ((1, 2, entries * 16), 4)}
+    assert "attention_fwd" in jax.jit(fwd).lower(
+        q, kv, kv).compile().as_text()
+    text = jax.jit(bwd).lower(q, kv, kv, q, lse, q).compile().as_text()
+    assert "attention_dq" in text and "attention_dkv" in text

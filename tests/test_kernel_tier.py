@@ -348,8 +348,8 @@ def _routed_experts_site(tokens, width):
 
 
 def _grouped_matmul_site(supported):
-    # the experts' width: lanes or not
-    return _routed_experts_site(256, 128 if supported else 64)
+    # the experts' width: whole halves of a lane tile or not
+    return _routed_experts_site(256, 128 if supported else 96)
 
 
 def _moe_combine_site(supported):

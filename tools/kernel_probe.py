@@ -467,9 +467,12 @@ def cases(tiny):
             yield (f"attention_{'bwd' if bwd else 'fwd'}_len{T}_window"
                    f"{window}", "attention",
                    lambda a=(T, heads, kv, window, bwd): attention_case(*a))
-    gm = (256, 2, 128, 128) if tiny else (1024, 8, 2304, 896)
-    yield ("grouped_matmul_{1}x{0}rows_{2}x{3}".format(*gm),
-           "grouped_matmul", lambda a=gm: grouped_matmul_case(*a))
+    # the Mellum2 cell's experts, then the Nemotron cell's: a width of 14.5
+    # lane tiles (tiny: 1.5)
+    for gm in ([(256, 2, 128, 128), (256, 2, 128, 192)] if tiny
+               else [(1024, 8, 2304, 896), (256, 8, 2688, 1856)]):
+        yield ("grouped_matmul_{1}x{0}rows_{2}x{3}".format(*gm),
+               "grouped_matmul", lambda a=gm: grouped_matmul_case(*a))
     mc = (256, 2, 4, 8, 128) if tiny else (8192, 8, 8, 64, 2304)
     yield ("moe_combine_{0}tokens_top{1}_{2}of{3}_width{4}".format(*mc),
            "moe_combine", lambda a=mc: moe_combine_case(*a))
