@@ -65,10 +65,14 @@ Beside it: ``causal_conv1d`` (a causal depthwise convolution, one filter a
 channel, a bias where the op has one, then SiLU), ``kda_decay_gate`` (``g =
 -exp(A_log) * softplus(x + dt_bias)``, float32) and ``gated_rms_norm``
 (RMSNorm per head times a sigmoid gate; ``gate_first``: the Mamba form);
-their grad ops are ``jax.vjp`` of their forwards.
+their grad ops are ``jax.vjp`` of their forwards, but ``causal_conv1d``'s
+where the Pallas family ``causal_conv1d`` takes the op (``_conv_route``: a
+forward and a backward kernel, each one pass over HBM).
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -372,11 +376,25 @@ def gated_delta_rule_grad(ctx):
 # the ops around it: forward functions, grad ops by jax.vjp
 # ---------------------------------------------------------------------------
 
-def _register_with_vjp(op_type, slots, out_slot, fn, doc, out_dtype=None):
+def _vjp_grads(fn, ctx, args, dout):
+    """Gradients of ``fn(ctx, *args)`` to ``args`` by ``jax.vjp``."""
+    # the barrier keeps the compiler from finding the forward op's own
+    # float32 intermediates and holding them from there to here, where
+    # rebuilding them from the (bfloat16) inputs costs one fused pass
+    args, dout = jax.lax.optimization_barrier((args, dout))
+    out, back = jax.vjp(lambda *a: fn(ctx, *a), *args)
+    return back(dout.astype(out.dtype))
+
+
+def _register_with_vjp(op_type, slots, out_slot, fn, doc, out_dtype=None,
+                       grad_fn=None):
     """Register ``op_type`` (inputs ``slots`` -> ``out_slot``, of the first
     input's shape and, but for ``out_dtype``, type) and its grad op,
-    ``jax.vjp`` of the same ``fn(ctx, *inputs)``. A slot the op was built
-    without is left out of the call, and of the grad op."""
+    ``jax.vjp`` of the same ``fn(ctx, *inputs)`` unless the op brings its
+    own ``grad_fn(ctx, inputs, dout)``. A slot the op was built without is
+    left out of the call, and of the grad op."""
+    grad_fn = grad_fn or functools.partial(_vjp_grads, fn)
+
     def maker(op):
         held = [s for s in slots if op.input(s)]
         inputs = {s: op.input(s) for s in held}
@@ -390,15 +408,9 @@ def _register_with_vjp(op_type, slots, out_slot, fn, doc, out_dtype=None):
             data_of(ctx.input(s)) for s in slots if ctx.op.input(s))))
 
     def backward(ctx):
-        # the barrier keeps the compiler from finding the forward op's own
-        # float32 intermediates and holding them from there to here, where
-        # rebuilding them from the (bfloat16) inputs costs one fused pass
         held = [s for s in slots if ctx.op.input(s)]
-        args, dout = jax.lax.optimization_barrier((
-            [data_of(ctx.input(s)) for s in held],
-            data_of(ctx.input(out_slot + "@GRAD"))))
-        out, back = jax.vjp(lambda *a: fn(ctx, *a), *args)
-        grads = back(dout.astype(out.dtype))
+        grads = grad_fn(ctx, [data_of(ctx.input(s)) for s in held],
+                        data_of(ctx.input(out_slot + "@GRAD")))
         for slot, dx in zip(held, grads):
             ctx.set_output(slot + "@GRAD", dx)
 
@@ -425,13 +437,50 @@ def _causal_conv1d(ctx, x, w, bias=None):
     return jax.nn.silu(y).astype(x.dtype)
 
 
+def _conv_route(x, w):
+    """(the kernel module, "pallas" | "jnp"): ONE question for the op and
+    its grad op, so they never disagree. The module is imported here, at
+    the first dispatch, and not with the ops package."""
+    from .pallas import causal_conv1d as cc
+    return cc, "pallas" if use_pallas(
+        "causal_conv1d", cc.supported(x, w)) else "jnp"
+
+
+def _conv_forward(ctx, x, w, bias=None):
+    cc, route = _conv_route(x, w)
+    with kernel_span(route, "causal_conv1d"):
+        if route == "jnp":
+            return _causal_conv1d(ctx, x, w, bias)
+        return cc.causal_conv1d_fwd(x, w, bias)
+
+
+def _conv_backward(ctx, args, dout):
+    cc, route = _conv_route(*args[:2])
+    with kernel_span(route, "causal_conv1d"):
+        if route == "jnp":
+            return _vjp_grads(_causal_conv1d, ctx, args, dout)
+        x, w, *bias = args
+        grads = cc.causal_conv1d_bwd(x, w, bias[0] if bias else None, dout)
+        return [g.astype(a.dtype) for g, a in zip(grads, args)]
+
+
 _register_with_vjp(
-    "causal_conv1d", ("X", "Filter", "Bias"), "Out", _causal_conv1d,
+    "causal_conv1d", ("X", "Filter", "Bias"), "Out", _conv_forward,
     """A causal depthwise convolution over time: ``X`` [b, T, channels],
     ``Filter`` [taps, channels] (one filter a channel; the LAST tap meets
     the current token, the first the token ``taps - 1`` back; before the
     first token lie zeros), plus ``Bias`` [channels] where the op has one,
-    then SiLU. Float32 inside, X's type out.""")
+    then SiLU. Float32 inside, X's type out. By the kernel tier's rule,
+    ``use_pallas("causal_conv1d", supported(x, w))``, the same answer for
+    the op and its grad op: the Pallas family ``causal_conv1d``
+    (ops/pallas/causal_conv1d.py: one kernel each way that reads its
+    inputs from HBM once and writes its outputs once, hand-derived
+    gradients) on a TPU for channels in whole 128-lane widths, tokens in
+    whole sublane tiles, at most 8 taps and a whole time axis that fits a
+    block in VMEM; ``_causal_conv1d`` above and ``jax.vjp`` of it on the
+    CPU, under ``kernel_tier=jnp`` and for any other shape (which under a
+    Pallas tier bumps ``paddle_tpu_pallas_fallbacks{kernel=
+    causal_conv1d}``).""", grad_fn=_conv_backward)
 
 
 def _kda_decay_gate(ctx, x, a_log, dt_bias):

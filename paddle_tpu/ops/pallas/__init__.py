@@ -89,6 +89,26 @@ from ...obs.metrics import REGISTRY as _METRICS
 #                      by one bfloat16 rounding of the outputs (4.5e-3 /
 #                      7.8e-3 of the largest); at the step (four layers)
 #                      172.3 ms against 336.8, six of six pairs
+#   causal_conv1d IN   (PR 37) the mixers' short convolution with its SiLU,
+#                      forward and backward: the whole time axis of 256
+#                      channels in VMEM, shifted copies as loads at a sublane
+#                      offset from a float32 scratch column, hand-derived
+#                      gradients, each array across HBM once. At 4096 tokens,
+#                      4 taps, bfloat16: 4096 channels without a bias
+#                      forward 0.122 ms a call in a chain (0.215 by the
+#                      probe's host clock) against the compiled jnp op's
+#                      0.138 (0.248), backward 0.205 (0.492) against
+#                      0.961 (1.202); 6144 channels with a bias 0.231 /
+#                      0.367 against 0.247 / 2.542; one bfloat16 rounding
+#                      apart, filter and bias gradients 1.2e-5. At the step
+#                      the Kimi-Linear cell (12 + 12 calls) reads 159.8 ms
+#                      against 172.5, four of four pairs, 4.4 ms of it
+#                      `mul_grad` fusions that no longer carry the old
+#                      backward's float32 passes; the Nemotron cell (3 + 3)
+#                      112.6 against 114.9, three of three: its own calls
+#                      fall from 5.56 to 1.10 ms a step, but `ssd_scan` and
+#                      `gated_rms_norm` pay 4.3 ms for operands that now
+#                      arrive row-major (XLA had them time-minor)
 #   conv_bn       out  lowers, but 0.2-0.65x of XLA's conv+BN fusions at
 #                      6 of 7 ResNet-50 shapes; fused flagship step 318.6
 #                      vs 102.5 ms unfused
@@ -100,7 +120,7 @@ from ...obs.metrics import REGISTRY as _METRICS
 #   gru           out  recurrence 1.61x its scan, but no step measured: no
 #                      cell runs a GRU
 AUTO_PALLAS = frozenset({"lstm", "attention", "grouped_matmul",
-                         "moe_combine", "delta_rule"})
+                         "moe_combine", "delta_rule", "causal_conv1d"})
 
 # pallas->jnp silent-fallback counter, in the obs.metrics registry
 # (fallback_counts() derives its historical dict from this family)
@@ -156,8 +176,8 @@ def use_pallas(kernel, supported=True):
 
     ``kernel`` names the kernel family ("lstm", "gru", "ctc", "conv_bn",
     "optimizer", "embedding_sgd", "paged_attention", "attention",
-    "grouped_matmul", "moe_combine", "delta_rule"); ``supported`` is the
-    call site's
+    "grouped_matmul", "moe_combine", "delta_rule", "causal_conv1d");
+    ``supported`` is the call site's
     shape/config predicate. Unsupported shapes under a Pallas tier fall
     back to the jnp twin with a counter bump (never an error).
     """

@@ -37,10 +37,11 @@ def native_lowering(monkeypatch):
     the CPU, so steer that one predicate to compile them natively. The
     persistent cache cannot read such an executable back: keep it off."""
     from jax.experimental.compilation_cache import compilation_cache
-    from paddle_tpu.ops.pallas import (attention, delta_rule,
+    from paddle_tpu.ops.pallas import (attention, causal_conv1d, delta_rule,
                                        grouped_matmul, moe_combine, rnn)
     monkeypatch.setattr(rnn, "_on_cpu", lambda: False)
-    for module in attention, delta_rule, grouped_matmul, moe_combine:
+    for module in (attention, causal_conv1d, delta_rule, grouped_matmul,
+                   moe_combine):
         monkeypatch.setattr(module, "on_cpu", lambda: False)
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
@@ -313,6 +314,49 @@ def test_delta_rule_kernels_compile_for_v5e(one_chip, native_lowering,
             *args, s(jnp.float32, 1, T // chunk, heads, d, d), x,
             heads=heads, chunk=chunk, scale=d ** -0.5)
     assert f"delta_rule_{direction}" in lowered.compile().as_text()
+
+
+# the short convolutions of the Kimi-Linear cell (three a KDA layer over 32
+# heads of 128, no bias) and of the Nemotron cell (one a mixer over x, B and
+# C: 4096 + 2 x 1024 channels, with its bias), 4096 tokens, 4 taps
+@pytest.mark.parametrize("channels,bias", [(4096, False), (6144, True)],
+                         ids=["4096", "6144_bias"])
+def test_causal_conv1d_kernels_compile_for_v5e(one_chip, native_lowering,
+                                               channels, bias):
+    """``causal_conv1d_fwd`` / ``causal_conv1d_bwd`` at both cells' shapes,
+    bfloat16 x and ``Out@GRAD``, float32 filter and bias: blocks of 256
+    channels of the whole time axis (three of them twice and two float32
+    scratch columns are 16 MiB of the kernels' 48), a grid of (channel
+    blocks, 1), and the longest float32 sequence ``supported`` admits
+    still compiles at its block of 128."""
+    from paddle_tpu.ops.pallas import causal_conv1d as cc
+
+    def s(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    x = s(jnp.bfloat16, 1, 4096, channels)
+    w = s(jnp.float32, 4, channels)
+    b = s(jnp.float32, channels) if bias else None
+    assert cc.supported(x, w)
+    assert cc.block_width(x, backward=True) == 256
+    assert cc.vmem_bytes(4096, 256, x.dtype, backward=True) == (
+        12 << 20) + 2 * (4096 + 16) * 512
+    # (the entry points are jitted: their own functions hold the call)
+    assert _pallas_grids(cc.causal_conv1d_fwd.__wrapped__, x, w, b) == {
+        "causal_conv1d_fwd": ((channels // 256, 1), 0)}
+    assert _pallas_grids(cc.causal_conv1d_bwd.__wrapped__, x, w, b, x) == {
+        "causal_conv1d_bwd": ((channels // 256, 1), 0)}
+    assert "causal_conv1d_fwd" in cc.causal_conv1d_fwd.lower(
+        x, w, b).compile().as_text()
+    assert "causal_conv1d_bwd" in cc.causal_conv1d_bwd.lower(
+        x, w, b, x).compile().as_text()
+    if bias:
+        long = s(jnp.float32, 2, 12280, 128)
+        w, b = s(jnp.float32, 4, 128), s(jnp.float32, 128)
+        assert cc.supported(long, w)
+        assert cc.vmem_bytes(12280, 128, long.dtype, True) \
+            <= cc.VMEM_BUDGET < cc.vmem_bytes(12288, 128, long.dtype, True)
+        assert "causal_conv1d_bwd" in cc.causal_conv1d_bwd.lower(
+            long, w, b, long).compile().as_text()
 
 
 def _primitives(jaxpr):

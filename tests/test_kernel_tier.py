@@ -97,8 +97,8 @@ with fluid.program_guard(main, startup):
     prob = fluid.layers.fc(bn, size=10, act="softmax")
     loss = fluid.layers.mean(fluid.layers.cross_entropy(prob, label))
     fluid.optimizer.Momentum(0.01, 0.9).minimize(loss, startup)
-    # a linear-attention layer too: its delta_rule kernels load at the
-    # first dispatch, not here
+    # a linear-attention layer too: its delta_rule and causal_conv1d
+    # kernels load at the first dispatch, not here
     tokens = fluid.layers.data("tokens", shape=[1, 64, 32],
                                append_batch_size=False)
     kda = fluid.layers.kda_attention(tokens, 2, 16, conv_size=4,
@@ -371,6 +371,18 @@ def _delta_rule_site(supported):
                    {"num_heads": heads, "chunk_size": 32})
 
 
+def _causal_conv1d_site(supported):
+    # 32 tokens: two 128-lane columns are the kernels' shape, 192 channels
+    # (a column and a half) the twin's
+    rng = np.random.RandomState(6)
+    channels = 256 if supported else 192
+    x = rng.randn(2, 32, channels).astype(np.float32)
+    w = rng.uniform(-0.5, 0.5, (4, channels)).astype(np.float32)
+    bias = rng.uniform(-0.5, 0.5, channels).astype(np.float32)
+    return _run_op("causal_conv1d", {"X": x, "Filter": w, "Bias": bias},
+                   {"Out": 0}, {})
+
+
 # family -> (the op at a tiny shape, run(supported) -> outputs; Pallas
 # dispatches one supported run traces)
 SITES = {
@@ -385,6 +397,7 @@ SITES = {
     "grouped_matmul": (_grouped_matmul_site, 3),    # gate, up, down
     "moe_combine": (_moe_combine_site, 1),
     "delta_rule": (_delta_rule_site, 1),
+    "causal_conv1d": (_causal_conv1d_site, 1),
 }
 # the other family's dispatches in each run of a site whose op holds two
 # (routed_experts: its products and its combine), whatever the site's own
@@ -533,6 +546,8 @@ AOT_ENTRY_POINTS = {
     "grouped_matmul": ("grouped_matmul", ("gmm", "gmm_t", "tgmm")),
     "moe_combine": ("moe_combine", ("combine",)),
     "delta_rule": ("delta_rule", ("delta_rule_fwd", "delta_rule_bwd")),
+    "causal_conv1d": ("causal_conv1d", ("causal_conv1d_fwd",
+                                        "causal_conv1d_bwd")),
 }
 
 

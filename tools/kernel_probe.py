@@ -21,7 +21,9 @@ bfloat16), forward and backward, a window layer and the full one; the
 grouped product at that cell's expert shape (8 experts of 2304 x 896) and
 the experts' combine at its buffer (18432 rows of 2304 to 8192 tokens);
 the gated delta rule at the Kimi-Linear cell's shape (4096 tokens, 32 heads
-of 128, chunks of 64), forward and backward.
+of 128, chunks of 64), forward and backward; the mixers' short convolution
+at the Kimi-Linear cell's shape (4096 tokens x 4096 channels, 4 taps, no
+bias) and the Nemotron cell's (x 6144, with a bias), forward and backward.
 Every family with a dispatch site in ``paddle_tpu/ops/`` has a case.
 
 A kernel that fails to lower is a RESULT here (``lowered: false`` with the
@@ -349,6 +351,35 @@ def delta_rule_case(T, heads, d, chunk, backward):
             "pallas": route(dr.delta_rule_fwd, dr.delta_rule_bwd)}
 
 
+def causal_conv1d_case(T, channels, taps, bias, backward):
+    """The mixers' short convolution with its SiLU: the two kernels vs the
+    jnp op and ``jax.vjp`` of it (what the grad op runs off the tier, its
+    barrier included), bfloat16 x and ``Out@GRAD``, float32 filter and
+    bias."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import linear_attention_ops as la
+    from paddle_tpu.ops.pallas import causal_conv1d as cc
+
+    keys = jax.random.split(jax.random.PRNGKey(taps), 4)
+    x, dout = (jax.random.normal(k, (1, T, channels), jnp.bfloat16)
+               for k in keys[:2])
+    w = jax.random.uniform(keys[2], (taps, channels), minval=-0.5, maxval=0.5)
+    b = jax.random.uniform(keys[3], (channels,), minval=-0.5, maxval=0.5) \
+        if bias else None
+    assert cc.supported(x, w)
+    args = (x, w) + ((b,) if bias else ())
+    if not backward:
+        twin = jax.jit(lambda *a: la._causal_conv1d(None, *a))
+        return {"jnp": lambda: twin(*args),
+                "pallas": lambda: cc.causal_conv1d_fwd(x, w, b)}
+    twin = jax.jit(lambda dout, *a: la._vjp_grads(
+        la._causal_conv1d, None, list(a), dout))
+    return {"jnp": lambda: twin(dout, *args),
+            "pallas": lambda: tuple(g for g in cc.causal_conv1d_bwd(
+                x, w, b, dout) if g is not None)}
+
+
 def momentum_case(shapes):
     """One fused-momentum step over ``shapes``: the arena megakernel (with
     the concat/split the fused op pays) vs the per-param twin."""
@@ -481,6 +512,15 @@ def cases(tiny):
         yield (f"delta_rule_{'bwd' if bwd else 'fwd'}_len{T}_{heads}x128"
                "_chunk64", "delta_rule",
                lambda a=(T, heads, 128, 64, bwd): delta_rule_case(*a))
+    # the Kimi-Linear cell's three convolutions a KDA layer, then the
+    # Nemotron cell's one a mixer
+    for conv in ([(64, 256, 4, True)] if tiny
+                 else [(4096, 4096, 4, False), (4096, 6144, 4, True)]):
+        for bwd in (False, True):
+            yield ("causal_conv1d_{4}_len{0}_{1}ch_{2}taps{3}".format(
+                *conv[:3], "_bias" if conv[3] else "",
+                "bwd" if bwd else "fwd"), "causal_conv1d",
+                lambda a=conv + (bwd,): causal_conv1d_case(*a))
 
 
 def lstm_lane_step(tiny, rounds=3):
