@@ -53,15 +53,24 @@ class CacheStats:
             self.misses += 1
 
 
+_listening = None       # the CacheStats whose listener is registered
+
+
 def enable():
     """Turn the persistent compilation cache on at :func:`resolve_dir`.
-    Returns ``(directory, CacheStats)``. Call before the first compile."""
+    Returns ``(directory, CacheStats)``, the counts starting at zero. Call
+    before the first compile. A second call in one process takes the
+    first call's listener off JAX's monitoring bus before it puts its own
+    on: one listener a process, however often this is called."""
+    global _listening
     import jax
 
     path = resolve_dir()
     if not os.environ.get(ENV_VAR):
         jax.config.update("jax_compilation_cache_dir", path)
-    stats = CacheStats()
+    if _listening is not None:
+        jax.monitoring.unregister_event_listener(_listening._on_event)
+    _listening = stats = CacheStats()
     jax.monitoring.register_event_listener(stats._on_event)
     return path, stats
 

@@ -25,6 +25,7 @@ temporaries in a dropped local scope). The compiled step function is pure:
 from __future__ import annotations
 
 import weakref
+from time import perf_counter as _perf_counter
 
 import numpy as np
 import jax
@@ -37,6 +38,7 @@ from .lod import LoDArray, flat_to_lodarray, pack_sequences
 from .scope import Scope, global_scope
 from .types import np_dtype
 from ..obs.metrics import REGISTRY as _METRICS
+from ..obs import perf as _perf
 
 _RNG_KEY = "__rng_key__"
 
@@ -540,49 +542,48 @@ class _InstrumentedFn:
     see) and lands every build as a ``paddle_tpu_compile_seconds``
     observation + CompileRecord + ``compile`` flight event, labeled by
     the active ``obs.perf.compile_site`` (engines set theirs) or this
-    wrapper's default kind. With the layer off (``obs_compile_log`` 0 —
-    NOT in ``_JIT_KEY_FLAGS``, flipping never retraces) a dispatch pays
-    one flag lookup."""
+    wrapper's default kind. While the call is under way the thread's
+    ``obs.perf`` build state names it as the owner, so the stage seconds
+    JAX reports (trace, lower, XLA compile, cache load) go to the build
+    found after it and not to the ``eager`` site. With the layer off
+    (``obs_compile_log`` 0 — NOT in ``_JIT_KEY_FLAGS``, flipping never
+    retraces) a dispatch pays one flag lookup."""
 
-    __slots__ = ("_fn", "_kind", "_version")
+    __slots__ = ("_fn", "_kind", "_version", "_n_ops", "_n_fetch")
 
-    def __init__(self, fn, kind, version):
+    def __init__(self, fn, kind, program, n_fetch):
         self._fn = fn
         self._kind = kind
-        self._version = version
+        self._version = program._version
+        self._n_ops = len(program.global_block().ops)
+        self._n_fetch = n_fetch
 
     def __call__(self, state, feeds, *rest):
         # *rest carries the optional donated-feed dict (KV-arena
         # donation, _compiled(donate_feed_names=...)) through untouched
-        from ..obs import perf as _perf
         if not _perf.enabled():
             return self._fn(state, feeds, *rest)
-        import time as _time
         try:
             before = self._fn._cache_size()
         except Exception:
             before = None
-        t0 = _time.perf_counter()
-        out = self._fn(state, feeds, *rest)
+        t0 = _perf_counter()
+        with _perf.building():
+            out = self._fn(state, feeds, *rest)
         if before is not None:
             try:
                 grew = self._fn._cache_size() > before
             except Exception:
                 grew = False
             if grew:
-                dt = _time.perf_counter() - t0
+                dt = _perf_counter() - t0
                 site, detail = _perf.current_site(default=self._kind)
                 identity = dict(detail)
                 identity.setdefault("program_version", self._version)
+                identity["n_ops"] = self._n_ops
+                identity["n_fetch"] = self._n_fetch
                 identity["feeds"] = _feed_shapes(feeds)
-                flops = bytes_accessed = None
-                from .flags import get_flag as _gf
-                if _gf("obs_compile_cost"):
-                    flops, bytes_accessed = _perf.harvest_cost(
-                        self._fn, state, feeds)
-                _perf.note_compile(site, dt, identity=identity,
-                                   flops=flops,
-                                   bytes_accessed=bytes_accessed)
+                _perf.note_compile(site, dt, identity=identity)
         return out
 
     def lower(self, *args, **kwargs):
@@ -868,7 +869,7 @@ class Executor:
         donate = (0,) if self.donate else ()
         fn = _InstrumentedFn(
             tpu_jit(_scheme_named(multi, "multi"), donate_argnums=donate),
-            "jit_scan", program._version)
+            "jit_scan", program, len(fetch_names))
         self._cache[key] = fn
         return fn
 
@@ -925,7 +926,7 @@ class Executor:
             tpu_jit(_scheme_named(step, "step"),
                     auto_state_layout=self.auto_layout,
                     donate_argnums=donate),
-            "jit_step", program._version)
+            "jit_step", program, len(fetch_names))
         self._cache[key] = fn
         return fn
 

@@ -467,22 +467,13 @@ DEFINE_flag("obs_flight_events", 2048,
 
 DEFINE_flag("obs_compile_log", 256,
             "capacity of the per-process obs.perf CompileLog ring: how "
-            "many recent CompileRecords (site, wall seconds, executable "
-            "identity, optional cost_analysis flops/bytes) are retained "
+            "many recent CompileRecords (site, wall seconds and their "
+            "split by stage, executable identity) are retained "
             "for stats()/bench stamps; 0 disables compile telemetry "
-            "entirely (no histogram observations, no records, no "
-            "'compile' flight events). NOT in the executor jit key — "
-            "flipping it never retraces")
-
-DEFINE_flag("obs_compile_cost", False,
-            "harvest compiled.cost_analysis() flops/bytes-accessed into "
-            "each CompileRecord by AOT-lowering the just-built "
-            "executable. The backend compiles the computation a SECOND "
-            "time for the harvest (jax shares the trace but not the "
-            "executable between jit dispatch and AOT lower().compile()), "
-            "so this roughly doubles compile cost — a profiling-session "
-            "switch, off by default. Not in the jit key: flipping never "
-            "retraces")
+            "entirely (no histogram observations, no stage counters, no "
+            "records, no 'compile' flight events; the listener on JAX's "
+            "monitoring events returns at once). NOT in the executor jit "
+            "key — flipping it never retraces")
 
 DEFINE_flag("obs_incident_dir", "",
             "directory obs.recorder.IncidentCollector writes incident "

@@ -10,13 +10,15 @@ profile-driven kernel work needs:
   prefill/chunk/decode clones, ``run_steps`` scans) lands a
   ``paddle_tpu_compile_seconds`` observation labeled by *site*, a
   :class:`CompileRecord` in the bounded per-process :data:`COMPILE_LOG`
-  (wall time, bucket/program identity, ``cost_analysis()`` flops /
-  bytes-accessed when harvested — the ``obs_compile_cost`` flag), and a
+  (wall time, bucket/program identity, and the wall time split by
+  *stage*: trace / lower / xla_compile / cache_load / other, from JAX's
+  own monitoring events — see ``_on_duration``), and a
   ``compile`` flight-recorder event carrying the active trace id, so a
   rollout that pays warmup compiles is visible in the incident bundle.
   The existing ``paddle_tpu_executor_retraces`` counter says *that*
-  something retraced; this layer says *which* executable and *what it
-  cost*. Detection rides the jit trace-cache size (one C++ probe per
+  something retraced; this layer says *which* executable, *what it
+  cost* and *where the cost went*. Detection rides the jit trace-cache
+  size (one C++ probe per
   dispatch, ~0.02 us), so per-bucket internal retraces of one compiled
   fn are each attributed. The ``obs_compile_log`` flag (capacity; 0
   disables) is deliberately NOT in the executor's ``_JIT_KEY_FLAGS`` —
@@ -53,15 +55,25 @@ from contextlib import contextmanager
 from ..core.flags import get_flag
 from .metrics import REGISTRY as _METRICS, json_safe
 
-# the obs_compile_log / obs_compile_cost flags are DEFINEd in
-# core/flags.py with every other flag (check_flags_doc.py regex-scans
-# that one file)
+# the obs_compile_log flag is DEFINEd in core/flags.py with every other
+# flag (check_flags_doc.py regex-scans that one file)
 
 _M_COMPILE_SECONDS = _METRICS.histogram(
     "paddle_tpu_compile_seconds",
     "wall seconds per compiled-executable build (trace + XLA compile + "
     "the dispatch that triggered it), labeled by compile site",
     labels=("site",), span_name="perf/compile", span_kind="stage")
+_M_STAGE_SECONDS = _METRICS.counter(
+    "paddle_tpu_compile_stage_seconds",
+    "seconds spent building executables, by compile site and stage "
+    "(trace / lower / xla_compile / cache_load / other); site=eager holds "
+    "the builds no executor-owned function asked for",
+    labels=("site", "stage"))
+_M_BUILDS = _METRICS.counter(
+    "paddle_tpu_compile_builds",
+    "executables the backend handed back, by compile site and source "
+    "(compiled by XLA / cache: loaded from the persistent compile cache)",
+    labels=("site", "source"))
 _M_BYTES_LIVE = _METRICS.gauge(
     "paddle_tpu_device_bytes_live",
     "live device memory bytes per local device — backend memory_stats "
@@ -98,43 +110,62 @@ def enabled():
     return int(get_flag("obs_compile_log")) > 0
 
 
+STAGES = ("trace", "lower", "xla_compile", "cache_load", "other")
+
+
 class CompileRecord:
     """One compiled-executable build: where it happened (``site``), what
     it cost (``seconds`` wall: trace + XLA compile + the dispatch that
     triggered it), which executable (``identity`` — bucket / phase /
-    feed shapes / program version, site-dependent; engines with a
-    persistent executable cache stamp a ``cache_hit`` detail field:
-    False marks the compile a warm replica would have skipped), and the
-    backend's
-    ``cost_analysis()`` ``flops`` / ``bytes_accessed`` when harvested
-    (``obs_compile_cost``; None otherwise)."""
+    feed shapes / program version, op and fetch counts, site-dependent;
+    engines with a persistent executable cache stamp a ``cache_hit``
+    detail field: False marks the compile a warm replica would have
+    skipped), and where the seconds went (``stages``: one entry per
+    :data:`STAGES`, summing to ``seconds``; ``cache_read`` is the part of
+    ``cache_load`` spent reading the persistent cache's entry)."""
 
-    __slots__ = ("site", "seconds", "t", "identity", "flops",
-                 "bytes_accessed", "trace", "seq")
+    __slots__ = ("site", "seconds", "t", "identity", "stages", "cache_read",
+                 "trace", "seq")
 
-    def __init__(self, site, seconds, identity=None, flops=None,
-                 bytes_accessed=None, trace=None):
+    def __init__(self, site, seconds, identity=None, stages=None,
+                 cache_read=0.0, trace=None):
         self.site = str(site)
         self.seconds = float(seconds)
         self.t = time.time()
         self.identity = json_safe(identity or {})
-        self.flops = None if flops is None else float(flops)
-        self.bytes_accessed = None if bytes_accessed is None \
-            else float(bytes_accessed)
+        self.stages = split_seconds(self.seconds, stages or ())
+        self.cache_read = float(cache_read)
         self.trace = trace
         self.seq = 0
 
     def as_dict(self):
         return json_safe({
             "site": self.site, "seconds": self.seconds, "t": self.t,
-            "identity": self.identity, "flops": self.flops,
-            "bytes_accessed": self.bytes_accessed, "trace": self.trace,
+            "identity": self.identity, "stages": self.stages,
+            "cache_read": self.cache_read, "trace": self.trace,
             "seq": self.seq,
         })
 
     def __repr__(self):
         return (f"CompileRecord({self.site!r}, {self.seconds:.3f}s, "
                 f"identity={self.identity})")
+
+
+def split_seconds(seconds, measured):
+    """``{stage: seconds}`` over :data:`STAGES` for a build of ``seconds``
+    wall whose first four stages were ``measured`` (JAX's events; missing
+    ones are 0): ``other`` is what they leave, never negative. JAX clocks
+    its events with ``time.time()`` and a build is clocked here with
+    ``perf_counter()``; should the four pass the wall (a stepped clock)
+    they are scaled to it, so the five always sum to ``seconds``."""
+    four = ([max(float(x), 0.0) for x in measured] + [0.0] * 4)[:4]
+    total = sum(four)
+    if total > seconds:
+        four = [x * seconds / total for x in four]
+        total = seconds
+    out = dict(zip(STAGES, four))
+    out["other"] = max(seconds - total, 0.0)
+    return out
 
 
 class CompileLog:
@@ -188,16 +219,21 @@ class CompileLog:
 
     def stats(self):
         """``{count, total_seconds, by_site}`` — count/total cover the
-        process lifetime (not just the ring window)."""
+        process lifetime (not just the ring window); ``by_site`` holds the
+        window's count, seconds and seconds by stage."""
         self._check_fork()
         with self._lock:
             recs = list(self._ring_locked())
             count, total = self._seq, self._total_seconds
         by_site = {}
         for r in recs:
-            s = by_site.setdefault(r.site, {"count": 0, "seconds": 0.0})
+            s = by_site.setdefault(r.site, {"count": 0, "seconds": 0.0,
+                                            "stages": dict.fromkeys(
+                                                STAGES, 0.0)})
             s["count"] += 1
             s["seconds"] += r.seconds
+            for stage, x in r.stages.items():
+                s["stages"][stage] += x
         return json_safe({"count": count,
                           "total_seconds": total,
                           "by_site": by_site})
@@ -243,44 +279,205 @@ def current_site(default="jit_step"):
     return v
 
 
-def note_compile(site, seconds, identity=None, flops=None,
-                 bytes_accessed=None):
+# ---------------------------------------------------------------------------
+# compile stages: where a build's seconds went
+# ---------------------------------------------------------------------------
+_EV_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+_EV_LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_EV_BACKEND = "/jax/core/compile/backend_compile_duration"
+_EV_CACHE_READ = "/jax/compilation_cache/cache_retrieval_time_sec"
+_EV_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+# a span's event -> index into STAGES (a backend compile that was served
+# from the cache moves on to ``cache_load``)
+_SPAN_STAGE = {_EV_TRACE: 0, _EV_LOWER: 1, _EV_BACKEND: 2}
+_SOURCES = ("compiled", "cache")
+EAGER = "eager"
+# the ``eager`` site's children, made once: its seconds are credited from
+# inside the listener
+_EAGER_SECONDS = [_M_STAGE_SECONDS.labels(site=EAGER, stage=s)
+                  for s in STAGES[:4]]
+_EAGER_BUILDS = [_M_BUILDS.labels(site=EAGER, source=s) for s in _SOURCES]
+
+
+class _Building:
+    """One thread's builds in progress. ``owners``: how many owned builds
+    (an ``_InstrumentedFn`` call, ``with building():``) are under way on the
+    thread; while it is 0 the listener credits what arrives to the site
+    ``eager``. ``seconds`` / ``builds`` / ``cache_read``: what arrived
+    since the owner last took it. ``open``: spans entered and not yet
+    left, each ``[event, its children's seconds, a cache hit inside]``."""
+
+    __slots__ = ("owners", "seconds", "builds", "cache_read", "open")
+
+    def __init__(self):
+        self.owners = 0
+        self.open = []
+        self.drop()
+
+    def drop(self):
+        """Forget what arrived (a build that raised built nothing)."""
+        self.seconds = [0.0, 0.0, 0.0, 0.0]
+        self.builds = [0, 0]
+        self.cache_read = 0.0
+
+    def take(self):
+        """(stage seconds, builds by source, cache_read) since the last
+        take, handed over to the caller."""
+        out = self.seconds, self.builds, self.cache_read
+        self.drop()
+        return out
+
+    def __enter__(self):
+        self.owners += 1
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        self.owners -= 1
+        if exc_type is not None:
+            self.drop()
+        return False
+
+
+class _PerThread(threading.local):
+    def __init__(self):
+        self.building = _Building()
+
+
+_THREAD = _PerThread()
+
+
+def building():
+    """The calling thread's :class:`_Building`. ``with building():`` marks
+    an owned build: stage seconds that arrive inside belong to the
+    :func:`note_compile` that follows, not to ``eager``."""
+    return _THREAD.building
+
+
+def _on_span_enter(event, _start_time, **_kw):
+    if event in _SPAN_STAGE and enabled():
+        _THREAD.building.open.append([event, 0.0, False])
+
+
+def _on_event(event, **_kw):
+    if event == _EV_CACHE_HIT and enabled():
+        open_ = _THREAD.building.open
+        if open_:
+            open_[-1][2] = True
+
+
+def _on_duration(event, seconds, **_kw):
+    """The listener for JAX's duration events: credits a span's own seconds
+    to its stage, on this thread's owned build or on ``eager``.
+
+    JAX 0.9.0 times the parts of a build itself and publishes them on
+    jax.monitoring, the bus core/compile_cache.CacheStats already counts
+    hits and misses from. As read from the installed jax/_src:
+
+    * pjit.py ``_create_pjit_jaxpr`` wraps ``trace_to_jaxpr`` in
+      ``dispatch.log_elapsed_time(..., event=JAXPR_TRACE_EVENT)``. For an
+      executor-owned function that is ``_run_ops`` over every Fluid op of
+      the program, the op lowerings' Python, the kernel tier's routing and
+      lazy kernel imports. Every jitted ``jnp`` function called for the
+      first time INSIDE that trace opens a span of the same event inside
+      the outer one.
+    * interpreters/pxla.py ``lower_sharding_computation`` wraps
+      ``mlir.lower_jaxpr_to_module`` in JAXPR_TO_MLIR_MODULE_EVENT (a
+      lowering rule that traces a jitted function opens a trace span
+      inside it).
+    * pxla.py ``_cached_compilation`` wraps ``compiler.
+      compile_or_get_cached`` WHOLE in BACKEND_COMPILE_EVENT: hashing the
+      module for its cache key, then either the cache's read and
+      deserialisation (compiler.py records the event ``cache_hits`` and
+      the duration ``cache_retrieval_time_sec``, the read alone, inside
+      the span) or XLA's compile (Mosaic's for a Pallas kernel) and the
+      write into the cache (``cache_misses`` where it is written).
+    * ``log_elapsed_time.__enter__`` announces each span with
+      ``record_scalar(event, start_time)``; ``__exit__`` records the
+      duration. A duration holds everything nested in it.
+
+    So a span's OWN seconds are its duration less its children's, and the
+    own seconds of all spans of a build sum to no more than its wall time.
+    The listeners keep, per thread, the stack of open spans and four
+    floats; they run only while something is being built, never in a steady
+    step. ``backend_compile_duration`` counts as ``cache_load`` where a
+    ``cache_hits`` event arrived inside it and as ``xla_compile`` otherwise.
+    """
+    stage = _SPAN_STAGE.get(event)
+    if stage is None:
+        if event == _EV_CACHE_READ and enabled():
+            b = _THREAD.building
+            if b.owners:
+                b.cache_read += seconds
+        return
+    if not enabled():
+        return
+    b = _THREAD.building
+    open_ = b.open
+    children, hit = 0.0, False
+    while open_:                # spans close innermost first
+        ev, inside, hit_inside = open_.pop()
+        if ev == event:
+            children, hit = inside, hit_inside
+            break
+    if open_:
+        open_[-1][1] += seconds
+    own = max(seconds - children, 0.0)
+    built = event == _EV_BACKEND        # an executable was handed back
+    if built and hit:
+        stage += 1                      # xla_compile -> cache_load
+    if b.owners:
+        b.seconds[stage] += own
+        b.builds[hit] += built
+    else:
+        _EAGER_SECONDS[stage].inc(own)
+        if built:
+            _EAGER_BUILDS[hit].inc()
+
+
+def _install_listeners():
+    """Once a process (this module's import): JAX's monitoring bus offers
+    no way to ask whether a listener is on it."""
+    from jax import monitoring
+    monitoring.register_scalar_listener(_on_span_enter)
+    monitoring.register_event_listener(_on_event)
+    monitoring.register_event_duration_secs_listener(_on_duration)
+
+
+_install_listeners()
+
+
+def note_compile(site, seconds, identity=None):
     """Land one compiled-executable build in the telemetry layer:
-    histogram observation (labeled by site), CompileRecord in
-    :data:`COMPILE_LOG`, and a ``compile`` flight-recorder event (which
+    histogram observation (labeled by site), the stage seconds and builds
+    that arrived on this thread since the last build (they are this
+    build's: see :class:`_Building`) into the two counter families,
+    CompileRecord in :data:`COMPILE_LOG`, and a ``compile``
+    flight-recorder event (which
     carries the active distributed trace id — a reload RPC's warmup
     compiles join the rollout's trace). No-op when the layer is off."""
     if not enabled():
         return None
-    rec = CompileRecord(site, seconds, identity=identity, flops=flops,
-                        bytes_accessed=bytes_accessed)
+    measured, builds, cache_read = _THREAD.building.take()
+    rec = CompileRecord(site, seconds, identity=identity, stages=measured,
+                        cache_read=cache_read)
     from .recorder import record as _flight_record
     _M_COMPILE_SECONDS.labels(site=rec.site).observe(rec.seconds)
+    for stage, x in rec.stages.items():
+        if x:
+            _M_STAGE_SECONDS.labels(site=rec.site, stage=stage).inc(x)
+    for source, n in zip(_SOURCES, builds):
+        if n:
+            _M_BUILDS.labels(site=rec.site, source=source).inc(n)
     ev = _flight_record("compile", component=rec.site,
                         seconds=round(rec.seconds, 4),
+                        stages={k: round(v, 4)
+                                for k, v in rec.stages.items()},
                         **{k: v for k, v in rec.identity.items()
                            if k in ("bucket", "phase", "instance",
                                     "program_version", "cache_hit")})
     rec.trace = ev.get("trace")
     COMPILE_LOG.add(rec)
     return rec
-
-
-def harvest_cost(fn, *args):
-    """Best-effort ``cost_analysis()`` totals of ``fn`` AOT-lowered at
-    ``args`` — ``(flops, bytes_accessed)``, (None, None) when the
-    backend provides nothing. The backend compiles a second executable
-    for this (jit dispatch and AOT lower().compile() do not share), so
-    callers gate it (``obs_compile_cost``)."""
-    try:
-        ca = fn.lower(*args).compile().cost_analysis()
-        if isinstance(ca, (list, tuple)):
-            ca = ca[0] if ca else {}
-        if not isinstance(ca, dict):
-            return None, None
-        return ca.get("flops"), ca.get("bytes accessed")
-    except Exception:
-        return None, None
 
 
 # ---------------------------------------------------------------------------
@@ -664,8 +861,9 @@ def attribute(target, feed=None, fetch_list=None, batch=1, top=40,
                 "(bundle dirs and engines synthesize their own)")
 
     t0 = time.perf_counter()
-    _lowered, compiled = lower_program(program, feed, fetch_list,
-                                       executor=executor, scope=scope)
+    with building():
+        _lowered, compiled = lower_program(program, feed, fetch_list,
+                                           executor=executor, scope=scope)
     compile_seconds = time.perf_counter() - t0
     cost = cost_totals(compiled)
     hlo = compiled.as_text()
@@ -675,9 +873,7 @@ def attribute(target, feed=None, fetch_list=None, batch=1, top=40,
     rows, kind_totals = hlo_entry_rows(hlo)
     note_compile("attribute", compile_seconds,
                  identity={"fetch": [f if isinstance(f, str) else f.name
-                                     for f in fetch_list][:4]},
-                 flops=cost.get("flops"),
-                 bytes_accessed=cost.get("bytes_accessed"))
+                                     for f in fetch_list][:4]})
     out = {
         "cost": cost,
         "kind_totals": dict(sorted(kind_totals.items(),
@@ -723,8 +919,8 @@ def per_op_rows(rows, total_flops=None):
 
 __all__ = [
     "COMPILE_LOG", "CompileLog", "CompileRecord", "MemorySampler",
-    "attribute", "compile_site", "cost_totals",
-    "current_site", "enabled", "harvest_cost", "hlo_entry_rows",
+    "STAGES", "attribute", "building", "compile_site", "cost_totals",
+    "current_site", "enabled", "hlo_entry_rows",
     "hlo_shape_bytes", "lower_program", "memory_section", "note_compile",
     "per_op_rows", "program_jaxpr", "sample_device_memory", "template_feed",
 ]

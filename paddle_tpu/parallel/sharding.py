@@ -336,13 +336,15 @@ def shard_program_step(executor, program, feed_example, fetch_list, plan,
 
     # pin state shardings on both sides so the step iterates; tpu_jit
     # forwards the xla_compiler_options flag to the backend compiler
-    from ..core.executor import tpu_jit
-    jitted = tpu_jit(
+    # (the wrapper lands the step's builds in obs.perf's compile
+    # telemetry, site "sharded_step", as Executor._compiled's are)
+    from ..core.executor import _InstrumentedFn, tpu_jit
+    jitted = _InstrumentedFn(tpu_jit(
         _scheme_named(step, "sharded_step"),
         in_shardings=(state_shardings, feed_shardings),
         out_shardings=(state_shardings, None),
         donate_argnums=(0,) if donate else (),
-    )
+    ), "sharded_step", program, len(fetch_names))
 
     step_num = 0
 

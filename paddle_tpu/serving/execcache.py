@@ -229,9 +229,10 @@ def compile_and_save(cache, fp, program, feed, fetch_names, executor,
     from ..obs import perf as _perf
 
     t0 = time.perf_counter()
-    _lowered, compiled = _perf.lower_program(
-        program, feed, list(fetch_names), executor=executor, scope=scope,
-        donate_feeds=donate_feeds)
+    with _perf.building():
+        _lowered, compiled = _perf.lower_program(
+            program, feed, list(fetch_names), executor=executor,
+            scope=scope, donate_feeds=donate_feeds)
     seconds = time.perf_counter() - t0
     ident = dict(identity or {})
     ident["tag"] = fp["tag"]

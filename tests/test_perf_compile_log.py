@@ -91,7 +91,6 @@ def test_run_steps_scan_attributed_to_jit_scan():
 def test_flag_off_disables_layer_and_never_retraces():
     from paddle_tpu.core.executor import _JIT_KEY_FLAGS
     assert "obs_compile_log" not in _JIT_KEY_FLAGS
-    assert "obs_compile_cost" not in _JIT_KEY_FLAGS
 
     main, startup, loss = build_mlp()
     exe = fluid.Executor()
@@ -117,22 +116,6 @@ def test_flag_off_disables_layer_and_never_retraces():
     # back on: the layer resumes without retracing the old shapes
     exe.run(main, feed=mlp_feed(4), fetch_list=[loss], scope=scope)
     assert REGISTRY.get("paddle_tpu_executor_retraces").total() == retraces
-
-
-def test_obs_compile_cost_harvests_cost_analysis():
-    main, startup, loss = build_mlp(hidden=8, seed=11)
-    exe = fluid.Executor()
-    scope = fluid.Scope()
-    fluid.set_flags({"obs_compile_cost": True})
-    try:
-        exe.run(startup, scope=scope)
-        exe.run(main, feed=mlp_feed(4), fetch_list=[loss], scope=scope)
-    finally:
-        fluid.set_flags({"obs_compile_cost": False})
-    step = perf.COMPILE_LOG.records()[-1]
-    # the CPU backend provides cost_analysis — flops/bytes must land
-    assert step.flops is not None and step.flops > 0
-    assert step.bytes_accessed is not None and step.bytes_accessed > 0
 
 
 def test_compile_log_ring_bounded_and_stats():

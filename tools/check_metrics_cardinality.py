@@ -58,9 +58,14 @@ BOUNDED_LABELS = {
     "trigger": "incident trigger enums: breach/canary_failed/"
                "child_restart/manual",
     "site": "compile-site enums (obs.perf: jit_step/jit_scan/"
-            "engine_warmup/engine_infer/genengine_*/attribute/"
-            "exec_cache_save) — a fixed code-site set; per-executable "
+            "sharded_step/engine_warmup/engine_infer/genengine_*/"
+            "attribute/exec_cache_save, and eager for builds no such "
+            "site owns) — "
+            "a fixed code-site set; per-executable "
             "identity rides the CompileRecord, never a label",
+    "stage": "compile stages (obs.perf.STAGES): trace/lower/"
+             "xla_compile/cache_load/other",
+    "source": "where a built executable came from: compiled/cache",
     "reason": "artifact reject reasons — the fixed enums "
               "serving.execcache.REJECT_REASONS (format/manifest/"
               "fingerprint/deserialize/run_failed), "
