@@ -109,6 +109,24 @@ from ...obs.metrics import REGISTRY as _METRICS
 #                      fall from 5.56 to 1.10 ms a step, but `ssd_scan` and
 #                      `gated_rms_norm` pay 4.3 ms for operands that now
 #                      arrive row-major (XLA had them time-minor)
+#   ssd_scan      IN   (PR 39) the Mamba-2 state-space core, forward and
+#                      backward: a grid step is a group's heads of one
+#                      chunk, `C B^T` once a group, the pairwise decay, the
+#                      read and the contribution as values in VMEM, the
+#                      group's [R * P, N] states (or their gradient) carried
+#                      in scratch, hand-derived gradients; operands stay
+#                      row-major. At 4096 tokens, 64 heads of 64 in 8 groups
+#                      of state 128, chunks of 128, bfloat16, the Nemotron
+#                      configuration's decays, `_prepare` on both sides:
+#                      forward 0.454 ms on the device (0.668 by the probe's
+#                      host clock) against the chunked jnp program's 1.720
+#                      (1.946), backward 0.973 (1.271) against 3.293
+#                      (3.590); outputs one bfloat16 rounding apart, every
+#                      gradient nearer the float32 core than the twin's. At
+#                      the step (three layers) the Nemotron cell reads
+#                      105.3 ms against 112.8, six of six pairs: the 2.5 ms
+#                      the jnp core paid to change its operands' layout
+#                      went with it
 #   conv_bn       out  lowers, but 0.2-0.65x of XLA's conv+BN fusions at
 #                      6 of 7 ResNet-50 shapes; fused flagship step 318.6
 #                      vs 102.5 ms unfused
@@ -120,7 +138,8 @@ from ...obs.metrics import REGISTRY as _METRICS
 #   gru           out  recurrence 1.61x its scan, but no step measured: no
 #                      cell runs a GRU
 AUTO_PALLAS = frozenset({"lstm", "attention", "grouped_matmul",
-                         "moe_combine", "delta_rule", "causal_conv1d"})
+                         "moe_combine", "delta_rule", "causal_conv1d",
+                         "ssd_scan"})
 
 # pallas->jnp silent-fallback counter, in the obs.metrics registry
 # (fallback_counts() derives its historical dict from this family)
@@ -176,7 +195,8 @@ def use_pallas(kernel, supported=True):
 
     ``kernel`` names the kernel family ("lstm", "gru", "ctc", "conv_bn",
     "optimizer", "embedding_sgd", "paged_attention", "attention",
-    "grouped_matmul", "moe_combine", "delta_rule", "causal_conv1d");
+    "grouped_matmul", "moe_combine", "delta_rule", "causal_conv1d",
+    "ssd_scan");
     ``supported`` is the call site's
     shape/config predicate. Unsupported shapes under a Pallas tier fall
     back to the jnp twin with a counter bump (never an error).

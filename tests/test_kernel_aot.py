@@ -38,10 +38,11 @@ def native_lowering(monkeypatch):
     persistent cache cannot read such an executable back: keep it off."""
     from jax.experimental.compilation_cache import compilation_cache
     from paddle_tpu.ops.pallas import (attention, causal_conv1d, delta_rule,
-                                       grouped_matmul, moe_combine, rnn)
+                                       grouped_matmul, moe_combine, rnn,
+                                       ssd_scan)
     monkeypatch.setattr(rnn, "_on_cpu", lambda: False)
     for module in (attention, causal_conv1d, delta_rule, grouped_matmul,
-                   moe_combine):
+                   moe_combine, ssd_scan):
         monkeypatch.setattr(module, "on_cpu", lambda: False)
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
@@ -489,6 +490,71 @@ def test_the_ssd_core_compiles_for_v5e_inside_a_gigabyte(one_chip):
         compiled = jax.jit(fn).lower(*operands).compile()
         assert " while(" not in compiled.as_text()
         assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_ssd_scan_kernels_compile_for_v5e(one_chip, native_lowering,
+                                          direction):
+    """``ssd_scan_fwd`` / ``ssd_scan_bwd`` at the cell's shape, bfloat16 x,
+    B, C and ``Out@GRAD``, float32 step, log-decays and kept states: a grid
+    of (batch, 8 groups, 32 chunks), blocks of [128, 512] and [128, 128], a
+    backward step's blocks and values inside ``vmem_bytes``'s count; the
+    op's own dispatch reaches them under ``kernel_tier=pallas``, and heads
+    of 48 do not reach Mosaic."""
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.ops import state_space_ops as ss
+    from paddle_tpu.ops.pallas import ssd_scan as kernels
+
+    def s(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    chunks = SSD_T // SSD_CHUNK
+    x = s(jnp.bfloat16, 1, SSD_T, SSD_HEADS * SSD_P)
+    bc = s(jnp.bfloat16, 1, SSD_T, SSD_GROUPS * SSD_N)
+    head = s(jnp.float32, SSD_HEADS)
+    steps = s(jnp.float32, 1, chunks, SSD_CHUNK, SSD_HEADS)
+    states = s(jnp.float32, 1, chunks, SSD_HEADS, SSD_P, SSD_N)
+    assert kernels.supported(x, bc, SSD_HEADS, SSD_GROUPS, SSD_CHUNK)
+    assert kernels.vmem_bytes(SSD_CHUNK, 512, SSD_N, x.dtype) < 8 << 20
+    args = (x, bc, bc, head, steps, steps) + (
+        () if direction == "fwd" else (states, x))
+    entry = {"fwd": kernels.ssd_scan_fwd,
+             "bwd": kernels.ssd_scan_bwd}[direction]
+    # (the entry points are jitted: their own functions hold the call)
+    assert _pallas_grids(lambda *a: entry.__wrapped__(
+        *a, heads=SSD_HEADS, groups=SSD_GROUPS), *args) == {
+        f"ssd_scan_{direction}": ((1, SSD_GROUPS, chunks), 0)}
+    assert f"ssd_scan_{direction}" in entry.lower(
+        *args, heads=SSD_HEADS, groups=SSD_GROUPS).compile().as_text()
+    # the widest blocks ``supported`` admits of each kind still compile:
+    # float32, chunks of 256, heads of 256 (a unit is one head's two lane
+    # tiles), a state of 256
+    wide = (s(jnp.float32, 1, 512, 4 * 256), s(jnp.float32, 1, 512, 2 * 256))
+    assert kernels.supported(*wide, 4, 2, 256)
+    wide_args = (wide[0], wide[1], wide[1], s(jnp.float32, 4),
+                 s(jnp.float32, 1, 2, 256, 4), s(jnp.float32, 1, 2, 256, 4))
+    if direction == "bwd":
+        wide_args += (s(jnp.float32, 1, 2, 4, 256, 256), wide[0])
+    assert f"ssd_scan_{direction}" in entry.lower(
+        *wide_args, heads=4, groups=2).compile().as_text()
+
+    raw = s(jnp.bfloat16, 1, SSD_T, SSD_HEADS)
+    op_args = (x, raw, bc, bc, head, head, head) + (
+        () if direction == "fwd" else (states, x))
+    op = ss.ssd_chunked if direction == "fwd" else ss.ssd_chunked_bwd
+    narrow = s(jnp.bfloat16, 1, SSD_T, SSD_HEADS * 48)
+
+    def traced(fn, *operands):
+        return set(_primitives(jax.make_jaxpr(lambda *a: fn(
+            *a, SSD_HEADS, SSD_GROUPS, SSD_CHUNK))(*operands).jaxpr))
+    fluid.set_flags({"kernel_tier": "pallas"})
+    try:
+        assert "pallas_call" in traced(op, *op_args)
+        assert not kernels.supported(narrow, bc, SSD_HEADS, SSD_GROUPS,
+                                     SSD_CHUNK)
+        assert "pallas_call" not in traced(ss.ssd_chunked, narrow,
+                                           *op_args[1:7])
+    finally:
+        fluid.set_flags({"kernel_tier": "auto"})
 
 
 def test_attention_at_32_and_2_heads_reaches_the_kernels(one_chip,

@@ -103,6 +103,8 @@ with fluid.program_guard(main, startup):
                                append_batch_size=False)
     kda = fluid.layers.kda_attention(tokens, 2, 16, conv_size=4,
                                      chunk_size=16)
+    # and a state-space layer: its ssd_scan kernels load the same way
+    mamba = fluid.layers.mamba2_mixer(tokens, 2, 16, 1, 16, chunk_size=16)
 print(sorted(m for m in sys.modules
              if m.startswith(("jax.experimental.pallas",
                               "paddle_tpu.ops.pallas."))))
@@ -383,6 +385,24 @@ def _causal_conv1d_site(supported):
                    {"Out": 0}, {})
 
 
+def _ssd_scan_site(supported):
+    # 32 tokens, two heads in one group of state 128: heads of 64 fill a
+    # lane tile and are the kernels' shape, heads of 48 are the twin's
+    rng = np.random.RandomState(8)
+    heads, p = 2, 64 if supported else 48
+    x = rng.randn(1, 32, heads * p).astype(np.float32)
+    dt = 0.5 * rng.randn(1, 32, heads).astype(np.float32)
+    b, c = (rng.randn(1, 32, 128).astype(np.float32) for _ in range(2))
+    a_log = np.log(rng.uniform(1.0, 16.0, heads)).astype(np.float32)
+    dt_bias = np.log(np.expm1(rng.uniform(0.001, 0.1, heads))) \
+        .astype(np.float32)
+    return _run_op("ssd_scan",
+                   {"X": x, "Dt": dt, "B": b, "C": c, "ALog": a_log,
+                    "DtBias": dt_bias, "D": np.ones(heads, np.float32)},
+                   {"Out": 0, "States": 0},
+                   {"num_heads": heads, "n_groups": 1, "chunk_size": 16})
+
+
 # family -> (the op at a tiny shape, run(supported) -> outputs; Pallas
 # dispatches one supported run traces)
 SITES = {
@@ -398,6 +418,7 @@ SITES = {
     "moe_combine": (_moe_combine_site, 1),
     "delta_rule": (_delta_rule_site, 1),
     "causal_conv1d": (_causal_conv1d_site, 1),
+    "ssd_scan": (_ssd_scan_site, 1),
 }
 # the other family's dispatches in each run of a site whose op holds two
 # (routed_experts: its products and its combine), whatever the site's own
@@ -548,6 +569,7 @@ AOT_ENTRY_POINTS = {
     "delta_rule": ("delta_rule", ("delta_rule_fwd", "delta_rule_bwd")),
     "causal_conv1d": ("causal_conv1d", ("causal_conv1d_fwd",
                                         "causal_conv1d_bwd")),
+    "ssd_scan": ("ssd_scan", ("ssd_scan_fwd", "ssd_scan_bwd")),
 }
 
 

@@ -455,8 +455,9 @@ def test_the_kept_states_are_the_recurrences_at_the_chunk_starts():
 
 
 def test_the_core_reports_its_gauge_and_runs_under_its_kernel_span():
-    """``paddle_tpu_ssd_scan{kind=}`` as last traced, and the span a later
-    kernel is counted under: ``jnp/ssd_scan`` today, no Pallas dispatch."""
+    """``paddle_tpu_ssd_scan{kind=}`` as last traced, and the span the
+    kernels are counted under: ``jnp/ssd_scan`` on the CPU (and at heads of 8
+    anywhere), no Pallas dispatch."""
     from paddle_tpu.core import profiler
     from paddle_tpu.obs.metrics import REGISTRY
     from paddle_tpu.ops import pallas as tier
@@ -475,7 +476,8 @@ def test_the_core_reports_its_gauge_and_runs_under_its_kernel_span():
         profiler.disable_profiler(sorted_key=None)
     names = [(kind, name) for kind, name, *_ in events]
     assert names.count(("kernel", "jnp/ssd_scan")) == 2    # op and grad op
-    assert tier.dispatch_counts() == before and ss._route() == "jnp"
+    assert tier.dispatch_counts() == before
+    assert ss._route(feed["x"], feed["b"], 8, 4, 32)[1] == "jnp"
     gauge = {k[0]: c.value for k, c in
              REGISTRY.get("paddle_tpu_ssd_scan").children().items()}
     assert gauge == {"chunk": 32, "chunks": 3, "heads": 8, "state": 8 * 16}

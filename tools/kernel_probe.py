@@ -8,7 +8,11 @@ twin in a same-process A/B. This tool takes those three observations per
 case and prints one JSON line each (also collected into ``--out``):
 
     {"case", "family", "lowered", "error", "max_abs_err", "max_rel_err",
-     "jnp_ms", "pallas_ms", "speedup"}
+     "jnp_ms", "pallas_ms", "speedup", "jnp_device_ms", "pallas_device_ms"}
+
+(``*_ms`` by the host's clock around calls that end in ``block_until_ready``;
+``*_device_ms`` from a profiler trace of a few calls: the union of the
+intervals in which an operation runs on device 0, a call.)
 
 Cases: conv_bn forward and backward at ResNet-50 bottleneck shapes
 (batch 256, bf16 — what ``chip_smoke.py`` dispatches under
@@ -23,7 +27,9 @@ the experts' combine at its buffer (18432 rows of 2304 to 8192 tokens);
 the gated delta rule at the Kimi-Linear cell's shape (4096 tokens, 32 heads
 of 128, chunks of 64), forward and backward; the mixers' short convolution
 at the Kimi-Linear cell's shape (4096 tokens x 4096 channels, 4 taps, no
-bias) and the Nemotron cell's (x 6144, with a bias), forward and backward.
+bias) and the Nemotron cell's (x 6144, with a bias), forward and backward;
+the Mamba-2 state-space core at the Nemotron cell's shape (4096 tokens, 64
+heads of 64 in 8 groups of state 128, chunks of 128), forward and backward.
 Every family with a dispatch site in ``paddle_tpu/ops/`` has a case.
 
 A kernel that fails to lower is a RESULT here (``lowered: false`` with the
@@ -100,15 +106,42 @@ def measure(runners, repeats=3, inner=2):
     return best, dropped
 
 
+def device_ms(run, calls=4):
+    """Device time of one call of ``run`` (already compiled): ``calls`` of
+    them under the profiler, the union of the intervals in which an
+    operation runs on device 0 over ``calls``. None without a device
+    plane in the trace (the CPU)."""
+    import tempfile
+
+    import jax
+    from benchmark import trace
+
+    with tempfile.TemporaryDirectory() as logdir:
+        jax.profiler.start_trace(logdir)
+        try:
+            out = None
+            for _ in range(calls):
+                out = run()
+            jax.block_until_ready(out)
+        finally:
+            jax.profiler.stop_trace()
+        loaded = trace.load_xplane(logdir, ())
+    ops = (loaded or {}).get("devices", {}).get(0)
+    if not ops:
+        return None
+    return trace.measure(trace.union([e[1:] for e in ops])) * 1e3 / calls
+
+
 def probe(case, family, runners, repeats=5, inner=4):
     """Run one case: twin first (must work), then the Pallas runner (a
-    failure is recorded, not raised), then parity and the interleaved A/B
-    (:func:`measure`)."""
+    failure is recorded, not raised), then parity, the interleaved A/B
+    (:func:`measure`) and each runner's device time (:func:`device_ms`)."""
     import jax
 
     rec = {"case": case, "family": family, "lowered": False, "error": None,
            "max_abs_err": None, "max_rel_err": None, "jnp_ms": None,
-           "pallas_ms": None, "speedup": None}
+           "pallas_ms": None, "speedup": None, "jnp_device_ms": None,
+           "pallas_device_ms": None}
     want = jax.block_until_ready(runners["jnp"]())
     try:
         got = jax.block_until_ready(runners["pallas"]())
@@ -124,6 +157,10 @@ def probe(case, family, runners, repeats=5, inner=4):
     rec["jnp_ms"] = round(ms["jnp"], 4)
     rec["pallas_ms"] = round(ms["pallas"], 4)
     rec["speedup"] = round(ms["jnp"] / ms["pallas"], 4)
+    if jax.default_backend() != "cpu":      # no device plane in a CPU trace
+        for name in ("jnp", "pallas"):
+            on_device = device_ms(runners[name])
+            rec[name + "_device_ms"] = on_device and round(on_device, 4)
     return rec
 
 
@@ -380,6 +417,48 @@ def causal_conv1d_case(T, channels, taps, bias, backward):
                 x, w, b, dout) if g is not None)}
 
 
+def ssd_scan_case(T, heads, p, groups, n, chunk, backward):
+    """The Mamba-2 state-space core through the op's own two functions,
+    which route by ``kernel_tier`` when they are traced (``_prepare`` and its
+    backward on both sides): bfloat16 x, B, C and raw step, decays drawn as
+    the Nemotron configuration's initial state draws them (A in U(1, 16) a
+    head, the step log-uniform in [0.001, 0.1]), the backward of either
+    route from the twin's kept states."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import state_space_ops as ss
+
+    keys = jax.random.split(jax.random.PRNGKey(chunk), 8)
+    x, dout = (jax.random.normal(k, (1, T, heads * p), jnp.bfloat16)
+               for k in keys[:2])
+    b, c = (jax.random.normal(k, (1, T, groups * n), jnp.bfloat16)
+            for k in keys[2:4])
+    dt = (0.5 * jax.random.normal(keys[4], (1, T, heads))) \
+        .astype(jnp.bfloat16)
+    a_log = jnp.log(jax.random.uniform(keys[5], (heads,), minval=1.0,
+                                       maxval=16.0))
+    dt_bias = jnp.log(jnp.expm1(jnp.exp(jax.random.uniform(
+        keys[6], (heads,), minval=np.log(0.001), maxval=np.log(0.1)))))
+    d = 1.0 + 0.3 * jax.random.normal(keys[7], (heads,))
+    args = (x, dt, b, c, a_log, dt_bias, d)
+    states = jax.jit(lambda *a: ss.ssd_chunked_jnp(
+        *a, heads, groups, chunk))(*args)[1] if backward else None
+
+    def route(tier):
+        def compute(*a):        # a function of its own per route (jit's key)
+            if backward:
+                return ss.ssd_chunked_bwd(*a, heads, groups, chunk)
+            return ss.ssd_chunked(*a, heads, groups, chunk)
+        fn = jax.jit(compute)
+
+        def run():
+            with _tier(tier):   # the first call traces, and pins the route
+                return fn(*args, states, dout) if backward else fn(*args)
+        return run
+
+    return {"jnp": route("jnp"), "pallas": route("pallas")}
+
+
 def momentum_case(shapes):
     """One fused-momentum step over ``shapes``: the arena megakernel (with
     the concat/split the fused op pays) vs the per-param twin."""
@@ -521,6 +600,12 @@ def cases(tiny):
                 *conv[:3], "_bias" if conv[3] else "",
                 "bwd" if bwd else "fwd"), "causal_conv1d",
                 lambda a=conv + (bwd,): causal_conv1d_case(*a))
+    # the Nemotron cell's Mamba-2 core
+    ssd = (256, 4, 64, 2, 128, 128) if tiny else (4096, 64, 64, 8, 128, 128)
+    for bwd in (False, True):
+        yield ("ssd_scan_{6}_len{0}_{1}x{2}_{3}groups_state{4}_chunk{5}"
+               .format(*ssd, "bwd" if bwd else "fwd"), "ssd_scan",
+               lambda a=ssd + (bwd,): ssd_scan_case(*a))
 
 
 def lstm_lane_step(tiny, rounds=3):
