@@ -335,6 +335,15 @@ def _op_phase(op):
     return "fwd"
 
 
+def _op_scope(op):
+    """``<phase>/<op type>``, and ``/<module>`` after it for an op a
+    ``fluid.name_scope`` tagged (its ``op_namescope``): inside the op's own
+    pair, so whatever reads the outermost pair reads what it read."""
+    tag = op.attrs.get("op_namescope")
+    scope = f"{_op_phase(op)}/{op.type}"
+    return f"{scope}/{tag}" if tag else scope
+
+
 class _ProgramAnalysis:
     """Cached per-(program, version) block-walk results: the free-read and
     written name lists plus the persistable subset of the writes. Computing
@@ -374,7 +383,7 @@ class _ProgramAnalysis:
         names = self._op_scopes.get(block.idx)
         if names is None or len(names) != len(block.ops):
             names = self._op_scopes[block.idx] = tuple(
-                f"{_op_phase(op)}/{op.type}" for op in block.ops)
+                _op_scope(op) for op in block.ops)
         return names
 
 

@@ -7,7 +7,8 @@ backward, regularizer, ParamAttr, places, Scope.
 from .framework import (Program, Block, Operator, Variable, Parameter,
                         program_guard, default_main_program,
                         default_startup_program, switch_main_program,
-                        switch_startup_program, grad_var_name, unique_name)
+                        switch_startup_program, grad_var_name, unique_name,
+                        name_scope)
 from ..core.executor import Executor, CPUPlace, TPUPlace
 from ..core.amp import amp_guard
 from ..core.flags import set_flags, get_flag, flags, init_flags
@@ -61,5 +62,5 @@ __all__ = [
     "DistributeTranspiler", "SimpleDistributeTranspiler",
     "WeightNormParamAttr", "average", "recordio_writer", "executor",
     "LoDTensor", "analysis", "ProgramVerifyError", "lint_program",
-    "verify_program",
+    "verify_program", "name_scope",
 ]

@@ -17,7 +17,8 @@ from __future__ import annotations
 
 import collections
 
-from .framework import Program, Variable, Parameter, grad_var_name, unique_name
+from .framework import (NAMESCOPE_ATTR, Program, Variable, Parameter,
+                        grad_var_name, unique_name)
 from ..core import registry
 
 
@@ -115,6 +116,9 @@ def append_backward(loss, parameter_list=None, no_grad_set=None):
                     "While) or mark its inputs stop_gradient=True.")
             continue
         specs = info.grad(op)
+        # a tagged op's grad ops belong to its module (fluid.name_scope)
+        tag = ({NAMESCOPE_ATTR: op.attrs[NAMESCOPE_ATTR]}
+               if NAMESCOPE_ATTR in op.attrs else {})
         # outputs whose grad was never produced (unused forward outputs, e.g.
         # softmax_with_cross_entropy's Softmax when only Loss is used): feed
         # zeros, mirroring the reference's fill_zeros_like insertion
@@ -129,7 +133,8 @@ def append_backward(loss, parameter_list=None, no_grad_set=None):
                 if g not in produced and g in spec_inputs:
                     _create_grad_var(block, n, g)
                     block.append_op("fill_zeros_like",
-                                    inputs={"X": [n]}, outputs={"Out": [g]})
+                                    inputs={"X": [n]}, outputs={"Out": [g]},
+                                    attrs=dict(tag))
                     produced.add(g)
 
         for spec in specs:
@@ -158,14 +163,16 @@ def append_backward(loss, parameter_list=None, no_grad_set=None):
                         new_names.append(n)
                     spec_seen.add(n)
                 spec.outputs[slot] = new_names
-            block.append_op(spec.type, spec.inputs, spec.outputs, spec.attrs)
+            block.append_op(spec.type, spec.inputs, spec.outputs,
+                            {**spec.attrs, **tag})
             for slot, names in spec.outputs.items():
                 for n in names:
                     produced.add(n)
             # accumulate renamed grads into the canonical name
             for canonical, tmp in renames:
                 block.append_op("sum", inputs={"X": [canonical, tmp]},
-                                outputs={"Out": [canonical]})
+                                outputs={"Out": [canonical]},
+                                attrs=dict(tag))
 
     # collect (param, grad) pairs
     if parameter_list is not None:

@@ -60,6 +60,36 @@ def reset_unique_name():
     _name_counters.clear()
 
 
+# the op attribute a name scope writes: the module an op belongs to
+NAMESCOPE_ATTR = "op_namescope"
+_name_scopes = []
+
+
+@contextlib.contextmanager
+def name_scope(prefix):
+    """Tag every op created inside with the attribute ``op_namescope``
+    (nested scopes join with ``/``): the module the op belongs to, as the
+    reference's later ``fluid.name_scope`` does. ``append_backward`` copies
+    the tag onto the grad ops it makes from a tagged op, and the executor
+    names a tagged op's lowering ``<phase>/<op type>/<tag>`` (an untagged
+    op's stays ``<phase>/<op type>``), so a device trace can tell one
+    module's time from another's where both run the same op types."""
+    if not prefix or "/" in str(prefix):
+        raise ValueError(f"name_scope: {prefix!r} is not one segment")
+    _name_scopes.append(str(prefix))
+    try:
+        yield
+    finally:
+        _name_scopes.pop()
+
+
+def _scoped(attrs):
+    """An op's attrs as created now: tagged where a name scope is open."""
+    if not _name_scopes:
+        return attrs
+    return {NAMESCOPE_ATTR: "/".join(_name_scopes), **(attrs or {})}
+
+
 class Variable:
     """A typed symbolic variable inside a Block.
 
@@ -321,19 +351,19 @@ class Block:
 
     # ---- ops ----
     def append_op(self, type, inputs=None, outputs=None, attrs=None):
-        op = Operator(self, type, inputs, outputs, attrs)
+        op = Operator(self, type, inputs, outputs, _scoped(attrs))
         self.ops.append(op)
         self.program._bump_version()
         return op
 
     def prepend_op(self, type, inputs=None, outputs=None, attrs=None):
-        op = Operator(self, type, inputs, outputs, attrs)
+        op = Operator(self, type, inputs, outputs, _scoped(attrs))
         self.ops.insert(0, op)
         self.program._bump_version()
         return op
 
     def insert_op(self, index, type, inputs=None, outputs=None, attrs=None):
-        op = Operator(self, type, inputs, outputs, attrs)
+        op = Operator(self, type, inputs, outputs, _scoped(attrs))
         self.ops.insert(index, op)
         self.program._bump_version()
         return op

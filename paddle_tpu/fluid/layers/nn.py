@@ -13,7 +13,7 @@ import copy
 
 import numpy as np
 
-from ..framework import Variable, unique_name
+from ..framework import Variable, name_scope, unique_name
 from ..layer_helper import LayerHelper
 from ..param_attr import ParamAttr
 from ..initializer import Constant, Mapped, Normal, Uniform, Xavier
@@ -1023,23 +1023,41 @@ def rms_norm(input, epsilon=1e-6, param_attr=None, name=None):
 
 def rotary_embedding(q, k, head_dim, theta=10000.0, rope_type="default",
                      factor=1.0, original_max_position=0, beta_fast=32.0,
-                     beta_slow=1.0, attention_factor=1.0, name=None):
+                     beta_slow=1.0, attention_factor=1.0, rotary_dim=None,
+                     interleaved=False, k_head_dim=None, name=None):
     """Rotary positions (the ``rotate_half`` convention) on projected Q and
     K, [batch, seq, heads * head_dim], positions 0..seq-1. ``rope_type``
     ``yarn`` blends each frequency with itself over ``factor`` by YaRN's
     linear ramp (``original_max_position``, ``beta_fast``, ``beta_slow``)
-    and scales cos and sin by ``attention_factor``. Returns (q, k)."""
+    and scales cos and sin by ``attention_factor``. ``rotary_dim`` turns
+    only each head's trailing ``rotary_dim`` coordinates (at
+    theta^(-2j / rotary_dim)), ``interleaved`` pairs adjacent coordinates
+    (2j, 2j + 1) instead of halves, and ``k_head_dim`` gives K's heads
+    another width than Q's (latent attention's ONE shared rope key); these
+    three stay out of the op at their defaults, which is then the one every
+    program built before them holds. Returns (q, k)."""
     helper = LayerHelper("rotary_embedding", name=name)
     q_out = helper.create_tmp_variable(q.dtype, shape=q.shape)
     k_out = helper.create_tmp_variable(k.dtype, shape=k.shape)
+    attrs = {"head_dim": int(head_dim), "theta": float(theta),
+             "rope_type": rope_type, "factor": float(factor),
+             "original_max_position": int(original_max_position),
+             "beta_fast": float(beta_fast), "beta_slow": float(beta_slow),
+             "attention_factor": float(attention_factor)}
+    if rotary_dim and int(rotary_dim) != int(head_dim):
+        attrs["rotary_dim"] = int(rotary_dim)
+    if interleaved:
+        attrs["interleaved"] = True
+    if k_head_dim and int(k_head_dim) != int(head_dim):
+        attrs["k_head_dim"] = int(k_head_dim)
+    if len(attrs) > 8 and (rope_type != "default" or attention_factor != 1):
+        raise NotImplementedError(
+            "rotary_embedding: rotary_dim, interleaved and k_head_dim turn "
+            "by theta^(-2j / rotary_dim) alone; YaRN and attention_factor "
+            "are not built for them")
     helper.append_op(
         "rotary_embedding", inputs={"Q": [q.name], "K": [k.name]},
-        outputs={"QOut": [q_out.name], "KOut": [k_out.name]},
-        attrs={"head_dim": int(head_dim), "theta": float(theta),
-               "rope_type": rope_type, "factor": float(factor),
-               "original_max_position": int(original_max_position),
-               "beta_fast": float(beta_fast), "beta_slow": float(beta_slow),
-               "attention_factor": float(attention_factor)})
+        outputs={"QOut": [q_out.name], "KOut": [k_out.name]}, attrs=attrs)
     return q_out, k_out
 
 
@@ -1068,8 +1086,9 @@ def latent_kv_heads(kv, k_rope, num_heads, nope_dim, name=None):
 
 def latent_attention(input, num_heads, kv_lora_rank, qk_nope_head_dim,
                      qk_rope_head_dim, v_head_dim, q_lora_rank=None,
-                     rope_theta=None, epsilon=1e-6, param_attr=None,
-                     down_attr=None, up_attr=None, name=None):
+                     rope_theta=None, rope_interleave=False, epsilon=1e-6,
+                     param_attr=None, down_attr=None, up_attr=None,
+                     name=None):
     """Causal multi-head latent attention (MLA) in its expanded, training
     form, over an already normed ``input`` [batch, seq, hidden]; no bias
     anywhere. Queries: ``num_heads`` heads of [nope | rope], projected whole
@@ -1077,21 +1096,18 @@ def latent_attention(input, num_heads, kv_lora_rank, qk_nope_head_dim,
     and values: ``x W_kva`` is [latent (``kv_lora_rank``) | ONE rope key];
     the normed latent goes up to ``num_heads`` heads of [k_nope | v], and
     every head's key is [k_nope | the shared rope key]
-    (``latent_kv_heads``). With ``rope_theta`` the rope slices of the
-    queries and the rope key are rotated by their positions; with None
-    nothing is rotated (NoPE: the slice is 64 more coordinates of a key
-    that all heads share). Scores are scaled by (nope + rope)^-0.5 and the
-    values' heads may be of another size than the keys'
-    (``causal_self_attention``); the heads' outputs go back to ``hidden``
-    through ``W_o``. Parameters in the order W_q (or W_qa, the query
-    latent's norm, W_qb), W_kva, the key/value latent's norm, W_kvb, W_o.
-    ``down_attr`` (W_qa, W_kva) and ``up_attr`` (W_q / W_qb, W_kvb) default
-    to ``param_attr``."""
-    if rope_theta is not None:
-        raise NotImplementedError(
-            "latent_attention: a rotated slice needs rotary_embedding over "
-            "the last qk_rope_head_dim coordinates of a head, which the op "
-            "does not have; pass rope_theta=None (NoPE)")
+    (``latent_kv_heads``). With ``rope_theta`` the rope slice of every query
+    head and the ONE rope key (rotated once, before the heads share it) turn
+    by their positions 0..seq-1 (``rotary_embedding`` over a head's trailing
+    ``qk_rope_head_dim`` coordinates; adjacent pairs where
+    ``rope_interleave``, else halves); with None nothing is rotated (NoPE:
+    the slice is 64 more coordinates of a key that all heads share). Scores
+    are scaled by (nope + rope)^-0.5 and the values' heads may be of another
+    size than the keys' (``causal_self_attention``); the heads' outputs go
+    back to ``hidden`` through ``W_o``. Parameters in the order W_q (or
+    W_qa, the query latent's norm, W_qb), W_kva, the key/value latent's
+    norm, W_kvb, W_o. ``down_attr`` (W_qa, W_kva) and ``up_attr`` (W_q /
+    W_qb, W_kvb) default to ``param_attr``."""
     head = int(qk_nope_head_dim) + int(qk_rope_head_dim)
 
     def proj(x, size, attr=None):
@@ -1105,11 +1121,68 @@ def latent_attention(input, num_heads, kv_lora_rank, qk_nope_head_dim,
     c_kv, k_rope = tensor.split(
         proj(input, kv_lora_rank + qk_rope_head_dim, down_attr),
         [int(kv_lora_rank), int(qk_rope_head_dim)], dim=-1)
+    if rope_theta is not None:
+        q, k_rope = rotary_embedding(
+            q, k_rope, head_dim=head, theta=rope_theta,
+            rotary_dim=int(qk_rope_head_dim), interleaved=rope_interleave,
+            k_head_dim=int(qk_rope_head_dim))
     kv = proj(rms_norm(c_kv, epsilon=epsilon),
               num_heads * (qk_nope_head_dim + v_head_dim), up_attr)
     k, v = latent_kv_heads(kv, k_rope, num_heads, qk_nope_head_dim)
     out = causal_self_attention(q, k, v, num_heads=num_heads, name=name)
     return proj(out, int(input.shape[-1]))
+
+
+MTP_SCOPE = "mtp"
+
+
+def multi_token_prediction(hidden, next_ids, embedding, head, block,
+                           epsilon=1e-6, param_attr=None):
+    """One depth of multi-token prediction (MTP; DeepSeek-V3's technical
+    report, section 2.2) over the main model's last hidden state ``hidden``
+    [batch, seq, width] (before its final norm): ``h' = W_eh [enorm(Emb(
+    next_ids)) ; hnorm(hidden)]`` (the embedding first), ``block(h')`` (one
+    transformer block, built by the caller), the module's own RMSNorm, then
+    the SHARED output head. ``next_ids`` [batch, seq, 1] are the tokens one
+    position ahead (t_{i+1} at position i); ``embedding`` [vocab, width]
+    and ``head`` [width, vocab] are the main model's parameters, read and
+    not created again. Every op is made under ``name_scope("mtp")``, its
+    grad ops inherit the tag, and a device trace tells the module's time
+    from the main blocks'. Parameters in the order enorm, hnorm, W_eh, the
+    block's, the module's norm. Returns the logits [batch, seq, vocab],
+    which predict t_{i+2} at position i."""
+    width = int(hidden.shape[-1])
+    with name_scope(MTP_SCOPE):
+        helper = LayerHelper("lookup_table")
+        emb = helper.create_tmp_variable(
+            hidden.dtype, shape=tuple(next_ids.shape[:-1]) + (width,))
+        helper.append_op("lookup_table",
+                         inputs={"W": [embedding.name],
+                                 "Ids": [next_ids.name]},
+                         outputs={"Out": [emb.name]},
+                         attrs={"is_sparse": False, "padding_idx": None})
+        both = tensor.concat([rms_norm(emb, epsilon=epsilon),
+                              rms_norm(hidden, epsilon=epsilon)], axis=-1)
+        # the module's residual stream is float32, as the main one is
+        x = block(tensor.cast(_project(both, width, param_attr), "float32"))
+        return mul(rms_norm(x, epsilon=epsilon), head, x_num_col_dims=2)
+
+
+def mtp_loss(loss, logits, labels, loss_weight, depth=1):
+    """``loss + loss_weight * mean(cross-entropy of the MTP's logits against
+    labels)`` (the tokens two positions ahead) under ``name_scope("mtp")``:
+    the op ``mtp_loss`` sets the gauge ``paddle_tpu_mtp`` (depth, positions,
+    loss weight) at trace time. Returns the total, of ``loss``'s shape."""
+    with name_scope(MTP_SCOPE):
+        ce = softmax_with_cross_entropy(logits, labels)
+        helper = LayerHelper("mtp_loss")
+        out = helper.create_tmp_variable("float32", shape=loss.shape)
+        helper.append_op("mtp_loss",
+                         inputs={"Loss": [loss.name], "MtpLoss": [ce.name]},
+                         outputs={"Out": [out.name]},
+                         attrs={"loss_weight": float(loss_weight),
+                                "depth": int(depth)})
+    return out
 
 
 def gated_mlp(input, width, param_attr=None):

@@ -278,24 +278,88 @@ def _rotary_tables(ctx, length):
     return jnp.cos(angles) * scale, jnp.sin(angles) * scale
 
 
-def _rotate(x, cos, sin, head_dim):
+def _rotate(x, cos, sin, head_dim, swap=None):
     """x * cos + rotate_half(x) * sin per head, in float32; rotate_half
     maps a head's halves (a, b) to (-b, a). The halves change places in a
     product with a signed permutation matrix: exact (one +-1 a column), a
     few GFLOP on the MXU, where slicing and concatenating 64-lane halves
-    cost the rotation 20 x its HBM time on a v5e (PERF.md, PR 28)."""
+    cost the rotation 20 x its HBM time on a v5e (PERF.md, PR 28). A
+    ``swap`` given (``_slice_tables``') takes the place of rotate_half's."""
     b, t, e = x.shape
     xh = x.reshape(b, t, e // head_dim, head_dim)
-    half = head_dim // 2
-    swap = jnp.zeros((head_dim, head_dim), x.dtype)
-    swap = swap.at[jnp.arange(half) + half, jnp.arange(half)].set(-1)
-    swap = swap.at[jnp.arange(half), jnp.arange(half) + half].set(1)
+    if swap is None:
+        half = head_dim // 2
+        swap = jnp.zeros((head_dim, head_dim), x.dtype)
+        swap = swap.at[jnp.arange(half) + half, jnp.arange(half)].set(-1)
+        swap = swap.at[jnp.arange(half), jnp.arange(half) + half].set(1)
     rot = jnp.einsum("bthd,de->bthe", xh, swap,
                      precision=jax.lax.Precision.HIGHEST,
                      preferred_element_type=jnp.float32)
     out = xh.astype(jnp.float32) * cos[None, :, None, :] \
         + rot * sin[None, :, None, :]
     return out.reshape(x.shape).astype(x.dtype)
+
+
+def _is_sliced(ctx):
+    """A rotation of adjacent pairs, or of a head's trailing slice only,
+    or of K's heads at another width than Q's: attributes the programs
+    built before them do not hold, which keep ``_rotate`` as it was."""
+    return any(ctx.op.has_attr(a)
+               for a in ("interleaved", "rotary_dim", "k_head_dim"))
+
+
+def _slice_tables(ctx, length, head_dim):
+    """(cos, sin, swap) of one head of ``head_dim`` for positions 0..T-1:
+    its leading ``head_dim - rotary_dim`` coordinates are left alone (cos
+    1, sin 0) and the trailing ``rotary_dim`` are turned, pair j being
+    coordinates (2j, 2j + 1) (``interleaved``) or (j, j + rotary_dim / 2)
+    (``rotate_half``) at the angle of frequency j. ``swap`` is the signed
+    permutation that maps each pair (a, b) to (-b, a) inside the slice,
+    zero outside it: the rotation is ``x * cos + (x @ swap) * sin``."""
+    import numpy as np
+
+    rd = int(ctx.attr("rotary_dim", 0) or ctx.attr("head_dim"))
+    lead, half = head_dim - rd, rd // 2
+    inv = rotary_inv_freq(rd, ctx.attr("theta", 10000.0))
+    if ctx.attr("interleaved", False):
+        first = lead + 2 * np.arange(half)
+        freq = np.repeat(inv, 2)
+    else:
+        first = lead + np.arange(half)
+        freq = np.concatenate([inv, inv])
+    second = first + (1 if ctx.attr("interleaved", False) else half)
+    swap = np.zeros((head_dim, head_dim), np.float32)
+    swap[second, first] = -1.0
+    swap[first, second] = 1.0
+    angles = jnp.arange(length, dtype=jnp.float32)[:, None] \
+        * jnp.asarray(freq, jnp.float32)[None, :]
+    ones = jnp.ones((length, lead), jnp.float32)
+    cos = jnp.concatenate([ones, jnp.cos(angles)], axis=-1)
+    sin = jnp.concatenate([jnp.zeros((length, lead), jnp.float32),
+                           jnp.sin(angles)], axis=-1)
+    return cos, sin, swap
+
+
+def _rotate_slice(ctx, x, head_dim, inverse):
+    """``_slice_tables``' rotation of one tensor [b, T, heads * head_dim];
+    by the opposite angle where ``inverse``."""
+    cos, sin, swap = _slice_tables(ctx, x.shape[1], head_dim)
+    return _rotate(x, cos, -sin if inverse else sin, head_dim,
+                   jnp.asarray(swap, x.dtype))
+
+
+def _rotate_pair(ctx, q, k, inverse=False):
+    """(Q, K) turned by their positions' angles, or by the opposite ones
+    where ``inverse``."""
+    d = int(ctx.attr("head_dim"))
+    if _is_sliced(ctx):
+        kd = int(ctx.attr("k_head_dim", 0) or d)
+        return (_rotate_slice(ctx, q, d, inverse),
+                _rotate_slice(ctx, k, kd, inverse))
+    cos, sin = _rotary_tables(ctx, q.shape[1])
+    if inverse:
+        return _rotate(q, cos, -sin, d), _rotate(k, cos, -sin, d)
+    return _rotate(q, cos, sin, d), _rotate(k, cos, sin, d)
 
 
 def _rotary_grad_maker(op):
@@ -321,12 +385,15 @@ def rotary_embedding(ctx):
     positions 0..T-1. Attrs: ``head_dim``, ``theta``, ``rope_type``
     (``default`` | ``yarn``), and for YaRN ``factor``,
     ``original_max_position``, ``beta_fast``, ``beta_slow``,
-    ``attention_factor`` (on cos and sin)."""
+    ``attention_factor`` (on cos and sin). Optional (absent: the whole
+    head, ``rotate_half``): ``interleaved`` (adjacent pairs (2j, 2j + 1)),
+    ``rotary_dim`` (only a head's trailing ``rotary_dim`` coordinates turn,
+    at theta^(-2j / rotary_dim)) and ``k_head_dim`` (K's heads are of that
+    width, e.g. the ONE rope key latent attention shares)."""
     q, k = data_of(ctx.input("Q")), data_of(ctx.input("K"))
-    cos, sin = _rotary_tables(ctx, q.shape[1])
-    d = int(ctx.attr("head_dim"))
-    ctx.set_output("QOut", _rotate(q, cos, sin, d))
-    ctx.set_output("KOut", _rotate(k, cos, sin, d))
+    q_out, k_out = _rotate_pair(ctx, q, k)
+    ctx.set_output("QOut", q_out)
+    ctx.set_output("KOut", k_out)
 
 
 @register_op("rotary_embedding_grad")
@@ -335,10 +402,9 @@ def rotary_embedding_grad(ctx):
     gradient is the rotation by the opposite angle."""
     dq = data_of(ctx.input("QOut@GRAD"))
     dk = data_of(ctx.input("KOut@GRAD"))
-    cos, sin = _rotary_tables(ctx, dq.shape[1])
-    d = int(ctx.attr("head_dim"))
-    ctx.set_output("Q@GRAD", _rotate(dq, cos, -sin, d))
-    ctx.set_output("K@GRAD", _rotate(dk, cos, -sin, d))
+    dq, dk = _rotate_pair(ctx, dq, dk, inverse=True)
+    ctx.set_output("Q@GRAD", dq)
+    ctx.set_output("K@GRAD", dk)
 
 
 # ---------------------------------------------------------------------------

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 
 from ..core.amp import upcast_f32
 from ..core.registry import register_op, same_shape, OpSpec
+from ..obs.metrics import REGISTRY as _METRICS
 from .common import G, data_of, like
 
 
@@ -335,3 +336,62 @@ def hsigmoid_grad(ctx):
     ctx.set_output("W@GRAD", grads[1])
     if has_bias:
         ctx.set_output("Bias@GRAD", grads[2])
+
+
+# ---------------------------------------------------------------------------
+# mtp_loss — the main loss plus the multi-token-prediction module's, weighted
+# ---------------------------------------------------------------------------
+
+
+_M_MTP = _METRICS.gauge(
+    "paddle_tpu_mtp",
+    "the multi-token-prediction objective as last traced: kind=depth the "
+    "modules (predicted tokens beyond the next), kind=positions the "
+    "positions its loss averages over, kind=loss_weight its weight in the "
+    "total",
+    labels=("kind",))
+
+
+def _mtp_loss_grad_maker(op):
+    return [OpSpec("mtp_loss_grad",
+                   {"MtpLoss": op.input("MtpLoss"),
+                    "Out@GRAD": G(op.output("Out"))},
+                   {"Loss@GRAD": G(op.input("Loss")),
+                    "MtpLoss@GRAD": G(op.input("MtpLoss"))},
+                   dict(op.attrs))]
+
+
+def _mtp_loss_infer(op, block):
+    loss = block.var(op.input("Loss")[0])
+    for name in op.output("Out"):
+        out = block.var(name)
+        out.shape, out.dtype = loss.shape, out.dtype or "float32"
+
+
+@register_op("mtp_loss", infer_shape=_mtp_loss_infer,
+             grad=_mtp_loss_grad_maker)
+def mtp_loss(ctx):
+    """``Out = Loss + loss_weight * mean(MtpLoss)``: the main objective (a
+    scalar, of any shape of one element) and the MTP module's cross-entropy
+    at every position, float32. Attrs
+    ``loss_weight`` (lambda) and ``depth``, which with the positions set
+    the gauge ``paddle_tpu_mtp`` at trace time."""
+    loss = upcast_f32(data_of(ctx.input("Loss")))
+    mtp = upcast_f32(data_of(ctx.input("MtpLoss")))
+    weight = float(ctx.attr("loss_weight"))
+    _M_MTP.labels(kind="depth").set(int(ctx.attr("depth", 1)))
+    _M_MTP.labels(kind="positions").set(int(mtp.size))
+    _M_MTP.labels(kind="loss_weight").set(weight)
+    ctx.set_output("Out", loss + weight * jnp.mean(mtp))
+
+
+@register_op("mtp_loss_grad")
+def mtp_loss_grad(ctx):
+    """``Loss`` takes the gradient as it comes, every position of
+    ``MtpLoss`` ``loss_weight / positions`` of it."""
+    mtp = data_of(ctx.input("MtpLoss"))
+    d = upcast_f32(data_of(ctx.input("Out@GRAD")))
+    weight = float(ctx.attr("loss_weight"))
+    ctx.set_output("Loss@GRAD", d)
+    ctx.set_output("MtpLoss@GRAD", jnp.broadcast_to(
+        jnp.sum(d) * (weight / mtp.size), mtp.shape).astype(mtp.dtype))

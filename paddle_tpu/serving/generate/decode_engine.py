@@ -344,6 +344,23 @@ class GenerationEngine:
                     "arena and no recurrent-state cache, so a decode step "
                     "would start every token from nothing; programs with "
                     "state layers cannot be served yet")
+            if op.attr("op_namescope") == "mtp" or op.type == "mtp_loss":
+                raise ValueError(
+                    f"{op.type} of the multi-token-prediction module "
+                    "(op_namescope 'mtp'): it predicts a second token from "
+                    "the main model's last state, and GenerationEngine "
+                    "decodes one token a step with no draft-and-verify "
+                    "path; programs with an MTP module cannot be served "
+                    "yet (save the inference program without it)")
+            if op.type == "rotary_embedding" and op.has_attr("rotary_dim"):
+                raise ValueError(
+                    f"rotary_embedding with rotary_dim="
+                    f"{op.attr('rotary_dim')}: a rotated slice of a latent "
+                    "head turns the ONE key every head shares, which a "
+                    "latent (compressed-KV) cache keeps apart from the "
+                    "latent; GenerationEngine keeps a paged arena of whole "
+                    "per-head keys and positions from 0 in every window, "
+                    "so rotated latent attention cannot be served yet")
             if op.type == "routed_experts" \
                     and op.attr("expert_form", "gated_silu") != "gated_silu":
                 raise ValueError(

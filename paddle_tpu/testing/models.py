@@ -265,6 +265,40 @@ def build_mellum2_lm(cfg, length, batch=1):
     return main, startup, loss, logits, loads
 
 
+def _biased_experts_and_shared(layers, cfg, x, b, expert_attr, routed,
+                               top_k, held, renormalize, scoring, shared,
+                               aux, loads):
+    """``x + routed_experts(b) + gated_mlp(b)``: the sparse feed-forward
+    part of the latent-attention families (sigmoid or softmax scores, a
+    selection bias with its own update, ``routed_scaling_factor``, the
+    ``held`` experts from ``expert_offset``, and ``shared`` shared experts
+    as one gated MLP); the balance term and the held experts' load are
+    appended to ``aux`` and ``loads``."""
+    from paddle_tpu.fluid.initializer import Normal
+    from paddle_tpu.fluid.param_attr import ParamAttr
+
+    y, load, balance = layers.routed_experts(
+        b, num_experts=routed, top_k=top_k,
+        expert_width=cfg["moe_intermediate_size"], held_experts=held,
+        expert_offset=cfg.get("expert_offset", 0),
+        norm_topk_prob=renormalize,
+        row_buffer_factor=cfg.get("row_buffer_factor", 2.0),
+        router_task_gradient=cfg.get("router_task_gradient", True),
+        scoring_func=scoring,
+        routed_scaling_factor=cfg.get("routed_scaling_factor", 1.0),
+        selection_bias=True,
+        bias_update_rate=cfg.get("bias_update_rate", 0.0),
+        bias_attr=ParamAttr(initializer=Normal(
+            cfg.get("selection_bias_init_mean", 0.0),
+            cfg.get("selection_bias_init_std", 0.0))),
+        param_attr=expert_attr)
+    aux.append(balance)
+    loads.append(load)
+    x = layers.elementwise_add(x, y)
+    return layers.elementwise_add(x, layers.gated_mlp(
+        b, shared * cfg["moe_intermediate_size"], expert_attr))
+
+
 def build_kimi_linear_lm(cfg, length, batch=1):
     """The Kimi-Linear block stack (``model_type`` ``kimi_linear``, its
     published ``config.json`` keys) as a Fluid program, and the chip's share
@@ -333,29 +367,13 @@ def build_kimi_linear_lm(cfg, length, batch=1):
         if mlp == "dense":
             return layers.elementwise_add(
                 x, layers.gated_mlp(b, cfg["intermediate_size"], init()))
-        y, load, balance = layers.routed_experts(
-            b, num_experts=cfg["num_experts_routed"],
-            top_k=cfg["num_experts_per_token"],
-            expert_width=cfg["moe_intermediate_size"],
-            held_experts=cfg["num_experts"],
-            expert_offset=cfg.get("expert_offset", 0),
-            norm_topk_prob=cfg.get("moe_renormalize", True),
-            row_buffer_factor=cfg.get("row_buffer_factor", 2.0),
-            router_task_gradient=cfg.get("router_task_gradient", True),
-            scoring_func=cfg.get("moe_router_activation_func", "sigmoid"),
-            routed_scaling_factor=cfg.get("routed_scaling_factor", 1.0),
-            selection_bias=True,
-            bias_update_rate=cfg.get("bias_update_rate", 0.0),
-            bias_attr=ParamAttr(initializer=Normal(
-                cfg.get("selection_bias_init_mean", 0.0),
-                cfg.get("selection_bias_init_std", 0.0))),
-            param_attr=init(cfg.get("expert_init_std", std)))
-        aux.append(balance)
-        loads.append(load)
-        x = layers.elementwise_add(x, y)
-        return layers.elementwise_add(x, layers.gated_mlp(
-            b, cfg["num_shared_experts"] * cfg["moe_intermediate_size"],
-            init(cfg.get("expert_init_std", std))))
+        return _biased_experts_and_shared(
+            layers, cfg, x, b, init(cfg.get("expert_init_std", std)),
+            routed=cfg["num_experts_routed"],
+            top_k=cfg["num_experts_per_token"], held=cfg["num_experts"],
+            renormalize=cfg.get("moe_renormalize", True),
+            scoring=cfg.get("moe_router_activation_func", "sigmoid"),
+            shared=cfg["num_shared_experts"], aux=aux, loads=loads)
 
     main, startup = fluid.Program(), fluid.Program()
     with fluid.program_guard(main, startup):
@@ -378,6 +396,121 @@ def build_kimi_linear_lm(cfg, length, batch=1):
             loss = layers.elementwise_add(loss, layers.scale(
                 layers.mean(layers.sums(aux)), scale=coef / len(aux)))
     return main, startup, loss, logits, loads
+
+
+def build_joyai_llm_flash_lm(cfg, length, batch=1):
+    """The JoyAI-LLM-Flash block stack (``model_type`` ``joyai_llm_flash``,
+    its published ``config.json`` keys) as a Fluid program, and the chip's
+    share of it: ``num_hidden_layers`` pre-norm blocks, each latent
+    attention (``layers.latent_attention``: queries through a rank of
+    ``q_lora_rank`` with its RMSNorm, ``kv_lora_rank``, heads of
+    ``qk_nope_head_dim`` + ``qk_rope_head_dim`` for queries and keys and
+    ``v_head_dim`` for values, the rope slices of the queries and the ONE
+    shared rope key rotated at ``rope_theta``, adjacent pairs where
+    ``rope_interleave``) and a dense gated-SiLU MLP of ``intermediate_size``
+    in the first ``first_k_dense_replace`` blocks, ``routed_experts``
+    after (sigmoid scores by ``scoring_func``, a selection bias with its own
+    update, top ``num_experts_per_tok`` renormalised where
+    ``norm_topk_prob`` times ``routed_scaling_factor``) holding
+    ``n_routed_experts`` of the router's ``num_experts_routed`` from
+    ``expert_offset``, beside ``n_shared_experts`` shared experts as one
+    gated MLP. Then the final RMSNorm and an untied head over ``vocab_size``
+    rows. With ``num_nextn_predict_layers`` (1) the multi-token-prediction
+    module (``layers.multi_token_prediction``: the embedding of the next
+    token and the last block's output before the final norm, each normed,
+    through ``W_eh``, one more sparse block, its own norm, the SHARED head)
+    predicts the token two ahead, and its cross-entropy enters the loss at
+    ``mtp_loss_weight`` (``layers.mtp_loss``); its sparse block's row buffer
+    is ``mtp_row_buffer_factor`` times the expectation where given (its
+    routing can crowd onto the held experts early in training, and a step
+    over the capacity is a NaN loss), ``row_buffer_factor`` otherwise. The
+    loss is the mean next-token cross-entropy plus ``balance_loss_coef``
+    times the sparse blocks' (the module's among them) mean balance term,
+    plus the MTP's. The plain reference is
+    ``testing/reference/joyai_llm_flash.py``; parameters are created in the
+    order its ``unpack`` reads. Feeds
+    ``tokens``, ``labels`` and, with the module, ``mtp_labels`` [batch,
+    length, 1] int64. Returns (main, startup, loss, logits [batch, length,
+    vocab], the MTP's logits or None, [expert_load of each sparse
+    block])."""
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.fluid.initializer import Normal
+    from paddle_tpu.fluid.param_attr import ParamAttr
+    from paddle_tpu.testing.reference.joyai_llm_flash import layer_kinds
+
+    layers = fluid.layers
+    hidden, eps = cfg["hidden_size"], cfg["rms_norm_eps"]
+    std = cfg.get("init_std", 0.02)
+    depth = int(cfg.get("num_nextn_predict_layers", 0))
+    if depth > 1:
+        raise ValueError("build_joyai_llm_flash_lm: MTP depth above 1 is "
+                         "not built")
+
+    def init(scale=std):
+        return ParamAttr(initializer=Normal(0.0, scale))
+
+    aux, loads = [], []
+
+    def block(x, mlp, c=cfg):
+        a = layers.latent_attention(
+            layers.rms_norm(x, epsilon=eps),
+            num_heads=cfg["num_attention_heads"],
+            q_lora_rank=cfg["q_lora_rank"], kv_lora_rank=cfg["kv_lora_rank"],
+            qk_nope_head_dim=cfg["qk_nope_head_dim"],
+            qk_rope_head_dim=cfg["qk_rope_head_dim"],
+            v_head_dim=cfg["v_head_dim"], rope_theta=cfg["rope_theta"],
+            rope_interleave=cfg.get("rope_interleave", False), epsilon=eps,
+            param_attr=init(),
+            down_attr=init(cfg.get("latent_down_init_std", std)),
+            up_attr=init(cfg.get("latent_up_init_std", std)))
+        x = layers.elementwise_add(x, a)
+        b = layers.rms_norm(x, epsilon=eps)
+        if mlp == "dense":
+            return layers.elementwise_add(
+                x, layers.gated_mlp(b, cfg["intermediate_size"], init()))
+        return _biased_experts_and_shared(
+            layers, c, x, b, init(cfg.get("expert_init_std", std)),
+            routed=cfg["num_experts_routed"],
+            top_k=cfg["num_experts_per_tok"], held=cfg["n_routed_experts"],
+            renormalize=cfg.get("norm_topk_prob", True),
+            scoring=cfg.get("scoring_func", "sigmoid"),
+            shared=cfg["n_shared_experts"], aux=aux, loads=loads)
+
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        def feed(name):
+            return layers.data(name, shape=[batch, length, 1], dtype="int64",
+                               append_batch_size=False)
+
+        tokens, labels = feed("tokens"), feed("labels")
+        x = layers.embedding(
+            tokens, size=(cfg["vocab_size"], hidden),
+            param_attr=init(cfg.get("embedding_init_std", std)))
+        for mlp in layer_kinds(cfg):
+            x = block(x, mlp)
+        logits = layers.fc(
+            layers.rms_norm(x, epsilon=eps), cfg["vocab_size"],
+            num_flatten_dims=2, bias_attr=False,
+            param_attr=init(cfg.get("head_init_std", std)))
+        params = main.global_block().all_parameters()
+        loss = layers.mean(layers.softmax_with_cross_entropy(logits, labels))
+        mtp_logits = None
+        if depth:
+            # the module's block has a row buffer of its own (docstring)
+            mtp_cfg = dict(cfg, row_buffer_factor=cfg.get(
+                "mtp_row_buffer_factor", cfg.get("row_buffer_factor", 2.0)))
+            mtp_logits = layers.multi_token_prediction(
+                x, labels, params[0], params[-1],
+                lambda h: block(h, "sparse", mtp_cfg), epsilon=eps,
+                param_attr=init())
+        coef = cfg.get("balance_loss_coef", 0.0)
+        if coef and aux:
+            loss = layers.elementwise_add(loss, layers.scale(
+                layers.mean(layers.sums(aux)), scale=coef / len(aux)))
+        if depth:
+            loss = layers.mtp_loss(loss, mtp_logits, feed("mtp_labels"),
+                                   cfg["mtp_loss_weight"], depth)
+    return main, startup, loss, logits, mtp_logits, loads
 
 
 def build_nemotron_h_lm(cfg, length, batch=1):

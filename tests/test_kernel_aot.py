@@ -585,3 +585,52 @@ def test_attention_at_32_and_2_heads_reaches_the_kernels(one_chip,
         q, kv, kv).compile().as_text()
     text = jax.jit(bwd).lower(q, kv, kv, q, lse, q).compile().as_text()
     assert "attention_dq" in text and "attention_dkv" in text
+
+
+def test_the_rotated_latent_slice_compiles_for_v5e_at_the_cells_shape(
+        one_chip):
+    """``rotary_embedding`` as the JoyAI-LLM-Flash cell's latent blocks call
+    it (32 query heads of 192 whose trailing 64 turn over adjacent pairs,
+    the ONE shared 64-wide key), forward and grad, bfloat16 as under AMP: a
+    product with the pair-swap per head and element-wise passes, no
+    gather, and temporaries of a few passes over q by the compiler's count
+    (q is 48 MiB in bfloat16)."""
+    from paddle_tpu.ops import attention_ops as att
+
+    attrs = dict(head_dim=192, theta=3.2e7, rotary_dim=64, interleaved=True,
+                 k_head_dim=64)
+
+    class Ctx:
+        def __init__(self, env):
+            self.env, self.out = env, {}
+            self.op = type("Op", (), {"has_attr": lambda _, a: a in attrs})()
+
+        def input(self, slot):
+            return self.env[slot]
+
+        def attr(self, name, default=None):
+            return attrs.get(name, default)
+
+        def set_output(self, slot, value):
+            self.out[slot] = value
+
+    def run(fn, names):
+        def call(a, b):
+            ctx = Ctx(dict(zip(names, (a, b))))
+            fn(ctx)
+            return tuple(ctx.out.values())
+        return call
+
+    q = jax.ShapeDtypeStruct((1, MLA_T, MLA_HEADS * 192), jnp.bfloat16,
+                             sharding=one_chip)
+    k = jax.ShapeDtypeStruct((1, MLA_T, 64), jnp.bfloat16, sharding=one_chip)
+    for fn, names in ((att.rotary_embedding, ("Q", "K")),
+                      (att.rotary_embedding_grad, ("QOut@GRAD",
+                                                   "KOut@GRAD"))):
+        call = run(fn, names)
+        assert [(x.shape, x.dtype) for x in jax.eval_shape(call, q, k)] == [
+            (q.shape, q.dtype), (k.shape, k.dtype)]
+        compiled = jax.jit(call).lower(q, k).compile()
+        assert " gather(" not in compiled.as_text()
+        assert compiled.memory_analysis().temp_size_in_bytes < 4 * (
+            MLA_T * MLA_HEADS * 192 * 4)

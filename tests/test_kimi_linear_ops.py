@@ -658,9 +658,10 @@ def test_wrong_shapes_and_names_are_refused_by_name():
     main, startup = fluid.Program(), fluid.Program()
     with fluid.program_guard(main, startup):
         x = fluid.layers.data("x", shape=[1, 8, 64], append_batch_size=False)
-        with pytest.raises(NotImplementedError, match="rotated slice"):
-            fluid.layers.latent_attention(x, 4, 16, 24, 8, 16,
-                                          rope_theta=10000.0)
+        # a rotated slice is built since PR 40, YaRN on a slice is not
+        with pytest.raises(NotImplementedError, match="YaRN"):
+            fluid.layers.rotary_embedding(x, x, head_dim=16, rotary_dim=8,
+                                          rope_type="yarn", factor=4.0)
         with pytest.raises(ValueError, match="without selection_bias"):
             fluid.layers.routed_experts(x, 8, 2, 16, bias_update_rate=0.1)
         fluid.layers.routed_experts(x, 8, 2, 16, scoring_func="tanh")
