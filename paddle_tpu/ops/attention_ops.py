@@ -62,6 +62,12 @@ _M_BLOCKS = _METRICS.gauge(
     "kind=scheduled is the band's blocks, a grid step each; kind=skipped "
     "the empty steps a rectangular grid would have walked besides",
     labels=("window", "kind"))
+_M_BWD = _METRICS.counter(
+    "paddle_tpu_attention_bwd",
+    "attention backward dispatches on the kernels, by the form the shapes "
+    "chose (ops/pallas/attention.backward_form): form=fused is the one "
+    "kernel attention_bwd, form=split attention_dq + attention_dkv",
+    labels=("form",))
 
 
 def _split_heads(x, num_heads):
@@ -215,10 +221,12 @@ def causal_self_attention_grad(ctx):
             dq, dk, dv = att.attention_jnp_bwd(qc, kc, vc, out, lse, d, heads,
                                                kv_heads, window)
         else:
+            qp = _lane_padded(qc, heads)
+            _M_BWD.labels(form=att.backward_form(qp, vc, heads,
+                                                 kv_heads)).inc()
             dq, dk, dv = att.attention_pallas_bwd(
-                _lane_padded(qc, heads), _lane_padded(kc, kv_heads), vc, out,
-                lse, d, heads, kv_heads, window,
-                scale=(q.shape[-1] // heads) ** -0.5)
+                qp, _lane_padded(kc, kv_heads), vc, out, lse, d, heads,
+                kv_heads, window, scale=(q.shape[-1] // heads) ** -0.5)
             dq, dk = _cut_heads(dq, q, heads), _cut_heads(dk, k, kv_heads)
     ctx.set_output("Q@GRAD", dq.astype(q.dtype))
     ctx.set_output("K@GRAD", dk.astype(k.dtype))

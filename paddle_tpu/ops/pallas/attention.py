@@ -10,7 +10,11 @@ meets only the key blocks its band touches (a window layer's work is
 each block's probabilities from the forward's saved log-sum-exp. The
 kernels' grid is the band itself: ``band_schedule`` lists the (query block,
 key block) pairs when the op is traced, the tables are prefetched into
-scalar memory, and a grid step is one pair.
+scalar memory, and a grid step is one pair. The backward is one kernel,
+``attention_bwd``, that walks the band once with ``dq``, ``dk`` and ``dv``
+of the whole sequence in VMEM; at lengths where those do not fit
+(``backward_form``) two kernels walk it, ``attention_dq`` by query block
+and ``attention_dkv`` by key block, rebuilding the same scores each.
 
 Arrays keep the model's layout, ``[b, T, heads * head_dim]``: a block
 spec's last index picks the head's columns, so nothing is transposed.
@@ -58,10 +62,11 @@ MAX_ENTRIES = 32768     # of one kernel's schedule: its four int32 tables are
 class Schedule(NamedTuple):
     """The blocks a band touches, one entry a grid step, in the order a
     kernel walks them: int32 tables of the entry's query block, key block,
-    query head within its group (the ``key`` order; zeros otherwise) and
-    flags. ``FIRST`` / ``LAST``: the entry opens / closes its accumulator's
-    run. ``skipped`` is what a rectangular grid of (blocks x the longest
-    run) would have walked besides."""
+    query head within its group (the ``key`` and ``head`` orders; zeros
+    otherwise) and flags. ``FIRST`` / ``LAST``: the entry opens / closes
+    its accumulator's run. ``skipped`` is what a rectangular grid of
+    (blocks x one head's longest run) for each head would have walked
+    besides."""
     q: np.ndarray
     k: np.ndarray
     head: np.ndarray
@@ -79,24 +84,32 @@ def band_schedule(T, block, window, by="query", group=1):
     block's key blocks, ascending, so the diagonal comes last.
     ``by="key"`` (``attention_dkv``): a run is a key block's query blocks,
     ascending, once for each of the ``group`` query heads that share the
-    key/value head."""
+    key/value head.
+    ``by="head"`` (``attention_bwd``): the same pairs with the heads taken
+    outermost; a run is one query head's walk of the whole band, key block
+    by key block, each key block's query blocks ascending."""
     n = T // block
     i, j = np.indices((n, n))
     lo, hi = (i - j) * block - (block - 1), (i - j) * block + (block - 1)
     some = _visible(np.clip(0, lo, hi), 0, window)
-    runs = []
-    for a in range(n):
-        if by == "query":
-            runs.append([(a, b, 0) for b in np.flatnonzero(some[a])])
-        else:
-            runs.append([(b, a, g) for g in range(group)
-                         for b in np.flatnonzero(some[:, a])])
+    column = [[(b, a) for b in np.flatnonzero(some[:, a])] for a in range(n)]
+    if by == "query":
+        runs = [[(a, b, 0) for b in np.flatnonzero(some[a])]
+                for a in range(n)]
+    elif by == "key":
+        runs = [[(qi, ki, g) for g in range(group) for qi, ki in column[a]]
+                for a in range(n)]
+    else:
+        runs = [[(qi, ki, g) for pairs in column for qi, ki in pairs]
+                for g in range(group)]
     entries = [(qi, ki, g, (FIRST if e == 0 else 0)
                 | (LAST if e == len(run) - 1 else 0))
                for run in runs for e, (qi, ki, g) in enumerate(run)]
     q, k, head, flags = np.asarray(entries, np.int32).T
-    longest = max(len(run) for run in runs)
-    return Schedule(q, k, head, flags, n * longest - len(entries))
+    longest = int(some.sum(1 if by == "query" else 0).max())  # of one head
+    heads = 1 if by == "query" else group
+    return Schedule(q, k, head, flags,
+                    heads * (n * longest - int(some.sum())))
 
 
 # ------------------------------------------------------------ blocked twin
@@ -316,8 +329,79 @@ def _dkv_kernel(q_tab, k_tab, head_tab, flags_tab, q_ref, k_ref, v_ref,
         dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
 
 
+def _rows(i, blk):
+    return pl.ds(pl.multiple_of(i * blk, blk), blk)
+
+
+def _by_rows(T, blk, fn):
+    """``fn(rows)`` for each block of rows of a whole-sequence ref."""
+    def step(r, carry):
+        fn(_rows(r, blk))
+        return carry
+    jax.lax.fori_loop(0, T // blk, step, 0)
+
+
+def _zero(blk, *scratch):
+    def clear(rows):
+        for scr in scratch:
+            scr[rows, :] = jnp.zeros((blk, scr.shape[1]), jnp.float32)
+    _by_rows(scratch[0].shape[0], blk, clear)
+
+
+def _flush(blk, scr, out, scale=None):
+    """``out = scr * scale`` in ``out``'s type, as the split kernels end."""
+    def put(rows):
+        x = scr[rows, :]
+        out[rows, :] = (x if scale is None else x * scale).astype(out.dtype)
+    _by_rows(scr.shape[0], blk, put)
+
+
+def _bwd_kernel(q_tab, k_tab, head_tab, flags_tab, q_ref, k_ref, v_ref,
+                do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref, dq_scr,
+                dk_scr, dv_scr, *, blk, window, scale):
+    """One walk of the band for all three gradients. The accumulators
+    cover the whole sequence: ``dq``'s the current query head's, opened
+    and flushed by its run's flags; ``dk``'s and ``dv``'s the key/value
+    head's, opened by the first grid step and flushed by the last."""
+    del head_tab                            # the index maps' alone
+    qi, ki, flags = _entry(q_tab, k_tab, flags_tab)
+    e = pl.program_id(2)
+
+    @pl.when(e == 0)
+    def _():
+        _zero(blk, dk_scr, dv_scr)
+
+    @pl.when(flags & FIRST != 0)
+    def _():
+        _zero(blk, dq_scr)
+
+    q, k, do = q_ref[...], k_ref[...], do_ref[...]
+    s = _scores(q, k, qi, ki, blk, window, scale)
+    p = jnp.exp(s - _wide(lse_ref[...], s.shape[1]))
+    kr = _rows(ki, blk)
+    dv_scr[kr, :] += jax.lax.dot_general(
+        p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    dp = jax.lax.dot_general(do, v_ref[...], (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+    ds = (p * (dp - _wide(delta_ref[...], s.shape[1]))).astype(q.dtype)
+    dk_scr[kr, :] += jax.lax.dot_general(
+        ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    dq_scr[_rows(qi, blk), :] += jnp.dot(ds, k,
+                                         preferred_element_type=jnp.float32)
+
+    @pl.when(flags & LAST != 0)
+    def _():
+        _flush(blk, dq_scr, dq_ref, scale)
+
+    @pl.when(e == pl.num_programs(2) - 1)
+    def _():
+        _flush(blk, dk_scr, dk_ref, scale)
+        _flush(blk, dv_scr, dv_ref)
+
+
 def _call(kernel, name, heads, tables, operands, in_specs, out_specs,
-          out_shape, scratch_shapes):
+          out_shape, scratch_shapes, vmem_limit_bytes=None):
     """One kernel over the grid (batch, ``heads``, the schedule's entries),
     the schedule's ``tables`` prefetched into scalar memory, where the
     index maps and the body read them."""
@@ -330,7 +414,8 @@ def _call(kernel, name, heads, tables, operands, in_specs, out_specs,
             scratch_shapes=scratch_shapes),
         out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_limit_bytes),
         interpret=on_cpu(),
     )(*(jnp.asarray(t) for t in tables), *operands)
 
@@ -380,19 +465,117 @@ def attention_pallas(q, k, v, num_heads, num_kv_heads, window, scale=None):
                         pltpu.VMEM((blk, dv), jnp.float32)])
 
 
+# The fused backward holds whole-sequence accumulators and output blocks in
+# VMEM, so its bytes grow with the length: it is the form wherever its count
+# fits the budget, and the split pair (a [blk, d] accumulator each) above
+# it. A v5e TensorCore has 128 MiB of VMEM; the budget is three quarters of
+# it, so that the limit's headroom and what Mosaic keeps for itself stay
+# inside the chip.
+VMEM_BYTES = 128 << 20
+BWD_VMEM_BUDGET = VMEM_BYTES * 3 // 4
+BWD_VMEM_HEADROOM = 4 << 20
+
+
+def bwd_vmem_bytes(T, d, dv, blk, dtype):
+    """VMEM bytes of ``attention_bwd`` from its shapes: the float32
+    accumulators and the output blocks of ``dq``, ``dk`` (``d`` wide) and
+    ``dv`` over the whole sequence, each output block twice (the pipeline
+    keeps two); the streamed blocks twice (``q``, ``k``, ``v``, ``d out``
+    and the two float32 lane-replicated row statistics); two float32
+    ``[blk, blk]`` values. Mosaic's own allocation reads 1.0-1.5 MiB less
+    at the cells' shapes (27.0 of 28 MiB at 8192 tokens and heads of 128)."""
+    item = jnp.dtype(dtype).itemsize
+    whole = T * (2 * d + dv) * (4 + 2 * item)
+    streamed = 2 * blk * ((2 * d + 2 * dv) * item + 2 * LANES * 4)
+    return whole + streamed + 2 * blk * blk * 4
+
+
+def backward_form(q, v, num_heads, num_kv_heads):
+    """"fused" where ``attention_bwd``'s count fits ``BWD_VMEM_BUDGET``,
+    else "split"."""
+    T, d, blk, _ = _geometry(q, num_heads, num_kv_heads)
+    dv = v.shape[-1] // num_kv_heads
+    fits = bwd_vmem_bytes(T, d, dv, blk, q.dtype) <= BWD_VMEM_BUDGET
+    return "fused" if fits else "split"
+
+
 def attention_pallas_bwd(q, k, v, out, lse, dout, num_heads, num_kv_heads,
                          window, scale=None):
-    """(dq, dk, dv): one kernel over query blocks for ``dq``, one over key
-    blocks for ``dk``/``dv`` that sums a group's query heads in VMEM."""
-    T, d, blk, group = _geometry(q, num_heads, num_kv_heads)
-    dv = v.shape[-1] // num_kv_heads
-    b = q.shape[0]
-    scale = scale or d ** -0.5
+    """(dq, dk, dv) by the form ``backward_form`` picks from the shapes."""
+    bwd = (attention_bwd_fused
+           if backward_form(q, v, num_heads, num_kv_heads) == "fused"
+           else attention_bwd_split)
+    return bwd(q, k, v, out, lse, dout, num_heads, num_kv_heads, window,
+               scale)
+
+
+def _bwd_operands(q, k, v, out, lse, dout, num_heads):
+    """(q, k, v, d out, lse, delta): delta is each row's ``out . d out``,
+    lane-replicated as the log-sum-exp is."""
+    b, T, _ = q.shape
     delta = jnp.sum((out.astype(jnp.float32) * dout.astype(jnp.float32))
-                    .reshape(b, T, num_heads, dv), axis=-1)
+                    .reshape(b, T, num_heads, -1), axis=-1)
     delta = jnp.broadcast_to(jnp.swapaxes(delta, 1, 2)[..., None],
                              (b, num_heads, T, LANES))
-    operands = (q, k, v, dout, lse, delta)
+    return q, k, v, dout, lse, delta
+
+
+def _key_order_specs(blk, widths, group):
+    """At grid step (batch, key/value head, entry), where the entry names
+    the query head of the group beside its blocks: for each head size of
+    ``widths`` the block specs of the entry's query head's blocks and of
+    the key/value head's blocks, then that of its rows' statistics."""
+    own = [pl.BlockSpec(
+        (None, blk, w),
+        lambda b, h, e, qt, kt, gt, fl: (b, qt[e], h * group + gt[e]))
+        for w in widths]
+    kv = [pl.BlockSpec((None, blk, w),
+                       lambda b, h, e, qt, kt, gt, fl: (b, kt[e], h))
+          for w in widths]
+    rows = pl.BlockSpec(
+        (None, None, blk, LANES),
+        lambda b, h, e, qt, kt, gt, fl: (b, h * group + gt[e], qt[e], 0))
+    return own, kv, rows
+
+
+def attention_bwd_fused(q, k, v, out, lse, dout, num_heads, num_kv_heads,
+                        window, scale=None):
+    """(dq, dk, dv) by ``attention_bwd``: one walk of the band a key/value
+    head, its query heads outermost, five products a pair."""
+    T, d, blk, group = _geometry(q, num_heads, num_kv_heads)
+    dv = v.shape[-1] // num_kv_heads
+    sched = band_schedule(T, blk, window, by="head", group=group)
+    (own, own_v), (kv, kv_v), rows = _key_order_specs(blk, (d, dv), group)
+    # whole-sequence output blocks: dq's follows the query head, so it is
+    # written back as each head's walk ends; dk's and dv's once
+    whole_q = pl.BlockSpec((None, T, d), lambda b, h, e, qt, kt, gt, fl: (
+        b, 0, h * group + gt[e]))
+    whole_k, whole_v = (pl.BlockSpec(
+        (None, T, w), lambda b, h, e, qt, kt, gt, fl: (b, 0, h))
+        for w in (d, dv))
+    return _call(
+        functools.partial(_bwd_kernel, blk=blk, window=window,
+                          scale=scale or d ** -0.5),
+        "attention_bwd", num_kv_heads,
+        (sched.q, sched.k, sched.head, sched.flags),
+        _bwd_operands(q, k, v, out, lse, dout, num_heads),
+        in_specs=[own, kv, kv_v, own_v, rows, rows],
+        out_specs=[whole_q, whole_k, whole_v],
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype) for x in (q, k, v)],
+        scratch_shapes=[pltpu.VMEM((T, w), jnp.float32) for w in (d, d, dv)],
+        vmem_limit_bytes=bwd_vmem_bytes(T, d, dv, blk, q.dtype)
+        + BWD_VMEM_HEADROOM)
+
+
+def attention_bwd_split(q, k, v, out, lse, dout, num_heads, num_kv_heads,
+                        window, scale=None):
+    """(dq, dk, dv) by the long-sequence form: one kernel over query blocks
+    for ``dq``, one over key blocks for ``dk``/``dv`` that sums a group's
+    query heads in VMEM."""
+    T, d, blk, group = _geometry(q, num_heads, num_kv_heads)
+    dv = v.shape[-1] // num_kv_heads
+    scale = scale or d ** -0.5
+    operands = _bwd_operands(q, k, v, out, lse, dout, num_heads)
     tables, (own, own_v), (kv, kv_v), rows = _query_order(
         T, blk, (d, dv), window, group)
     dq = _call(
@@ -402,19 +585,8 @@ def attention_pallas_bwd(q, k, v, out, lse, dout, num_heads, num_kv_heads,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((blk, d), jnp.float32)])
 
-    # grid step (batch, key/value head, entry): the entry names the query
-    # head of the group beside its blocks
     sched = band_schedule(T, blk, window, by="key", group=group)
-    own, own_v = (pl.BlockSpec(
-        (None, blk, w),
-        lambda b, h, e, qt, kt, gt, fl: (b, qt[e], h * group + gt[e]))
-        for w in (d, dv))
-    kv, kv_v = (pl.BlockSpec((None, blk, w),
-                             lambda b, h, e, qt, kt, gt, fl: (b, kt[e], h))
-                for w in (d, dv))
-    rows = pl.BlockSpec(
-        (None, None, blk, LANES),
-        lambda b, h, e, qt, kt, gt, fl: (b, h * group + gt[e], qt[e], 0))
+    (own, own_v), (kv, kv_v), rows = _key_order_specs(blk, (d, dv), group)
     dk, dv_out = _call(
         functools.partial(_dkv_kernel, blk=blk, window=window, scale=scale),
         "attention_dkv", num_kv_heads,

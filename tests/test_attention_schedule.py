@@ -65,3 +65,22 @@ def test_schedule_is_the_band(T, block, window):
         queries = by_key.q[run].reshape(GROUP, per_head)
         assert (queries == queries[0]).all()
         assert (np.diff(queries[0]) == 1).all()
+
+
+@pytest.mark.parametrize("T,block,window", GEOMETRIES)
+def test_head_order_is_the_key_order_with_heads_outermost(T, block, window):
+    """``attention_bwd``'s walk: the ``key`` order's entries sorted stably
+    by head, so a key block's entries keep their (head, query block)
+    order (``dk``/``dv`` sum as ``attention_dkv`` sums) and a query block
+    meets its key blocks ascending (``dq`` sums as ``attention_dq``); a
+    run is one head's whole walk."""
+    by_key = att.band_schedule(T, block, window, by="key", group=GROUP)
+    by_head = att.band_schedule(T, block, window, by="head", group=GROUP)
+    order = np.argsort(by_key.head, kind="stable")
+    for table in ("q", "k", "head"):
+        assert (getattr(by_head, table)
+                == getattr(by_key, table)[order]).all()
+    runs = _runs(by_head, key=lambda e: by_head.head[e])
+    assert [by_head.head[run[0]] for run in runs] == list(range(GROUP))
+    assert {len(run) for run in runs} == {len(_band(T, block, window))}
+    assert by_head.skipped == by_key.skipped

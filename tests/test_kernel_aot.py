@@ -114,13 +114,14 @@ def _pallas_grids(fn, *args):
 
 
 # a head's schedule at 8192 in blocks of 512: the window layers' 45
-# entries, the full layer's 136; attention_dkv walks each of a group's 8
+# entries, the full layer's 136; attention_bwd walks each of a group's 8
 # query heads through a key/value head's
 @pytest.mark.parametrize("window,entries", [(1024, 45), (0, 136)])
 def test_attention_kernels_compile_for_v5e(one_chip, native_lowering,
                                            window, entries):
     """Both of the cell's layers, on the scalar-prefetch grid: its last
-    axis is the schedule's length, so no step's body is skipped."""
+    axis is the schedule's length, so no step's body is skipped. The
+    backward is the one kernel ``attention_bwd``, inside its VMEM count."""
     from paddle_tpu.ops.pallas import attention as att
     q, kv, lse = _attention_shapes(one_chip)
     fwd = lambda q, k, v: att.attention_pallas(         # noqa: E731
@@ -130,12 +131,11 @@ def test_attention_kernels_compile_for_v5e(one_chip, native_lowering,
     assert _pallas_grids(fwd, q, kv, kv) == {
         "attention_fwd": ((1, ATT_HEADS, entries), 3)}
     assert _pallas_grids(bwd, q, kv, kv, q, lse, q) == {
-        "attention_dq": ((1, ATT_HEADS, entries), 3),
-        "attention_dkv": ((1, ATT_KV, entries * ATT_HEADS // ATT_KV), 4)}
+        "attention_bwd": ((1, ATT_KV, entries * ATT_HEADS // ATT_KV), 4)}
     assert "attention_fwd" in jax.jit(fwd).lower(
         q, kv, kv).compile().as_text()
     text = jax.jit(bwd).lower(q, kv, kv, q, lse, q).compile().as_text()
-    assert "attention_dq" in text and "attention_dkv" in text
+    _assert_inside_the_count(text, ATT_T, ATT_D, ATT_D)
 
 
 def test_attention_schedule_fits_scalar_memory_at_the_longest_length(
@@ -158,8 +158,12 @@ def test_attention_schedule_fits_scalar_memory_at_the_longest_length(
     entries = len(att.band_schedule(T, 512, 0, by="key",
                                     group=ATT_HEADS // ATT_KV).q)
     assert att.MAX_ENTRIES - 2048 < entries <= att.MAX_ENTRIES
+    # whole-sequence gradients do not fit VMEM here: the split pair
+    assert att.backward_form(q, kv, ATT_HEADS, ATT_KV) == "split"
+    assert sorted(_pallas_grids(bwd, q, kv, kv, q, lse, q)) == [
+        "attention_dkv", "attention_dq"]
     text = jax.jit(bwd).lower(q, kv, kv, q, lse, q).compile().as_text()
-    assert "attention_dq" in text and "attention_dkv" in text
+    assert sorted(_kernel_vmem(text)) == ["attention_dkv", "attention_dq"]
 
 
 # ---- the Kimi-Linear cell: latent attention trains expanded, 32 query AND
@@ -168,27 +172,41 @@ def test_attention_schedule_fits_scalar_memory_at_the_longest_length(
 MLA_HEADS, MLA_QK, MLA_V, MLA_T = 32, 256, 128, 4096
 
 
-def _kernel_vmem(text):
-    """{kernel name: bytes of VMEM (memory space 1) Mosaic's allocation
-    asks for it}, read off a compiled module's text."""
+def _kernel_vmem(text, key="used_scoped_memory_configs"):
+    """{kernel name: bytes of VMEM (memory space 1)}, read off a compiled
+    module's text: under ``key`` Mosaic's own allocation for it; under
+    ``scoped_memory_configs`` the limit the call set, where it set one."""
     import re
     out = {}
     for line in text.splitlines():
         if "tpu_custom_call" not in line:
             continue
-        (name,) = set(re.findall(r"attention_(?:fwd|dq|dkv)", line))
+        (name,) = set(re.findall(r"attention_(?:fwd|dq|dkv|bwd)", line))
         out[name] = sum(int(n) for n in re.findall(
-            r'"memory_space":"1","offset":"0","size":"(\d+)"', line))
+            '"%s":\\[\\{"memory_space":"1","offset":"0","size":"(\\d+)"'
+            % key, line))
     return out
+
+
+def _assert_inside_the_count(text, T, d, dv):
+    """``attention_bwd``'s allocation lies within 2 MiB under
+    ``bwd_vmem_bytes`` and under the limit the call sets from it."""
+    from paddle_tpu.ops.pallas import attention as att
+    count = att.bwd_vmem_bytes(T, d, dv, att.kernel_block(T), jnp.bfloat16)
+    used = _kernel_vmem(text)["attention_bwd"]
+    limit = _kernel_vmem(text, "scoped_memory_configs")["attention_bwd"]
+    assert count - (2 << 20) < used <= count < limit, (used, count, limit)
+    assert limit == count + att.BWD_VMEM_HEADROOM
 
 
 def test_attention_kernels_compile_for_v5e_at_heads_of_192_and_128(
         one_chip, native_lowering):
     """The op pads 192 to 256 lanes and asks the kernels about that: two
-    head sizes in one call, the block from the length as before (512), a
-    head's blocks, scratch and scores under Mosaic's default scoped limit
-    (16 MiB on a v5e) by the compile's own count, and with a group of 1
-    ``attention_dkv``'s schedule is a head's own."""
+    head sizes in one call, the block from the length as before (512), the
+    forward's blocks, scratch and scores under Mosaic's default scoped
+    limit (16 MiB on a v5e) by the compile's own count, and with a group
+    of 1 ``attention_bwd``'s schedule is a head's own, inside its VMEM
+    count."""
     from paddle_tpu.ops import attention_ops
     from paddle_tpu.ops.pallas import attention as att
 
@@ -212,18 +230,18 @@ def test_attention_kernels_compile_for_v5e_at_heads_of_192_and_128(
     assert _pallas_grids(fwd, q, q, v) == {
         "attention_fwd": ((1, MLA_HEADS, entries), 3)}
     assert _pallas_grids(bwd, q, q, v, v, lse, v) == {
-        "attention_dq": ((1, MLA_HEADS, entries), 3),
-        "attention_dkv": ((1, MLA_HEADS, entries), 4)}
+        "attention_bwd": ((1, MLA_HEADS, entries), 4)}
     assert jax.eval_shape(fwd, q, q, v)[0].shape == v.shape
     assert [x.shape for x in jax.eval_shape(bwd, q, q, v, v, lse, v)] == [
         q.shape, q.shape, v.shape]
     vmem = _kernel_vmem(jax.jit(fwd).lower(q, q, v).compile().as_text())
-    vmem.update(_kernel_vmem(
-        jax.jit(bwd).lower(q, q, v, v, lse, v).compile().as_text()))
-    assert sorted(vmem) == ["attention_dkv", "attention_dq", "attention_fwd"]
+    assert sorted(vmem) == ["attention_fwd"]
     # the blocks alone (double-buffered bfloat16 q, k of 512 x 256 and v,
-    # out or d out of 512 x 128) are 1.5 MiB
-    assert all(3 << 19 < n < 16 << 20 for n in vmem.values()), vmem
+    # out of 512 x 128) are 1.5 MiB
+    assert 3 << 19 < vmem["attention_fwd"] < 16 << 20, vmem
+    _assert_inside_the_count(
+        jax.jit(bwd).lower(q, q, v, v, lse, v).compile().as_text(),
+        MLA_T, MLA_QK, MLA_V)
 
 
 # (tokens, top k, held, experts, buffer factor, hidden, expert width): the
@@ -560,8 +578,9 @@ def test_ssd_scan_kernels_compile_for_v5e(one_chip, native_lowering,
 def test_attention_at_32_and_2_heads_reaches_the_kernels(one_chip,
                                                          native_lowering):
     """The cell's one attention layer: sixteen query heads a key/value head
-    is a shape the three kernels take natively (no fallback), on the
-    full-causal schedule of 4096 tokens in blocks of 512."""
+    is a shape the two kernels take natively (no fallback), on the
+    full-causal schedule of 4096 tokens in blocks of 512; the backward's
+    walk is the group's heads in turn."""
     from paddle_tpu.ops.pallas import attention as att
 
     def s(heads):
@@ -579,12 +598,12 @@ def test_attention_at_32_and_2_heads_reaches_the_kernels(one_chip,
     assert _pallas_grids(fwd, q, kv, kv) == {
         "attention_fwd": ((1, 32, entries), 3)}
     assert _pallas_grids(bwd, q, kv, kv, q, lse, q) == {
-        "attention_dq": ((1, 32, entries), 3),
-        "attention_dkv": ((1, 2, entries * 16), 4)}
+        "attention_bwd": ((1, 2, entries * 16), 4)}
     assert "attention_fwd" in jax.jit(fwd).lower(
         q, kv, kv).compile().as_text()
-    text = jax.jit(bwd).lower(q, kv, kv, q, lse, q).compile().as_text()
-    assert "attention_dq" in text and "attention_dkv" in text
+    _assert_inside_the_count(
+        jax.jit(bwd).lower(q, kv, kv, q, lse, q).compile().as_text(),
+        SSD_T, 128, 128)
 
 
 def test_the_rotated_latent_slice_compiles_for_v5e_at_the_cells_shape(

@@ -606,6 +606,19 @@ def test_kernel_probe_tiny_has_a_record_for_every_family_with_a_site():
         assert rec["max_rel_err"] < 2e-2, rec
 
 
+def test_kernel_probe_tiny_attention_backward_forms_agree_to_the_bit():
+    """The record the chip run reads for the fused attention backward: at
+    the tiny shapes (a window over a group of 2; heads of 256 / 128) both
+    forms run, and dq, dk, dv are equal to the bit."""
+    rec = _kernel_probe().attention_bwd_forms(True)
+    assert rec["error"] is None and len(rec["shapes"]) == 2
+    for row in rec["shapes"]:
+        assert row["form"] == "fused"
+        assert row["bit_equal"] == [True] * 3, row
+        assert row["sha256"]["fused"] == row["sha256"]["split"]
+        assert set(row["ms"]) == {"fused", "split"}
+
+
 def test_measure_interleaves_windows_and_drops_raising_runner():
     kernel_probe = _kernel_probe()
     calls = []

@@ -221,10 +221,16 @@ def test_a_rigged_router_drops_no_row_and_an_overflow_is_loud():
 def test_attention_kernels_match_the_twin_through_the_op(length, window,
                                                          heads, kv_heads, d,
                                                          dv):
-    """kernel_tier=pallas runs the attention family's three kernels in the
-    interpreter; outputs, the log-sum-exp and all three gradients match
-    the blocked twin."""
+    """kernel_tier=pallas runs the attention family's kernels in the
+    interpreter, the backward as the one kernel ``attention_bwd`` at every
+    one of these lengths; outputs, the log-sum-exp and all three gradients
+    match the blocked twin."""
+    from paddle_tpu.obs.metrics import REGISTRY
     from paddle_tpu.ops.pallas import dispatch_counts
+
+    def forms():
+        counter = REGISTRY.get("paddle_tpu_attention_bwd")
+        return [counter.labels(form=f).value for f in ("fused", "split")]
 
     batch = 2 if length <= 256 else 1
     shapes = {"q": (batch, length, heads * d),
@@ -258,11 +264,77 @@ def test_attention_kernels_match_the_twin_through_the_op(length, window,
             fluid.set_flags({"kernel_tier": "auto"})
 
     before = dispatch_counts().get("attention", {}).get("interpret", 0)
+    fused, split = forms()
     kernel, twin = run("pallas"), run("jnp")
     assert dispatch_counts()["attention"]["interpret"] == before + 2
+    assert forms() == [fused + 1, split]
     kernel[1] = np.asarray(kernel[1])[..., 0]       # lane-replicated
     for a, b in zip(kernel, twin):
         assert _err(a, b) < 1e-5
+
+
+def _attention_bwd_inputs(length, heads, kv_heads, window):
+    """Seeded float32 q, k, v, d out at heads of 128, with the forward
+    kernel's (out, lse) and the twin's."""
+    from paddle_tpu.ops.pallas import attention as att
+
+    rng = np.random.RandomState(length + window)
+    q, dout = (jnp.asarray(rng.randn(1, length, heads * 128), jnp.float32)
+               for _ in range(2))
+    k, v = (jnp.asarray(rng.randn(1, length, kv_heads * 128), jnp.float32)
+            for _ in range(2))
+    return ((q, k, v, dout),
+            att.attention_pallas(q, k, v, heads, kv_heads, window),
+            att.attention_jnp(q, k, v, heads, kv_heads, window))
+
+
+# (T, window, query heads, key/value heads): a window no multiple of the
+# block over a group of 2; a window wider than T over a group of 4
+@pytest.mark.parametrize("length,window,heads,kv_heads",
+                         [(768, 300, 4, 2), (384, 4096, 4, 1)])
+def test_attention_split_backward_matches_the_twin(length, window, heads,
+                                                   kv_heads):
+    """The long-sequence form, ``attention_dq`` + ``attention_dkv``, called
+    directly in the interpreter: its three gradients match the twin's, and
+    the fused kernel's equal its to the bit (each gradient's sums arrive
+    in the same order in both)."""
+    from paddle_tpu.ops.pallas import attention as att
+
+    (q, k, v, dout), (out, lse), (t_out, t_lse) = _attention_bwd_inputs(
+        length, heads, kv_heads, window)
+    args = (heads, kv_heads, window)
+    split = att.attention_bwd_split(q, k, v, out, lse, dout, *args)
+    fused = att.attention_bwd_fused(q, k, v, out, lse, dout, *args)
+    twin = att.attention_jnp_bwd(q, k, v, t_out, t_lse, dout, *args)
+    for a, b, c in zip(split, twin, fused):
+        assert _err(a, b) < 1e-5
+        assert np.array_equal(np.asarray(a), np.asarray(c))
+
+
+def test_attention_backward_form_follows_the_vmem_count():
+    """``attention_bwd`` keeps dq, dk and dv of the whole sequence in VMEM:
+    float32 accumulators (4 bytes) and double-buffered bfloat16 output
+    blocks (2 x 2), T x (2 d + dv) x 8 bytes, beside the streamed blocks
+    twice, 2 x 512 x ((2 d + 2 dv) x 2 + 2 x 128 x 4), and two float32
+    [512, 512] values, 2 MiB. Cell 5 (8192 tokens, heads of 128): 24 + 2 +
+    2 = 28 MiB; cells 6 and 8 (4096, queries and keys padded to 256,
+    values 128): 20 + 2.5 + 2 = 24.5 MiB; cell 7 (4096, heads of 128,
+    sixteen a group): 12 + 2 + 2 = 16 MiB; all fused under the budget of
+    96 MiB. At 88 x 512 tokens with cell 5's heads: 132 + 2 + 2 = 136 MiB,
+    split."""
+    from paddle_tpu.ops.pallas import attention as att
+
+    def form(T, heads, kv_heads, d, dv):
+        q = jax.ShapeDtypeStruct((1, T, heads * d), jnp.bfloat16)
+        v = jax.ShapeDtypeStruct((1, T, kv_heads * dv), jnp.bfloat16)
+        return (att.bwd_vmem_bytes(T, d, dv, 512, jnp.bfloat16) / 2 ** 20,
+                att.backward_form(q, v, heads, kv_heads))
+
+    assert att.BWD_VMEM_BUDGET == 96 << 20
+    assert form(8192, 32, 4, 128, 128) == (28.0, "fused")
+    assert form(4096, 32, 32, 256, 128) == (24.5, "fused")
+    assert form(4096, 32, 2, 128, 128) == (16.0, "fused")
+    assert form(88 * 512, 32, 4, 128, 128) == (136.0, "split")
 
 
 def test_attention_blocks_gauge_reads_the_schedule_as_last_traced():

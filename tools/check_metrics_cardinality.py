@@ -66,6 +66,8 @@ BOUNDED_LABELS = {
     "stage": "compile stages (obs.perf.STAGES): trace/lower/"
              "xla_compile/cache_load/other",
     "source": "where a built executable came from: compiled/cache",
+    "form": "the attention backward's two forms "
+            "(ops/pallas/attention.backward_form): fused/split",
     "reason": "artifact reject reasons — the fixed enums "
               "serving.execcache.REJECT_REASONS (format/manifest/"
               "fingerprint/deserialize/run_failed), "

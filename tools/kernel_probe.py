@@ -29,8 +29,10 @@ of 128, chunks of 64), forward and backward; the mixers' short convolution
 at the Kimi-Linear cell's shape (4096 tokens x 4096 channels, 4 taps, no
 bias) and the Nemotron cell's (x 6144, with a bias), forward and backward;
 the Mamba-2 state-space core at the Nemotron cell's shape (4096 tokens, 64
-heads of 64 in 8 groups of state 128, chunks of 128), forward and backward.
-Every family with a dispatch site in ``paddle_tpu/ops/`` has a case.
+heads of 64 in 8 groups of state 128, chunks of 128), forward and backward;
+and the attention backward's two forms against each other, bit for bit, at
+the four transformer cells' shapes (``attention_bwd_forms``). Every family
+with a dispatch site in ``paddle_tpu/ops/`` has a case.
 
 A kernel that fails to lower is a RESULT here (``lowered: false`` with the
 compiler's message), never a crash. A watchdog ends the process if one
@@ -532,6 +534,67 @@ def attention_case(T, heads, kv_heads, window, backward):
             "pallas": route(att.attention_pallas, att.attention_pallas_bwd)}
 
 
+def attention_bwd_forms(tiny):
+    """The attention backward's two forms on the same inputs: the one
+    kernel ``attention_bwd`` and the split pair ``attention_dq`` +
+    ``attention_dkv``, at the Mellum2 cell's two layers (8192 tokens, 32 / 4
+    heads of 128), the latent cells' (4096, 32 heads of 256 / 128, scale of
+    192) and the Nemotron cell's (4096, 32 / 2 of 128). Per shape: whether
+    each of dq, dk, dv is equal to the bit, its largest difference and
+    sha256, and each form's time by ``measure`` and ``device_ms``."""
+    import hashlib
+
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops.pallas import attention as att
+
+    shapes = ([(384, 2, 1, 128, 128, 256, None), (256, 2, 2, 256, 128, 0,
+                                                   192 ** -0.5)] if tiny else
+              [(8192, 32, 4, 128, 128, 1024, None),
+               (8192, 32, 4, 128, 128, 0, None),
+               (4096, 32, 32, 256, 128, 0, 192 ** -0.5),
+               (4096, 32, 2, 128, 128, 0, None)])
+    rec = {"case": "attention_bwd_forms", "family": "attention",
+           "error": None, "shapes": []}
+    for T, heads, kv_heads, d, dv, window, scale in shapes:
+        keys = jax.random.split(jax.random.PRNGKey(T + window), 4)
+        q = jax.random.normal(keys[0], (1, T, heads * d), jnp.bfloat16)
+        dout = jax.random.normal(keys[1], (1, T, heads * dv), jnp.bfloat16)
+        k = jax.random.normal(keys[2], (1, T, kv_heads * d), jnp.bfloat16)
+        v = jax.random.normal(keys[3], (1, T, kv_heads * dv), jnp.bfloat16)
+        args = (heads, kv_heads, window, scale)
+        out, lse = jax.jit(lambda q, k, v: att.attention_pallas(
+            q, k, v, *args))(q, k, v)
+        forms = {name: jax.jit(lambda q, k, v, o, l, do, fn=fn: fn(
+                     q, k, v, o, l, do, *args))
+                 for name, fn in (("fused", att.attention_bwd_fused),
+                                  ("split", att.attention_bwd_split))}
+        runners = {name: (lambda fn=fn: fn(q, k, v, out, lse, dout))
+                   for name, fn in forms.items()}
+        got = {name: [np.asarray(x) for x in jax.block_until_ready(run())]
+               for name, run in runners.items()}
+        row = {"T": T, "heads": heads, "kv_heads": kv_heads, "d": d,
+               "dv": dv, "window": window,
+               "form": att.backward_form(q, v, heads, kv_heads),
+               "bit_equal": [], "max_abs_diff": [], "sha256": {}}
+        for a, b in zip(got["fused"], got["split"]):
+            row["bit_equal"].append(a.tobytes() == b.tobytes())
+            row["max_abs_diff"].append(float(np.max(np.abs(
+                a.astype(np.float32) - b.astype(np.float32)))))
+        for name, grads in got.items():
+            row["sha256"][name] = [hashlib.sha256(x.tobytes()).hexdigest()
+                                   [:16] for x in grads]
+        ms, dropped = measure(runners, repeats=3, inner=2)
+        row["ms"] = {n: round(t, 4) for n, t in ms.items()}
+        if dropped:
+            rec["error"] = dropped
+        if jax.default_backend() != "cpu":
+            row["device_ms"] = {n: round(device_ms(run), 4)
+                                for n, run in runners.items()}
+        rec["shapes"].append(row)
+    return rec
+
+
 def resnet50_param_shapes():
     """The tensors the flagship's optimizer updates, read off the built
     program (the PR-21 run also counted the 106 BN running statistics:
@@ -671,6 +734,8 @@ def main():
             for name, family, build in cases(args.tiny)]
     todo.append(("lstm_textcls_train_step", "lstm",
                  lambda: lstm_lane_step(args.tiny)))
+    todo.append(("attention_bwd_forms", "attention",
+                 lambda: attention_bwd_forms(args.tiny)))
     for name, family, run in todo:
         if args.only and family != args.only:
             continue
