@@ -1133,6 +1133,48 @@ def latent_attention(input, num_heads, kv_lora_rank, qk_nope_head_dim,
     return proj(out, int(input.shape[-1]))
 
 
+QK_NORM_SCOPE = "qk_norm"
+
+
+def _head_rms_norm(x, heads, head_dim, epsilon):
+    """RMSNorm over each head of [batch, seq, heads * head_dim] with ONE
+    learned scale [head_dim] for all heads: ``reshape`` to [batch, seq,
+    heads, head_dim], ``rms_norm``, back."""
+    h = tensor.reshape(x, [0, 0, int(heads), int(head_dim)])
+    return tensor.reshape(rms_norm(h, epsilon=epsilon),
+                          [0, 0, int(heads) * int(head_dim)])
+
+
+def grouped_query_attention(input, num_heads, num_kv_heads, head_dim,
+                            rope_theta=None, qk_norm=False, epsilon=1e-6,
+                            param_attr=None, qk_attr=None, name=None):
+    """Causal grouped-query attention over an already normed ``input``
+    [batch, seq, hidden]; no bias anywhere. ``q, k, v = x W_q, x W_k, x
+    W_v``: ``num_heads`` query heads over ``num_kv_heads`` key/value heads
+    of ``head_dim``. With ``qk_norm`` every query head and every key head is
+    RMSNorm'd (``epsilon``) with a learned scale [head_dim] of the queries'
+    and one of the keys', under ``fluid.name_scope("qk_norm")`` (a trace
+    tells those ops from the block norms). With ``rope_theta`` q and k then
+    turn by ``rotary_embedding`` (``rotate_half``) at positions 0..seq-1.
+    ``causal_self_attention`` over the whole sequence, then ``W_o`` back to
+    hidden. W_q and W_k take ``qk_attr`` (default ``param_attr``), W_v and
+    W_o ``param_attr``. Parameters in the order W_q, W_k, W_v, the query
+    norm's scale, the key norm's, W_o."""
+    q = _project(input, int(num_heads) * int(head_dim), qk_attr or param_attr)
+    k = _project(input, int(num_kv_heads) * int(head_dim),
+                 qk_attr or param_attr)
+    v = _project(input, int(num_kv_heads) * int(head_dim), param_attr)
+    if qk_norm:
+        with name_scope(QK_NORM_SCOPE):
+            q = _head_rms_norm(q, num_heads, head_dim, epsilon)
+            k = _head_rms_norm(k, num_kv_heads, head_dim, epsilon)
+    if rope_theta is not None:
+        q, k = rotary_embedding(q, k, head_dim=head_dim, theta=rope_theta)
+    a = causal_self_attention(q, k, v, num_heads, num_kv_heads=num_kv_heads,
+                              name=name)
+    return _project(a, int(input.shape[-1]), param_attr)
+
+
 MTP_SCOPE = "mtp"
 
 
@@ -1219,6 +1261,44 @@ def causal_conv1d(input, taps, param_attr=None, bias_attr=False, name=None):
     helper.append_op("causal_conv1d", inputs=inputs,
                      outputs={"Out": [out.name]})
     return out
+
+
+def gated_short_conv(input, taps, param_attr=None, name=None):
+    """The gated short convolution of LFM2's conv mixer over the
+    in-projection's output ``input`` [batch, seq, 3 * channels], which is
+    ``[B | C | x]``: ``C * conv(B * x)`` [batch, seq, channels], with
+    ``conv`` a causal depthwise convolution over time (each channel's own
+    filter of ``taps`` over the current and the ``taps - 1`` earlier tokens,
+    zeros before the first; the filter parameter is [taps, channels], the
+    last tap on the current token). No bias, no activation."""
+    width = int(input.shape[-1])
+    if width % 3:
+        raise ValueError(f"gated_short_conv: {width} channels are not "
+                         f"[B | C | x] of three equal widths")
+    helper = LayerHelper("gated_short_conv", name=name)
+    w = helper.create_parameter(ParamAttr.to_attr(param_attr),
+                                shape=(int(taps), width // 3),
+                                dtype="float32")
+    out = helper.create_tmp_variable(
+        input.dtype, shape=tuple(input.shape[:-1]) + (width // 3,))
+    helper.append_op("gated_short_conv",
+                     inputs={"X": [input.name], "Filter": [w.name]},
+                     outputs={"Out": [out.name]})
+    return out
+
+
+def short_conv_mixer(input, taps=3, param_attr=None, conv_attr=None,
+                     name=None):
+    """LFM2's gated short-convolution mixer over an already normed ``input``
+    [batch, seq, hidden]; no bias anywhere: ``[B | C | x] = x W_in`` (3 x
+    hidden), ``gated_short_conv`` (``C * conv(B * x)``, ``taps`` taps),
+    then ``W_out`` back to hidden. Parameters in the order W_in, the filter
+    (``conv_attr``, default ``param_attr``), W_out (``param_attr``)."""
+    hidden = int(input.shape[-1])
+    bcx = _project(input, 3 * hidden, param_attr)
+    y = gated_short_conv(bcx, taps, param_attr=copy.deepcopy(
+        ParamAttr.to_attr(conv_attr or param_attr)), name=name)
+    return _project(y, hidden, param_attr)
 
 
 def kda_decay_gate(input, num_heads, name=None):

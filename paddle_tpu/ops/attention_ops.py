@@ -97,15 +97,13 @@ def _causal_mha(q, k, v, num_heads):
 def _attention_route(q, heads, kv_heads, window, v=None):
     """"pallas" or "jnp" for this call; the forward op and its grad op ask
     the same question and get the same answer. The kernels are asked about
-    the query/key heads as ``_lane_padded`` would hand them over."""
+    the heads as ``_lane_padded`` would hand them over."""
     from .pallas import use_pallas
     from .pallas import attention as att
 
-    d = q.shape[-1] // heads
-    wide = jax.ShapeDtypeStruct(
-        q.shape[:-1] + (heads * (d + -d % att.LANES),), q.dtype)
     if not use_pallas("attention", att.attention_supported(
-            wide, heads, kv_heads, window, v)):
+            _wide(q, heads), heads, kv_heads, window,
+            None if v is None else _wide(v, kv_heads))):
         return "jnp"
     T = q.shape[1]
     sched = att.band_schedule(T, att.kernel_block(T), window)
@@ -124,6 +122,14 @@ def _attention_attrs(ctx, q, k, v):
             f"attention: {heads} query heads over {kv_heads} key/value "
             f"heads do not fit Q {q.shape}, K {k.shape} and V {v.shape}")
     return heads, kv_heads, int(ctx.attr("window", 0) or 0)
+
+
+def _wide(x, heads):
+    """``x``'s shape and type as ``_lane_padded`` hands it over."""
+    from .pallas.attention import LANES
+    d = x.shape[-1] // heads
+    return jax.ShapeDtypeStruct(x.shape[:-1] + (heads * (d + -d % LANES),),
+                                x.dtype)
 
 
 def _lane_padded(x, heads):
@@ -159,9 +165,14 @@ def _attention_forward(q, k, v, heads, kv_heads, window):
     with kernel_span(route, "attention"):
         if route == "jnp":
             return att.attention_jnp(q, k, v, heads, kv_heads, window)
-        return att.attention_pallas(
-            _lane_padded(q, heads), _lane_padded(k, kv_heads), v, heads,
-            kv_heads, window, scale=(q.shape[-1] // heads) ** -0.5)
+        out, lse = att.attention_pallas(
+            _lane_padded(q, heads), _lane_padded(k, kv_heads),
+            _lane_padded(v, kv_heads), heads, kv_heads, window,
+            scale=(q.shape[-1] // heads) ** -0.5)
+        # the values' zero columns give zero output columns
+        return _cut_heads(out, jax.ShapeDtypeStruct(
+            out.shape[:-1] + (v.shape[-1] // kv_heads * heads,), out.dtype),
+            heads), lse
 
 
 def _attention_grad_maker(op):
@@ -221,13 +232,17 @@ def causal_self_attention_grad(ctx):
             dq, dk, dv = att.attention_jnp_bwd(qc, kc, vc, out, lse, d, heads,
                                                kv_heads, window)
         else:
-            qp = _lane_padded(qc, heads)
-            _M_BWD.labels(form=att.backward_form(qp, vc, heads,
+            # ``Out`` and its gradient padded with zeros too, so the row
+            # sums the backward forms are the true ones
+            qp, vp = _lane_padded(qc, heads), _lane_padded(vc, kv_heads)
+            _M_BWD.labels(form=att.backward_form(qp, vp, heads,
                                                  kv_heads)).inc()
             dq, dk, dv = att.attention_pallas_bwd(
-                qp, _lane_padded(kc, kv_heads), vc, out, lse, d, heads,
-                kv_heads, window, scale=(q.shape[-1] // heads) ** -0.5)
-            dq, dk = _cut_heads(dq, q, heads), _cut_heads(dk, k, kv_heads)
+                qp, _lane_padded(kc, kv_heads), vp, _lane_padded(out, heads),
+                lse, _lane_padded(d, heads), heads, kv_heads, window,
+                scale=(q.shape[-1] // heads) ** -0.5)
+            dq, dk, dv = (_cut_heads(dq, q, heads), _cut_heads(dk, k, kv_heads),
+                          _cut_heads(dv, v, kv_heads))
     ctx.set_output("Q@GRAD", dq.astype(q.dtype))
     ctx.set_output("K@GRAD", dk.astype(k.dtype))
     ctx.set_output("V@GRAD", dv.astype(v.dtype))

@@ -531,3 +531,89 @@ _register_with_vjp(
     ``silu(Gate)`` and multiplies BEFORE the norm, the norm is over groups
     of ``group_size`` channels and ``Scale`` is one a channel [heads * d].
     Float32 inside, X's type out.""")
+
+
+# ---------------------------------------------------------------------------
+# gated_short_conv — the gated short-convolution mixer's core (LFM2)
+# ---------------------------------------------------------------------------
+
+def _gated_short_conv(ctx, x, w):
+    """The plain formula: ``C * conv(B * x)``, float32 inside."""
+    taps, h = w.shape
+    xf, wf = x.astype(jnp.float32), w.astype(jnp.float32)
+    u = xf[..., :h] * xf[..., 2 * h:]
+    t = x.shape[1]
+    back = jnp.pad(u, ((0, 0), (taps - 1, 0), (0, 0)))
+    z = sum(back[:, j:j + t] * wf[j] for j in range(taps))
+    return (xf[..., h:2 * h] * z).astype(x.dtype)
+
+
+def _gated_route(x, w):
+    """(the kernel module, "pallas" | "jnp"): the ``causal_conv1d``
+    family's question for its gated form, one answer for the op and its
+    grad op."""
+    from .pallas import causal_conv1d as cc
+    return cc, "pallas" if use_pallas(
+        "causal_conv1d", cc.gated_supported(x, w)) else "jnp"
+
+
+def _gated_forward(ctx, x, w):
+    cc, route = _gated_route(x, w)
+    with kernel_span(route, "causal_conv1d"):
+        if route == "jnp":
+            return _gated_short_conv(ctx, x, w)
+        return cc.gated_short_conv_fwd(x, w)
+
+
+def _gated_backward(ctx, args, dout):
+    cc, route = _gated_route(*args)
+    with kernel_span(route, "causal_conv1d"):
+        if route == "jnp":
+            return _vjp_grads(_gated_short_conv, ctx, args, dout)
+        return cc.gated_short_conv_bwd(*args, dout)
+
+
+def _gated_infer(op, block):
+    x = block.var(op.input("X")[0])
+    for name in op.output("Out"):
+        out = block.var(name)
+        out.dtype = out.dtype or x.dtype
+        if x.shape is not None and x.shape[-1] is not None:
+            out.shape = tuple(x.shape[:-1]) + (int(x.shape[-1]) // 3,)
+
+
+def _gated_grad_maker(op):
+    return [OpSpec("gated_short_conv_grad",
+                   {"X": op.input("X"), "Filter": op.input("Filter"),
+                    "Out@GRAD": G(op.output("Out"))},
+                   {"X@GRAD": G(op.input("X")),
+                    "Filter@GRAD": G(op.input("Filter"))}, dict(op.attrs))]
+
+
+@register_op("gated_short_conv", infer_shape=_gated_infer,
+             grad=_gated_grad_maker)
+def gated_short_conv(ctx):
+    """The gated short convolution of LFM2's conv mixer: ``X`` [b, T, 3 *
+    channels] is ``[B | C | x]`` (the in-projection's output in that
+    order), ``Filter`` [taps, channels] (one filter a channel, the LAST tap
+    on the current token, zeros before the first), and ``Out = C * conv(B
+    * x)`` [b, T, channels]: no bias, no activation. Float32 inside, X's
+    type out. By the kernel tier's rule, ``use_pallas("causal_conv1d",
+    gated_supported(x, w))``, one answer for the op and its grad op: the
+    gated form of the Pallas family ``causal_conv1d``
+    (ops/pallas/causal_conv1d.py: one kernel each way that reads X once,
+    keeps ``B * x``, the convolution and ``dz`` in VMEM and writes
+    ``X@GRAD`` in X's own layout) on a TPU; ``_gated_short_conv`` and
+    ``jax.vjp`` of it on the CPU, under ``kernel_tier=jnp`` and for any
+    other shape."""
+    ctx.set_output("Out", _gated_forward(ctx, data_of(ctx.input("X")),
+                                         data_of(ctx.input("Filter"))))
+
+
+@register_op("gated_short_conv_grad")
+def gated_short_conv_grad(ctx):
+    """``X@GRAD`` in X's type and layout, ``Filter@GRAD`` float32."""
+    x, w = data_of(ctx.input("X")), data_of(ctx.input("Filter"))
+    dx, dw = _gated_backward(ctx, (x, w), data_of(ctx.input("Out@GRAD")))
+    ctx.set_output("X@GRAD", dx.astype(x.dtype))
+    ctx.set_output("Filter@GRAD", dw.astype(jnp.float32))

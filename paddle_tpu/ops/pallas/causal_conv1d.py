@@ -106,16 +106,20 @@ def _rows(t, tile):
     return rows if t % rows == 0 else tile
 
 
-def _shifted(x_ref, s_ref, start, rows, cols, taps):
+def _columns_of(x_ref, cols):
+    """The loader of ``_shifted`` that reads ``x``'s columns ``cols``."""
+    return lambda at, n: x_ref[0, pl.ds(at, n), cols]
+
+
+def _shifted(load, tile, s_ref, start, rows, taps):
     """``x[t - s]`` for s = 0 .. taps - 1 over the piece's tokens ``[start,
-    start + rows)`` of the columns ``cols``: float32 [rows, 128] each. The
-    piece and the sublane tile before it (zeros before the first token) go
-    to the float32 scratch ``s_ref`` (token t in row t + tile), and the
-    shifted copies are loads from it at a sublane offset."""
-    tile = _tile(x_ref.dtype)
-    piece = x_ref[0, pl.ds(start, rows), cols].astype(jnp.float32)
-    before = x_ref[0, pl.ds(pl.multiple_of(jnp.maximum(start - tile, 0),
-                                           tile), tile), cols]
+    start + rows)``: float32 [rows, 128] each, ``load(at, n)`` giving the
+    tokens ``[at, at + n)`` of the column (``tile``: the input's sublane
+    tile). The piece and the sublane tile before it (zeros before the first
+    token) go to the float32 scratch ``s_ref`` (token t in row t + tile),
+    and the shifted copies are loads from it at a sublane offset."""
+    piece = load(start, rows).astype(jnp.float32)
+    before = load(pl.multiple_of(jnp.maximum(start - tile, 0), tile), tile)
     s_ref[pl.ds(start, tile), :] = jnp.where(
         start > 0, before.astype(jnp.float32), 0.0)
     s_ref[pl.ds(start + tile, rows), :] = piece
@@ -162,7 +166,8 @@ def _fwd_kernel(*refs, has_bias):
         def piece(c, carry, cols=cols, w=w, bias=bias):
             start = pl.multiple_of(c * rows, rows)
             y = _pre_activation(
-                _shifted(x_ref, s_ref, start, rows, cols, taps), w, bias)
+                _shifted(_columns_of(x_ref, cols), _tile(x_ref.dtype), s_ref,
+                         start, rows, taps), w, bias)
             o_ref[0, pl.ds(start, rows), cols] = (
                 y * _sigmoid(y, exact)).astype(o_ref.dtype)
             return carry
@@ -202,7 +207,8 @@ def _bwd_kernel(*refs, has_bias):
 
         def piece(i, carry, cols=cols, w=w, bias=bias):
             start = pl.multiple_of((pieces - 1 - i) * rows, rows)
-            shifted = _shifted(x_ref, s_ref, start, rows, cols, taps)
+            shifted = _shifted(_columns_of(x_ref, cols), _tile(x_ref.dtype),
+                               s_ref, start, rows, taps)
             y = _pre_activation(shifted, w, bias)
             sig = _sigmoid(y, exact)
             dy = (g_ref[0, pl.ds(start, rows), cols].astype(jnp.float32)
@@ -290,3 +296,216 @@ def causal_conv1d_bwd(x, w, bias, dout, *, width=None):
         interpret=on_cpu(),
     )(*operands)
     return dx, dw, (db[0][0] if has_bias else None)
+
+
+# ------------------------------------------------------------ the gated form
+# ``gated_short_conv``: X [b, T, 3 * channels] holds [B | C | x] side by
+# side, and ``out = C * conv(B * x)`` with no bias and no activation (the
+# short-convolution mixer of LFM2). A grid step takes B's, C's and x's
+# column blocks of the ONE input through three block specs of it, so X
+# crosses HBM once each way and ``B * x``, the convolution and ``dz`` stay
+# in VMEM. Forward, first token to last: ``u = B * x`` goes to the float32
+# scratch column as ``x`` does above, ``z = sum_s u[t - s] * w[taps - 1 -
+# s]``, ``out = C * z``. Backward, LAST token to first, at the grid's
+# part 0: ``dz = dout * C`` to the second scratch column, ``du[t] = sum_s
+# dz[t + s] * w[taps - 1 - s]``, ``Filter@GRAD[taps - 1 - s] = sum_t dz[t]
+# * u[t - s]``; ``X@GRAD`` in the same [B | C | x] layout, one column block
+# of it a grid step along a last grid axis of three parts: ``du * x``
+# (dB) written in the walk, ``dout * z`` (dC) and ``du`` kept in scratch
+# for parts 1 and 2, where they are written (dC) and multiplied by B (dx).
+# The inputs' blocks do not change along that axis, so nothing is read
+# twice, and no ``concat`` follows.
+
+PARTS = 3               # [B | C | x]
+
+
+def gated_vmem_bytes(t, width, dtype, backward):
+    """A step's blocks of the gated form, each twice for the pipeline (B,
+    C, x and ``out`` forward; B, C, x, ``Out@GRAD`` and the ``X@GRAD`` block
+    backward), its float32 scratch columns and, backward, ``du`` (float32)
+    and dC (in X's type) of the whole block."""
+    size = jnp.dtype(dtype).itemsize
+    if not backward:
+        return 2 * 4 * t * width * size + (t + 16) * LANES * 4
+    return (2 * 5 * t * width * size + 2 * (t + 16) * LANES * 4
+            + t * width * (4 + size))
+
+
+def gated_block_width(x, backward):
+    """The widest block of channels (of ``channels`` = X's last axis over
+    three) that divides them and fits the budget, or None."""
+    _, t, wide = x.shape
+    for width in WIDTHS:
+        if (wide // PARTS) % width == 0 and gated_vmem_bytes(
+                t, width, x.dtype, backward) <= VMEM_BUDGET:
+            return width
+    return None
+
+
+def gated_supported(x, w):
+    """``supported`` for the gated form: X [b, T, 3 * channels] float32 or
+    bfloat16, the channels in whole 128-lane widths, the filter [taps,
+    channels], the tokens in whole sublane tiles and a 128-lane block of
+    the whole time axis inside the budget, backward as forward."""
+    return (x.ndim == 3 and w.ndim == 2
+            and x.dtype in (jnp.float32, jnp.bfloat16)
+            and x.shape[2] == PARTS * w.shape[1]
+            and w.shape[1] % LANES == 0 and x.shape[1] % _tile(x.dtype) == 0
+            and 1 <= w.shape[0] <= MAX_TAPS
+            and gated_block_width(x, backward=True) is not None)
+
+
+def _products_of(b_ref, x_ref, cols):
+    """The loader of ``_shifted`` that reads ``u = B * x`` (float32) from
+    B's and x's blocks at the columns ``cols``."""
+    return lambda at, n: (b_ref[0, pl.ds(at, n), cols].astype(jnp.float32)
+                          * x_ref[0, pl.ds(at, n), cols].astype(jnp.float32))
+
+
+def _gated_fwd_kernel(b_ref, c_ref, x_ref, w_ref, o_ref, s_ref):
+    t, width = x_ref.shape[1:]
+    taps = w_ref.shape[0]
+    rows = _rows(t, _tile(x_ref.dtype))
+    for lane in range(0, width, LANES):
+        cols = pl.ds(lane, LANES)
+        w, _ = _columns(w_ref, None, cols)
+
+        def piece(c, carry, cols=cols, w=w):
+            start = pl.multiple_of(c * rows, rows)
+            z = _pre_activation(_shifted(
+                _products_of(b_ref, x_ref, cols), _tile(x_ref.dtype), s_ref,
+                start, rows, taps), w, None)
+            gate = c_ref[0, pl.ds(start, rows), cols].astype(jnp.float32)
+            o_ref[0, pl.ds(start, rows), cols] = (gate * z).astype(
+                o_ref.dtype)
+            return carry
+
+        jax.lax.fori_loop(0, t // rows, piece, 0)
+
+
+def _gated_bwd_kernel(b_ref, c_ref, x_ref, w_ref, g_ref, dx_ref, dw_ref,
+                      s_ref, d_ref, du_ref, dc_ref):
+    t, width = x_ref.shape[1:]
+    taps = w_ref.shape[0]
+    rows = _rows(t, _tile(x_ref.dtype))
+    pieces = t // rows
+    part = pl.program_id(2)
+
+    @pl.when((pl.program_id(1) == 0) & (part == 0))
+    def _():
+        dw_ref[...] = jnp.zeros_like(dw_ref)
+
+    @pl.when(part == 0)
+    def _():
+        zeros = jnp.zeros((8, LANES), jnp.float32)
+        d_ref[pl.ds(t, 8), :] = zeros       # no token after the last
+        for lane in range(0, width, LANES):
+            cols = pl.ds(lane, LANES)
+            w, _ = _columns(w_ref, None, cols)
+
+            def piece(i, carry, cols=cols, w=w):
+                start = pl.multiple_of((pieces - 1 - i) * rows, rows)
+                at = pl.ds(start, rows)
+                shifted = _shifted(_products_of(b_ref, x_ref, cols),
+                                   _tile(x_ref.dtype), s_ref, start, rows,
+                                   taps)
+                z = _pre_activation(shifted, w, None)
+                g = g_ref[0, at, cols].astype(jnp.float32)
+                dc_ref[at, cols] = (g * z).astype(dc_ref.dtype)
+                dz = g * c_ref[0, at, cols].astype(jnp.float32)
+                d_ref[at, :] = dz
+                du = dz * w[taps - 1]
+                for s in range(1, taps):
+                    du += d_ref[pl.ds(start + s, rows), :] * w[taps - 1 - s]
+                du_ref[at, cols] = du
+                dx_ref[0, at, cols] = (
+                    du * x_ref[0, at, cols].astype(jnp.float32)).astype(
+                        dx_ref.dtype)
+                return tuple(a + _eight(dz * us)
+                             for a, us in zip(carry, shifted))
+
+            sums = jax.lax.fori_loop(0, pieces, piece, (zeros,) * taps)
+            for s in range(taps):
+                dw_ref[pl.ds(taps - 1 - s, 1), cols] += jnp.sum(
+                    sums[s], axis=0, keepdims=True)
+
+    @pl.when(part == 1)
+    def _():
+        def piece(i, carry):
+            at = pl.ds(pl.multiple_of(i * rows, rows), rows)
+            dx_ref[0, at, :] = dc_ref[at, :]
+            return carry
+
+        jax.lax.fori_loop(0, pieces, piece, 0)
+
+    @pl.when(part == 2)
+    def _():
+        def piece(i, carry):
+            at = pl.ds(pl.multiple_of(i * rows, rows), rows)
+            dx_ref[0, at, :] = (du_ref[at, :] * b_ref[0, at, :].astype(
+                jnp.float32)).astype(dx_ref.dtype)
+            return carry
+
+        jax.lax.fori_loop(0, pieces, piece, 0)
+
+
+def _gated_specs(x, w, width, parts):
+    """The grid (channel block, batch[, part]) and the block specs of B's,
+    C's and x's columns of X, of the filter and of a ``[b, T, channels]``
+    array (``out``, ``Out@GRAD``); backward, the ``X@GRAD`` block of the
+    grid's part."""
+    b, t, wide = x.shape
+    blocks = wide // PARTS // width
+
+    def of(p):
+        return pl.BlockSpec((1, t, width),
+                            lambda j, i, *k: (i, 0, p * blocks + j))
+
+    grid = (blocks, b) + ((PARTS,) if parts else ())
+    whole = pl.BlockSpec((1, t, width), lambda j, i, *k: (i, 0, j))
+    taps = pl.BlockSpec((w.shape[0], width), lambda j, i, *k: (0, j))
+    part = pl.BlockSpec((1, t, width),
+                        lambda j, i, k: (i, 0, k * blocks + j))
+    return grid, [of(p) for p in range(PARTS)], whole, taps, part
+
+
+@functools.partial(jax.jit, static_argnames="width")
+def gated_short_conv_fwd(x, w, *, width=None):
+    """``C * (causal depthwise convolution of B * x with w [taps,
+    channels])`` for X [b, T, 3 * channels] = [B | C | x], in X's type;
+    ``width``: the block's channels, by default ``gated_block_width``'s."""
+    width = width or gated_block_width(x, backward=False)
+    grid, thirds, whole, taps, _ = _gated_specs(x, w, width, parts=False)
+    b, t, wide = x.shape
+    return pl.pallas_call(
+        _gated_fwd_kernel, name="gated_short_conv_fwd", grid=grid,
+        in_specs=thirds + [taps], out_specs=whole,
+        out_shape=jax.ShapeDtypeStruct((b, t, wide // PARTS), x.dtype),
+        scratch_shapes=[_scratch(x, _tile(x.dtype))],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=VMEM_LIMIT),
+        interpret=on_cpu(),
+    )(x, x, x, w)
+
+
+@functools.partial(jax.jit, static_argnames="width")
+def gated_short_conv_bwd(x, w, dout, *, width=None):
+    """(``X@GRAD`` [b, T, 3 * channels] = [dB | dC | dx] in X's type,
+    ``Filter@GRAD`` [taps, channels] float32, its sum over batch and time)
+    from X, the filter and ``Out@GRAD``."""
+    width = width or gated_block_width(x, backward=True)
+    grid, thirds, whole, taps, part = _gated_specs(x, w, width, parts=True)
+    return pl.pallas_call(
+        _gated_bwd_kernel, name="gated_short_conv_bwd", grid=grid,
+        in_specs=thirds + [taps, whole], out_specs=[part, taps],
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
+                   jax.ShapeDtypeStruct(w.shape, jnp.float32)],
+        scratch_shapes=[_scratch(x, _tile(x.dtype)), _scratch(x, 8),
+                        pltpu.VMEM((x.shape[1], width), jnp.float32),
+                        pltpu.VMEM((x.shape[1], width), x.dtype)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT),
+        interpret=on_cpu(),
+    )(x, x, x, w, dout.astype(x.dtype))

@@ -330,6 +330,8 @@ class GenerationEngine:
         "ssd_scan": "a state-space layer's recurrent state",
         "gated_delta_rule": "a linear-attention layer's recurrent state",
         "causal_conv1d": "a short convolution's last taps",
+        "gated_short_conv": "a gated short convolution's last taps "
+                            "(of B * x)",
     }
 
     @classmethod
@@ -352,6 +354,13 @@ class GenerationEngine:
                     "decodes one token a step with no draft-and-verify "
                     "path; programs with an MTP module cannot be served "
                     "yet (save the inference program without it)")
+            if "qk_norm" in str(op.attr("op_namescope") or "").split("/"):
+                raise ValueError(
+                    f"{op.type} of QK-norm (op_namescope 'qk_norm'): the "
+                    "phase ops attend over the arena's keys as the "
+                    "projection wrote them, and no phase program has "
+                    "normed a query or key head per token; programs with "
+                    "QK-norm cannot be served by GenerationEngine yet")
             if op.type == "rotary_embedding" and op.has_attr("rotary_dim"):
                 raise ValueError(
                     f"rotary_embedding with rotary_dim="

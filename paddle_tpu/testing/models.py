@@ -269,11 +269,12 @@ def _biased_experts_and_shared(layers, cfg, x, b, expert_attr, routed,
                                top_k, held, renormalize, scoring, shared,
                                aux, loads):
     """``x + routed_experts(b) + gated_mlp(b)``: the sparse feed-forward
-    part of the latent-attention families (sigmoid or softmax scores, a
-    selection bias with its own update, ``routed_scaling_factor``, the
-    ``held`` experts from ``expert_offset``, and ``shared`` shared experts
-    as one gated MLP); the balance term and the held experts' load are
-    appended to ``aux`` and ``loads``."""
+    part of the latent-attention families and LFM2 (sigmoid or softmax
+    scores, a selection bias with its own update,
+    ``routed_scaling_factor``, the ``held`` experts from ``expert_offset``,
+    and ``shared`` shared experts as one gated MLP, none where ``shared`` is
+    0); the balance term and the held experts' load are appended to ``aux``
+    and ``loads``."""
     from paddle_tpu.fluid.initializer import Normal
     from paddle_tpu.fluid.param_attr import ParamAttr
 
@@ -295,6 +296,8 @@ def _biased_experts_and_shared(layers, cfg, x, b, expert_attr, routed,
     aux.append(balance)
     loads.append(load)
     x = layers.elementwise_add(x, y)
+    if not shared:
+        return x
     return layers.elementwise_add(x, layers.gated_mlp(
         b, shared * cfg["moe_intermediate_size"], expert_attr))
 
@@ -626,6 +629,100 @@ def build_nemotron_h_lm(cfg, length, batch=1):
         for kind in layer_kinds(cfg):
             x = layers.elementwise_add(
                 x, mixer(kind, layers.rms_norm(x, epsilon=eps)))
+        logits = layers.fc(
+            layers.rms_norm(x, epsilon=eps), cfg["vocab_size"],
+            num_flatten_dims=2, bias_attr=False,
+            param_attr=init(cfg.get("head_init_std", std)))
+        loss = layers.mean(layers.softmax_with_cross_entropy(logits, labels))
+        coef = cfg.get("balance_loss_coef", 0.0)
+        if coef and aux:
+            loss = layers.elementwise_add(loss, layers.scale(
+                layers.mean(layers.sums(aux)), scale=coef / len(aux)))
+    return main, startup, loss, logits, loads
+
+
+def build_lfm2_moe_lm(cfg, length, batch=1):
+    """The LFM2-MoE layer stack (``model_type`` ``lfm2_moe``, its published
+    ``config.json`` keys) as a Fluid program, and the chip's share of it:
+    the published layers ``first_layer`` .. ``first_layer +
+    num_hidden_layers - 1`` (counted from 0), each ``x + mixer(rms_norm(x))``
+    then ``x + ffn(rms_norm(x))``. The mixer is the layer's ``layer_types``
+    entry: ``conv``, the gated short convolution (``layers.short_conv_mixer``:
+    ``[B | C | x] = x W_in``, ``C * conv(B * x)`` over ``conv_L_cache`` taps,
+    ``W_out``; no bias, no activation), or ``full_attention``
+    (``layers.grouped_query_attention``: ``num_attention_heads`` /
+    ``num_key_value_heads`` heads of hidden / heads, QK-norm, rotary at
+    ``rope_parameters.rope_theta``). The feed-forward part is a dense
+    gated-SiLU MLP of ``intermediate_size`` below ``num_dense_layers`` and
+    ``routed_experts`` from there (sigmoid scores, a selection bias with its
+    own update where ``use_expert_bias``, top ``num_experts_per_tok``
+    renormalised where ``norm_topk_prob`` times ``routed_scaling_factor``,
+    no shared expert) holding ``num_experts`` of the router's
+    ``num_experts_routed`` from ``expert_offset``. Then the final RMSNorm
+    (eps ``norm_eps`` everywhere) and an untied head over ``vocab_size``
+    rows. The filter is drawn uniform in +-1 / sqrt(taps), as the family's
+    framework draws a depthwise convolution; W_q and W_k normal
+    (``attention_qk_init_std``), every other projection normal
+    (``init_std``). The loss is the mean next-token
+    cross-entropy plus ``balance_loss_coef`` times the routed layers' mean
+    balance term. The plain reference is ``testing/reference/lfm2_moe.py``;
+    parameters are created in the order its ``unpack`` reads. Feeds
+    ``tokens`` and ``labels`` [batch, length, 1] int64. Returns (main,
+    startup, loss, logits [batch, length, vocab], [expert_load of each
+    routed layer])."""
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.fluid.initializer import Normal, Uniform
+    from paddle_tpu.fluid.param_attr import ParamAttr
+    from paddle_tpu.testing.reference.lfm2_moe import layer_kinds
+
+    layers = fluid.layers
+    hidden, eps = cfg["hidden_size"], cfg["norm_eps"]
+    heads = cfg["num_attention_heads"]
+    std = cfg.get("init_std", 0.02)
+    if not cfg.get("use_expert_bias", True):
+        raise ValueError("build_lfm2_moe_lm: routing without the expert "
+                         "bias is not built (use_expert_bias)")
+
+    def init(scale=std):
+        return ParamAttr(initializer=Normal(0.0, scale))
+
+    bound = cfg["conv_L_cache"] ** -0.5
+    aux, loads = [], []
+
+    def mixer(kind, a):
+        if kind == "conv":
+            return layers.short_conv_mixer(
+                a, cfg["conv_L_cache"], param_attr=init(),
+                conv_attr=ParamAttr(initializer=Uniform(-bound, bound)))
+        return layers.grouped_query_attention(
+            a, heads, cfg["num_key_value_heads"], hidden // heads,
+            rope_theta=cfg["rope_parameters"]["rope_theta"], qk_norm=True,
+            epsilon=eps, param_attr=init(),
+            qk_attr=init(cfg.get("attention_qk_init_std", std)))
+
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        tokens = layers.data("tokens", shape=[batch, length, 1],
+                             dtype="int64", append_batch_size=False)
+        labels = layers.data("labels", shape=[batch, length, 1],
+                             dtype="int64", append_batch_size=False)
+        x = layers.embedding(
+            tokens, size=(cfg["vocab_size"], hidden),
+            param_attr=init(cfg.get("embedding_init_std", std)))
+        for kind, mlp in layer_kinds(cfg):
+            x = layers.elementwise_add(
+                x, mixer(kind, layers.rms_norm(x, epsilon=eps)))
+            b = layers.rms_norm(x, epsilon=eps)
+            if mlp == "dense":
+                x = layers.elementwise_add(
+                    x, layers.gated_mlp(b, cfg["intermediate_size"], init()))
+                continue
+            x = _biased_experts_and_shared(
+                layers, cfg, x, b, init(cfg.get("expert_init_std", std)),
+                routed=cfg["num_experts_routed"],
+                top_k=cfg["num_experts_per_tok"], held=cfg["num_experts"],
+                renormalize=cfg.get("norm_topk_prob", True),
+                scoring="sigmoid", shared=0, aux=aux, loads=loads)
         logits = layers.fc(
             layers.rms_norm(x, epsilon=eps), cfg["vocab_size"],
             num_flatten_dims=2, bias_attr=False,

@@ -378,6 +378,121 @@ def test_causal_conv1d_kernels_compile_for_v5e(one_chip, native_lowering,
             long, w, b, long).compile().as_text()
 
 
+# the LFM2 cell's gated short convolution: X = [B | C | x] of 3 x 2048
+# channels, 8192 tokens, 3 taps
+def test_gated_short_conv_kernels_compile_for_v5e(one_chip, native_lowering):
+    """``gated_short_conv_fwd`` / ``gated_short_conv_bwd`` at the cell's
+    shape, bfloat16 X and ``Out@GRAD``, float32 filter: blocks of 256
+    channels forward and 128 backward over the whole time axis, a grid of
+    (channel blocks, 1) forward and (channel blocks, 1, the three parts of
+    ``X@GRAD``) backward, ``X@GRAD`` in X's shape and layout, each kernel
+    inside the family's VMEM limit and within a MiB above
+    ``gated_vmem_bytes`` by the compiler's count."""
+    import re
+
+    from paddle_tpu.ops.pallas import causal_conv1d as cc
+
+    def s(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    x, w, g = (s(jnp.bfloat16, 1, 8192, 6144), s(jnp.float32, 3, 2048),
+               s(jnp.bfloat16, 1, 8192, 2048))
+    assert cc.gated_supported(x, w)
+    assert (cc.gated_block_width(x, backward=False),
+            cc.gated_block_width(x, backward=True)) == (256, 128)
+    assert _pallas_grids(cc.gated_short_conv_fwd.__wrapped__, x, w) == {
+        "gated_short_conv_fwd": ((8, 1), 0)}
+    assert _pallas_grids(cc.gated_short_conv_bwd.__wrapped__, x, w, g) == {
+        "gated_short_conv_bwd": ((16, 1, 3), 0)}
+    dx, dw = jax.eval_shape(cc.gated_short_conv_bwd, x, w, g)
+    assert (dx.shape, dx.dtype, dw.shape, dw.dtype) == (
+        x.shape, x.dtype, w.shape, jnp.float32)
+    for name, lowered in (
+            ("gated_short_conv_fwd", cc.gated_short_conv_fwd.lower(x, w)),
+            ("gated_short_conv_bwd", cc.gated_short_conv_bwd.lower(x, w, g))):
+        text = lowered.compile().as_text()
+        (used,) = [int(n) for n in re.findall(
+            '"used_scoped_memory_configs":\\[\\{"memory_space":"1",'
+            '"offset":"0","size":"(\\d+)"', text)]
+        count = cc.gated_vmem_bytes(8192, 256 if name.endswith("fwd") else
+                                    128, x.dtype, name.endswith("bwd"))
+        assert count <= used < count + (1 << 20) and used <= cc.VMEM_LIMIT
+
+
+def _payloads(lowered):
+    """sha256[:16] of each Mosaic kernel's payload in a lowered call, read
+    back as MLIR and printed without its debug locations (they hold the
+    checkout's path)."""
+    import base64
+    import hashlib
+    import re
+
+    from jaxlib.mlir import ir
+
+    out = []
+    for config in re.findall(r'backend_config = "([^"]*)"',
+                             lowered.as_text()):
+        body = re.search(r'body\\22: \\22([A-Za-z0-9+/=]+)', config)
+        if body is None:
+            continue
+        with ir.Context() as ctx:
+            ctx.allow_unregistered_dialects = True
+            module = ir.Module.parse(base64.b64decode(body.group(1)))
+            text = module.operation.get_asm(enable_debug_info=False)
+        out.append(hashlib.sha256(text.encode()).hexdigest()[:16])
+    return out
+
+
+# The Mosaic payloads, both ways, of the accepted cells' kernels that the
+# LFM2 configuration shares: the SiLU form of causal_conv1d at cells 6 and
+# 7's shapes and the banded attention kernels at cells 5 and 8's (as the op
+# hands them over: heads of 192 padded to 256), as they were before the
+# gated form came, so those cells compile the kernels they compiled. A
+# change to one of these kernels on purpose writes its new hashes.
+KERNEL_PAYLOADS = {
+    "conv_kimi": ("1696c895b0b53f17", "aa85fbc4b2a43f01"),
+    "conv_nemotron": ("1d1df6de9dc5afd4", "ea77eb8739f5d5c5"),
+    "attention_mellum2_w1024": ("f988f79319e3fa00", "2074b9931285ff57"),
+    "attention_mellum2_w0": ("671fe854044c19eb", "4dd6de0774da912a"),
+    "attention_joyai": ("8fee1855ad3bdd4a", "204b03d078916fd8"),
+}
+
+
+@pytest.mark.parametrize("route", sorted(KERNEL_PAYLOADS))
+def test_the_shared_kernels_payloads_are_as_they_were(one_chip,
+                                                      native_lowering, route):
+    from paddle_tpu.ops.pallas import attention as att
+    from paddle_tpu.ops.pallas import causal_conv1d as cc
+
+    def s(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    if route.startswith("conv"):
+        channels, bias = (4096, False) if route == "conv_kimi" else (6144,
+                                                                     True)
+        x, w = s(1, 4096, channels), s(4, channels, dtype=jnp.float32)
+        b = s(channels, dtype=jnp.float32) if bias else None
+        got = (_payloads(cc.causal_conv1d_fwd.lower(x, w, b))
+               + _payloads(cc.causal_conv1d_bwd.lower(x, w, b, x)))
+    else:
+        t, heads, kv, d, dv, window, head = {
+            "attention_mellum2_w1024": (8192, 32, 4, 128, 128, 1024, 128),
+            "attention_mellum2_w0": (8192, 32, 4, 128, 128, 0, 128),
+            "attention_joyai": (4096, 32, 32, 256, 128, 0, 192)}[route]
+        q, k, v = s(1, t, heads * d), s(1, t, kv * d), s(1, t, kv * dv)
+
+        def fwd(q, k, v):
+            return att.attention_pallas(q, k, v, heads, kv, window,
+                                        scale=head ** -0.5)
+
+        def bwd(q, k, v, o, lse, do):
+            return att.attention_pallas_bwd(q, k, v, o, lse, do, heads, kv,
+                                            window, scale=head ** -0.5)
+        o, lse = (s(*a.shape, dtype=a.dtype)
+                  for a in jax.eval_shape(fwd, q, k, v))
+        got = (_payloads(jax.jit(fwd).lower(q, k, v))
+               + _payloads(jax.jit(bwd).lower(q, k, v, o, lse, o)))
+    assert tuple(got) == KERNEL_PAYLOADS[route]
+
+
 def _primitives(jaxpr):
     """Every primitive's name in a jaxpr, those of its sub-jaxprs (jit,
     loops, a kernel's body) among them."""

@@ -319,15 +319,15 @@ def _paged_attention_site(supported):
 
 
 def _attention_site(supported):
-    # 128 positions, 128 lanes: one head of 128 is the kernels' shape, two
-    # heads of 64 are the twin's
+    # one head of 128 lanes: 128 positions are the kernels' shape, 120 (no
+    # whole block of 128) the twin's; heads narrower than 128 lanes reach
+    # the kernels padded by the op
     rng = np.random.RandomState(1)
-    q, k, v = (rng.randn(1, 128, 128).astype(np.float32) for _ in range(3))
-    heads = 1 if supported else 2
+    t = 128 if supported else 120
+    q, k, v = (rng.randn(1, t, 128).astype(np.float32) for _ in range(3))
     return _run_op("causal_self_attention", {"Q": q, "K": k, "V": v},
                    {"Out": 0, "LogSumExp": 0},
-                   {"num_heads": heads, "num_kv_heads": heads,
-                    "window": 0})[:1]
+                   {"num_heads": 1, "num_kv_heads": 1, "window": 0})[:1]
 
 
 def _routed_experts_site(tokens, width):
