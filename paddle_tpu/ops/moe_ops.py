@@ -79,6 +79,13 @@ _M_ROW_BUFFER = _METRICS.gauge(
     "buffer a step's held rows must fit, kind=products the grouped "
     "products over it forward and backward (9: gated experts, 6: relu2)",
     labels=("kind",))
+_M_WEIGHTS = _METRICS.counter(
+    "paddle_tpu_grouped_matmul_weights",
+    "expert weights routed_experts hands to the grouped_matmul kernels "
+    "(counted at trace time, once a weight, from the forward), by "
+    "orientation: stored as the parameter is, or swapped (its transpose, "
+    "where that is how XLA:TPU stores it: ops/pallas/grouped_matmul.swapped)",
+    labels=("orientation",))
 
 ROW_ALIGN = 512
 TILE = 256              # every expert's group starts on a tile of rows
@@ -217,7 +224,10 @@ class _Products:
     """The three grouped products of one call over one buffer layout, by
     the route: the ``grouped_matmul`` kernels, or ``jax.lax.ragged_dot``
     over the same aligned groups (XLA:TPU's own grouped kernel there; the
-    twin on the CPU)."""
+    twin on the CPU). On the kernels each weight goes over in the
+    orientation the chip stores it in (``grouped_matmul.swapped``: a
+    bitcast, where the other orientation costs a copy of the weight and of
+    its Adam moments each way); ragged_dot takes it as it is."""
 
     _CONTRACT_ROWS = jax.lax.RaggedDotDimensionNumbers(
         dot_dimension_numbers=(((0,), (0,)), ((), ())),
@@ -233,12 +243,27 @@ class _Products:
     def span(self):
         return kernel_span(self.route, "grouped_matmul")
 
+    def count(self, ws):
+        """Each weight's orientation on the kernels, on the counter: once a
+        trace, from the forward."""
+        if self.route == "pallas":
+            for w in ws:
+                _M_WEIGHTS.labels(orientation="swapped" if self.gm.swapped(
+                    *w.shape[1:]) else "stored").inc()
+
+    def _kernel(self, plain, swapped, rows, w, dtype=jnp.float32):
+        """``plain`` over ``w``, or ``swapped`` over its transpose."""
+        if self.gm.swapped(*w.shape[1:]):
+            plain, w = swapped, jnp.swapaxes(w, 1, 2)
+        return plain(rows, w, self.lay["tile_expert"], self.lay["tiles"],
+                     dtype)
+
     def rows_by(self, rows, w, dtype=jnp.float32):
         """[R, a] x [held, a, b] -> [R, b]."""
         with self.span():
             if self.route == "pallas":
-                return self.gm.gmm(rows, w, self.lay["tile_expert"],
-                                   self.lay["tiles"], dtype)
+                return self._kernel(self.gm.gmm, self.gm.gmm_t, rows, w,
+                                    dtype)
             return jax.lax.ragged_dot(
                 rows, w, self.lay["sizes"],
                 preferred_element_type=jnp.float32).astype(dtype)
@@ -251,8 +276,7 @@ class _Products:
         (row, expert) pair, held times the work (AOT cost analysis)."""
         with self.span():
             if self.route == "pallas":
-                return self.gm.gmm_t(rows, w, self.lay["tile_expert"],
-                                     self.lay["tiles"])
+                return self._kernel(self.gm.gmm_t, self.gm.gmm, rows, w)
             return jax.lax.ragged_dot(
                 rows, jnp.swapaxes(w, 1, 2), self.lay["sizes"],
                 preferred_element_type=jnp.float32)
@@ -262,9 +286,13 @@ class _Products:
         apart; an expert without a row gets zeros."""
         with self.span():
             if self.route == "pallas":
-                out = self.gm.tgmm(rows, grads, self.load.shape[0],
-                                   self.lay["tile_expert"],
-                                   self.lay["tiles"])
+                held = self.load.shape[0]
+                tiles = self.lay["tile_expert"], self.lay["tiles"]
+                if self.gm.swapped(rows.shape[1], grads.shape[1]):
+                    out = jnp.swapaxes(
+                        self.gm.tgmm(grads, rows, held, *tiles), 1, 2)
+                else:
+                    out = self.gm.tgmm(rows, grads, held, *tiles)
                 return jnp.where((self.load > 0)[:, None, None], out, 0.0)
             return jax.lax.ragged_dot_general(
                 rows, grads, self.lay["sizes"], self._CONTRACT_ROWS,
@@ -347,6 +375,7 @@ def routed_experts(ctx):
     token = jnp.maximum(r["assign"], 0) // a["top_k"]
     rows = xc[token]
     dot = _Products(r["load"], r["layout"], rows, ws[0])
+    dot.count(ws)
     # a row of padding holds some token's data and whatever the product
     # makes of it: kept out of everything that sums over rows
     *pre, up = (jnp.where(keep, dot.rows_by(rows, w, xc.dtype), 0)

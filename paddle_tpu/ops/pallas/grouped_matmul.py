@@ -14,6 +14,18 @@ skipped (their index maps stay on the last real tile, so nothing moves).
 * ``tgmm(rows [R, a], grads [R, b])    -> [held, a, b]`` (the weights'
   gradient: each expert's tiles accumulate into its resident output block)
 
+A Mosaic kernel takes its operands row-major, and XLA:TPU stores a float32
+``[held, a, b]`` whose ``b`` is no whole number of 128-lane tiles but whose
+``a`` is with ``a`` on the lanes (layout ``{1,2,0}``: 2688 x 1856 keeps 2688,
+21 whole tiles, there, where row-major would pad 1856 to 1920). Handed such a
+matrix as it is, the step copies it, and the Adam moments that meet its
+gradient, to row-major and back: 320 MB of traffic a copy at 2688 x 1856. So
+the caller hands it over as ``swapaxes(w, 1, 2)`` (:func:`swapped`), a
+bitcast of what is stored, and swaps the forms: ``gmm_t`` over the swapped
+matrix is ``gmm`` over the stored one, ``gmm`` the input gradient, and
+``tgmm(grads, rows)`` swapped back the weights' gradient. The same three
+kernels run in either orientation; only the contracted axis differs.
+
 Rows of padding inside a group's last tile are multiplied like any other;
 the caller keeps them zero where they would be summed (``tgmm``) and masks
 what comes out of them. An expert with no row has no tile: ``tgmm`` leaves
@@ -39,7 +51,8 @@ TILE = 256
 VMEM_LIMIT = 64 * 1024 * 1024
 
 
-LANES = 64              # widths come in whole halves of a 128-lane tile
+LANE_TILE = 128
+LANES = LANE_TILE // 2  # widths come in whole halves of a lane tile
 
 
 def supported(rows, w):
@@ -52,6 +65,13 @@ def supported(rows, w):
     itemsize = jnp.dtype(w.dtype).itemsize
     return (rows.shape[0] % TILE == 0 and a % LANES == 0 and b % LANES == 0
             and 2 * a * b * itemsize <= VMEM_LIMIT // 3)
+
+
+def swapped(a, b):
+    """Whether an expert's matrix ``[a, b]`` goes to the kernels as its
+    transpose ``[b, a]``: where ``b`` is no whole number of lane tiles and
+    ``a`` is, the orientation in which XLA:TPU stores it."""
+    return a % LANE_TILE == 0 and b % LANE_TILE != 0
 
 
 def _tile(t, tiles):

@@ -9,6 +9,7 @@ TPU's library, and every xdist worker imports every test file.
 """
 
 import os
+import re
 
 import pytest
 
@@ -176,7 +177,6 @@ def _kernel_vmem(text, key="used_scoped_memory_configs"):
     """{kernel name: bytes of VMEM (memory space 1)}, read off a compiled
     module's text: under ``key`` Mosaic's own allocation for it; under
     ``scoped_memory_configs`` the limit the call set, where it set one."""
-    import re
     out = {}
     for line in text.splitlines():
         if "tpu_custom_call" not in line:
@@ -388,8 +388,6 @@ def test_gated_short_conv_kernels_compile_for_v5e(one_chip, native_lowering):
     ``X@GRAD``) backward, ``X@GRAD`` in X's shape and layout, each kernel
     inside the family's VMEM limit and within a MiB above
     ``gated_vmem_bytes`` by the compiler's count."""
-    import re
-
     from paddle_tpu.ops.pallas import causal_conv1d as cc
 
     def s(dtype, *shape):
@@ -424,7 +422,6 @@ def _payloads(lowered):
     checkout's path)."""
     import base64
     import hashlib
-    import re
 
     from jaxlib.mlir import ir
 
@@ -502,6 +499,47 @@ def _primitives(jaxpr):
             yield from _primitives(sub)
 
 
+class _OpCtx:
+    """What an op lowering reads and writes, without a program."""
+
+    def __init__(self, attrs, **env):
+        self.attrs, self.env, self.out = attrs, env, {}
+
+    def input(self, slot):
+        return self.env[slot]
+
+    def has_input(self, slot):
+        return slot in self.env
+
+    def attr(self, name, default=None):
+        return self.attrs.get(name, default)
+
+    def set_output(self, slot, value):
+        self.out[slot] = value
+
+
+_KEPT = ("Gate", "Up", "RowAssign", "RowWeight", "ExpertLoad", "TopIdx",
+         "Probs")
+
+
+def _experts_forward(attrs, env):
+    from paddle_tpu.core.registry import get_op_info
+
+    ctx = _OpCtx(attrs, **env)
+    get_op_info("routed_experts").forward(ctx)
+    return ctx.out
+
+
+def _experts_backward(attrs, env, kept, dout):
+    from paddle_tpu.core.registry import get_op_info
+
+    ctx = _OpCtx(attrs, **env, **{k: kept[k] for k in _KEPT if k in kept},
+                 **{"Out@GRAD": dout,
+                    "AuxLoss@GRAD": jnp.ones((1,), jnp.float32)})
+    get_op_info("routed_experts_grad").forward(ctx)
+    return ctx.out
+
+
 @pytest.mark.parametrize("tier,scatters", [("pallas", False), ("jnp", True)])
 def test_routed_experts_on_the_kernel_route_has_no_scatter_add(tier,
                                                                scatters):
@@ -511,38 +549,11 @@ def test_routed_experts_on_the_kernel_route_has_no_scatter_add(tier,
     (one update after another on a TPU), so the serial path cannot come
     back unnoticed. The twin's route is the control: it holds one each."""
     import paddle_tpu.fluid as fluid
-    from paddle_tpu.core.registry import get_op_info
 
-    class Ctx:
-        attrs = dict(num_experts=8, top_k=2, router_task_gradient=False)
+    attrs = dict(num_experts=8, top_k=2, router_task_gradient=False)
 
-        def __init__(self, **env):
-            self.env, self.out = env, {}
-
-        def input(self, slot):
-            return self.env[slot]
-
-        def has_input(self, slot):
-            return slot in self.env
-
-        def attr(self, name, default=None):
-            return self.attrs.get(name, default)
-
-        def set_output(self, slot, value):
-            self.out[slot] = value
-
-    def forward(**env):
-        ctx = Ctx(**env)
-        get_op_info("routed_experts").forward(ctx)
-        return ctx.out
-
-    def backward(env, kept, dout):
-        ctx = Ctx(**env, **{k: kept[k] for k in (
-            "Gate", "Up", "RowAssign", "RowWeight", "ExpertLoad", "TopIdx",
-            "Probs")}, **{"Out@GRAD": dout,
-                          "AuxLoss@GRAD": jnp.ones((1,), jnp.float32)})
-        get_op_info("routed_experts_grad").forward(ctx)
-        return ctx.out
+    def forward(env):
+        return _experts_forward(attrs, env)
 
     f32 = jnp.zeros
     env = dict(X=f32((1, 256, 128)), RouterW=f32((128, 8)),
@@ -550,15 +561,75 @@ def test_routed_experts_on_the_kernel_route_has_no_scatter_add(tier,
                WDown=f32((4, 128, 128)))
     fluid.set_flags({"kernel_tier": tier})
     try:
-        fwd = jax.make_jaxpr(lambda env: forward(**env))(env)
-        kept = jax.eval_shape(lambda env: forward(**env), env)
-        bwd = jax.make_jaxpr(backward)(env, kept, env["X"])
+        fwd = jax.make_jaxpr(forward)(env)
+        kept = jax.eval_shape(forward, env)
+        bwd = jax.make_jaxpr(lambda *a: _experts_backward(attrs, *a))(
+            env, kept, env["X"])
     finally:
         fluid.set_flags({"kernel_tier": "auto"})
     for traced in fwd, bwd:
         names = set(_primitives(traced.jaxpr))
         assert ("scatter-add" in names) == scatters, sorted(names)
         assert ("pallas_call" in names) == (not scatters)
+
+
+@pytest.mark.parametrize("case", [MELLUM2_EXPERTS, NEMOTRON_EXPERTS],
+                         ids=["2304x896", "2688x1856"])
+def test_an_expert_layers_train_step_copies_no_expert_weight_for_v5e(
+        one_chip, native_lowering, case):
+    """One un-gated expert layer forward and backward under AMP, its two
+    weights' gradients clipped by their global norm and applied by Adam,
+    weights and moments donated, compiled for a v5e at a cell's shapes.
+    XLA:TPU stores a float32 [8, 2688, 1856] with 2688 on the lanes, and a
+    Mosaic kernel takes its operands row-major: handed over as stored, the
+    step copied ``WUp`` and both its moments to row-major and back, six
+    passes of 320 MB. The kernels take it swapped (a bitcast), and no copy
+    of an expert weight's size is left; at 2304 x 896 nothing is swapped.
+    The counter reads the orientations."""
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.core.amp import amp_guard
+    from paddle_tpu.obs.metrics import REGISTRY
+    from paddle_tpu.ops.optimizer_ops import _adam_dense
+
+    tokens, top_k, held, experts, factor, hidden, width = case
+    attrs = dict(num_experts=experts, top_k=top_k, row_buffer_factor=factor,
+                 expert_form="relu2", scoring_func="sigmoid")
+
+    def step(x, router, w_up, w_down, m_up, v_up, m_down, v_down, dout):
+        env = dict(X=x, RouterW=router, WUp=w_up, WDown=w_down)
+        with amp_guard():
+            grads = _experts_backward(attrs, env, _experts_forward(
+                attrs, env), dout)
+        grads = [grads["WUp@GRAD"], grads["WDown@GRAD"]]
+        norm = jnp.sqrt(sum(jnp.sum(jnp.square(g)) for g in grads))
+        clip = jnp.minimum(1.0, 1.0 / (norm + 1e-6))
+        return [_adam_dense(p, g * clip, m, v, 1e-3, 0.9, 0.999, 1e-8)
+                for p, g, m, v in ((w_up, grads[0], m_up, v_up),
+                                   (w_down, grads[1], m_down, v_down))]
+
+    def f32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    up, down = f32(held, hidden, width), f32(held, width, hidden)
+    counter = REGISTRY.get("paddle_tpu_grouped_matmul_weights")
+    before = [counter.labels(orientation=o).value
+              for o in ("stored", "swapped")]
+    fluid.set_flags({"kernel_tier": "pallas"})
+    try:
+        text = jax.jit(step, donate_argnums=tuple(range(2, 8))).lower(
+            f32(1, tokens, hidden), f32(hidden, experts), up, down, up, up,
+            down, down, f32(1, tokens, hidden)).compile().as_text()
+    finally:
+        fluid.set_flags({"kernel_tier": "auto"})
+    after = [counter.labels(orientation=o).value
+             for o in ("stored", "swapped")]
+    swapped = width % 128 != 0
+    assert [b - a for a, b in zip(before, after)] == (
+        [1, 1] if swapped else [2, 0])
+    assert "grouped_matmul_t" in text and "grouped_matmul_w" in text
+    copies = re.findall(r"= f32\[(\d+),(\d+),(\d+)\]\{[^}]*\} copy\(", text)
+    weight = held * hidden * width
+    assert not [c for c in copies
+                if int(c[0]) * int(c[1]) * int(c[2]) == weight], copies
 
 
 def test_ragged_dot_route_lowers_to_a_grouped_kernel_on_v5e(one_chip,

@@ -341,16 +341,20 @@ def _relu2_step(tier, x_value, router, width):
         fluid.set_flags({"kernel_tier": "auto"})
 
 
-@pytest.mark.parametrize("width,kernels", [(192, True), (96, False)],
-                         ids=["a_tile_and_a_half", "no_whole_half"])
+@pytest.mark.parametrize("width,kernels,swapped", [
+    (192, True, 1), (256, True, 0), (96, False, 0)],
+    ids=["a_tile_and_a_half", "two_tiles", "no_whole_half"])
 def test_ungated_experts_reach_the_grouped_kernels_at_half_a_lane_tile(
-        width, kernels):
+        width, kernels, swapped):
     """The cell's expert width is 14.5 lane tiles (1856). The
     ``grouped_matmul`` kernels' blocks span a whole width, so a width in
     whole HALVES of a tile is theirs: at 1.5 tiles the un-gated layer runs
-    its six grouped products on the kernels (interpreted here) and equals
-    the ``ragged_dot`` route in ``Out`` and every gradient; at three
-    quarters of a tile it falls back, counted."""
+    its six grouped products on the kernels (interpreted here), ``WUp``
+    [8, 128, 192] handed over swapped, as the chip stores it, and equals
+    the ``ragged_dot`` route in ``Out`` and every gradient; at two whole
+    tiles both weights go over as stored; at three quarters of a tile it
+    falls back, counted, and no orientation is counted."""
+    from paddle_tpu.obs.metrics import REGISTRY
     from paddle_tpu.ops import pallas as tier
 
     rng = np.random.RandomState(2)
@@ -361,11 +365,18 @@ def test_ungated_experts_reach_the_grouped_kernels_at_half_a_lane_tile(
     def interpreted():
         return tier.dispatch_counts().get("grouped_matmul", {}).get(
             "interpret", 0)
+
+    def orientations():
+        counter = REGISTRY.get("paddle_tpu_grouped_matmul_weights")
+        return [counter.labels(orientation=o).value
+                for o in ("stored", "swapped")]
     tier.reset_fallback_counts()
-    before = interpreted()
+    before, counted = interpreted(), orientations()
     kernel = _relu2_step("pallas", x_value, router, width)
     assert interpreted() == before + (6 if kernels else 0)
     assert ("grouped_matmul" in tier.fallback_counts()) == (not kernels)
+    assert [b - a for a, b in zip(counted, orientations())] == (
+        [2 - swapped, swapped] if kernels else [0, 0])
     twin = _relu2_step("jnp", x_value, router, width)
     assert int(np.asarray(twin[1])[3]) == 0 and len(kernel) == 6
     for a, b in zip(kernel, twin):

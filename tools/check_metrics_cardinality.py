@@ -68,6 +68,9 @@ BOUNDED_LABELS = {
     "source": "where a built executable came from: compiled/cache",
     "form": "the attention backward's two forms "
             "(ops/pallas/attention.backward_form): fused/split",
+    "orientation": "an expert weight's two orientations on the "
+                   "grouped_matmul kernels "
+                   "(ops/pallas/grouped_matmul.swapped): stored/swapped",
     "reason": "artifact reject reasons — the fixed enums "
               "serving.execcache.REJECT_REASONS (format/manifest/"
               "fingerprint/deserialize/run_failed), "

@@ -31,7 +31,10 @@ bias) and the Nemotron cell's (x 6144, with a bias), forward and backward;
 the Mamba-2 state-space core at the Nemotron cell's shape (4096 tokens, 64
 heads of 64 in 8 groups of state 128, chunks of 128), forward and backward;
 and the attention backward's two forms against each other, bit for bit, at
-the four transformer cells' shapes (``attention_bwd_forms``). Every family
+the four transformer cells' shapes (``attention_bwd_forms``); and the
+grouped products' three kernels with an expert's matrix as stored and as
+its transpose, against each other, at the Nemotron cell's up projection
+(``grouped_matmul_orientations``). Every family
 with a dispatch site in ``paddle_tpu/ops/`` has a case.
 
 A kernel that fails to lower is a RESULT here (``lowered: false`` with the
@@ -321,6 +324,73 @@ def grouped_matmul_case(rows_per_expert, held, a, b):
     pf = jax.jit(lambda rows, w: gm.gmm(rows, w, lay["tile_expert"],
                                         lay["tiles"]))
     return {"jnp": lambda: tf(rows, w), "pallas": lambda: pf(rows, w)}
+
+
+def grouped_matmul_orientations(tiny):
+    """The three ``grouped_matmul`` kernels in both orientations of an
+    expert's matrix ``w [held, a, b]`` at the Nemotron cell's up
+    projection (a buffer of 6656 rows, 192 of them per expert, over 8
+    experts of 2688 x 1856, bfloat16): ``stored`` hands the kernels ``w``,
+    ``swapped`` its transpose ``[held, b, a]``, as ``routed_experts`` does
+    where ``b`` is no whole number of lane tiles (``forward``: ``gmm``
+    against ``gmm_t``; ``input_grad``: ``gmm_t`` against ``gmm``;
+    ``weights_grad``: ``tgmm(rows, grads)`` against ``tgmm(grads, rows)``,
+    swapped back on the host). Each side's operands are made beforehand in
+    the orientation its kernel takes, so a time is the kernel's alone. Per
+    product: whether the two are equal to the bit, the largest difference,
+    and each side's time by ``measure`` and ``device_ms``."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import moe_ops
+    from paddle_tpu.ops.pallas import grouped_matmul as gm
+
+    R, per, held, a, b = (512, 96, 2, 128, 192) if tiny \
+        else (6656, 192, 8, 2688, 1856)
+    keys = jax.random.split(jax.random.PRNGKey(0), 3)
+    rows = jax.random.normal(keys[0], (R, a), jnp.bfloat16)
+    grads = jax.random.normal(keys[1], (R, b), jnp.bfloat16)
+    w = jax.random.normal(keys[2], (held, a, b), jnp.bfloat16) * 0.05
+    wt = jnp.swapaxes(w, 1, 2)
+    lay = moe_ops.layout(jnp.full((held,), per, jnp.int32), R)
+    walk = lay["tile_expert"], lay["tiles"]
+    products = {
+        "forward": (jax.jit(lambda x, w: gm.gmm(x, w, *walk)),
+                    jax.jit(lambda x, w: gm.gmm_t(x, w, *walk)),
+                    (rows, w), (rows, wt), False),
+        "input_grad": (jax.jit(lambda x, w: gm.gmm_t(x, w, *walk)),
+                       jax.jit(lambda x, w: gm.gmm(x, w, *walk)),
+                       (grads, w), (grads, wt), False),
+        "weights_grad": (jax.jit(lambda x, g: gm.tgmm(x, g, held, *walk)),
+                         jax.jit(lambda x, g: gm.tgmm(g, x, held, *walk)),
+                         (rows, grads), (rows, grads), True)}
+    rec = {"case": f"grouped_matmul_orientations_{R}rows_{held}x{a}x{b}",
+           "family": "grouped_matmul", "error": None, "products": []}
+    for name, (plain, swap, p_args, s_args, back) in products.items():
+        runners = {"stored": lambda f=plain, x=p_args: f(*x),
+                   "swapped": lambda f=swap, x=s_args: f(*x)}
+        got = {n: np.asarray(jax.block_until_ready(run()))
+               for n, run in runners.items()}
+        if back:
+            got["swapped"] = np.swapaxes(got["swapped"], 1, 2)
+        # a tile past the last group holds what the kernel left there
+        used = int(lay["rows"])
+        if not back:
+            got = {n: v[:used] for n, v in got.items()}
+        row = {"product": name,
+               "bit_equal": got["stored"].tobytes()
+               == got["swapped"].tobytes(),
+               "max_abs_diff": float(np.max(np.abs(
+                   got["stored"].astype(np.float32)
+                   - got["swapped"].astype(np.float32))))}
+        ms, dropped = measure(runners, repeats=3, inner=4)
+        row["ms"] = {n: round(t, 4) for n, t in ms.items()}
+        if dropped:
+            rec["error"] = dropped
+        if jax.default_backend() != "cpu":
+            row["device_ms"] = {n: round(device_ms(run), 4)
+                                for n, run in runners.items()}
+        rec["products"].append(row)
+    return rec
 
 
 def moe_combine_case(n, top_k, held, num_experts, h):
@@ -736,6 +806,8 @@ def main():
                  lambda: lstm_lane_step(args.tiny)))
     todo.append(("attention_bwd_forms", "attention",
                  lambda: attention_bwd_forms(args.tiny)))
+    todo.append(("grouped_matmul_orientations", "grouped_matmul",
+                 lambda: grouped_matmul_orientations(args.tiny)))
     for name, family, run in todo:
         if args.only and family != args.only:
             continue
